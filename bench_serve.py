@@ -6,9 +6,10 @@ pool, and reports decode throughput (tokens/s), time-to-first-token, and
 slot occupancy as ONE JSON line per config, plus a summary line in the
 driver's ``{"metric": ...}`` shape.
 
-On TPU hardware it uses the 1b model config; on CPU fallback it runs the
-debug config and marks the artifact accordingly (the same loud-fallback
-contract as bench.py — a CPU number is never presented as the headline).
+The engine rows run the 1b config and need a TPU: where jax finds none the
+bench says so and exits non-zero (bench.py's contract — a CPU timing is
+never written under a device metric's name). ``--proxy``, ``--prefix`` and
+``--overload`` measure host-side orchestration and run anywhere.
 """
 
 from __future__ import annotations
@@ -888,9 +889,6 @@ def _merge_proxy_section(proxy: dict) -> None:
 
 
 def main():
-    # reuse bench.py's loud TPU-vs-CPU contract
-    from bench import _tpu_responsive
-
     if "--proxy" in sys.argv:
         # proxy/data-plane rows only: CPU orchestration cost, valid on any
         # box (the captioned contract above)
@@ -920,25 +918,19 @@ def main():
         print(json.dumps(section["results"], indent=1))
         return 0
 
-    tpu_ok, reason = _tpu_responsive()
-    import os
+    # the engine rows are device numbers: no chip, no run (bench.py's
+    # contract — a CPU timing is never written under a device's name)
+    from bench import require_tpu
 
-    if not tpu_ok:
-        os.environ["JAX_PLATFORMS"] = "cpu"
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
-        model, slots, n_req, plen, mtok = "debug", 8, 16, 32, 32
-    else:
-        model, slots, n_req, plen, mtok = "1b", 8, 24, 128, 128
+    dev = require_tpu()
+    model, slots, n_req, plen, mtok = "1b", 8, 24, 128, 128
 
     result = run_engine_bench(model, slots, n_req, plen, mtok)
     result["chunked_prefill_interference"] = run_chunked_prefill_bench(
         model, long_len=max(48, plen), chunk=max(8, plen // 4))
     result["speculation"] = run_speculation_bench(
         model, prompt_len=min(24, plen), max_tokens=mtok)
-    if not tpu_ok:
-        result["tpu_unavailable"] = reason
+    result["device"] = {"platform": dev.platform, "kind": dev.device_kind}
     print(json.dumps(result))
     headline = {
         "metric": f"llm_serve_{result['model']}_decode_tokens_per_s",
@@ -948,8 +940,6 @@ def main():
         "ttft_p50_ms": result["ttft_p50_ms"],
         "slot_occupancy_mean": result["slot_occupancy_mean"],
     }
-    if not tpu_ok:
-        headline["tpu_unavailable"] = reason
     print(json.dumps(headline))
     import os as _os
 
@@ -962,7 +952,7 @@ def main():
             result["proxy"] = prev["proxy"]
     with open("BENCH_serve.json", "w") as f:
         json.dump(result, f, indent=1)
-    return 0 if tpu_ok else 1
+    return 0
 
 
 if __name__ == "__main__":

@@ -142,9 +142,6 @@ def _make_percall_stage():
             import cloudpickle
             import jax
 
-            from ray_tpu.parallel.sharding import _ensure_partitionable_rng
-
-            _ensure_partitionable_rng()
             fns = cloudpickle.loads(fns_blob)
             init_fn, self._apply = fns["init"], fns["apply"]
             loss_fn = fns.get("loss")

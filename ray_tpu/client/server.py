@@ -80,9 +80,9 @@ class ClientServer:
             import json
 
             env["RT_JOB_RUNTIME_ENV"] = json.dumps(runtime_env)
-        from ray_tpu.common.tpu_detect import defer_tpu_preload
+        from ray_tpu.common.tpu_detect import leaseless_env
 
-        env = defer_tpu_preload(env)
+        env = leaseless_env(env)  # a session driver holds no TPU lease
         proc = await asyncio.to_thread(
             subprocess.Popen,
             [sys.executable, "-m", "ray_tpu.client.session_main"],

@@ -97,15 +97,8 @@ class XlaGroup:
         import jax
         from jax.sharding import PartitionSpec as P
 
-        shard_map = getattr(jax, "shard_map", None)
-        if shard_map is None:  # jax < 0.5
-            from jax.experimental.shard_map import shard_map
-        try:
-            return shard_map(body, mesh=self.mesh, in_specs=P(self.axis),
-                             out_specs=out_spec, check_rep=check_rep)
-        except TypeError:  # newer jax renamed/dropped check_rep
-            return shard_map(body, mesh=self.mesh, in_specs=P(self.axis),
-                             out_specs=out_spec)
+        return jax.shard_map(body, mesh=self.mesh, in_specs=P(self.axis),
+                             out_specs=out_spec, check_vma=check_rep)
 
     # ------------------------------------------------- quantized substrate
     def _quantization_block(self) -> int:

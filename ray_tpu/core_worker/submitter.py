@@ -1213,7 +1213,13 @@ class ActorTaskSubmitter:
                 self._mark_dead(ActorDiedError(self.actor_id, info.get("death_cause", "")))
                 return
             # actor still PENDING/RESTARTING: wake on the pubsub state
-            # event (sub-ms after ALIVE) with a poll-interval fallback
+            # event (sub-ms after ALIVE) with a poll-interval fallback.
+            # The GCS knows the actor and has not given up on it, so it is
+            # not dead, however long its chips or its constructor take (a
+            # chip comes back only once its last holder has exited; a
+            # full-width model takes a while to land): the deadline bounds
+            # silence from the GCS, not a start-up it still vouches for.
+            deadline = loop.time() + 60.0
             self._state_event.clear()
             try:
                 await asyncio.wait_for(self._state_event.wait(), 0.2)

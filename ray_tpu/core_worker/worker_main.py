@@ -112,6 +112,10 @@ class _TeeStream:
 
 
 def main():
+    # before anything can import jax: no TPU lease yet → JAX on the CPU
+    from ray_tpu.common.tpu_detect import pin_cpu_until_granted
+
+    pin_cpu_until_granted()
     logging.basicConfig(
         level=os.environ.get("RT_LOG_LEVEL", "INFO"),
         format=f"[worker {os.getpid()}] %(levelname)s %(name)s: %(message)s",

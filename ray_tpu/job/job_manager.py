@@ -119,11 +119,11 @@ class JobManager:
             await self._save_async(info)
             return
         self._env_agent.acquire(ctx.env_key)
-        from ray_tpu.common.tpu_detect import defer_tpu_preload
+        from ray_tpu.common.tpu_detect import leaseless_env
 
-        # job drivers must not boot the TPU runtime at interpreter start —
-        # they reconnect it lazily if they actually run jax on this host
-        env = ctx.apply(defer_tpu_preload(dict(os.environ)))
+        # a job driver holds no TPU lease: its jax stays on the CPU, the
+        # chips belong to the workers its tasks and actors are leased
+        env = ctx.apply(leaseless_env(dict(os.environ)))
         pkg_root = os.path.dirname(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))))
         if pkg_root not in env.get("PYTHONPATH", "").split(os.pathsep):

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.llama import LlamaConfig, Params
+from ray_tpu.ops.attention import on_tpu
 from ray_tpu.ops.norms import rmsnorm
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
 
@@ -55,11 +56,7 @@ def _attend_cached(q, k_cache, v_cache, lengths, scale):
     B, _, H, D = q.shape
     KV = k_cache.shape[2]
     group = H // KV
-    try:
-        on_tpu = jax.default_backend() == "tpu"
-    except Exception:  # noqa: BLE001
-        on_tpu = False
-    if on_tpu:
+    if on_tpu():
         from ray_tpu.ops.pallas.decode_attention import decode_attention
 
         return decode_attention(q, k_cache, v_cache, lengths, scale=scale)
@@ -72,6 +69,20 @@ def _attend_cached(q, k_cache, v_cache, lengths, scale):
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bkgs,bskd->bkgd", p, vf)            # (B,KV,group,D)
     return out.reshape(B, 1, H, D).astype(q.dtype)
+
+
+def _bind_params(jitted, params: Params):
+    """The builders' outer signatures take no weights: bind the engine's
+    one device-resident ``params`` as the program's first argument at
+    call time. A closed-over array would lower to a literal — a copy of
+    the weights in every compiled program. ``call.jitted`` is the
+    program itself (``params`` first), for lowering from shapes."""
+
+    def call(*args):
+        return jitted(params, *args)
+
+    call.jitted = jitted
+    return call
 
 
 def _decode_block(x, layer, k_cache, v_cache, lengths, cos, sin,
@@ -115,10 +126,11 @@ def make_decode_step(params: Params, config: LlamaConfig):
     Inactive slots pass through untouched (their length doesn't advance).
     """
     c = config
-    cos, sin = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta)
 
-    def step(cache: Cache, tokens: jax.Array, active: jax.Array):
+    def step(params: Params, cache: Cache, tokens: jax.Array,
+             active: jax.Array):
         lengths = cache["length"]
+        cos, sin = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta)
         x = params["embed"].astype(c.dtype)[tokens][:, None, :]  # (B,1,E)
 
         def body(x, scanned):
@@ -139,7 +151,7 @@ def make_decode_step(params: Params, config: LlamaConfig):
         new_len = jnp.where(active, lengths + 1, lengths)
         return ({"k": new_k, "v": new_v, "length": new_len}, logits)
 
-    return jax.jit(step, donate_argnums=(0,))
+    return _bind_params(jax.jit(step, donate_argnums=(1,)), params)
 
 
 def make_prefill(params: Params, config: LlamaConfig):
@@ -150,12 +162,12 @@ def make_prefill(params: Params, config: LlamaConfig):
     Jitted per padded length P (bucket prompt lengths to limit compiles).
     """
     c = config
-    cos, sin = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta)
 
-    @functools.partial(jax.jit, donate_argnums=(0,),
+    @functools.partial(jax.jit, donate_argnums=(1,),
                        static_argnames=("pad_len",))
-    def prefill(cache: Cache, tokens: jax.Array, true_len: jax.Array,
-                slot: jax.Array, pad_len: int):
+    def prefill(params: Params, cache: Cache, tokens: jax.Array,
+                true_len: jax.Array, slot: jax.Array, pad_len: int):
+        cos, sin = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta)
         x = params["embed"].astype(c.dtype)[tokens]          # (1, P, E)
         positions = jnp.arange(pad_len)[None, :]
         mask_valid = positions[0] < true_len                 # (P,)
@@ -202,9 +214,11 @@ def make_prefill(params: Params, config: LlamaConfig):
 
     def call(cache, tokens, true_len, slot):
         pad_len = tokens.shape[1]
-        return prefill(cache, tokens, jnp.asarray(true_len, jnp.int32),
+        return prefill(params, cache, tokens,
+                       jnp.asarray(true_len, jnp.int32),
                        jnp.asarray(slot, jnp.int32), pad_len=pad_len)
 
+    call.jitted = prefill
     return call
 
 
@@ -225,13 +239,14 @@ def make_chunked_prefill(params: Params, config: LlamaConfig):
     token (only meaningful on the final chunk).
     """
     c = config
-    cos, sin = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta)
 
-    @functools.partial(jax.jit, donate_argnums=(0,),
+    @functools.partial(jax.jit, donate_argnums=(1,),
                        static_argnames=("pad_len",))
-    def chunk(cache: Cache, tokens: jax.Array, true_len: jax.Array,
-              start_pos: jax.Array, slot: jax.Array, pad_len: int):
+    def chunk(params: Params, cache: Cache, tokens: jax.Array,
+              true_len: jax.Array, start_pos: jax.Array, slot: jax.Array,
+              pad_len: int):
         S = cache["k"].shape[2]
+        cos, sin = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta)
         x = params["embed"].astype(c.dtype)[tokens]          # (1, C, E)
         rel = jnp.arange(pad_len)                            # (C,)
         positions = (start_pos + rel)[None, :]               # (1, C)
@@ -293,10 +308,12 @@ def make_chunked_prefill(params: Params, config: LlamaConfig):
 
     def call(cache, tokens, true_len, start_pos, slot):
         pad_len = tokens.shape[1]
-        return chunk(cache, tokens, jnp.asarray(true_len, jnp.int32),
+        return chunk(params, cache, tokens,
+                     jnp.asarray(true_len, jnp.int32),
                      jnp.asarray(start_pos, jnp.int32),
                      jnp.asarray(slot, jnp.int32), pad_len=pad_len)
 
+    call.jitted = chunk
     return call
 
 
@@ -352,14 +369,14 @@ def _make_window_forward(params: Params, config: LlamaConfig,
     lm-head projection.  See :func:`make_batched_spec_verify` for the
     window semantics."""
     c = config
-    cos, sin = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta)
 
-    @functools.partial(jax.jit, donate_argnums=(0,),
+    @functools.partial(jax.jit, donate_argnums=(1,),
                        static_argnames=("pad_len",))
-    def verify(cache: Cache, tokens: jax.Array, true_lens: jax.Array,
-               start_pos: jax.Array, pad_len: int):
+    def verify(params: Params, cache: Cache, tokens: jax.Array,
+               true_lens: jax.Array, start_pos: jax.Array, pad_len: int):
         S = cache["k"].shape[2]
         B = tokens.shape[0]
+        cos, sin = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta)
         x = params["embed"].astype(c.dtype)[tokens]          # (B, C, E)
         rel = jnp.arange(pad_len)                            # (C,)
         positions = start_pos[:, None] + rel[None, :]        # (B, C)
@@ -439,10 +456,11 @@ def _make_window_forward(params: Params, config: LlamaConfig,
 
     def call(cache, tokens, true_lens, start_pos):
         pad_len = tokens.shape[1]
-        return verify(cache, tokens,
+        return verify(params, cache, tokens,
                       jnp.asarray(true_lens, jnp.int32),
                       jnp.asarray(start_pos, jnp.int32), pad_len=pad_len)
 
+    call.jitted = verify
     return call
 
 

@@ -20,6 +20,8 @@ deployments/llm/vllm/vllm_models.py:206-220``):
 from __future__ import annotations
 
 import dataclasses
+import functools
+import math
 from typing import Any, Dict, Optional
 
 import jax
@@ -174,10 +176,37 @@ def _attention(x, layer, cos, sin, config: LlamaConfig,
                              batch_axes=rules.batch,
                              block=c.attn_block)
     else:
-        out = flash_attention(q, kk, v, causal=True, block=c.attn_block)
+        out = _flash_on_mesh(q, kk, v, c, rules)
     out = with_logical_constraint(
         out, ("batch", "seq", "heads", "head_dim"), rules)
     return jnp.einsum("bshd,hde->bse", out, layer["wo"].astype(x.dtype))
+
+
+def _flash_on_mesh(q, k, v, config: LlamaConfig, rules: ShardingRules):
+    """Flash attention under whatever mesh is active. A Pallas kernel
+    cannot be partitioned by the compiler ("Mosaic kernels cannot be
+    automatically partitioned"), so on a multi-device mesh each device
+    runs the kernel on its own (batch, heads) shard under ``shard_map`` —
+    attention mixes neither batch rows nor heads, so no collective is
+    needed. Shapes the mesh does not divide stay with the compiler."""
+    attend = functools.partial(flash_attention, causal=True,
+                               block=config.attn_block)
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1:
+        return attend(q, k, v)
+    batch_axes, _, heads_axis, _ = (
+        a if a is None or isinstance(a, tuple) else (a,)
+        for a in rules.mesh_axes(("batch", "seq", "heads", "head_dim")))
+    batch_axes = tuple(a for a in batch_axes or () if a in mesh.shape)
+    heads_axis = tuple(a for a in heads_axis or () if a in mesh.shape)
+    n_batch = math.prod(mesh.shape[a] for a in batch_axes)
+    n_heads = math.prod(mesh.shape[a] for a in heads_axis)
+    if q.shape[0] % n_batch or k.shape[2] % n_heads:
+        return attend(q, k, v)
+    spec = jax.sharding.PartitionSpec(batch_axes or None, None,
+                                      heads_axis or None, None)
+    return jax.shard_map(attend, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
 
 
 def _mlp(x, layer):

@@ -37,7 +37,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_tpu.models.decoding import _bind_params
 from ray_tpu.models.llama import LlamaConfig, Params
+from ray_tpu.ops.attention import on_tpu
 from ray_tpu.ops.norms import rmsnorm
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
 
@@ -210,11 +212,7 @@ class BlockAllocator:
 
 def _attend_paged(q, k_pool, v_pool, tables, lengths, scale):
     """q (B,1,H,D); pools (NB,bs,KV,D); tables (B,MBS); lengths (B,)."""
-    try:
-        on_tpu = jax.default_backend() == "tpu"
-    except Exception:  # noqa: BLE001
-        on_tpu = False
-    if on_tpu:
+    if on_tpu():
         from ray_tpu.ops.pallas.paged_decode_attention import (
             paged_decode_attention)
 
@@ -249,12 +247,12 @@ def make_chunked_paged_prefill(params: Params, config: LlamaConfig,
     c = config
     bs = page.block_size
     MBS = page.max_blocks_per_seq
-    cos, sin = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta)
 
-    @functools.partial(jax.jit, donate_argnums=(0,),
+    @functools.partial(jax.jit, donate_argnums=(1,),
                        static_argnames=("pad_len",))
-    def chunk(cache: PagedCache, table_row, tokens, true_len, start_pos,
-              slot, pad_len: int):
+    def chunk(params: Params, cache: PagedCache, table_row, tokens,
+              true_len, start_pos, slot, pad_len: int):
+        cos, sin = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta)
         x = params["embed"].astype(c.dtype)[tokens]           # (1, C, E)
         rel = jnp.arange(pad_len)
         positions = (start_pos + rel)[None, :]
@@ -323,11 +321,12 @@ def make_chunked_paged_prefill(params: Params, config: LlamaConfig,
             raise ValueError(
                 f"chunk length {pad_len} must be a multiple of "
                 f"block_size {bs}")
-        return chunk(cache, jnp.asarray(table_row, jnp.int32),
+        return chunk(params, cache, jnp.asarray(table_row, jnp.int32),
                      tokens, jnp.asarray(true_len, jnp.int32),
                      jnp.asarray(start_pos, jnp.int32),
                      jnp.asarray(slot, jnp.int32), pad_len=pad_len)
 
+    call.jitted = chunk
     return call
 
 
@@ -339,11 +338,11 @@ def make_paged_decode_step(params: Params, config: LlamaConfig,
     steps); inactive slots write into the null block."""
     c = config
     bs = page.block_size
-    cos, sin = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta)
 
-    def step(cache: PagedCache, tables, tokens, active):
+    def step(params: Params, cache: PagedCache, tables, tokens, active):
         lengths = cache["length"]
         B = tokens.shape[0]
+        cos, sin = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta)
         x = params["embed"].astype(c.dtype)[tokens][:, None, :]   # (B,1,E)
         slot_rows = jnp.arange(B)
         # physical write target of the new token per slot
@@ -384,7 +383,7 @@ def make_paged_decode_step(params: Params, config: LlamaConfig,
         new_len = jnp.where(active, lengths + 1, lengths)
         return ({"k": new_k, "v": new_v, "length": new_len}, logits)
 
-    return jax.jit(step, donate_argnums=(0,))
+    return _bind_params(jax.jit(step, donate_argnums=(1,)), params)
 
 
 def make_paged_prefill(params: Params, config: LlamaConfig,
@@ -395,13 +394,13 @@ def make_paged_prefill(params: Params, config: LlamaConfig,
     table row names, padding rows in the null block."""
     c = config
     bs = page.block_size
-    cos, sin = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta)
 
-    @functools.partial(jax.jit, donate_argnums=(0,),
+    @functools.partial(jax.jit, donate_argnums=(1,),
                        static_argnames=("pad_len",))
-    def prefill(cache: PagedCache, table_row, tokens, true_len, slot,
-                pad_len: int):
+    def prefill(params: Params, cache: PagedCache, table_row, tokens,
+                true_len, slot, pad_len: int):
         nblk = pad_len // bs
+        cos, sin = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta)
         x = params["embed"].astype(c.dtype)[tokens]           # (1, P, E)
         positions = jnp.arange(pad_len)[None, :]
         mask_valid = positions[0] < true_len                  # (P,)
@@ -450,10 +449,11 @@ def make_paged_prefill(params: Params, config: LlamaConfig,
         if pad_len % bs:
             raise ValueError(f"padded prompt {pad_len} not a multiple of "
                              f"block_size {bs}")
-        return prefill(cache, jnp.asarray(table_row, jnp.int32), tokens,
-                       jnp.asarray(true_len, jnp.int32),
+        return prefill(params, cache, jnp.asarray(table_row, jnp.int32),
+                       tokens, jnp.asarray(true_len, jnp.int32),
                        jnp.asarray(slot, jnp.int32), pad_len=pad_len)
 
+    call.jitted = prefill
     return call
 
 

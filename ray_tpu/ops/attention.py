@@ -21,11 +21,10 @@ import jax.numpy as jnp
 NEG_INF = -1e30
 
 
-def _use_pallas() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+def on_tpu() -> bool:
+    """The one question the kernel dispatchers ask. A backend that fails
+    to start raises here — it never turns into the reference path."""
+    return jax.default_backend() == "tpu"
 
 
 def mha_reference(q, k, v, causal: bool = True, scale: Optional[float] = None):
@@ -79,7 +78,7 @@ def _flash_lse(q, k, v, causal, scale, block):
 
 
 def _flash_fwd_dispatch(q, k, v, causal, scale, block):
-    if _use_pallas():
+    if on_tpu():
         from ray_tpu.ops.pallas.flash_attention import flash_attention_fwd_pallas
 
         return flash_attention_fwd_pallas(
@@ -96,7 +95,7 @@ def _flash_lse_fwd(q, k, v, causal, scale, block):
 def _flash_lse_bwd(causal, scale, block, res, cotangents):
     """Backward dispatch: Pallas TPU kernels on TPU, blockwise XLA scan
     elsewhere. Both compute the standard recompute-form flash backward."""
-    if _use_pallas():
+    if on_tpu():
         from ray_tpu.ops.pallas.flash_attention import flash_attention_bwd_pallas
 
         dout, dlse = cotangents

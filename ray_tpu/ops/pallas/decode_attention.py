@@ -32,7 +32,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ray_tpu.ops.pallas._compat import CompilerParams as _CompilerParams
 
 NEG_INF = -1e30
 _LANES = 128
@@ -137,7 +136,7 @@ def decode_attention(q, k_cache, v_cache, lengths, *, scale: float,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(lengths.astype(jnp.int32), qh, k_cache, v_cache)

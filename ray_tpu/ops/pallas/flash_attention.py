@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ray_tpu.ops.pallas._compat import CompilerParams as _CompilerParams
 
 from ray_tpu.ops.attention import NEG_INF
 
@@ -144,7 +143,7 @@ def flash_attention_fwd_pallas(q, k, v, *, causal: bool, scale: float,
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q.reshape(b * hq, sq_p, d),
@@ -337,7 +336,7 @@ def flash_attention_bwd_pallas(q, k, v, lse, delta, dout, *,
         out_specs=pl.BlockSpec((1, block_q, d), q_ix),
         out_shape=jax.ShapeDtypeStruct((b * hq, sq_p, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf, doutf, lsef, deltaf)
@@ -383,7 +382,7 @@ def flash_attention_bwd_pallas(q, k, v, lse, delta, dout, *,
             pltpu.VMEM((block_kv, d), jnp.float32),
             pltpu.VMEM((block_kv, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf, doutf, lsef, deltaf)

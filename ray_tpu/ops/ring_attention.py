@@ -121,18 +121,6 @@ def ring_attention(q, k, v, mesh, causal: bool = True,
     """
     from jax.sharding import PartitionSpec as P
 
-    import inspect
-
-    try:
-        from jax import shard_map
-    except ImportError:  # jax < 0.5: experimental home
-        from jax.experimental.shard_map import shard_map
-    # the replication-check kwarg was renamed check_rep -> check_vma
-    # independently of the module move; pick by signature, not version
-    params = inspect.signature(shard_map).parameters
-    smap_kw = {"check_vma": False} if "check_vma" in params \
-        else {"check_rep": False}
-
     spec = P(batch_axes, sp_axis, heads_axis, None)
     local = (ring_attention_local if mode == "ring"
              else ulysses_attention_local)
@@ -141,5 +129,5 @@ def ring_attention(q, k, v, mesh, causal: bool = True,
         return local(q, k, v, axis_name=sp_axis, causal=causal, scale=scale,
                      block=block)
 
-    return shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                     out_specs=spec, **smap_kw)(q, k, v)
+    return jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)

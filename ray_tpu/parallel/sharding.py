@@ -77,50 +77,12 @@ TP_RULES = ShardingRules(embed_fsdp=None)
 FSDP_TP_RULES = ShardingRules()
 
 
-def _ensure_partitionable_rng() -> None:
-    """jax < 0.5 defaults ``jax_threefry_partitionable`` to False, under
-    which a jitted init whose output is sharded along an array's LEADING
-    dim generates different random bits than the unsharded computation
-    (measured on jax 0.4.37: ``truncated_normal`` under
-    ``out_shardings=P("fsdp", None)`` diverges; trailing-dim sharding does
-    not).  That breaks the sharded-from-birth contract — "same seed ⇒ same
-    params as single-device" — for any weight whose dim 0 is sharded
-    (e.g. llama's ``lm_head`` under ZeRO-3 rules).  jax >= 0.5 flips the
-    default to True; align older versions with the modern semantics."""
-    import jax
-
-    try:
-        major, minor = (int(x) for x in jax.__version__.split(".")[:2])
-        if (major, minor) >= (0, 5):
-            return
-        jax.config.update("jax_threefry_partitionable", True)
-    except Exception:  # noqa: BLE001 — unknown version string: leave as-is
-        pass
-
-
-# At import, not per-call: the flag must flip BEFORE any RNG value that
-# will later be compared against a sharded computation is drawn — the
-# stream itself changes, so a mid-session flip would split one process
-# into two incompatible RNG regimes.
-_ensure_partitionable_rng()
-
-
 def set_mesh(mesh):
-    """Context manager activating ``mesh`` for jitted computations.
-
-    Compat shim: jax >= 0.5 exposes ``jax.set_mesh`` (populates the
-    abstract mesh that ``with_logical_constraint`` reads); older
-    releases only have the legacy ``with mesh:`` context, which the
-    constraint path also honors — callers use this instead of either
-    spelling so the same test/model code runs on both.
-    """
+    """Context manager activating ``mesh`` for jitted computations (the
+    abstract mesh that ``with_logical_constraint`` reads)."""
     import jax
 
-    setter = getattr(jax, "set_mesh", None) \
-        or getattr(jax.sharding, "set_mesh", None)
-    if setter is not None:
-        return setter(mesh)
-    return mesh  # legacy: Mesh is itself a context manager
+    return jax.set_mesh(mesh)
 
 
 def logical_spec(logical_axes: Sequence[Optional[str]],
@@ -145,12 +107,9 @@ def with_logical_constraint(x, logical_axes, rules: ShardingRules):
     """
     import jax
 
-    # jax >= 0.5 exposes the abstract mesh; on older releases only the
-    # legacy `with mesh:` context exists — fall through to it.
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    mesh = get_abstract() if get_abstract is not None else None
+    mesh = jax.sharding.get_abstract_mesh()
     legacy_mesh = None
-    if mesh is None or mesh.empty:
+    if mesh.empty:
         # A legacy `with mesh:` context doesn't populate the abstract mesh;
         # honor it rather than silently dropping the constraint.
         from jax._src import mesh as mesh_lib

@@ -358,10 +358,8 @@ class _SebulbaLearner:
                      result_ch: ShmChannel) -> Dict[str, Any]:
         import ray_tpu
 
-        from ray_tpu.parallel.sharding import _ensure_partitionable_rng
         from ray_tpu.rl.learner import PPOLearner, build_ppo_batch
 
-        _ensure_partitionable_rng()
         c = self._cfg
         learner = PPOLearner(
             c["weights"], lr=c["lr"], clip=c["clip"],

@@ -217,10 +217,13 @@ def llm_app(model: str = "tiny", *, name: str = "llm",
     deployment override ``request_router: prefix_aware`` so the handle
     routes shared-prefix traffic at the replica whose radix tree
     already holds it."""
-    from ray_tpu.models.speculation import SpeculationConfig
     from ray_tpu.serve.llm import LLMServer
 
     if speculation is not None:
+        # imported here, not above: ray_tpu.models pulls in jax, and a
+        # driver that only deploys must be able to stay off it
+        from ray_tpu.models.speculation import SpeculationConfig
+
         # validate eagerly, but hand the ORIGINAL spec to the engine:
         # programmatic draft_config/draft_params objects are legal here
         # (schema.validate_speculation would reject them — its canonical

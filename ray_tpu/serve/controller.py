@@ -540,13 +540,10 @@ class ServeController:
             ready = 0
             for i, r in enumerate(list(nxt["replicas"])):
                 key = r._actor_id.hex()
-                try:
-                    faults.fault_point("serve.controller.probe")
-                    ray_tpu.get([r.ping.remote()],
-                                timeout=self.PING_TIMEOUT_S)
-                    self._ping_failures.pop(key, None)
+                answered = self._probe(r)
+                if answered:
                     ready += 1
-                except Exception:  # noqa: BLE001 — still warming or dead
+                elif answered is False:     # a miss; None = still starting
                     fails = self._ping_failures.get(key, 0) + 1
                     self._ping_failures[key] = fails
                     if fails >= self.PING_FAILURE_THRESHOLD:
@@ -580,6 +577,34 @@ class ServeController:
             logger.info("rolled %s to version %d (%d replicas warm)",
                         name, app["version"], len(app["replicas"]))
 
+    def _probe(self, r) -> Optional[bool]:
+        """One health probe. True: answered. False: a missed probe (the
+        callers count these against PING_FAILURE_THRESHOLD). None: silent
+        because its constructor is still running — the GCS has its actor
+        PENDING_CREATION. A full-width model takes longer than three
+        pings to land on its chip, and that is not a health failure; the
+        raylet's create_actor timeout bounds it. A constructor that
+        raised fails the ping at once (ActorDiedError): a miss."""
+        import ray_tpu
+        from ray_tpu.common.status import RtTimeoutError
+
+        try:
+            faults.fault_point("serve.controller.probe")
+            ray_tpu.get([r.ping.remote()], timeout=self.PING_TIMEOUT_S)
+        except RtTimeoutError:
+            return None if self._constructing(r) else False
+        except Exception:  # noqa: BLE001 — dead, or the probe itself failed
+            return False
+        self._ping_failures.pop(r._actor_id.hex(), None)
+        return True
+
+    @staticmethod
+    def _constructing(r) -> bool:
+        from ray_tpu.core_worker.worker import CoreWorker
+
+        rec = CoreWorker.current_or_raise().gcs.get_actor(r._actor_id)
+        return rec is not None and rec.get("state") == "PENDING_CREATION"
+
     def _reconcile_once(self):
         import ray_tpu
 
@@ -597,13 +622,9 @@ class ServeController:
             alive = []
             for r in app["replicas"]:
                 key = r._actor_id.hex()
-                try:
-                    faults.fault_point("serve.controller.probe")
-                    ray_tpu.get([r.ping.remote()],
-                                timeout=self.PING_TIMEOUT_S)
-                    self._ping_failures.pop(key, None)
+                if self._probe(r) is not False:   # answered, or starting
                     alive.append(r)
-                except Exception:  # noqa: BLE001 — slow or dead
+                else:
                     fails = self._ping_failures.get(key, 0) + 1
                     self._ping_failures[key] = fails
                     if fails < self.PING_FAILURE_THRESHOLD:

@@ -106,7 +106,18 @@ class LLMEngine:
             init_cache, make_batched_spec_verify, make_chunked_prefill,
             make_decode_step, make_inject, make_prefill)
 
+        from ray_tpu.common.compile_cache import compile_cache_counts
+
         self.config = config or llama.CONFIGS[model]
+        # the device this replica's process holds, as jax reports it —
+        # stats() carries it so a driver that stays off jax can tell
+        from ray_tpu.common import tpu_detect
+
+        self._dev = dev = jax.devices()[0]
+        self._device = {"platform": dev.platform, "kind": dev.device_kind,
+                        "count": len(jax.devices()),
+                        "granted_chips": tpu_detect.granted_chips}
+        self._compile_cache = compile_cache_counts()
         if params is None:
             params = llama.init_params(self.config, jax.random.key(seed))
         self.params = params
@@ -451,6 +462,10 @@ class LLMEngine:
                       cached_bytes=self._prefix_cache_hostbytes)
         out["prefix_cache"] = pc
         out["fair_share_skips"] = self._fair_share_skips
+        mem = self._dev.memory_stats() or {}
+        out["device"] = dict(self._device,
+                             peak_bytes_in_use=mem.get("peak_bytes_in_use"))
+        out["compile_cache"] = dict(self._compile_cache)
         return out
 
     def prefix_digest(self) -> List[int]:

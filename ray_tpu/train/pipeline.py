@@ -161,16 +161,11 @@ class _PipelineStageActor:
         import jax
         from jax.flatten_util import ravel_pytree
 
-        from ray_tpu.parallel.sharding import _ensure_partitionable_rng
         from ray_tpu.train.collectives import (
             FlatOptimizer,
             ZeroShardedOptimizer,
         )
 
-        # same-seed ⇒ same-params as a single-process reference requires
-        # the same PRNG regime (jax < 0.5 defaults it off; driver
-        # processes that imported ray_tpu.parallel already flipped it)
-        _ensure_partitionable_rng()
         params = _host(self._init_fn(
             jax.random.PRNGKey(self._seed + self._index)))
         fwd, bwd, fused = self._build_fns()

@@ -57,12 +57,11 @@ def _cluster_shape() -> Dict[str, Any]:
 def build_report() -> Dict[str, Any]:
     from ray_tpu._version import __version__
 
-    try:
-        import jax
+    # the installed version, without importing jax into a driver that
+    # must stay off it (the chips belong to the workers)
+    from importlib import metadata
 
-        jax_ver = jax.__version__
-    except Exception:  # noqa: BLE001
-        jax_ver = None
+    jax_ver = metadata.version("jax")
     with _lock:
         libs = sorted(_library_usages)
         feats = sorted(_feature_usages)

@@ -8,7 +8,7 @@
 #   FLAKY              some runs failed, some passed (timing/ordering)
 #   DETERMINISTIC-FAIL N/N runs failed (a real bug, not a flake)
 #
-# This is the adjudication VERDICT.md did by hand: a file that fails in
+# The adjudication that used to be done by hand: a file that fails in
 # the full suite but is GREEN here is suffering cross-test interference;
 # FLAKY files need wait-predicate/timeout fixes; DETERMINISTIC-FAIL
 # files have a reproducible defect.

@@ -40,10 +40,22 @@ def _cmdline(pid):
         return ""
 
 
+def _ours(pid):
+    """Started by THIS pytest process (tests/conftest.py marks everything
+    it starts): the tier-1 run has several pytest workers side by side,
+    and the chaos loop must not kill the other workers' clusters."""
+    try:
+        with open(f"/proc/{pid}/environ", "rb") as f:
+            return (f"RT_TEST_OWNER={os.environ['RT_TEST_OWNER']}".encode()
+                    in f.read().split(b"\0"))
+    except OSError:
+        return False
+
+
 def _worker_pids():
-    """Pids of live worker processes: exec'd workers by cmdline, plus
-    factory-forked workers (fork keeps the factory's cmdline, so they are
-    identified as CHILDREN of a factory process).
+    """Pids of THIS run's live worker processes: exec'd workers by cmdline,
+    plus factory-forked workers (fork keeps the factory's cmdline, so they
+    are identified as CHILDREN of a factory process).
 
     pgrep's snapshot races process exit: a listed pid may already be
     gone — or worse, REUSED by an unrelated process — by the time we
@@ -63,7 +75,8 @@ def _worker_pids():
     for cand in pgrep("ray_tpu.core_worker.worker_main"):
         st = _proc_status(cand)
         if (cand not in protected and st is not None and st[1] != "Z"
-                and "ray_tpu.core_worker.worker_main" in _cmdline(cand)):
+                and "ray_tpu.core_worker.worker_main" in _cmdline(cand)
+                and _ours(cand)):
             pids.append(cand)
     factories = set(pgrep("ray_tpu.raylet.worker_factory"))
     for cand in factories:
@@ -72,8 +85,8 @@ def _worker_pids():
             continue
         if "ray_tpu.raylet.worker_factory" not in _cmdline(cand):
             continue  # pid reused since the pgrep snapshot
-        if st[0] in factories:  # a forked worker, not the factory itself
-            pids.append(cand)
+        if st[0] in factories and _ours(cand):  # a forked worker, not
+            pids.append(cand)                   # the factory itself
     return pids
 
 

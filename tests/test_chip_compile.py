@@ -1,0 +1,213 @@
+"""Compile the main path's kernels and the ``1b`` serving programs for a
+described (not attached) v5e, at the published widths.
+
+Nothing runs: these tests ask the chip's compiler whether it accepts the
+programs, and read what it says about memory. The topology is described
+inside a fixture of THIS file and every compile happens in the test's
+own process (one process at a time may load the TPU library; see the
+on-chip-measurement guide, section 2).
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from ray_tpu.models import llama
+from ray_tpu.models.paged_cache import (PagedConfig, init_paged_cache,
+                                        make_paged_decode_step,
+                                        make_paged_prefill)
+
+CFG = llama.CONFIGS["1b"]
+HBM_BYTES = 16 * 1024 ** 3            # one v5e chip
+# a literal as large as the smallest per-layer weight of `tiny`
+# (wk: 512 x 4 x 64 bf16 = 256 KiB) would show as >= 512 Ki hex digits
+WEIGHT_LITERAL_HEX = 256 * 1024 * 2
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure to describe = skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    # compiles for a described chip are written to the persistent cache
+    # but cannot be read back without one: keep these tests silent
+    from jax.experimental.compilation_cache import compilation_cache
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """The dispatchers ask ``jax.default_backend()`` at trace time; here
+    it says cpu. Steer them from the test, not through a program option."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _on(sharding, tree):
+    return jax.tree.map(lambda a: _sds(a.shape, a.dtype, sharding), tree)
+
+
+def _largest_literal_hex(text: str) -> int:
+    return max((len(m) for m in re.findall(r'dense<"0x([0-9A-Fa-f]*)"', text)),
+               default=0)
+
+
+def _total_bytes(mem) -> int:
+    return (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes + mem.generated_code_size_in_bytes
+            - mem.alias_size_in_bytes)
+
+
+# ------------------------------------------------------------------ kernels
+B, S, H, KV, D = 4, 2048, CFG.n_heads, CFG.n_kv_heads, CFG.head_dim
+
+
+@pytest.mark.parametrize("block", [512, 1024])
+def test_flash_fwd_compiles_at_1b_widths(one_chip, block):
+    from ray_tpu.ops.pallas.flash_attention import flash_attention_fwd_pallas
+
+    q = _sds((B, H, S, D), jnp.bfloat16, one_chip)
+    kv = _sds((B, KV, S, D), jnp.bfloat16, one_chip)
+    fn = jax.jit(lambda q, k, v: flash_attention_fwd_pallas(
+        q, k, v, causal=True, scale=D ** -0.5, block_q=block,
+        block_kv=block))
+    compiled = fn.lower(q, kv, kv).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("block", [512, 1024])
+def test_flash_bwd_compiles_at_1b_widths(one_chip, block):
+    from ray_tpu.ops.pallas.flash_attention import flash_attention_bwd_pallas
+
+    q = _sds((B, H, S, D), jnp.bfloat16, one_chip)
+    kv = _sds((B, KV, S, D), jnp.bfloat16, one_chip)
+    vec = _sds((B, H, S), jnp.float32, one_chip)
+    fn = jax.jit(lambda q, k, v, lse, delta, do: flash_attention_bwd_pallas(
+        q, k, v, lse, delta, do, causal=True, scale=D ** -0.5,
+        block_q=block, block_kv=block))
+    compiled = fn.lower(q, kv, kv, vec, vec, q).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_decode_attention_compiles_at_1b_widths(one_chip):
+    from ray_tpu.ops.pallas.decode_attention import decode_attention
+
+    slots, seq = 8, 8192
+    q = _sds((slots, 1, H, D), jnp.bfloat16, one_chip)
+    kv = _sds((slots, seq, KV, D), jnp.bfloat16, one_chip)
+    lens = _sds((slots,), jnp.int32, one_chip)
+    fn = jax.jit(lambda q, k, v, n: decode_attention(q, k, v, n,
+                                                     scale=D ** -0.5))
+    compiled = fn.lower(q, kv, kv, lens).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("block_size", [64, 16])
+def test_paged_decode_attention_compiles_at_1b_widths(one_chip, block_size):
+    from ray_tpu.ops.pallas.paged_decode_attention import (
+        paged_decode_attention)
+
+    slots, seq = 8, 8192
+    nb = 1 + slots * seq // block_size
+    q = _sds((slots, 1, H, D), jnp.bfloat16, one_chip)
+    pool = _sds((nb, block_size, KV, D), jnp.bfloat16, one_chip)
+    tables = _sds((slots, seq // block_size), jnp.int32, one_chip)
+    lens = _sds((slots,), jnp.int32, one_chip)
+    fn = jax.jit(lambda q, k, v, t, n: paged_decode_attention(
+        q, k, v, t, n, scale=D ** -0.5))
+    compiled = fn.lower(q, pool, pool, tables, lens).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# ------------------------------------------------- the 1b serving programs
+def _engine_shapes(one_chip, num_slots=8):
+    """What LLMEngine(model="1b") builds by default, as shapes."""
+    page = PagedConfig(num_blocks=1 + num_slots * CFG.max_seq // 64,
+                       block_size=64, max_seq=CFG.max_seq)
+    params = _on(one_chip, jax.eval_shape(
+        lambda: llama.init_params(CFG, jax.random.key(0))))
+    cache = _on(one_chip, jax.eval_shape(
+        lambda: init_paged_cache(CFG, page, num_slots)))
+    return page, params, cache
+
+
+def _weight_bytes(params) -> int:
+    return sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params))
+
+
+def _check_program(lowered, params, cache, want_kernel: bool):
+    assert _largest_literal_hex(lowered.as_text()) < WEIGHT_LITERAL_HEX
+    compiled = lowered.compile()
+    if want_kernel:
+        assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    weights = _weight_bytes(params)
+    kv = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    # the weights are arguments of the program, not part of it
+    assert mem.argument_size_in_bytes >= weights + kv
+    assert mem.generated_code_size_in_bytes < weights // 100
+    assert _total_bytes(mem) < HBM_BYTES
+
+
+def test_1b_paged_decode_step_compiles_with_weights_as_arguments(
+        one_chip, as_tpu):
+    num_slots = 8
+    page, params, cache = _engine_shapes(one_chip, num_slots)
+    step = make_paged_decode_step(params, CFG, page)
+    lowered = step.jitted.lower(
+        params, cache,
+        _sds((num_slots, page.max_blocks_per_seq), jnp.int32, one_chip),
+        _sds((num_slots,), jnp.int32, one_chip),
+        _sds((num_slots,), jnp.bool_, one_chip))
+    _check_program(lowered, params, cache, want_kernel=True)
+
+
+def test_1b_paged_prefill_bucket_compiles_with_weights_as_arguments(
+        one_chip, as_tpu):
+    page, params, cache = _engine_shapes(one_chip)
+    prefill = make_paged_prefill(params, CFG, page)
+    pad_len = 512
+    lowered = prefill.jitted.lower(
+        params, cache,
+        _sds((page.max_blocks_per_seq,), jnp.int32, one_chip),
+        _sds((1, pad_len), jnp.int32, one_chip),
+        _sds((), jnp.int32, one_chip), _sds((), jnp.int32, one_chip),
+        pad_len=pad_len)
+    # the prompt's own attention is plain XLA (mha_reference): no kernel
+    _check_program(lowered, params, cache, want_kernel=False)
+
+
+# ----------------------------------------------------------------- CPU only
+def test_tiny_decode_module_holds_no_weights():
+    """A closed-over array lowers to a literal. `tiny` has 89 MB of
+    weights; with them as arguments the decode module is a few 10 KB."""
+    cfg = llama.CONFIGS["tiny"]
+    params = jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.key(0)))
+    page = PagedConfig(num_blocks=33, block_size=64, max_seq=cfg.max_seq)
+    cache = jax.eval_shape(lambda: init_paged_cache(cfg, page, 4))
+    step = make_paged_decode_step(params, cfg, page)
+    text = step.jitted.lower(
+        params, cache,
+        jax.ShapeDtypeStruct((4, page.max_blocks_per_seq), jnp.int32),
+        jax.ShapeDtypeStruct((4,), jnp.int32),
+        jax.ShapeDtypeStruct((4,), jnp.bool_)).as_text()
+    assert len(text) < 1_000_000
+    assert _largest_literal_hex(text) < WEIGHT_LITERAL_HEX

@@ -183,6 +183,42 @@ def test_threshold_misses_eject_then_recover(proxy_addr):
         ctrl.shutdown()
 
 
+def test_slow_constructor_is_not_a_missed_probe(proxy_addr):
+    """A replica whose constructor outlasts PING_FAILURE_THRESHOLD probe
+    timeouts (a full-width model landing on its chip) is starting, not
+    unhealthy: the GCS has it PENDING_CREATION, no miss is counted and
+    the SAME replica serves once it is up."""
+
+    class SlowStart:
+        def __init__(self):
+            time.sleep(3.0)  # > PING_FAILURE_THRESHOLD * PING_TIMEOUT_S
+
+        def __call__(self, request):
+            return "ok"
+
+    ctrl = _manual_controller()
+    try:
+        dep = make_deployment(SlowStart, name="slowstart", num_replicas=1)
+        _deploy_direct(ctrl, dep)
+        ctrl._reconcile_once()  # starts it
+        _, (replica,), *_ = ctrl.get_replicas("slowstart")
+        rid = replica._actor_id.hex()
+        probes = []
+        probe = ctrl._probe
+        ctrl._probe = lambda r: probes.append(probe(r)) or probes[-1]
+        deadline = time.monotonic() + 30
+        while probes[-1:] != [True] and time.monotonic() < deadline:
+            ctrl._reconcile_once()  # one probe of PING_TIMEOUT_S each
+            _, replicas, *_ = ctrl.get_replicas("slowstart")
+            assert [r._actor_id.hex() for r in replicas] == [rid], \
+                "a constructing replica must not be ejected"
+            assert rid not in ctrl._ping_failures
+        assert probes[-1] is True and set(probes[:-1]) == {None}
+        assert len(probes) - 1 > ctrl.PING_FAILURE_THRESHOLD
+    finally:
+        ctrl.shutdown()
+
+
 # --------------------------------------------------------------------------
 # Whole-batch transport failure semantics (satellite: batch re-route)
 # --------------------------------------------------------------------------
