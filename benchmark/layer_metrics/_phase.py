@@ -1,0 +1,39 @@
+"""The engine loop's named phases, as differences of
+``stats()["phases"]`` across the window. A row is ``[count, wall_s,
+self_wall_s, timed_self_wall_s, timed_self_cpu_s]``: wall on
+``time.monotonic()``, self = without the phases entered inside, the last
+two over the turns in which the engine thread's CPU
+(``time.thread_time()``) was timed too, one in eight. A program without
+phases gives None.
+
+``admit_ms_per_step``: what admission and prefill add to a decode step
+(the whole of ``admit``, prefill included, over the decode steps).
+``gil_wait_pct``: over the phases that only compute on the host, the
+share of their wall time in which the engine thread was not running:
+waiting for the GIL (the stream generators poll under it) or
+descheduled."""
+
+from _lib import counters
+
+HOST_ONLY = ("grow", "admit", "decode_dispatch", "sample")
+WALL, TIMED_SELF_WALL, TIMED_SELF_CPU = 1, 3, 4
+
+
+def delta(a, b, name, column):
+    zero = [0, 0.0, 0.0, 0.0, 0.0]
+    return b.get(name, zero)[column] - a.get(name, zero)[column]
+
+
+def read(run, what):
+    c = counters(run)
+    if c is None or "phases" not in c[0] or "phases" not in c[1]:
+        return None
+    a, b = c[0]["phases"], c[1]["phases"]
+    if what == "admit_ms_per_step":
+        steps = c[1]["steps"] - c[0]["steps"]
+        return 1e3 * delta(a, b, "admit", WALL) / steps if steps else None
+    if what == "gil_wait_pct":
+        wall = sum(delta(a, b, n, TIMED_SELF_WALL) for n in HOST_ONLY)
+        cpu = sum(delta(a, b, n, TIMED_SELF_CPU) for n in HOST_ONLY)
+        return 100.0 * (wall - cpu) / wall if wall > 0 else None
+    raise ValueError(f"_phase: no reading called {what!r}")

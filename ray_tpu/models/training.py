@@ -133,7 +133,9 @@ def make_train_step(loss_fn: Callable[[Pytree, Dict[str, jax.Array]],
     """
     batch_spec = logical_spec(("batch", "seq"), rules)
 
-    def step(state: TrainState, batch: Dict[str, jax.Array]):
+    # named for the profiler: its trace event is ``jit_train_step``, not
+    # the serving decode step's ``jit_step``
+    def train_step(state: TrainState, batch: Dict[str, jax.Array]):
         batch = jax.tree.map(
             lambda x: jax.lax.with_sharding_constraint(x, batch_spec)
             if x.ndim == 2 else x, batch)
@@ -148,7 +150,7 @@ def make_train_step(loss_fn: Callable[[Pytree, Dict[str, jax.Array]],
         return TrainState(step=state.step + 1, params=new_params,
                           opt_state=new_opt), metrics
 
-    return jax.jit(step, donate_argnums=(0,) if donate else ())
+    return jax.jit(train_step, donate_argnums=(0,) if donate else ())
 
 
 def init_train_state(init_params_fn: Callable[[jax.Array], Pytree],

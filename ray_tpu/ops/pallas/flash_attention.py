@@ -122,33 +122,36 @@ def flash_attention_fwd_pallas(q, k, v, *, causal: bool, scale: float,
         _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
         block_kv=block_kv, kv_len=skv, num_kv_blocks=nk)
 
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=(b * hq, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), q_index),
-            pl.BlockSpec((1, block_kv, d), kv_index),
-            pl.BlockSpec((1, block_kv, d), kv_index),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), q_index),
-            pl.BlockSpec((1, block_q, _LANES), lambda bh, iq, ik: (bh, iq, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b * hq, sq_p, d), q.dtype),
-            jax.ShapeDtypeStruct((b * hq, sq_p, _LANES), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(q.reshape(b * hq, sq_p, d),
-      k.reshape(b * hkv, skv_p, d),
-      v.reshape(b * hkv, skv_p, d))
+    with jax.named_scope("flash_attention_fwd"):
+        out, lse = pl.pallas_call(
+            kernel,
+            grid=(b * hq, nq, nk),
+            in_specs=[
+                pl.BlockSpec((1, block_q, d), q_index),
+                pl.BlockSpec((1, block_kv, d), kv_index),
+                pl.BlockSpec((1, block_kv, d), kv_index),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_q, d), q_index),
+                pl.BlockSpec((1, block_q, _LANES),
+                             lambda bh, iq, ik: (bh, iq, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((b * hq, sq_p, d), q.dtype),
+                jax.ShapeDtypeStruct((b * hq, sq_p, _LANES), jnp.float32),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, d), jnp.float32),
+                pltpu.VMEM((block_q, _LANES), jnp.float32),
+                pltpu.VMEM((block_q, _LANES), jnp.float32),
+            ],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=interpret,
+            name="flash_attention_fwd",
+        )(q.reshape(b * hq, sq_p, d),
+          k.reshape(b * hkv, skv_p, d),
+          v.reshape(b * hkv, skv_p, d))
 
     out = out.reshape(b, hq, sq_p, d)[:, :, :sq]
     lse = lse[:, :, 0].reshape(b, hq, sq_p)[:, :, :sq]
@@ -322,24 +325,26 @@ def flash_attention_bwd_pallas(q, k, v, lse, delta, dout, *,
         _bwd_dq_kernel, scale=scale, causal=causal, block_q=block_q,
         block_kv=block_kv, q_len=sq, kv_len=skv, num_kv_blocks=nk)
 
-    dq = pl.pallas_call(
-        dq_kernel,
-        grid=(b * hq, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), q_ix),
-            pl.BlockSpec((1, block_kv, d), kv_ix),
-            pl.BlockSpec((1, block_kv, d), kv_ix),
-            pl.BlockSpec((1, block_q, d), q_ix),
-            pl.BlockSpec((1, 1, block_q), vec_ix),
-            pl.BlockSpec((1, 1, block_q), vec_ix),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), q_ix),
-        out_shape=jax.ShapeDtypeStruct((b * hq, sq_p, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(qf, kf, vf, doutf, lsef, deltaf)
+    with jax.named_scope("flash_attention_dq"):
+        dq = pl.pallas_call(
+            dq_kernel,
+            grid=(b * hq, nq, nk),
+            in_specs=[
+                pl.BlockSpec((1, block_q, d), q_ix),
+                pl.BlockSpec((1, block_kv, d), kv_ix),
+                pl.BlockSpec((1, block_kv, d), kv_ix),
+                pl.BlockSpec((1, block_q, d), q_ix),
+                pl.BlockSpec((1, 1, block_q), vec_ix),
+                pl.BlockSpec((1, 1, block_q), vec_ix),
+            ],
+            out_specs=pl.BlockSpec((1, block_q, d), q_ix),
+            out_shape=jax.ShapeDtypeStruct((b * hq, sq_p, d), q.dtype),
+            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=interpret,
+            name="flash_attention_dq",
+        )(qf, kf, vf, doutf, lsef, deltaf)
 
     # dkv: grid minor axis sweeps (group, q block) pairs while one kv tile
     # and its dk/dv accumulators stay resident in VMEM.
@@ -359,33 +364,35 @@ def flash_attention_bwd_pallas(q, k, v, lse, delta, dout, *,
         block_kv=block_kv, q_len=sq, kv_len=skv, num_q_blocks=nq,
         num_inner=num_inner)
 
-    dk, dv = pl.pallas_call(
-        dkv_kernel,
-        grid=(b * hkv, nk, num_inner),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), q_ix2),
-            pl.BlockSpec((1, block_kv, d), kv_ix2),
-            pl.BlockSpec((1, block_kv, d), kv_ix2),
-            pl.BlockSpec((1, block_q, d), q_ix2),
-            pl.BlockSpec((1, 1, block_q), vec_ix2),
-            pl.BlockSpec((1, 1, block_q), vec_ix2),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_kv, d), kv_ix2),
-            pl.BlockSpec((1, block_kv, d), kv_ix2),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b * hkv, skv_p, d), k.dtype),
-            jax.ShapeDtypeStruct((b * hkv, skv_p, d), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_kv, d), jnp.float32),
-            pltpu.VMEM((block_kv, d), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(qf, kf, vf, doutf, lsef, deltaf)
+    with jax.named_scope("flash_attention_dkv"):
+        dk, dv = pl.pallas_call(
+            dkv_kernel,
+            grid=(b * hkv, nk, num_inner),
+            in_specs=[
+                pl.BlockSpec((1, block_q, d), q_ix2),
+                pl.BlockSpec((1, block_kv, d), kv_ix2),
+                pl.BlockSpec((1, block_kv, d), kv_ix2),
+                pl.BlockSpec((1, block_q, d), q_ix2),
+                pl.BlockSpec((1, 1, block_q), vec_ix2),
+                pl.BlockSpec((1, 1, block_q), vec_ix2),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_kv, d), kv_ix2),
+                pl.BlockSpec((1, block_kv, d), kv_ix2),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((b * hkv, skv_p, d), k.dtype),
+                jax.ShapeDtypeStruct((b * hkv, skv_p, d), v.dtype),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_kv, d), jnp.float32),
+                pltpu.VMEM((block_kv, d), jnp.float32),
+            ],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=interpret,
+            name="flash_attention_dkv",
+        )(qf, kf, vf, doutf, lsef, deltaf)
 
     dq = dq.reshape(b, hq, sq_p, d)[:, :, :sq]
     dk = dk.reshape(b, hkv, skv_p, d)[:, :, :skv]

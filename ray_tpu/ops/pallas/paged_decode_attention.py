@@ -130,15 +130,17 @@ def paged_decode_attention(q, k_pool, v_pool, tables, lengths, *,
         ],
     )
 
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(tables.astype(jnp.int32), lengths.astype(jnp.int32),
-      qh, k_pool, v_pool)
+    with jax.named_scope("paged_decode_attention"):
+        out = pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary")),
+            interpret=interpret,
+            name="paged_decode_attention",
+        )(tables.astype(jnp.int32), lengths.astype(jnp.int32),
+          qh, k_pool, v_pool)
 
     return out.reshape(B, 1, H, D)
 

@@ -19,6 +19,8 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
+from ray_tpu.util import profiling, tracing
+
 
 @dataclasses.dataclass
 class _Request:
@@ -44,6 +46,19 @@ class _Request:
     # set by LLMEngine.cancel (replica-side abort): the engine thread
     # notices at its next finish check and frees the slot + blocks
     cancelled: bool = False
+    # lifecycle, on enqueued_at's clock (time.monotonic): first admission
+    # into a slot, first token appended by the engine, first chunk handed
+    # to a caller by poll() (None for generate()), and the end
+    admitted_at: Optional[float] = None
+    first_token_at: Optional[float] = None
+    first_picked_at: Optional[float] = None
+    finished_at: Optional[float] = None
+    preemptions: int = 0
+    # the finished request's row in LLMEngine's ring: a poll() that comes
+    # after the finish still fills in first_picked_at
+    record: Optional[list] = None
+    # the submitting task's span context, when its caller traces
+    trace_ctx: Optional[Dict[str, str]] = None
 
 
 def _parse_req_spec(speculation) -> Optional[dict]:
@@ -300,12 +315,18 @@ class LLMEngine:
         self._admit_seq = np.zeros(num_slots, np.int64)  # preempt-victim age
         self._admit_counter = 0
         self._stop = threading.Event()
-        self._thread = threading.Thread(target=self._loop, daemon=True,
-                                        name="llm-engine")
-        self._thread.start()
         self._steps = 0
         self._tokens_generated = 0
         self._preemptions = 0
+        # the loop's named phases (spans in a profiler capture, counters
+        # in stats()) and the last finished requests' lifecycle records
+        self._phases = profiling.Phases("rt.engine.")
+        self._finished = 0
+        self._recent: "collections.deque[list]" = collections.deque(
+            maxlen=512)
+        self._thread = threading.Thread(target=self._loop, daemon=True,
+                                        name="llm-engine")
+        self._thread.start()
 
     # ------------------------------------------------------------- public
     def _check_vocab(self, prompt: List[int]) -> None:
@@ -334,7 +355,8 @@ class LLMEngine:
                 f"exceeds max_seq {self.max_seq}")
         self._check_vocab(prompt)
         req = _Request(list(prompt), max_tokens, temperature, eos_token,
-                       spec=_parse_req_spec(speculation), tenant=tenant)
+                       spec=_parse_req_spec(speculation), tenant=tenant,
+                       trace_ctx=tracing.current_context())
         self._queue.put(req)
         if not req.done.wait(timeout_s):
             raise TimeoutError("generation timed out")
@@ -356,7 +378,8 @@ class LLMEngine:
             raise ValueError("prompt + max_tokens exceeds max_seq")
         self._check_vocab(prompt)
         req = _Request(list(prompt), max_tokens, temperature, eos_token,
-                       spec=_parse_req_spec(speculation), tenant=tenant)
+                       spec=_parse_req_spec(speculation), tenant=tenant,
+                       trace_ctx=tracing.current_context())
         rid = uuid.uuid4().hex
         with self._pending_lock:
             self._pending[rid] = {"req": req, "sent": 0}
@@ -399,7 +422,8 @@ class LLMEngine:
                 f"prefilled KV shape {k.shape}/{v.shape} != expected {want}")
         req = _Request(list(prompt), max_tokens, temperature, eos_token,
                        preload={"k": k, "v": v,
-                                "logits": np.asarray(logits)})
+                                "logits": np.asarray(logits)},
+                       trace_ctx=tracing.current_context())
         rid = uuid.uuid4().hex
         with self._pending_lock:
             self._pending[rid] = {"req": req, "sent": 0}
@@ -418,6 +442,10 @@ class LLMEngine:
             out = list(req.output)   # snapshot (engine thread appends)
             chunks = out[ent["sent"]:]
             ent["sent"] = len(out)
+            if chunks and req.first_picked_at is None:
+                req.first_picked_at = ent["last_poll"]
+                if req.record is not None:
+                    req.record[3] = req.first_picked_at
             finished = req.done.is_set() and ent["sent"] >= len(req.output)
             if finished:
                 del self._pending[request_id]
@@ -466,6 +494,9 @@ class LLMEngine:
         out["device"] = dict(self._device,
                              peak_bytes_in_use=mem.get("peak_bytes_in_use"))
         out["compile_cache"] = dict(self._compile_cache)
+        out["phases"] = self._phases.snapshot()
+        out["requests"] = {"finished": self._finished,
+                           "recent": [list(r) for r in list(self._recent)]}
         return out
 
     def prefix_digest(self) -> List[int]:
@@ -516,14 +547,15 @@ class LLMEngine:
             widths = ((0, 0), (0, pad), (0, 0), (0, 0))
             k = np.pad(k, widths)
             v = np.pad(v, widths)
-        if self.kv_cache == "paged":
-            self._cache = self._inject(self._cache,
-                                       self._alloc.tables[slot],
-                                       jnp.asarray(k), jnp.asarray(v),
-                                       true_len, slot)
-        else:
-            self._cache = self._inject(self._cache, jnp.asarray(k),
-                                       jnp.asarray(v), true_len, slot)
+        with self._phases("kv_inject", slot=slot):
+            if self.kv_cache == "paged":
+                self._cache = self._inject(self._cache,
+                                           self._alloc.tables[slot],
+                                           jnp.asarray(k), jnp.asarray(v),
+                                           true_len, slot)
+            else:
+                self._cache = self._inject(self._cache, jnp.asarray(k),
+                                           jnp.asarray(v), true_len, slot)
 
     def _extract_kv(self, slot: int, true_len: int):
         """Device→host copy of one slot's prompt KV (rows [0, true_len))."""
@@ -629,7 +661,8 @@ class LLMEngine:
         if self._prefix_mode != "legacy" or resumed:
             return
         self._prefix_misses += 1
-        k, v = self._extract_kv(slot, plen)
+        with self._phases("kv_extract", slot=slot):
+            k, v = self._extract_kv(slot, plen)
         self._prefix_cache[key] = {"k": k, "v": v, "logits": logits_np}
         self._prefix_cache_hostbytes += k.nbytes + v.nbytes
         while self._prefix_cache and (
@@ -659,6 +692,7 @@ class LLMEngine:
             req = self._waiting[idx]
             if req.cancelled:
                 del self._waiting[idx]
+                self._record_finish(req, "cancelled")
                 req.done.set()
                 continue
             # preempted requests resume by recomputing prompt+generated
@@ -677,6 +711,7 @@ class LLMEngine:
                     del self._waiting[idx]
                     req.error = (f"prompt of {plen} tokens exceeds KV "
                                  "pool capacity")
+                    self._record_finish(req, "error")
                     req.done.set()
                     continue
                 if self._radix is not None and req.preload is None:
@@ -706,10 +741,13 @@ class LLMEngine:
                     # MID-BLOCK at the divergence offset while the
                     # cached original stays read-only for its other
                     # references.
-                    self._cache = self._block_copy(
-                        self._cache, match.cow[0],
-                        int(self._alloc.tables[slot, len(shared)]))
+                    with self._phases("block_copy", slot=slot):
+                        self._cache = self._block_copy(
+                            self._cache, match.cow[0],
+                            int(self._alloc.tables[slot, len(shared)]))
             del self._waiting[idx]
+            if req.admitted_at is None:
+                req.admitted_at = time.monotonic()
             resumed = bool(req.output)
             matched = match.matched if match is not None else 0
             key = tuple(full_prompt)
@@ -760,19 +798,23 @@ class LLMEngine:
                 P = self._prompt_pad(plen)
                 tokens = np.zeros((1, P), np.int32)
                 tokens[0, :plen] = full_prompt
-                if self.kv_cache == "paged":
-                    self._cache, logits = self._prefill(
-                        self._cache, self._alloc.tables[slot],
-                        jnp.asarray(tokens), plen, slot)
-                else:
-                    self._cache, logits = self._prefill(
-                        self._cache, jnp.asarray(tokens), plen, slot)
-                logits_np = np.asarray(logits)
+                with self._phases("prefill", pad_len=P, prompt_len=plen,
+                                  slot=slot):
+                    if self.kv_cache == "paged":
+                        self._cache, logits = self._prefill(
+                            self._cache, self._alloc.tables[slot],
+                            jnp.asarray(tokens), plen, slot)
+                    else:
+                        self._cache, logits = self._prefill(
+                            self._cache, jnp.asarray(tokens), plen, slot)
+                    logits_np = np.asarray(logits)
                 self._legacy_insert(key, logits_np, slot, plen, resumed)
                 if self._radix is not None:
                     self._radix_insert(req, full_prompt, slot)
             tok = self._sample(logits_np.reshape(1, -1), req.temperature)[0]
             req.output.append(int(tok))
+            if req.first_token_at is None:
+                req.first_token_at = time.monotonic()
             self._slots[slot] = req
             self._last_token[slot] = tok
             self._slot_len[slot] = plen
@@ -819,7 +861,8 @@ class LLMEngine:
                                self.max_seq - start - 1))
             infos[slot] = {"seq": req.prompt + req.output,
                            "target_len": start, "k": k_eff}
-        proposals = self._proposer.propose(infos) if infos else {}
+        with self._phases("spec_propose"):
+            proposals = self._proposer.propose(infos) if infos else {}
         if not any(proposals.get(slot) for slot in infos):
             return False
         buf = np.zeros((self.num_slots, C), np.int32)
@@ -831,15 +874,17 @@ class LLMEngine:
             buf[slot, 1:1 + len(props)] = props
             true_lens[slot] = 1 + len(props)
             starts[slot] = info["target_len"]
-        self._cache, all_logits = self._spec_verify(
-            self._cache, jnp.asarray(buf), true_lens, starts)
-        # greedy slots need only the (B, C) argmax ids — ship the full
-        # (B, C, vocab) logits off-device only when some slot samples
-        # (a real vocab makes the difference ~(k+1)x the decode path's
-        # per-step transfer)
-        greedy_np = np.asarray(self._spec_argmax(all_logits))
-        need_full = any(self._slots[s].temperature > 0.0 for s in infos)
-        logits_np = np.asarray(all_logits) if need_full else None
+        with self._phases("spec_verify", k=self.spec_k):
+            self._cache, all_logits = self._spec_verify(
+                self._cache, jnp.asarray(buf), true_lens, starts)
+            # greedy slots need only the (B, C) argmax ids — ship the full
+            # (B, C, vocab) logits off-device only when some slot samples
+            # (a real vocab makes the difference ~(k+1)x the decode path's
+            # per-step transfer)
+            greedy_np = np.asarray(self._spec_argmax(all_logits))
+            need_full = any(self._slots[s].temperature > 0.0
+                            for s in infos)
+            logits_np = np.asarray(all_logits) if need_full else None
         # post-increment BEFORE seeding, like _sample: seeding first
         # would reuse the stream the previous plain step sampled with,
         # correlating accept/reject draws with the token just emitted
@@ -876,11 +921,12 @@ class LLMEngine:
             touched[slot] = True
             new_lens[slot] = new_len
             self._tokens_generated += len(emitted)
-        if touched.any():
-            self._cache["length"] = self._spec_fix_len(
-                self._cache["length"], jnp.asarray(new_lens),
-                jnp.asarray(touched))
-        self._proposer.after_verify(accepted_map)
+        with self._phases("spec_install"):
+            if touched.any():
+                self._cache["length"] = self._spec_fix_len(
+                    self._cache["length"], jnp.asarray(new_lens),
+                    jnp.asarray(touched))
+            self._proposer.after_verify(accepted_map)
         for slot in sorted(accepted_map):
             self._maybe_finish(slot)
         return True
@@ -900,27 +946,30 @@ class LLMEngine:
         n = min(C, len(toks) - pos)
         buf = np.zeros((1, C), np.int32)
         buf[0, :n] = toks[pos:pos + n]
-        if self.kv_cache == "paged":
-            self._cache, logits = self._chunk_prefill(
-                self._cache, self._alloc.tables[slot], jnp.asarray(buf),
-                n, pos, slot)
-        else:
-            self._cache, logits = self._chunk_prefill(
-                self._cache, jnp.asarray(buf), n, pos, slot)
-        self._chunks_run += 1
-        st["pos"] = pos + n
-        if st["pos"] < len(toks):
-            return
+        with self._phases("prefill_chunk", pad_len=C, slot=slot):
+            if self.kv_cache == "paged":
+                self._cache, logits = self._chunk_prefill(
+                    self._cache, self._alloc.tables[slot], jnp.asarray(buf),
+                    n, pos, slot)
+            else:
+                self._cache, logits = self._chunk_prefill(
+                    self._cache, jnp.asarray(buf), n, pos, slot)
+            self._chunks_run += 1
+            st["pos"] = pos + n
+            if st["pos"] < len(toks):
+                return
+            logits_np = np.asarray(logits)
         req = st["req"]
         del self._prefilling[slot]
         plen = len(toks)
-        logits_np = np.asarray(logits)
         resumed = bool(req.output)
         self._legacy_insert(tuple(toks), logits_np, slot, plen, resumed)
         if self._radix is not None:
             self._radix_insert(req, toks, slot)
         tok = self._sample(logits_np.reshape(1, -1), req.temperature)[0]
         req.output.append(int(tok))
+        if req.first_token_at is None:
+            req.first_token_at = time.monotonic()
         self._last_token[slot] = tok
         self._slot_len[slot] = plen
         if self._proposer is not None:
@@ -955,12 +1004,40 @@ class LLMEngine:
                 # blocks release() is about to drop its slot ref on.
                 seq = (req.prompt + req.output)[:int(self._slot_len[slot])]
                 self._radix_insert(req, seq, slot)
+            self._record_finish(req, "cancelled" if req.cancelled else "ok")
             req.done.set()
             self._slots[slot] = None
             if self._proposer is not None:
                 self._proposer.release(slot)
             if self.kv_cache == "paged":
                 self._alloc.release(slot)
+
+    def _record_finish(self, req: _Request, status: str) -> None:
+        """One row for a request that finished, failed or was cancelled,
+        in the ring that stats() shows; and, where its caller traces,
+        its queue / prefill / decode spans for the operator's timeline
+        (``ray_tpu.timeline()``), from the same timestamps."""
+        now = req.finished_at = time.monotonic()
+        req.record = rec = [
+            req.enqueued_at, req.admitted_at, req.first_token_at, None, now,
+            len(req.prompt), len(req.output), req.preemptions, status]
+        # read after the row is published: a poll() that sets it between
+        # the two lines writes the row itself
+        rec[3] = req.first_picked_at
+        self._recent.append(rec)
+        self._finished += 1
+        if req.trace_ctx is None:
+            return
+        wall = time.time() - now        # monotonic -> the spans' clock
+        marks = [req.enqueued_at, req.admitted_at, req.first_token_at, now]
+        attrs = {"prompt_len": rec[5], "output_len": rec[6],
+                 "preemptions": rec[7], "status": status}
+        for name, t0, t1 in zip(("llm.queue", "llm.prefill", "llm.decode"),
+                                marks, marks[1:]):
+            if t0 is not None:
+                tracing.record(name, wall + t0,
+                               wall + (now if t1 is None else t1),
+                               req.trace_ctx, attrs)
 
     def _preempt(self, slot: int):
         """Recompute preemption: free the slot's blocks and put the
@@ -975,6 +1052,7 @@ class LLMEngine:
         self._prefilling.pop(slot, None)
         self._waiting.appendleft(req)
         self._preemptions += 1
+        req.preemptions += 1
 
     def _grow_active_slots(self) -> None:
         """Before a decode step each active slot needs its next token's
@@ -1005,7 +1083,10 @@ class LLMEngine:
 
         while not self._stop.is_set():
             try:
-                self._loop_once()
+                with self._phases.step(
+                        "turn", self._steps,
+                        active=sum(r is not None for r in self._slots)):
+                    self._loop_once()
             except Exception as e:  # noqa: BLE001 — engine must survive
                 logging.getLogger(__name__).error(
                     "engine step failed:\n%s", traceback.format_exc())
@@ -1014,6 +1095,7 @@ class LLMEngine:
                     req = self._slots[slot]
                     if req is not None:
                         req.error = f"engine step failed: {e!r}"
+                        self._record_finish(req, "error")
                         req.done.set()
                         self._slots[slot] = None
                         if self.kv_cache == "paged":
@@ -1037,7 +1119,13 @@ class LLMEngine:
                 del self._pending[rid]
 
     def _loop_once(self):
+        """One turn of the engine loop. Every device dispatch and every
+        blocking fetch of a turn sits in a named phase (PERF.md section
+        3 lists them): a span in a profiler capture, a row of counters
+        in ``stats()["phases"]``."""
         import jax.numpy as jnp
+
+        phase = self._phases
 
         self._steps_since_sweep = getattr(self, "_steps_since_sweep", 0) + 1
         if self._steps_since_sweep >= 500:
@@ -1047,8 +1135,11 @@ class LLMEngine:
         # head (paying its prefill), then immediately preempts it as the
         # youngest slot to feed an older slot's growth — prefill thrash
         if self.kv_cache == "paged":
-            self._grow_active_slots()
-        self._admit()
+            with phase("grow"):
+                self._grow_active_slots()
+        with phase("admit",
+                   waiting=self._queue.qsize() + len(self._waiting)):
+            self._admit()
         # one prefill chunk per iteration: bounded interference with the
         # decode of already-active slots (vLLM-class chunked prefill)
         if self._prefilling:
@@ -1058,7 +1149,8 @@ class LLMEngine:
             for s in range(self.num_slots)])
         if not active.any():
             if not self._prefilling:
-                time.sleep(0.002)
+                with phase("idle_wait"):
+                    time.sleep(0.002)
             return
         if self._proposer is not None:
             # speculation replaces the decode step wholesale: every
@@ -1069,28 +1161,33 @@ class LLMEngine:
             # to the plain (cheaper) decode program below instead.
             if self._spec_decode_step(active):
                 return
-        if self.kv_cache == "paged":
-            self._cache, logits = self._decode(
-                self._cache, self._alloc.device_tables(),
-                jnp.asarray(self._last_token), jnp.asarray(active))
-        else:
-            self._cache, logits = self._decode(
-                self._cache, jnp.asarray(self._last_token),
-                jnp.asarray(active))
-        logits_np = np.asarray(logits)
+        with phase("decode_dispatch"):
+            if self.kv_cache == "paged":
+                self._cache, logits = self._decode(
+                    self._cache, self._alloc.device_tables(),
+                    jnp.asarray(self._last_token), jnp.asarray(active))
+            else:
+                self._cache, logits = self._decode(
+                    self._cache, jnp.asarray(self._last_token),
+                    jnp.asarray(active))
+        with phase("logits_fetch"):
+            logits_np = np.asarray(logits)
         self._steps += 1
-        for slot in range(self.num_slots):
-            req = self._slots[slot]
-            if req is None or slot in self._prefilling:
-                # mid-chunked-prefill slots were masked inactive in the
-                # decode; their logits row is garbage — no sampling
-                continue
-            tok = self._sample(logits_np[slot][None], req.temperature)[0]
-            req.output.append(int(tok))
-            self._last_token[slot] = tok
-            self._slot_len[slot] += 1
-            self._tokens_generated += 1
-            self._maybe_finish(slot)
+        # ONE span around the slots' loop, never one per slot
+        with phase("sample", active=int(active.sum())):
+            for slot in range(self.num_slots):
+                req = self._slots[slot]
+                if req is None or slot in self._prefilling:
+                    # mid-chunked-prefill slots were masked inactive in
+                    # the decode; their logits row is garbage — no sampling
+                    continue
+                tok = self._sample(logits_np[slot][None],
+                                   req.temperature)[0]
+                req.output.append(int(tok))
+                self._last_token[slot] = tok
+                self._slot_len[slot] += 1
+                self._tokens_generated += 1
+                self._maybe_finish(slot)
 
 
 class LLMServer:

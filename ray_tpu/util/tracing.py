@@ -7,13 +7,11 @@ into the task's runtime metadata so worker-side spans parent correctly)
 and the C++ span plumbing in ``src/ray/telemetry/``.
 
 Design here: a dependency-free span recorder with the OTel data model
-(trace_id / span_id / parent_id, name, t0/t1, attributes, status). If
-``opentelemetry`` is importable we ALSO forward finished spans to the
-installed OTel tracer provider — but nothing requires it, matching the
-"stub or gate" rule for optional deps. Span context crosses process
-boundaries as a small dict (w3c-traceparent-shaped) carried in the task
-spec's tracing field; the executing worker re-hydrates it so its
-execution span parents the driver's submit span.
+(trace_id / span_id / parent_id, name, t0/t1, attributes, status). Span
+context crosses process boundaries as a small dict
+(w3c-traceparent-shaped) carried in the task spec's tracing field; the
+executing worker re-hydrates it so its execution span parents the
+driver's submit span.
 
 Spans land in the worker's task-event buffer alongside task events, so
 ``ray_tpu.timeline()`` renders them in the same chrome trace.
@@ -152,42 +150,26 @@ def span(name: str, *, parent_context: Optional[Dict[str, str]] = None,
         s.t1 = time.time()
         _current_span.reset(token)
         _recorder.record(s)
-        _forward_otel(s)
 
 
-def _forward_otel(s: Span) -> None:
-    """Best-effort bridge into an installed OpenTelemetry SDK. Our
-    trace/span ids are mapped into the OTel SpanContext so exported
-    spans keep their cross-process parent links instead of appearing as
-    disconnected roots."""
-    try:
-        from opentelemetry import trace as otel_trace  # type: ignore
-        from opentelemetry.trace import (  # type: ignore
-            NonRecordingSpan,
-            SpanContext,
-            TraceFlags,
-            set_span_in_context,
-        )
-    except Exception:  # noqa: BLE001 — otel not installed: local-only
-        return
-    try:
-        tracer = otel_trace.get_tracer("ray_tpu")
-        parent_ctx = None
-        if s.parent_id:
-            parent_sc = SpanContext(
-                trace_id=int(s.trace_id, 16), span_id=int(s.parent_id, 16),
-                is_remote=True, trace_flags=TraceFlags(TraceFlags.SAMPLED))
-            parent_ctx = set_span_in_context(NonRecordingSpan(parent_sc))
-        ospan = tracer.start_span(
-            s.name, context=parent_ctx, start_time=int(s.t0 * 1e9),
-            attributes={k: str(v) for k, v in s.attributes.items()})
-        if s.status != "OK":
-            from opentelemetry.trace import Status, StatusCode  # type: ignore
-
-            ospan.set_status(Status(StatusCode.ERROR, s.status))
-        ospan.end(end_time=int(s.t1 * 1e9))
-    except Exception:  # noqa: BLE001 — never fail the traced path
-        pass
+def record(name: str, t0: float, t1: float,
+           parent_context: Optional[Dict[str, str]] = None,
+           attributes: Optional[Dict[str, Any]] = None) -> Optional[Span]:
+    """Record a span that already happened (``t0``/``t1`` on
+    ``time.time()``'s clock) under ``parent_context``: for work timed by
+    its owner's own records, such as a request's life in an engine
+    loop. Same rule as :func:`span`: nothing when tracing is off and no
+    caller's context arrived."""
+    if not enabled() and parent_context is None:
+        return None
+    s = Span(name=name, span_id=_new_id(8), t0=t0, t1=t1,
+             trace_id=(parent_context["trace_id"] if parent_context
+                       else _new_id(16)),
+             parent_id=(parent_context["span_id"] if parent_context
+                        else None),
+             attributes=dict(attributes or {}))
+    _recorder.record(s)
+    return s
 
 
 def spans_to_chrome_events(spans: List[Span], pid: str = "trace") -> list:

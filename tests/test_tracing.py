@@ -84,3 +84,34 @@ class TestProfilingHooks:
         st = profiling.device_memory_stats()
         if st is not None:
             assert "bytes_in_use" in st and "platform" in st
+
+
+class TestRequestSpans:
+    def test_request_phases_parent_to_the_callers_span(self):
+        """A request's queue, prefill and decode show in the timeline
+        under the span that carried it, from the engine's own records."""
+        from ray_tpu.models import llama
+        from ray_tpu.serve.llm import LLMEngine
+
+        eng = LLMEngine(config=llama.CONFIGS["debug"], num_slots=2,
+                        max_seq=64)
+        try:
+            with tracing.span("task::stream") as root:
+                eng.generate([5, 17, 99], max_tokens=4)
+            tracing.enable(False)
+            eng.generate([1, 2], max_tokens=2)      # no caller, no spans
+        finally:
+            eng.shutdown()
+        spans = {s.name: s for s in tracing.recorder().snapshot()}
+        assert set(spans) == {"task::stream", "llm.queue", "llm.prefill",
+                              "llm.decode"}
+        parts = [spans[n] for n in ("llm.queue", "llm.prefill",
+                                    "llm.decode")]
+        for s in parts:
+            assert s.trace_id == root.trace_id
+            assert s.parent_id == root.span_id
+            assert root.t0 <= s.t0 <= s.t1 <= root.t1 + 0.05
+            assert s.attributes["output_len"] == 4
+        assert parts[0].t1 == parts[1].t0 and parts[1].t1 == parts[2].t0
+        events = tracing.spans_to_chrome_events(parts)
+        assert {e["tid"] for e in events} == {root.trace_id[:8]}
