@@ -156,13 +156,13 @@ def run_kernels():
     MBS = PLEN // PBS
     NBLK = PB * MBS
     qd = jax.random.normal(key, (PB, 1, H, D), dt)
-    kp = jax.random.normal(key, (NBLK, PBS, PKV, D), dt)
-    vp = jax.random.normal(key, (NBLK, PBS, PKV, D), dt)
+    kp = jax.random.normal(key, (1, NBLK, PBS, PKV, D), dt)  # one layer
+    vp = jax.random.normal(key, (1, NBLK, PBS, PKV, D), dt)
     tables = jnp.arange(NBLK, dtype=jnp.int32).reshape(PB, MBS)
     lengths = jnp.full((PB,), PLEN, jnp.int32)
     paged = jax.jit(lambda *a: paged_decode_attention(
         *a, scale=D ** -0.5))
-    t_dec = _time(paged, qd, kp, vp, tables, lengths)
+    t_dec = _time(paged, qd, kp, vp, jnp.int32(0), tables, lengths)
     # HBM traffic is the decode bottleneck: bytes of KV streamed per step
     kv_bytes = 2 * NBLK * PBS * PKV * D * jnp.dtype(dt).itemsize
     dec_gbps = kv_bytes / t_dec / 1e9

@@ -20,6 +20,17 @@ arrays shipped per call. Block 0 is reserved as the null block: table
 entries past a slot's valid prefix point at it, and writes for inactive
 slots land in it, so no predication is needed on device.
 
+The pool is ONE buffer for the life of the engine. Every program takes
+it as a donated argument and returns it updated in place: the decode
+step, the prefill and the chunked prefill carry the whole
+(layers, num_blocks, ...) pool through their layer scan
+(:func:`_scan_layers`) and write a layer's new rows with
+``pool.at[l, ...].set``; the decode kernel gets the whole pool and the
+layer index; inject, block copy and extract index it by block. No
+program moves more of the pool than the rows it writes and the blocks it
+attends over, and none holds a second copy
+(``tests/test_chip_compile.py`` asks the chip's compiler).
+
 Reference parity: the reference's serving engine gets this from vLLM
 (``python/ray/llm/_internal/serve/deployments/llm/vllm/vllm_engine.py``);
 here it is in-framework. The decode attention rides
@@ -210,19 +221,35 @@ class BlockAllocator:
         return self._device_tables
 
 
-def _attend_paged(q, k_pool, v_pool, tables, lengths, scale):
-    """q (B,1,H,D); pools (NB,bs,KV,D); tables (B,MBS); lengths (B,)."""
+def _attend_paged(q, k_pool, v_pool, layer, tables, lengths, scale):
+    """q (B,1,H,D); pools (L,NB,bs,KV,D), whole; layer () i32; tables
+    (B,MBS); lengths (B,). The pool is handed over as the layer scan
+    carries it: a per-layer slice here would be a copy of that layer."""
     if on_tpu():
         from ray_tpu.ops.pallas.paged_decode_attention import (
             paged_decode_attention)
 
-        return paged_decode_attention(q, k_pool, v_pool, tables, lengths,
-                                      scale=scale)
+        return paged_decode_attention(q, k_pool, v_pool, layer, tables,
+                                      lengths, scale=scale)
     from ray_tpu.ops.pallas.paged_decode_attention import (
         paged_attention_reference)
 
-    return paged_attention_reference(q, k_pool, v_pool, tables, lengths,
-                                     scale=scale)
+    return paged_attention_reference(q, k_pool, v_pool, layer, tables,
+                                     lengths, scale=scale)
+
+
+def _scan_layers(body, x, params: Params, cache: PagedCache):
+    """Run ``body((x, k_pool, v_pool), (layer_weights, l))`` over the
+    layers with the whole pool in the scan's CARRY. A scan cannot alias
+    an ``xs`` input to a ``ys`` output, so a pool threaded through those
+    is sliced out, copied and written back layer by layer into a second
+    pool; a carry that the body only updates with ``.at[l, ...].set`` is
+    updated in place, and the donated argument becomes the result."""
+    n_layers = cache["k"].shape[0]
+    (x, k_pool, v_pool), _ = jax.lax.scan(
+        body, (x, cache["k"], cache["v"]),
+        (params["layers"], jnp.arange(n_layers, dtype=jnp.int32)))
+    return x, k_pool, v_pool
 
 
 def make_chunked_paged_prefill(params: Params, config: LlamaConfig,
@@ -230,7 +257,10 @@ def make_chunked_paged_prefill(params: Params, config: LlamaConfig,
     """Chunked prefill over the paged pool (vLLM/Sarathi chunked
     prefill, paged flavor): one fixed-size chunk per call; chunk k/v
     scatter into the blocks the table row names, attention runs over the
-    slot's full prefix+chunk rows gathered via the table.
+    slot's full prefix+chunk rows gathered via the table. The pool is
+    carried whole through the layer scan and updated in place
+    (:func:`_scan_layers`): a call moves the chunk's rows in and one
+    slot's blocks out, per layer, and nothing else of the pool.
 
     chunk(cache, table_row (MBS,), tokens (1, C), true_len-in-chunk,
           start_pos, slot) → (cache, last_logits)
@@ -266,8 +296,9 @@ def make_chunked_paged_prefill(params: Params, config: LlamaConfig,
         row_blk = jnp.where(mask_valid, table_row[row_abs // bs], 0)
         row_off = row_abs % bs                                # (C,)
 
-        def body(x, scanned):
-            layer, kc, vc = scanned            # (NB, bs, KV, D)
+        def body(carry, scanned):
+            x, kc, vc = carry                  # pools (L, NB, bs, KV, D)
+            layer, l = scanned
             h = rmsnorm(x, layer["attn_norm"], c.norm_eps)
             q = jnp.einsum("bse,ehd->bshd", h, layer["wq"].astype(h.dtype))
             k = jnp.einsum("bse,ehd->bshd", h, layer["wk"].astype(h.dtype))
@@ -276,12 +307,14 @@ def make_chunked_paged_prefill(params: Params, config: LlamaConfig,
             k = apply_rope(k, cos, sin, positions)
             kb = jnp.where(mask_valid[:, None, None], k[0], 0.0)  # (C,KV,D)
             vb = jnp.where(mask_valid[:, None, None], v[0], 0.0)
-            kc = kc.at[row_blk, row_off].set(kb.astype(kc.dtype))
-            vc = vc.at[row_blk, row_off].set(vb.astype(vc.dtype))
+            kc = kc.at[l, row_blk, row_off].set(kb.astype(kc.dtype))
+            vc = vc.at[l, row_blk, row_off].set(vb.astype(vc.dtype))
             # gather the slot's full row set (prefix + this chunk) and
             # attend with absolute-position causal visibility
-            ks = kc[table_row].reshape(MBS * bs, c.n_kv_heads, c.head_dim)
-            vs = vc[table_row].reshape(MBS * bs, c.n_kv_heads, c.head_dim)
+            ks = kc[l, table_row].reshape(MBS * bs, c.n_kv_heads,
+                                          c.head_dim)
+            vs = vc[l, table_row].reshape(MBS * bs, c.n_kv_heads,
+                                          c.head_dim)
             KV = c.n_kv_heads
             H = q.shape[2]
             group = H // KV
@@ -304,10 +337,9 @@ def make_chunked_paged_prefill(params: Params, config: LlamaConfig,
             u = jnp.einsum("bse,em->bsm", h2, layer["w_up"].astype(h2.dtype))
             x = x + jnp.einsum("bsm,me->bse", jax.nn.silu(g) * u,
                                layer["w_down"].astype(h2.dtype))
-            return x, (kc, vc)
+            return (x, kc, vc), None
 
-        x, (new_k, new_v) = jax.lax.scan(
-            body, x, (params["layers"], cache["k"], cache["v"]))
+        x, new_k, new_v = _scan_layers(body, x, params, cache)
         x = rmsnorm(x, params["final_norm"], c.norm_eps)
         last = x[0, jnp.maximum(true_len - 1, 0)]
         head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
@@ -335,7 +367,12 @@ def make_paged_decode_step(params: Params, config: LlamaConfig,
     """step(cache, tables (B,MBS) i32, tokens (B,) i32, active (B,) bool)
     → (cache, logits (B, vocab) f32). Each active slot's table must
     already cover position ``length`` (the engine allocates between
-    steps); inactive slots write into the null block."""
+    steps); inactive slots write into the null block.
+
+    The pool is carried whole through the layer scan and updated in
+    place (:func:`_scan_layers`): per layer a step writes one row for
+    each slot and the kernel reads the blocks the tables name, out of
+    the same buffer the caller donated and gets back."""
     c = config
     bs = page.block_size
 
@@ -352,17 +389,18 @@ def make_paged_decode_step(params: Params, config: LlamaConfig,
         positions = lengths[:, None]
         att_len = lengths + 1
 
-        def body(x, scanned):
-            layer, kc, vc = scanned           # kc/vc (NB, bs, KV, D)
+        def body(carry, scanned):
+            x, kc, vc = carry                 # pools (L, NB, bs, KV, D)
+            layer, l = scanned
             h = rmsnorm(x, layer["attn_norm"], c.norm_eps)
             q = jnp.einsum("bse,ehd->bshd", h, layer["wq"].astype(h.dtype))
             k = jnp.einsum("bse,ehd->bshd", h, layer["wk"].astype(h.dtype))
             v = jnp.einsum("bse,ehd->bshd", h, layer["wv"].astype(h.dtype))
             q = apply_rope(q, cos, sin, positions)
             k = apply_rope(k, cos, sin, positions)
-            kc = kc.at[blk, off].set(k[:, 0].astype(kc.dtype))
-            vc = vc.at[blk, off].set(v[:, 0].astype(vc.dtype))
-            out = _attend_paged(q, kc, vc, tables, att_len,
+            kc = kc.at[l, blk, off].set(k[:, 0].astype(kc.dtype))
+            vc = vc.at[l, blk, off].set(v[:, 0].astype(vc.dtype))
+            out = _attend_paged(q, kc, vc, l, tables, att_len,
                                 c.head_dim ** -0.5)
             x = x + jnp.einsum("bshd,hde->bse", out,
                                layer["wo"].astype(x.dtype))
@@ -372,10 +410,9 @@ def make_paged_decode_step(params: Params, config: LlamaConfig,
             u = jnp.einsum("bse,em->bsm", h2, layer["w_up"].astype(h2.dtype))
             x = x + jnp.einsum("bsm,me->bse", jax.nn.silu(g) * u,
                                layer["w_down"].astype(h2.dtype))
-            return x, (kc, vc)
+            return (x, kc, vc), None
 
-        x, (new_k, new_v) = jax.lax.scan(
-            body, x, (params["layers"], cache["k"], cache["v"]))
+        x, new_k, new_v = _scan_layers(body, x, params, cache)
         x = rmsnorm(x, params["final_norm"], c.norm_eps)
         head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
         logits = jnp.einsum("be,ev->bv", x[:, 0].astype(jnp.float32),
@@ -391,7 +428,10 @@ def make_paged_prefill(params: Params, config: LlamaConfig,
     """prefill(cache, table_row (MBS,) i32, tokens (1,P) padded, true_len,
     slot) → (cache, last_logits (vocab,) f32). P must be a multiple of
     block_size (jitted per bucketed P); prompt KV lands in the blocks the
-    table row names, padding rows in the null block."""
+    table row names, padding rows in the null block. The pool is carried
+    whole through the layer scan and updated in place
+    (:func:`_scan_layers`): a prefill moves the prompt's blocks into the
+    pool and nothing else of it."""
     c = config
     bs = page.block_size
 
@@ -408,8 +448,9 @@ def make_paged_prefill(params: Params, config: LlamaConfig,
         dest = jnp.where(jnp.arange(nblk) * bs < true_len,
                          table_row[:nblk], 0)                  # (nblk,)
 
-        def body(x, scanned):
-            layer, kc, vc = scanned            # (NB, bs, KV, D)
+        def body(carry, scanned):
+            x, kc, vc = carry                  # pools (L, NB, bs, KV, D)
+            layer, l = scanned
             h = rmsnorm(x, layer["attn_norm"], c.norm_eps)
             q = jnp.einsum("bse,ehd->bshd", h, layer["wq"].astype(h.dtype))
             k = jnp.einsum("bse,ehd->bshd", h, layer["wk"].astype(h.dtype))
@@ -431,12 +472,11 @@ def make_paged_prefill(params: Params, config: LlamaConfig,
                            0.0).reshape(nblk, bs, c.n_kv_heads, c.head_dim)
             vb = jnp.where(mask_valid[:, None, None], v[0],
                            0.0).reshape(nblk, bs, c.n_kv_heads, c.head_dim)
-            kc = kc.at[dest].set(kb.astype(kc.dtype))
-            vc = vc.at[dest].set(vb.astype(vc.dtype))
-            return x, (kc, vc)
+            kc = kc.at[l, dest].set(kb.astype(kc.dtype))
+            vc = vc.at[l, dest].set(vb.astype(vc.dtype))
+            return (x, kc, vc), None
 
-        x, (new_k, new_v) = jax.lax.scan(
-            body, x, (params["layers"], cache["k"], cache["v"]))
+        x, new_k, new_v = _scan_layers(body, x, params, cache)
         x = rmsnorm(x, params["final_norm"], c.norm_eps)
         last = x[0, jnp.maximum(true_len - 1, 0)]
         head = (params["embed"].T if c.tie_embeddings else params["lm_head"])

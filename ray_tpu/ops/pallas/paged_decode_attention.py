@@ -4,12 +4,16 @@ blocks (a vLLM-style paged KV pool, TPU-native).
 
 Capability bar: vLLM's paged attention, which the reference delegates to
 (``python/ray/llm/_internal/serve/deployments/llm/vllm/vllm_engine.py``).
-The TPU shape of the idea: the pool is one static (num_blocks, bs, KV, D)
-array; each slot's logical cache is the sequence of pool blocks named by
-its block-table row. Block tables ride as SCALAR-PREFETCH operands, so
-the kernel's BlockSpec index maps translate (slot, logical block) →
-physical pool block at grid-issue time — the gather never materializes a
-contiguous per-slot cache in HBM.
+The TPU shape of the idea: the pool is one static
+(layers, num_blocks, bs, KV, D) array for the whole model; each slot's
+logical cache in one layer is the sequence of that layer's pool blocks
+named by its block-table row. The layer index, the block tables and the
+lengths ride as SCALAR-PREFETCH operands, so the kernel's BlockSpec index
+maps translate (layer, slot, logical block) → physical pool block at
+grid-issue time — neither a layer's slice of the pool nor a contiguous
+per-slot cache is ever materialized in HBM. The caller (the decode step's
+layer scan) hands over the pool it carries, whole, and the kernel reads
+only the blocks the tables name.
 
 GQA is an unrolled static loop over kv heads inside each program (same
 rationale as ``decode_attention.py``: the KV axis is too small/unaligned
@@ -18,8 +22,10 @@ exactly once).
 
 Layout contract:
     q        (B, 1, H, D)    new-token queries
-    k_pool   (NB, bs, KV, D) paged key pool (one layer)
-    v_pool   (NB, bs, KV, D)
+    k_pool   (L, NB, bs, KV, D)  paged key pool, every layer
+    v_pool   (L, NB, bs, KV, D)
+    layer    () int32        which layer of the pool to attend over
+                             (scalar prefetch; may be traced)
     tables   (B, MBS) int32  physical block id per logical block; entries
                              past the valid prefix MUST name a real block
                              (conventionally the reserved null block 0) —
@@ -43,10 +49,11 @@ NEG_INF = -1e30
 _LANES = 128
 
 
-def _paged_kernel(tables_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                  acc_ref, m_ref, l_ref,
+def _paged_kernel(layer_ref, tables_ref, len_ref, q_ref, k_ref, v_ref,
+                  o_ref, acc_ref, m_ref, l_ref,
                   *, scale: float, block_s: int, num_blocks: int,
                   num_kv: int, group: int):
+    del layer_ref                        # used by the index maps only
     b = pl.program_id(0)
     ib = pl.program_id(1)
 
@@ -93,12 +100,12 @@ def _paged_kernel(tables_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
 
 
-def paged_decode_attention(q, k_pool, v_pool, tables, lengths, *,
+def paged_decode_attention(q, k_pool, v_pool, layer, tables, lengths, *,
                            scale: float, interpret: bool = False):
-    """q (B,1,H,D); k/v_pool (NB,bs,KV,D); tables (B,MBS) int32;
-    lengths (B,) int32. Returns (B, 1, H, D) in q.dtype."""
+    """q (B,1,H,D); k/v_pool (L,NB,bs,KV,D); layer () int32; tables
+    (B,MBS) int32; lengths (B,) int32. Returns (B, 1, H, D) in q.dtype."""
     B, _, H, D = q.shape
-    bs, KV = k_pool.shape[1], k_pool.shape[2]
+    bs, KV = k_pool.shape[2], k_pool.shape[3]
     MBS = tables.shape[1]
     if H % KV:
         raise ValueError(f"q heads {H} not a multiple of kv heads {KV}")
@@ -110,17 +117,20 @@ def paged_decode_attention(q, k_pool, v_pool, tables, lengths, *,
         _paged_kernel, scale=scale, block_s=bs, num_blocks=MBS,
         num_kv=KV, group=group)
 
-    def kv_ix(b, ib, tables_ref, len_ref):
+    def kv_ix(b, ib, layer_ref, tables_ref, len_ref):
         del len_ref
-        return (tables_ref[b, ib], 0, 0, 0)
+        return (layer_ref[0], tables_ref[b, ib], 0, 0, 0)
 
+    # the layer axis is squeezed out of the block: the body sees the
+    # (1, bs, KV, D) block of one layer, as if the pool had no layer axis
+    kv_spec = pl.BlockSpec((None, 1, bs, KV, D), kv_ix)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, MBS),
         in_specs=[
             pl.BlockSpec((1, H, D), lambda b, ib, *_: (b, 0, 0)),
-            pl.BlockSpec((1, bs, KV, D), kv_ix),
-            pl.BlockSpec((1, bs, KV, D), kv_ix),
+            kv_spec,
+            kv_spec,
         ],
         out_specs=pl.BlockSpec((1, H, D), lambda b, ib, *_: (b, 0, 0)),
         scratch_shapes=[
@@ -139,24 +149,26 @@ def paged_decode_attention(q, k_pool, v_pool, tables, lengths, *,
                 dimension_semantics=("parallel", "arbitrary")),
             interpret=interpret,
             name="paged_decode_attention",
-        )(tables.astype(jnp.int32), lengths.astype(jnp.int32),
+        )(jnp.asarray(layer, jnp.int32).reshape(1),
+          tables.astype(jnp.int32), lengths.astype(jnp.int32),
           qh, k_pool, v_pool)
 
     return out.reshape(B, 1, H, D)
 
 
-def paged_attention_reference(q, k_pool, v_pool, tables, lengths, *,
+def paged_attention_reference(q, k_pool, v_pool, layer, tables, lengths, *,
                               scale: float):
-    """XLA path (and the kernel's correctness oracle): gather the per-slot
-    cache via the block table, then grouped-einsum attention. Used on CPU
-    and as the non-Pallas fallback in ``models.paged_cache``."""
+    """XLA path (and the kernel's correctness oracle), same arguments as
+    the kernel: gather the per-slot cache of one layer via the block
+    table, then grouped-einsum attention. Used on CPU and as the
+    non-Pallas fallback in ``models.paged_cache``."""
     B, _, H, D = q.shape
-    bs, KV = k_pool.shape[1], k_pool.shape[2]
+    bs, KV = k_pool.shape[2], k_pool.shape[3]
     MBS = tables.shape[1]
     group = H // KV
     S = MBS * bs
-    k = k_pool[tables].reshape(B, S, KV, D)      # (B, MBS, bs, KV, D) →
-    v = v_pool[tables].reshape(B, S, KV, D)
+    k = k_pool[layer, tables].reshape(B, S, KV, D)   # (B, MBS, bs, KV, D) →
+    v = v_pool[layer, tables].reshape(B, S, KV, D)
     qg = q.astype(jnp.float32).reshape(B, KV, group, D)
     s = jnp.einsum("bkgd,bskd->bkgs", qg, k.astype(jnp.float32)) * scale
     mask = jnp.arange(S)[None, :] < lengths[:, None]

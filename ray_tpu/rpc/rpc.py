@@ -37,6 +37,7 @@ _HEADER = struct.Struct("<IB")  # payload length, frame type
 _FRAME_REQ = 1
 _FRAME_RESP = 2
 _FRAME_HELLO = 3  # version handshake (rpc/protocol.py)
+_STOP_FLUSH_S = 2.0  # how long a stopping server lets its peers read replies
 
 # schema.validate, bound on first validated dispatch (schema imports parts
 # of common/ that import this module — a boot-time cycle, not a real dep)
@@ -333,7 +334,17 @@ class RpcServer:
                 w.close()
             except Exception:
                 pass
-        await self._server.wait_closed()
+        # wait_closed() returns once every connection is gone, and a closed
+        # connection with replies still buffered is gone only when its peer
+        # has read them. A peer that is alive but no longer reads (a worker
+        # mid-teardown) would hold the stop for ever: give the flush a
+        # moment, then drop what is left.
+        try:
+            await asyncio.wait_for(self._server.wait_closed(), _STOP_FLUSH_S)
+        except asyncio.TimeoutError:
+            for w in list(self._conns):
+                w.transport.abort()
+            await self._server.wait_closed()
         self._server = None
 
 

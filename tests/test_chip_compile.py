@@ -8,6 +8,7 @@ own process (one process at a time may load the TPU library; see the
 on-chip-measurement guide, section 2).
 """
 
+import dataclasses
 import re
 
 import jax
@@ -17,6 +18,7 @@ from jax.sharding import SingleDeviceSharding
 
 from ray_tpu.models import llama
 from ray_tpu.models.paged_cache import (PagedConfig, init_paged_cache,
+                                        make_chunked_paged_prefill,
                                         make_paged_decode_step,
                                         make_paged_prefill)
 
@@ -67,6 +69,25 @@ def _on(sharding, tree):
 def _largest_literal_hex(text: str) -> int:
     return max((len(m) for m in re.findall(r'dense<"0x([0-9A-Fa-f]*)"', text)),
                default=0)
+
+
+def _nbytes(a) -> int:
+    return a.size * a.dtype.itemsize
+
+
+def _pool_movers(text: str, pool) -> list:
+    """Instructions of a compiled module that copy or slice a whole
+    layer of the KV pool, or the pool: a ``copy``, ``dynamic-slice`` or
+    ``dynamic-update-slice`` (alone or as a fusion named after it) with
+    an operand or result of shape (NB, bs, KV, D) or (L, NB, bs, KV, D).
+    The in-place scatter of the new rows is none of these."""
+    per_layer = ",".join(str(d) for d in pool.shape[1:])
+    shape = re.compile(rf"bf16\[({pool.shape[0]},)?{per_layer}\]")
+    mover = re.compile(r"= \S+ (copy|dynamic-slice|dynamic-update-slice)\("
+                       r"|^\s*(ROOT )?%\S*(copy|dynamic-slice|"
+                       r"dynamic-update-slice)\S* = ")
+    return [line.strip()[:200] for line in text.splitlines()
+            if mover.search(line) and shape.search(line)]
 
 
 def _total_bytes(mem) -> int:
@@ -127,29 +148,31 @@ def test_paged_decode_attention_compiles_at_1b_widths(one_chip, block_size):
     slots, seq = 8, 8192
     nb = 1 + slots * seq // block_size
     q = _sds((slots, 1, H, D), jnp.bfloat16, one_chip)
-    pool = _sds((nb, block_size, KV, D), jnp.bfloat16, one_chip)
+    pool = _sds((CFG.n_layers, nb, block_size, KV, D), jnp.bfloat16,
+                one_chip)
+    layer = _sds((), jnp.int32, one_chip)
     tables = _sds((slots, seq // block_size), jnp.int32, one_chip)
     lens = _sds((slots,), jnp.int32, one_chip)
-    fn = jax.jit(lambda q, k, v, t, n: paged_decode_attention(
-        q, k, v, t, n, scale=D ** -0.5))
-    compiled = fn.lower(q, pool, pool, tables, lens).compile()
+    fn = jax.jit(lambda q, k, v, l, t, n: paged_decode_attention(
+        q, k, v, l, t, n, scale=D ** -0.5))
+    compiled = fn.lower(q, pool, pool, layer, tables, lens).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
 # ------------------------------------------------- the 1b serving programs
-def _engine_shapes(one_chip, num_slots=8):
+def _engine_shapes(one_chip, num_slots=8, cfg=CFG):
     """What LLMEngine(model="1b") builds by default, as shapes."""
-    page = PagedConfig(num_blocks=1 + num_slots * CFG.max_seq // 64,
-                       block_size=64, max_seq=CFG.max_seq)
+    page = PagedConfig(num_blocks=1 + num_slots * cfg.max_seq // 64,
+                       block_size=64, max_seq=cfg.max_seq)
     params = _on(one_chip, jax.eval_shape(
-        lambda: llama.init_params(CFG, jax.random.key(0))))
+        lambda: llama.init_params(cfg, jax.random.key(0))))
     cache = _on(one_chip, jax.eval_shape(
-        lambda: init_paged_cache(CFG, page, num_slots)))
+        lambda: init_paged_cache(cfg, page, num_slots)))
     return page, params, cache
 
 
 def _weight_bytes(params) -> int:
-    return sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params))
+    return sum(_nbytes(a) for a in jax.tree.leaves(params))
 
 
 def _check_program(lowered, params, cache, want_kernel: bool):
@@ -159,7 +182,7 @@ def _check_program(lowered, params, cache, want_kernel: bool):
         assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     weights = _weight_bytes(params)
-    kv = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    kv = sum(_nbytes(a) for a in jax.tree.leaves(cache))
     # the weights are arguments of the program, not part of it
     assert mem.argument_size_in_bytes >= weights + kv
     assert mem.generated_code_size_in_bytes < weights // 100
@@ -168,30 +191,81 @@ def _check_program(lowered, params, cache, want_kernel: bool):
 
 def test_1b_paged_decode_step_compiles_with_weights_as_arguments(
         one_chip, as_tpu):
-    num_slots = 8
-    page, params, cache = _engine_shapes(one_chip, num_slots)
-    step = make_paged_decode_step(params, CFG, page)
-    lowered = step.jitted.lower(
-        params, cache,
-        _sds((num_slots, page.max_blocks_per_seq), jnp.int32, one_chip),
-        _sds((num_slots,), jnp.int32, one_chip),
-        _sds((num_slots,), jnp.bool_, one_chip))
+    params, cache, lowered = _lower_decode(one_chip)
     _check_program(lowered, params, cache, want_kernel=True)
 
 
 def test_1b_paged_prefill_bucket_compiles_with_weights_as_arguments(
         one_chip, as_tpu):
-    page, params, cache = _engine_shapes(one_chip)
-    prefill = make_paged_prefill(params, CFG, page)
-    pad_len = 512
-    lowered = prefill.jitted.lower(
-        params, cache,
-        _sds((page.max_blocks_per_seq,), jnp.int32, one_chip),
-        _sds((1, pad_len), jnp.int32, one_chip),
-        _sds((), jnp.int32, one_chip), _sds((), jnp.int32, one_chip),
-        pad_len=pad_len)
+    params, cache, lowered = _lower_prefill(one_chip)
     # the prompt's own attention is plain XLA (mha_reference): no kernel
     _check_program(lowered, params, cache, want_kernel=False)
+
+
+def _lower_decode(one_chip, cfg=CFG, num_slots=8):
+    page, params, cache = _engine_shapes(one_chip, num_slots, cfg)
+    step = make_paged_decode_step(params, cfg, page)
+    return params, cache, step.jitted.lower(
+        params, cache,
+        _sds((num_slots, page.max_blocks_per_seq), jnp.int32, one_chip),
+        _sds((num_slots,), jnp.int32, one_chip),
+        _sds((num_slots,), jnp.bool_, one_chip))
+
+
+def _lower_prefill(one_chip, cfg=CFG, pad_len=512):
+    page, params, cache = _engine_shapes(one_chip, cfg=cfg)
+    prefill = make_paged_prefill(params, cfg, page)
+    scalar = _sds((), jnp.int32, one_chip)
+    return params, cache, prefill.jitted.lower(
+        params, cache,
+        _sds((page.max_blocks_per_seq,), jnp.int32, one_chip),
+        _sds((1, pad_len), jnp.int32, one_chip), scalar, scalar,
+        pad_len=pad_len)
+
+
+def _lower_chunk(one_chip, cfg=CFG, pad_len=256):
+    page, params, cache = _engine_shapes(one_chip, cfg=cfg)
+    chunk = make_chunked_paged_prefill(params, cfg, page)
+    scalar = _sds((), jnp.int32, one_chip)
+    return params, cache, chunk.jitted.lower(
+        params, cache,
+        _sds((page.max_blocks_per_seq,), jnp.int32, one_chip),
+        _sds((1, pad_len), jnp.int32, one_chip), scalar, scalar, scalar,
+        pad_len=pad_len)
+
+
+# The 1b engine shapes with the 128-wide heads of the benchmark's cells
+# (16 x 128 = 32 x 64: same hidden size, same weights' bytes). A pool
+# whose rows are 64 wide is another matter: the device keeps
+# bf16[L, NB, bs, KV, 64] with the BLOCK axis on the lanes (its default
+# layout for a minor dimension under 128), so every program that touches
+# rows or blocks, before this change and after it, first turns the whole
+# pool row-major and turns it back at the end. That is the layout's
+# cost, not the scan's (ROADMAP, Speed), and the strict xfail below
+# keeps it in sight until the pool's layout is pinned.
+CFG_D128 = dataclasses.replace(CFG, n_heads=16, head_dim=128)
+
+
+@pytest.mark.parametrize("lower, cfg", [
+    (_lower_decode, CFG_D128), (_lower_prefill, CFG_D128),
+    (_lower_chunk, CFG_D128),
+    pytest.param(_lower_decode, CFG, marks=pytest.mark.xfail(
+        strict=True, reason="head_dim 64: the pool's device layout is "
+        "not row-major, the program re-lays it out around the loop"))],
+    ids=["decode", "prefill512", "chunk256", "decode-head64"])
+def test_1b_serving_program_holds_no_second_pool(one_chip, as_tpu, lower,
+                                                 cfg):
+    """The pool is a donated argument that the layer scan carries and
+    updates in place: the program's temporaries hold no copy of it, nor
+    of one layer of it, and nothing in the program moves a whole layer."""
+    _, cache, lowered = lower(one_chip, cfg)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert not _pool_movers(text, cache["k"])
+    pool_bytes = _nbytes(cache["k"]) + _nbytes(cache["v"])
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < pool_bytes // 2
+    assert mem.alias_size_in_bytes >= pool_bytes     # donated, and reused
 
 
 # ----------------------------------------------------------------- CPU only

@@ -78,12 +78,117 @@ class TestKernelVsOracle:
         # slot 0 uses blocks [3, 5], slot 1 blocks [1, 2, 6]
         tables = jnp.array([[3, 5, 0], [1, 2, 6]], jnp.int32)
         lengths = jnp.array([20, 41], jnp.int32)
-        want = paged_attention_reference(q, kp, vp, tables, lengths,
-                                         scale=D ** -0.5)
-        got = paged_decode_attention(q, kp, vp, tables, lengths,
-                                     scale=D ** -0.5, interpret=True)
+        want = paged_attention_reference(q, kp[None], vp[None], 0, tables,
+                                         lengths, scale=D ** -0.5)
+        got = paged_decode_attention(q, kp[None], vp[None], 0, tables,
+                                     lengths, scale=D ** -0.5,
+                                     interpret=True)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-3, atol=2e-3)
+
+    @pytest.mark.parametrize("bs", [16, 64])
+    def test_kernel_reads_the_layer_it_is_given(self, bs):
+        """A pool of three layers, each with other rows: the kernel,
+        given the whole pool and a traced layer index, attends over
+        that layer's blocks and no other's."""
+        from ray_tpu.ops.pallas.paged_decode_attention import (
+            paged_attention_reference, paged_decode_attention)
+
+        L, B, H, KV, D, NB, MBS = 3, 2, 4, 2, 128, 7, 3
+        k1, k2, k3 = jax.random.split(jax.random.key(2), 3)
+        q = jax.random.normal(k1, (B, 1, H, D), jnp.float32)
+        kp = jax.random.normal(k2, (L, NB, bs, KV, D), jnp.float32)
+        vp = jax.random.normal(k3, (L, NB, bs, KV, D), jnp.float32)
+        tables = jnp.array([[3, 5, 0], [1, 2, 6]], jnp.int32)
+        lengths = jnp.array([bs + 4, 2 * bs + 9], jnp.int32)
+        kernel = jax.jit(lambda l: paged_decode_attention(
+            q, kp, vp, l, tables, lengths, scale=D ** -0.5,
+            interpret=True))
+        outs = []
+        for layer in (2, 1):
+            # the oracle on that layer alone, as a pool of one layer
+            want = paged_attention_reference(
+                q, kp[layer][None], vp[layer][None], 0, tables, lengths,
+                scale=D ** -0.5)
+            ref = paged_attention_reference(q, kp, vp, layer, tables,
+                                            lengths, scale=D ** -0.5)
+            np.testing.assert_array_equal(np.asarray(ref),
+                                          np.asarray(want))
+            got = kernel(jnp.int32(layer))
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       rtol=2e-3, atol=2e-3)
+            outs.append(np.asarray(got))
+        assert np.abs(outs[0] - outs[1]).max() > 1e-2   # layers differ
+
+
+class TestWritesInPlace:
+    """The programs carry the whole pool and write into it: a step or a
+    prefill changes the rows it is meant to, in every layer, and leaves
+    every other row of every block bit-identical (the null block, where
+    padding and inactive slots land, aside)."""
+
+    @staticmethod
+    def _noise_cache(cfg, page, num_slots):
+        cache = init_paged_cache(cfg, page, num_slots)
+        k1, k2 = jax.random.split(jax.random.key(7))
+        shape = cache["k"].shape
+        return {"k": jax.random.normal(k1, shape, cache["k"].dtype),
+                "v": jax.random.normal(k2, shape, cache["v"].dtype),
+                "length": cache["length"]}
+
+    def test_prefill_touches_only_the_prompts_blocks(self, cfg, params):
+        page = PagedConfig(num_blocks=9, block_size=16, max_seq=64)
+        al = BlockAllocator(page, num_slots=2)
+        al.ensure(1, 16)                     # a neighbour's block
+        al.ensure(0, 20)                     # 2 blocks for the prompt
+        mine = al.tables[0, :2].tolist()
+        cache = self._noise_cache(cfg, page, 2)
+        before = {n: np.asarray(cache[n]) for n in ("k", "v")}
+        tokens = np.zeros((1, 32), np.int32)
+        tokens[0, :20] = np.arange(1, 21)
+        cache, _ = make_paged_prefill(params, cfg, page)(
+            cache, al.tables[0], jnp.asarray(tokens), 20, 0)
+        others = [b for b in range(1, page.num_blocks) if b not in mine]
+        for n in ("k", "v"):
+            after = np.asarray(cache[n])
+            np.testing.assert_array_equal(after[:, others],
+                                          before[n][:, others])
+            # every layer got the prompt's rows, and zeros past them
+            rows = after[:, mine].reshape(cfg.n_layers, 32, -1)
+            assert (rows[:, :20] != before[n][:, mine].reshape(
+                cfg.n_layers, 32, -1)[:, :20]).any(axis=(1, 2)).all()
+            assert not rows[:, 20:].any()
+        assert int(cache["length"][0]) == 20
+
+    def test_decode_step_touches_one_row_a_slot(self, cfg, params):
+        page = PagedConfig(num_blocks=9, block_size=16, max_seq=64)
+        al = BlockAllocator(page, num_slots=3)
+        lengths = [5, 17, 3]                 # slot 2 is inactive
+        for slot, n in enumerate(lengths):
+            al.ensure(slot, n + 1)
+        cache = self._noise_cache(cfg, page, 3)
+        cache["length"] = jnp.asarray(lengths, jnp.int32)
+        before = {n: np.asarray(cache[n]) for n in ("k", "v")}
+        active = np.array([True, True, False])
+        cache, logits = make_paged_decode_step(params, cfg, page)(
+            cache, al.device_tables(), jnp.asarray([7, 8, 9], jnp.int32),
+            jnp.asarray(active))
+        assert np.isfinite(np.asarray(logits)[:2]).all()
+        written = {(int(al.tables[s, n // 16]), n % 16)
+                   for s, n in enumerate(lengths) if active[s]}
+        same = np.ones(before["k"].shape[1:3], bool)   # (NB, bs)
+        same[0] = False                                # null block
+        for blk, off in written:
+            same[blk, off] = False
+        for n in ("k", "v"):
+            after = np.asarray(cache[n])
+            np.testing.assert_array_equal(after[:, same],
+                                          before[n][:, same])
+            for blk, off in written:                   # in every layer
+                assert (after[:, blk, off] != before[n][:, blk, off]
+                        ).any(axis=(1, 2)).all()
+        np.testing.assert_array_equal(np.asarray(cache["length"]),
+                                      [6, 18, 3])
 
 
 class TestPagedEqualsSlot:

@@ -83,6 +83,38 @@ class TestRpc:
         with pytest.raises(RpcError):
             c.call("echo", x=1)
 
+    def test_stop_does_not_wait_for_a_peer_that_never_reads(self):
+        """Replies pile up in the server's transport for a peer that is
+        alive and does not read (a worker mid-teardown). A graceful close
+        flushes them first, so stop() used to wait for ever; now it drops
+        what is left after a moment."""
+        import pickle
+        import socket
+
+        from ray_tpu.rpc import rpc
+
+        s = RpcServer()
+
+        async def blob(n):
+            return b"x" * n
+
+        s.register("blob", blob)
+        s.start()
+        sock = socket.create_connection(s.address)
+        try:
+            for i in range(8):
+                body = pickle.dumps(
+                    {"id": i, "method": "blob", "kwargs": {"n": 4 << 20}})
+                sock.sendall(rpc._HEADER.pack(len(body), rpc._FRAME_REQ)
+                             + body)
+            time.sleep(0.5)              # 32 MB of replies, nobody reads
+            stopper = threading.Thread(target=s.stop, daemon=True)
+            stopper.start()
+            stopper.join(rpc._STOP_FLUSH_S + 10)
+            assert not stopper.is_alive()
+        finally:
+            sock.close()
+
     def test_retryable_client_survives_server_restart(self):
         s = RpcServer()
 
