@@ -1,11 +1,12 @@
 """The control of ``correct``, on the chip at a configuration's own
 size: the numbers sound runs of the program give on many seeds, beside
-the numbers the control gives (the reference with its weight matrix
-multiplies computed in int8, the step below bfloat16 that this chip
-has units for). ``benchmark/limits.json`` is set from what this prints.
+the numbers the control gives (the configuration's reference with its
+weight matrix multiplies computed in int8, the step below bfloat16 that
+this chip has units for). A limits file (``benchmark/limits.json``, or
+the one a configuration names) is set from what this prints.
 
     python3 benchmark/control.py --config mistral-7b-l16 --kind serve \
-        --seeds 12 --control-seeds 3 [--first-seed 1000]
+        --cell serve-chat-steady --seeds 12 --control-seeds 3
 
 One process that holds the chip itself (no cluster): the benchmark's own
 runs never run it. The same comparison at a tiny size is a test under
@@ -28,6 +29,8 @@ def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--config", required=True)
     ap.add_argument("--kind", choices=("serve", "train"), required=True)
+    ap.add_argument("--cell", help="kind serve: the workload whose "
+                    "cells/<cell>.json holds the deployment")
     ap.add_argument("--seeds", type=int, default=12)
     ap.add_argument("--control-seeds", type=int, default=3)
     ap.add_argument("--first-seed", type=int, default=2_147_483_000)
@@ -41,12 +44,18 @@ def main() -> int:
     import jax
 
     from benchmark import checks, model_spec, weights
-    from benchmark.reference import dense_decoder as ref
 
     dev = jax.devices()[0]
     if dev.platform != "tpu":
         raise SystemExit(f"no chip: jax reports {dev.platform!r}")
     spec = model_spec.load_config(args.config)
+    ref = model_spec.reference(spec)
+    if args.kind == "serve":
+        if not args.cell:
+            ap.error("--kind serve needs --cell")
+        with open(os.path.join(model_spec.HERE, "cells",
+                               args.cell + ".json")) as f:
+            deployment = json.load(f)["deployment"]
     rows = []
     for i in range(args.seeds):
         seed = args.first_seed + 7919 * i
@@ -54,8 +63,7 @@ def main() -> int:
         params = weights.make(spec, seed)
         row = {"seed": seed}
         if args.kind == "serve":
-            got = checks.serve_check(params, spec, seed, num_slots=32,
-                                     max_seq=4096, block_size=64)
+            got = checks.serve_check(params, spec, seed, deployment)
             row["program"] = {k: v["value"] for k, v in got.items()}
             if i < args.control_seeds:
                 toks = checks.sample_tokens(

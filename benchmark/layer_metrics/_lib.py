@@ -21,3 +21,18 @@ def counters(run):
 def programs(trace, pattern):
     rx = re.compile(pattern)
     return [v for k, v in trace["programs"].items() if rx.search(k)]
+
+
+def live_kv_tokens(run):
+    """Cached tokens a decode step reads, as the mean of the window's
+    two ends: the allocator's blocks in use, less half a block for each
+    active slot. None outside a serve cell."""
+    c = counters(run)
+    if c is None:
+        return None
+    live = []
+    for st in c[:2]:
+        blocks = st["kv_blocks_total"] - st["kv_blocks_free"]
+        live.append(max(0.0, blocks * st["kv_block_size"]
+                        - st["active_slots"] * st["kv_block_size"] / 2))
+    return sum(live) / 2

@@ -1,109 +1,113 @@
-"""Sizes, parameter counts and required operations of a configuration.
+"""A configuration and what belongs to it, found by name.
+
+``configs/<config>.json`` names its block (``"architecture"``), its plain
+reference (``"reference"``, a path from the root of the repo) and, where
+the shared ``limits.json`` does not hold for it, its own limits
+(``"limits"``). The block's adapter is ``architectures/<architecture>.py``:
+everything the harness knows about a block sits there (the contract is
+the table in ``benchmark/README.md``), and nothing here or in the other
+modules names one. A piece that is missing fails with the path that was
+looked for.
 
 Pure Python (no jax): the driver process reads it, the worker that holds
-the chip reads it, the tests read it. Everything is computed from the
-published keys of ``benchmark/configs/<config>.json``; nothing is asked
-of the program (its ``LlamaConfig.flops_per_token`` counts the embedding
-gather as a matrix multiply, which it is not).
+the chip reads it, the tests read it. An adapter's import imports no jax
+either; a reference's does, so ``reference`` is for a process that holds
+the device.
 """
 
 from __future__ import annotations
 
+import functools
+import importlib.util
 import json
 import os
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
-# published key -> field of the program's LlamaConfig. Only these are set;
-# every other field keeps the program's default, so a later PR that
-# changes a default is measured.
-PROGRAM_FIELDS = {
-    "vocab_size": "vocab_size",
-    "hidden_size": "hidden",
-    "num_hidden_layers": "n_layers",
-    "num_attention_heads": "n_heads",
-    "num_key_value_heads": "n_kv_heads",
-    "head_dim": "head_dim",
-    "intermediate_size": "mlp_dim",
-    "max_position_embeddings": "max_seq",
-    "rope_theta": "rope_theta",
-    "rms_norm_eps": "norm_eps",
-    "tie_word_embeddings": "tie_embeddings",
-}
+
+@functools.lru_cache(maxsize=None)
+def load_module(path: str):
+    """The Python file at ``path`` as a module, loaded once: how every
+    piece that is found by name (an adapter, a reference, a per-layer
+    reader) comes in."""
+    stem = os.path.splitext(os.path.basename(path))[0]
+    found = importlib.util.spec_from_file_location(
+        "benchmark_file_" + "".join(c if c.isalnum() else "_"
+                                    for c in stem), path)
+    mod = importlib.util.module_from_spec(found)
+    found.loader.exec_module(mod)
+    return mod
+
+
+def adapter_path(spec: dict, root: str = HERE) -> str:
+    return os.path.join(root, "architectures", f"{spec['architecture']}.py")
+
+
+def _from_repo(spec: dict, key: str, root: str) -> str:
+    return os.path.join(os.path.dirname(root), spec[key])
+
+
+def adapter(spec: dict, root: str = HERE):
+    """The module ``architectures/<architecture>.py`` of this
+    configuration's block."""
+    return load_module(adapter_path(spec, root))
+
+
+def reference(spec: dict, root: str = HERE):
+    """The configuration's plain reference: ``logits(params, tokens,
+    spec, rows, quant=None)``, ``last_block_loss_and_grads``,
+    ``rel_err``. Imports jax."""
+    return load_module(_from_repo(spec, "reference", root))
+
+
+def limits_path(spec: dict, root: str = HERE) -> str:
+    """The configuration's own limits where it names a file, else
+    ``limits.json``."""
+    return (_from_repo(spec, "limits", root) if "limits" in spec
+            else os.path.join(root, "limits.json"))
+
+
+def limits(spec: dict, root: str = HERE) -> dict:
+    """{name: {"limit": ..., the readings it was set from}}."""
+    with open(limits_path(spec, root)) as f:
+        return json.load(f)["limits"]
 
 
 def load_config(name: str, root: str = HERE) -> dict:
     with open(os.path.join(root, "configs", f"{name}.json")) as f:
         spec = json.load(f)
-    if spec.get("architecture") != "dense_decoder":
-        raise SystemExit(f"config {name!r}: architecture "
-                         f"{spec.get('architecture')!r} has no reference "
-                         "under benchmark/reference/")
+    for key in ("architecture", "reference"):
+        if key not in spec:
+            raise SystemExit(f"config {name!r} names no {key!r}")
+    for what, path in (("architecture", adapter_path(spec, root)),
+                       ("reference", _from_repo(spec, "reference", root)),
+                       ("limits", limits_path(spec, root))):
+        if not os.path.isfile(path):
+            raise SystemExit(f"config {name!r}: its {what} is not there: "
+                             f"no file {path}")
     if spec.get("torch_dtype") != "bfloat16":
         raise SystemExit(f"config {name!r}: only bfloat16 is served")
+    adapter(spec, root).check_config(spec)
     return spec
 
 
-def program_kwargs(spec: dict) -> dict:
-    """Keyword arguments for the program's config class."""
-    return {field: spec[key] for key, field in PROGRAM_FIELDS.items()}
+def _of_adapter(name: str):
+    def call(spec: dict, *args, **kwargs):
+        return getattr(adapter(spec), name)(spec, *args, **kwargs)
+
+    call.__name__ = name
+    call.__doc__ = f"``{name}`` of the configuration's adapter."
+    return call
 
 
-def matrix_params(spec: dict, layers: int | None = None) -> dict:
-    """Parameters that take part in a matrix multiply, by group."""
-    h, m = spec["hidden_size"], spec["intermediate_size"]
-    q = spec["num_attention_heads"] * spec["head_dim"]
-    kv = spec["num_key_value_heads"] * spec["head_dim"]
-    n = spec["num_hidden_layers"] if layers is None else layers
-    per_layer = h * q + 2 * h * kv + q * h + 3 * h * m
-    return {"per_layer": per_layer, "layers": n * per_layer,
-            "head": h * spec["vocab_size"]}
-
-
-def num_params(spec: dict, layers: int | None = None) -> int:
-    """All stored parameters: embedding table, blocks with their two
-    norms, final norm, and the head where it is not tied."""
-    h, v = spec["hidden_size"], spec["vocab_size"]
-    n = spec["num_hidden_layers"] if layers is None else layers
-    mp = matrix_params(spec, layers)
-    total = v * h + mp["layers"] + n * 2 * h + h
-    if not spec["tie_word_embeddings"]:
-        total += mp["head"]
-    return total
-
-
-def train_flops_per_token(spec: dict, seq: int) -> float:
-    """Operations the forward and backward passes REQUIRE for one trained
-    token: 6 for every parameter in a matrix multiply (2 forward, 4
-    backward), none for the embedding gather, none for recomputation,
-    plus causal attention: forward QK^T and PV are 4*S*d over the full
-    square, halved by causality, and the backward costs twice the
-    forward: 3 * 2*S*d = 6*S*d a layer (d = heads * head size)."""
-    mp = matrix_params(spec)
-    q = spec["num_attention_heads"] * spec["head_dim"]
-    return (6.0 * (mp["layers"] + mp["head"])
-            + 6.0 * spec["num_hidden_layers"] * seq * q)
-
-
-def flash_flops(spec: dict, batch: int, seq: int) -> dict:
-    """Operations of ONE call of each causal flash kernel (one layer, one
-    step), counted over the lower triangle. Forward: QK^T and PV. The
-    backward is split in two kernels that each recompute what they need
-    (flash attention stores no scores): dq = scores, dP, dQ; dkv =
-    scores, dP, dV, dK."""
-    h, d = spec["num_attention_heads"], spec["head_dim"]
-    tri = batch * h * seq * seq * d      # one matmul over half the square
-    return {"fwd": 2 * tri, "bwd_dq": 3 * tri, "bwd_dkv": 4 * tri}
-
-
-def kv_bytes_per_token(spec: dict) -> int:
-    """Bytes of keys and values one cached token takes in ONE layer."""
-    return 2 * spec["num_key_value_heads"] * spec["head_dim"] * 2
-
-
-def paged_decode_bytes(spec: dict, live_tokens: int, slots: int) -> int:
-    """Bytes the paged decode-attention kernel has to move for ONE layer
-    and one step: the live keys and values once, the queries in and the
-    outputs out (bf16)."""
-    q = spec["num_attention_heads"] * spec["head_dim"]
-    return live_tokens * kv_bytes_per_token(spec) + 2 * slots * q * 2
+# the counts every adapter gives, by the configuration
+num_params = _of_adapter("num_params")
+matrix_params = _of_adapter("matrix_params")
+train_flops_per_token = _of_adapter("train_flops_per_token")
+kv_bytes_per_token = _of_adapter("kv_bytes_per_token")
+kernel_counts = _of_adapter("kernel_counts")
+# and three of the two configurations that are there, under the names
+# they had before the adapters (an adapter without them: AttributeError)
+flash_flops = _of_adapter("flash_flops")
+paged_decode_bytes = _of_adapter("paged_decode_bytes")
+program_kwargs = _of_adapter("program_kwargs")

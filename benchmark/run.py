@@ -19,7 +19,6 @@ import time
 T_START = time.monotonic()          # set-up counts from here
 
 import argparse  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import statistics  # noqa: E402
@@ -337,12 +336,7 @@ def load_reader(name: str):
     if not os.path.exists(base + ".py"):
         raise SystemExit(f"per-layer metric {name!r} has no reader under "
                          "benchmark/layer_metrics/")
-    spec = importlib.util.spec_from_file_location(
-        "layer_metric_" + name.replace(".", "_").replace("-", "_"),
-        base + ".py")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return model_spec.load_module(base + ".py").read
 
 
 def reports(metric: dict, workload: str, e2e_of_cell: set) -> bool:

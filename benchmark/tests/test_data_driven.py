@@ -1,8 +1,10 @@
-"""A configuration, a traffic mix, a cell and a per-layer metric are each
-added as NEW files and entries: in a temp copy of the benchmark, nothing
-that was there is edited, and ``run.py`` finds all of them by name. The
-run is a rehearsal on the CPU at a tiny size (pretend chip, the line
-names the cpu)."""
+"""A configuration, a traffic mix, a cell, a per-layer metric and an
+ARCHITECTURE (adapter, reference, limits, a deployment key of its own)
+are each added as NEW files and entries: in a temp copy of the
+benchmark, nothing that was there is edited, and ``run.py`` finds all of
+them by name. The run is a rehearsal on the CPU at a tiny size (pretend
+chip, the line names the cpu). A configuration whose adapter, reference
+or limits file is missing exits with the path that was looked for."""
 
 import hashlib
 import json
@@ -10,6 +12,8 @@ import os
 import shutil
 import subprocess
 import sys
+
+import pytest
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH = os.path.dirname(HERE)
@@ -28,40 +32,104 @@ def _hashes(folder):
     return out
 
 
-def test_a_new_cell_is_files_and_entries_only(tmp_path):
+TINY = {
+    "name": "new-tiny", "source": "test", "architecture": "dense_decoder",
+    "reference": "benchmark/reference/dense_decoder.py",
+    "hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 2,
+    "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
+    "vocab_size": 256, "max_position_embeddings": 1024,
+    "rope_theta": 10000.0, "rms_norm_eps": 1e-5,
+    "tie_word_embeddings": False, "torch_dtype": "bfloat16",
+    "reduced": []}
+DEPLOYMENT = {"num_slots": 3, "max_seq": 512, "kv_block_size": 64,
+              "kv_pool_tokens": 1024, "max_ongoing_requests": 16}
+# the other block's engine takes its pool in blocks, a deployment key
+# that only its adapter knows
+OTHER_ENGINE = '''
+
+_dense_engine_kwargs = engine_kwargs
+
+
+def engine_kwargs(spec, deployment):
+    return _dense_engine_kwargs(spec, dict(
+        deployment, kv_pool_tokens=deployment["pool_blocks"]
+        * deployment["kv_block_size"]))
+'''
+
+
+def _copy(tmp_path):
     shutil.copytree(BENCH, tmp_path / "benchmark", ignore=shutil.ignore_patterns(
         ".out", "__pycache__"))
     os.symlink(os.path.join(ROOT, "ray_tpu"), tmp_path / "ray_tpu")
-    before = _hashes(tmp_path / "benchmark")
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
+        return json.load(f)
 
-    def put(rel, obj):
-        path = tmp_path / "benchmark" / rel
-        assert not path.exists()
-        path.write_text(obj if isinstance(obj, str) else json.dumps(obj))
 
-    put("configs/new-tiny.json", {
-        "name": "new-tiny", "source": "test", "architecture": "dense_decoder",
-        "hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 2,
-        "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
-        "vocab_size": 256, "max_position_embeddings": 1024,
-        "rope_theta": 10000.0, "rms_norm_eps": 1e-5,
-        "tie_word_embeddings": False, "torch_dtype": "bfloat16",
-        "reduced": []})
-    put("traffic/new-mix.json", {
+def _put(tmp_path, rel, obj):
+    path = tmp_path / "benchmark" / rel
+    assert not path.exists()
+    path.parent.mkdir(exist_ok=True)
+    path.write_text(obj if isinstance(obj, str) else json.dumps(obj))
+
+
+def _run(tmp_path, trace):
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("PYTHONPATH", None)
+    return subprocess.run(
+        [sys.executable, "benchmark/run.py", "--workload", "new-cell",
+         "--seed", "2147483999", "--seconds", "3", "--trace", str(trace),
+         "--rehearse"], cwd=tmp_path, env=env, capture_output=True,
+        text=True, timeout=600)
+
+
+def _dense(tmp_path):
+    """A configuration of the block that is there."""
+    _put(tmp_path, "configs/new-tiny.json", TINY)
+    _put(tmp_path, "cells/new-cell.json", {"deployment": DEPLOYMENT})
+    return {"configs/new-tiny.json", "cells/new-cell.json"}, "limit 0.06 ok"
+
+
+def _other_block(tmp_path):
+    """A new ARCHITECTURE: its adapter (a copy of the dense one under
+    another name: the test is of the lookup, not of a block) with a
+    deployment key of its own, its reference, its limits."""
+    with open(os.path.join(BENCH, "architectures", "dense_decoder.py")) as f:
+        _put(tmp_path, "architectures/other_block.py", f.read() + OTHER_ENGINE)
+    with open(os.path.join(BENCH, "reference", "dense_decoder.py")) as f:
+        _put(tmp_path, "reference/other_block.py", f.read())
+    with open(os.path.join(BENCH, "limits.json")) as f:
+        limits = json.load(f)
+    for entry in limits["limits"].values():
+        entry["limit"] = 0.03      # this size on the CPU reads under 0.015
+    _put(tmp_path, "limits/new-tiny.json", limits)
+    _put(tmp_path, "configs/new-tiny.json", dict(
+        TINY, architecture="other_block",
+        reference="benchmark/reference/other_block.py",
+        limits="benchmark/limits/new-tiny.json"))
+    deployment = dict(DEPLOYMENT, pool_blocks=16)
+    del deployment["kv_pool_tokens"]
+    _put(tmp_path, "cells/new-cell.json", {"deployment": deployment})
+    return {"configs/new-tiny.json", "cells/new-cell.json",
+            "architectures/other_block.py", "reference/other_block.py",
+            "limits/new-tiny.json"}, "limit 0.03 ok"
+
+
+@pytest.mark.parametrize("block", [_dense, _other_block])
+def test_a_new_cell_is_files_and_entries_only(tmp_path, block):
+    bench = _copy(tmp_path)
+    before = _hashes(tmp_path / "benchmark")
+    added, compared = block(tmp_path)
+    _put(tmp_path, "traffic/new-mix.json", {
         "kind": "closed_loop_handle", "clients": 6, "block": 16,
         "prompt_len": {"dist": "uniform", "min": 40, "max": 100},
         "output_len": {"dist": "fixed", "value": 6, "min": 6, "max": 6},
         "temperature": 0.0, "lead_s": 1.0, "drain_s": 30.0,
         "trace_offset_s": 0.5, "trace_s": 1.0})
-    put("cells/new-cell.json", {"deployment": {
-        "num_slots": 3, "max_seq": 512, "kv_block_size": 64,
-        "kv_pool_tokens": 1024, "max_ongoing_requests": 16}})
-    put("layer_metrics/new_metric.py",
-        "def read(run):\n    return float(run['raw']['close']['stats']"
-        "['steps'] - run['raw']['open']['stats']['steps'])\n")
-    put("layer_metrics/new_alias.json", {"reader": "_engine_step_ms"})
+    _put(tmp_path, "layer_metrics/new_metric.py",
+         "def read(run):\n    return float(run['raw']['close']['stats']"
+         "['steps'] - run['raw']['open']['stats']['steps'])\n")
+    _put(tmp_path, "layer_metrics/new_alias.json",
+         {"reader": "_engine_step_ms"})
     bench["configs"].append({
         "name": "new-tiny", "source": "test",
         "file": "benchmark/configs/new-tiny.json", "reduced": [],
@@ -79,35 +147,55 @@ def test_a_new_cell_is_files_and_entries_only(tmp_path):
             "moves": "output_tokens_per_s", "workloads": ["new-cell"]})
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
 
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("PYTHONPATH", None)
-
     def run(trace):
-        proc = subprocess.run(
-            [sys.executable, "benchmark/run.py", "--workload", "new-cell",
-             "--seed", "2147483999", "--seconds", "3", "--trace", str(trace),
-             "--rehearse"], cwd=tmp_path, env=env, capture_output=True,
-            text=True, timeout=600)
+        proc = _run(tmp_path, trace)
         assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-3000:]
-        return json.loads(proc.stdout.strip().splitlines()[-1])
+        return json.loads(proc.stdout.strip().splitlines()[-1]), proc.stdout
 
-    line = run(0)
+    line, said = run(0)
     assert set(line) >= {"correct", "attempted", "failed", "metrics",
                          "device"}
     assert line["correct"] is True and line["failed"] == 0
     assert set(line["metrics"]) == {"output_tokens_per_s", "setup_s"}
     assert line["device"]["platform"] == "cpu"      # never a device number
-    traced = run(1)
+    # the limits are the file's that the configuration names
+    assert said.count(compared) == 2, said[-3000:]
+    traced, _ = run(1)
     assert traced["metrics"]["new_metric"]["value"] > 0
     assert traced["metrics"]["new_alias"]["unit"] == "count"
     assert "compiles_in_window" in traced["metrics"]    # no `workloads` key
     assert "ttft_p95_ms" not in traced["metrics"]
     after = _hashes(tmp_path / "benchmark")
     assert {k: v for k, v in after.items() if k in before} == before
-    assert set(after) - set(before) == {
-        "configs/new-tiny.json", "traffic/new-mix.json",
-        "cells/new-cell.json", "layer_metrics/new_metric.py",
+    assert set(after) - set(before) == added | {
+        "traffic/new-mix.json", "layer_metrics/new_metric.py",
         "layer_metrics/new_alias.json"}
+
+
+@pytest.mark.parametrize("missing, looked_for", [
+    ({"architecture": "nowhere_block"},
+     "benchmark/architectures/nowhere_block.py"),
+    ({"reference": "benchmark/reference/nowhere.py"},
+     "benchmark/reference/nowhere.py"),
+    ({"limits": "benchmark/limits/nowhere.json"},
+     "benchmark/limits/nowhere.json")], ids=["adapter", "reference",
+                                             "limits"])
+def test_a_missing_piece_fails_by_the_path_looked_for(tmp_path, missing,
+                                                      looked_for):
+    bench = _copy(tmp_path)
+    _put(tmp_path, "configs/new-tiny.json", dict(TINY, **missing))
+    bench["configs"].append({
+        "name": "new-tiny", "source": "test",
+        "file": "benchmark/configs/new-tiny.json", "reduced": [],
+        "why": "test"})
+    bench["workloads"].append({
+        "name": "new-cell", "config": "new-tiny", "traffic": "batch-decode",
+        "chips": 1, "why": "test"})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    proc = _run(tmp_path, 0)
+    assert proc.returncode != 0
+    assert str(tmp_path / looked_for) in proc.stderr, proc.stderr[-2000:]
+    assert not proc.stdout.strip().startswith("{")
 
 
 def test_without_the_program_the_command_fails_and_prints_no_result(tmp_path):

@@ -5,10 +5,11 @@ benchmark run; ``benchmark/control.py`` runs the control there."""
 
 import pytest
 
-from benchmark import checks, weights
-from benchmark.reference import dense_decoder as ref
+from benchmark import checks, model_spec, weights
 
-TINY = dict(hidden_size=64, intermediate_size=128, num_hidden_layers=2,
+TINY = dict(architecture="dense_decoder",
+            reference="benchmark/reference/dense_decoder.py",
+            hidden_size=64, intermediate_size=128, num_hidden_layers=2,
             num_attention_heads=4, num_key_value_heads=2, head_dim=16,
             vocab_size=256, max_position_embeddings=1024, rope_theta=1e4,
             rms_norm_eps=1e-5, tie_word_embeddings=False)
@@ -16,13 +17,14 @@ TINY = dict(hidden_size=64, intermediate_size=128, num_hidden_layers=2,
 # control 0.027-0.032 (logits) and 0.041 (gradients)
 LIMIT = 0.02
 SEEDS = (1, 3_000_000_000)
+DEPLOYMENT = dict(num_slots=3, max_seq=512, kv_block_size=64)
+ref = model_spec.reference(TINY)
 
 
 @pytest.mark.parametrize("seed", SEEDS)
 def test_serving_programs_agree_with_the_reference(seed):
     params = weights.make(TINY, seed)
-    got = checks.serve_check(params, TINY, seed, num_slots=3, max_seq=512,
-                             block_size=64)
+    got = checks.serve_check(params, TINY, seed, DEPLOYMENT)
     assert all(v["value"] < LIMIT for v in got.values()), got
 
 

@@ -218,11 +218,13 @@ def breakdown(summary: dict, top: int = 10) -> dict:
 
 
 def write_excerpt(trace_dir: str, out_path: str, program: str = "jit_step",
-                  text_limit: int = 300) -> None:
+                  text_limit: int = 300, only: str | None = None) -> None:
     """Cut one run of ``program`` out of a recorded trace and keep it,
-    with what ``reduce_planes`` reads from it, as the test's fixture.
-    Also writes the full text of every distinct custom call beside it
-    (how the kernels are named is read from there by hand)."""
+    with what ``reduce_planes`` reads from it, as the test's fixture
+    (``only``: just the operations whose opcode matches, where a whole
+    run is too long to keep). Also writes the full text of every distinct
+    custom call beside it (how the kernels are named is read from there
+    by hand)."""
     import json
 
     path = find_xplane(trace_dir)
@@ -236,13 +238,16 @@ def write_excerpt(trace_dir: str, out_path: str, program: str = "jit_step",
             return text
         _, opcode, _ = parse_op(text)
         t = _TARGET.search(text)
-        return (text[:text_limit] + f" {opcode.split(':')[0]}(...)"
+        head = text[:text_limit].split(", custom_call_target=")[0]
+        return (head + f" {opcode.split(':')[0]}(...)"
                 + (", " + t.group(0) if t else ""))
 
     keep = lambda evs: [[short(t), s, d] for t, s, d in evs  # noqa: E731
                         if s >= lo and s + d <= lo + dur]
-    cut = {chip: {OPS_LINE: keep(planes[chip][OPS_LINE]),
-                  MODULES_LINE: keep(mods)}}
+    ops = keep(planes[chip][OPS_LINE])
+    if only:
+        ops = [ev for ev in ops if re.search(only, parse_op(ev[0])[1])]
+    cut = {chip: {OPS_LINE: ops, MODULES_LINE: keep(mods)}}
     summary = reduce_planes(cut)
     top = sorted(summary["ops"].items(), key=lambda kv: -kv[1][1])[:8]
     with open(out_path, "w") as f:
@@ -264,4 +269,5 @@ def write_excerpt(trace_dir: str, out_path: str, program: str = "jit_step",
 if __name__ == "__main__":
     import sys
 
-    write_excerpt(*sys.argv[1:])
+    write_excerpt(*sys.argv[1:3], **dict(a.split("=", 1)
+                                         for a in sys.argv[3:]))

@@ -24,7 +24,7 @@ class BenchServer(LLMServer):
         from ray_tpu.common.compile_cache import compile_cache_counts
         from ray_tpu.serve.llm import LLMEngine
 
-        from benchmark import checks, weights
+        from benchmark import model_spec, weights
 
         self._counts = compile_cache_counts()
         self._spec, self._out_dir = spec, out_dir
@@ -41,11 +41,8 @@ class BenchServer(LLMServer):
         self._times["weights_s"] = time.monotonic() - t0 - jax_s
         self._seed, self._deployment = seed, deployment
         self.engine = LLMEngine(
-            config=checks.program_config(spec), params=params, seed=0,
-            num_slots=deployment["num_slots"],
-            max_seq=deployment["max_seq"], kv_cache="paged",
-            kv_pool_tokens=deployment["kv_pool_tokens"],
-            kv_block_size=deployment["kv_block_size"], prefix_cache="off")
+            params=params,
+            **model_spec.adapter(spec).engine_kwargs(spec, deployment))
         self._ingress = []
         self._tracing = None
 
@@ -72,11 +69,8 @@ class BenchServer(LLMServer):
         from benchmark import checks
 
         t0 = time.monotonic()
-        d = self._deployment
         self._check = checks.serve_check(
-            self.engine.params, self._spec, self._seed,
-            num_slots=d["num_slots"], max_seq=d["max_seq"],
-            block_size=d["kv_block_size"])
+            self.engine.params, self._spec, self._seed, self._deployment)
         self._times["check_s"] = time.monotonic() - t0
         return self._check
 

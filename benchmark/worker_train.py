@@ -13,10 +13,9 @@ def train_loop(config):
     import jax
     from ray_tpu import train
     from ray_tpu.common.compile_cache import compile_cache_counts
-    from ray_tpu.models import llama
-    from ray_tpu.models.training import init_train_state
 
-    from benchmark import checks, sizing, trace_reduce, traffic_gen, weights
+    from benchmark import (checks, model_spec, sizing, trace_reduce,
+                           traffic_gen, weights)
 
     t_loop = time.monotonic()
     counts = compile_cache_counts()
@@ -34,8 +33,8 @@ def train_loop(config):
     mesh = sizing.train_mesh(devices, job)
     batch, seq = job["batch"], mix["seq"]
     with jax.sharding.set_mesh(mesh):
-        state_shape, step_fn, rules, opt, init = sizing.train_setup(
-            spec, job, mesh)
+        state_shape, step_fn, rules, init_state = model_spec.adapter(
+            spec).train_setup(spec, job, mesh)
         # 1. correctness, before the train state takes the memory: the
         # program's loss and gradients on one seeded sequence against
         # the float32 reference, on weights of this seed
@@ -49,10 +48,7 @@ def train_loop(config):
         times["check_s"] = time.monotonic() - t0
         # 2. the train state, sharded from birth, from the same seed
         t0 = time.monotonic()
-        cfg = checks.program_config(spec)
-        state, _ = init_train_state(
-            init, llama.param_logical_axes(cfg), opt, mesh, rules,
-            weights.seed_key(seed))
+        state = init_state(weights.seed_key(seed))
         batches = traffic_gen.train_batches(mix, seed, spec["vocab_size"],
                                             batch)
         first = next(batches)
