@@ -45,6 +45,12 @@ class Config:
         self._flags: Dict[str, _Flag] = {}
         self._system_config: Dict[str, Any] = {}
         self._cache: Dict[str, Any] = {}
+        # orders the writers only. ``get`` takes no lock: a first read
+        # allocates (the env lookup raises and catches a KeyError), so the
+        # collector can run inside it, and an ``ObjectRef.__del__`` it
+        # finds frees through ``get`` on the same thread, holding other
+        # locks. A writer swaps ``_cache`` for a new dict, so a read that
+        # began before the swap stores into the dict nobody reads any more.
         self._lock = threading.Lock()
 
     def declare(self, name: str, type_: type, default: Any, doc: str = "") -> None:
@@ -63,7 +69,7 @@ class Config:
                 if key not in self._flags:
                     raise ValueError(f"unknown system_config key {key!r}")
             self._system_config = dict(system_config)
-            self._cache.clear()
+            self._cache = {}
 
     def system_config_json(self) -> str:
         return json.dumps(self._system_config)
@@ -74,24 +80,28 @@ class Config:
             if name not in self._flags:
                 raise ValueError(f"unknown system_config key {name!r}")
             self._system_config[name] = value
-            self._cache.pop(name, None)
+            cache = dict(self._cache)
+            cache.pop(name, None)
+            self._cache = cache
 
     def get(self, name: str) -> Any:
-        with self._lock:
-            if name in self._cache:
-                return self._cache[name]
-            flag = self._flags.get(name)
-            if flag is None:
-                raise KeyError(f"unknown flag {name!r}")
-            env_val = os.environ.get(_ENV_PREFIX + name)
-            if env_val is not None:
-                value = _PARSERS[flag.type](env_val)
-            elif name in self._system_config:
-                value = flag.type(self._system_config[name])
-            else:
-                value = flag.default
-            self._cache[name] = value
-            return value
+        cache = self._cache
+        try:
+            return cache[name]
+        except KeyError:
+            pass
+        flag = self._flags.get(name)
+        if flag is None:
+            raise KeyError(f"unknown flag {name!r}")
+        env_val = os.environ.get(_ENV_PREFIX + name)
+        if env_val is not None:
+            value = _PARSERS[flag.type](env_val)
+        elif name in self._system_config:
+            value = flag.type(self._system_config[name])
+        else:
+            value = flag.default
+        cache[name] = value
+        return value
 
     def __getattr__(self, name: str) -> Any:
         if name.startswith("_"):
@@ -100,7 +110,7 @@ class Config:
 
     def reset_cache(self) -> None:
         with self._lock:
-            self._cache.clear()
+            self._cache = {}
 
     def all_flags(self) -> Dict[str, _Flag]:
         return dict(self._flags)

@@ -68,6 +68,10 @@ from .submitter import ActorTaskSubmitter, NormalTaskSubmitter
 
 logger = logging.getLogger(__name__)
 
+# threads of a worker's executor; an actor that asks for a higher
+# max_concurrency gets a pool of its own size (see ``create`` below)
+_EXECUTOR_THREADS = 64
+
 MODE_DRIVER = "driver"
 MODE_WORKER = "worker"
 
@@ -209,7 +213,8 @@ class CoreWorker:
         self._ref_lock = threading.Lock()
 
         # execution state (executee side)
-        self._executor = ThreadPoolExecutor(max_workers=64, thread_name_prefix="rt-exec")
+        self._executor = ThreadPoolExecutor(
+            max_workers=_EXECUTOR_THREADS, thread_name_prefix="rt-exec")
         self._fn_cache: Dict[bytes, Any] = {}
         # C dispatch loop (rpc/native/fastloop.c): eligible actor pushes
         # bypass asyncio end to end — frames execute straight off the C
@@ -2283,6 +2288,17 @@ class CoreWorker:
                     self._actor_max_concurrency = max(1, task.max_concurrency)
                     self._actor_concurrency = threading.Semaphore(
                         self._actor_max_concurrency)
+                    # each sync call of an actor holds an executor thread
+                    # for as long as it runs: an actor that asks for more
+                    # than the pool's 64 gets them, with room for a
+                    # health probe beside a full house. (With 64, a
+                    # replica with 128 decode slots ran half empty and
+                    # its controller's pings queued behind the callers
+                    # until it was replaced.) Threads start on demand.
+                    if self._actor_max_concurrency + 8 > _EXECUTOR_THREADS:
+                        self._executor = ThreadPoolExecutor(
+                            max_workers=self._actor_max_concurrency + 8,
+                            thread_name_prefix="rt-exec")
                     self._actor_has_async = any(
                         inspect.iscoroutinefunction(getattr(inst, m, None))
                         for m in dir(inst) if not m.startswith("__"))

@@ -24,6 +24,7 @@ GShard/Switch):
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
@@ -31,6 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import llama
+from ray_tpu.ops.attention import on_tpu
 from ray_tpu.ops.norms import rmsnorm
 from ray_tpu.ops.rope import rope_frequencies
 from ray_tpu.parallel.sharding import ShardingRules, with_logical_constraint
@@ -261,3 +263,114 @@ def loss_fn(params: Params, batch: Dict[str, jax.Array], config: MoEConfig,
     return loss, {"loss": loss, "ce": ce, "accuracy": acc, "tokens": denom,
                   "aux_loss": moe["aux"], "router_z": moe["router_z"],
                   "dropped_frac": moe["dropped"]}
+
+
+# ---------------------------------------------------------------------
+# The expert layer by share (serving): a chip of an expert-parallel
+# deployment holds ``experts_held = (first, count)`` of the router's
+# experts, routes every token over ALL of them and computes the part of
+# the result that its own experts give. No capacity and no dropped
+# token: the row buffer is sized for the worst case (every pair routed
+# here) and a step fills what the routing sends. The partial sum is the
+# layer's output; on one chip nothing stands in for the absent experts.
+
+ROW_TILE = 16                 # rows of one expert a tile (bf16 sublanes)
+COUNTERS = ("expert_layer_calls", "expert_pairs", "experts_hit",
+            "expert_load_max_over_mean", "expert_pairs_dropped")
+
+
+def route_sigmoid_topk(x, router, bias, top_k: int, scale: float = 1.0):
+    """``noaux_tc`` routing: scores ``s = sigmoid(x_f32 @ W_r)`` in
+    float32 over every expert; the ``top_k`` largest of ``s + b`` are
+    chosen (``b`` the stored correction bias, used for the choice only);
+    the weights are ``s`` at the chosen, divided by their sum, times
+    ``scale``. x (T, h) -> (idx (T, k) int32, weights (T, k) f32)."""
+    s = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, idx = jax.lax.top_k(s + bias.astype(jnp.float32)[None, :], top_k)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    w = w / jnp.sum(w, axis=-1, keepdims=True) * scale
+    return idx.astype(jnp.int32), w
+
+
+def experts_by_share(x, layer: Params, *, experts_held: Tuple[int, int],
+                     top_k: int, scale: float = 1.0, valid=None,
+                     use_kernel: Optional[bool] = None,
+                     kernel_name: str = "grouped_expert_matmul"):
+    """The routed MLP of one layer on the chip that holds
+    ``experts_held = (first, count)``: x (T, h) -> (y (T, h) float32,
+    the partial sum over the experts held; counters (5,) float32 in the
+    order of ``COUNTERS``).
+
+    ``layer``: ``router`` (h, E), ``router_bias`` (E,), and the HELD
+    experts' SwiGLU matrices ``we_gate``/``we_up`` (count, h, m),
+    ``we_down`` (count, m, h). ``valid`` (T,) bool masks rows that are
+    no token (a padded prompt, an idle slot): they are routed nowhere.
+
+    The (token, expert) pairs whose expert is held are sorted by expert
+    and laid out in tiles of ``ROW_TILE`` rows, one expert a tile; three
+    grouped products (``ops/pallas/grouped_matmul.py``) run over the
+    tiles that hold rows; the weighted rows are gathered back by token.
+    ``kernel_name`` names the products' custom calls in a trace.
+    """
+    from ray_tpu.ops.pallas import grouped_matmul as gm
+
+    T, h = x.shape
+    first, G = experts_held
+    tm = ROW_TILE
+    idx, w = route_sigmoid_topk(x, layer["router"], layer["router_bias"],
+                                top_k, scale)
+    local = idx - first
+    held = (local >= 0) & (local < G)
+    if valid is not None:
+        held &= valid[:, None]
+    key = jnp.where(held, local, G).reshape(-1)               # (T*k,)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.zeros(G + 1, jnp.int32).at[key].add(1)[:G]
+    padded = -(-sizes // tm) * tm
+    pend = jnp.cumsum(padded)
+    ustart = jnp.cumsum(sizes) - sizes
+    n_tiles = -(-(T * top_k + G * (tm - 1)) // tm)
+    M = n_tiles * tm
+    skey = key[order]
+    g_of = jnp.minimum(skey, G - 1)
+    row_sorted = jnp.where(
+        skey < G, (pend - padded)[g_of] + jnp.arange(T * top_k)
+        - ustart[g_of], M)
+    token_of_row = jnp.full((M,), T, jnp.int32).at[row_sorted].set(
+        (order // top_k).astype(jnp.int32), mode="drop")
+    x_rows = jnp.concatenate([x, jnp.zeros((1, h), x.dtype)])[token_of_row]
+    n_active = pend[-1] // tm
+    tile = jnp.minimum(jnp.arange(n_tiles), jnp.maximum(n_active - 1, 0))
+    tile_group = jnp.minimum(
+        jnp.searchsorted(pend, tile * tm, side="right"), G - 1)
+
+    if use_kernel is None:
+        use_kernel = on_tpu()
+    mm = functools.partial(
+        gm.grouped_matmul if use_kernel else gm.grouped_matmul_reference,
+        name=kernel_name)
+    with jax.named_scope("expert_layer"):
+        gate = mm(x_rows, layer["we_gate"], tile_group, n_active, tm=tm,
+                  out_dtype=jnp.float32)
+        up = mm(x_rows, layer["we_up"], tile_group, n_active, tm=tm,
+                out_dtype=jnp.float32)
+        act = (jax.nn.silu(gate) * up).astype(x.dtype)
+        y_rows = mm(act, layer["we_down"], tile_group, n_active, tm=tm,
+                    out_dtype=jnp.float32)
+        row_pair = jnp.zeros(T * top_k, jnp.int32).at[order].set(
+            row_sorted.astype(jnp.int32)).reshape(T, top_k)
+        placed = held & (row_pair < M)
+        # rows of tiles past n_active were never written: select, never
+        # multiply, or what lies there leaks through a zero weight
+        y_pairs = jnp.where(placed[..., None],
+                            y_rows[jnp.minimum(row_pair, M - 1)], 0.0)
+        y = jnp.sum(y_pairs * w[..., None], axis=1)
+    pairs = jnp.sum(sizes).astype(jnp.float32)
+    counters = jnp.stack([
+        jnp.float32(1.0), pairs, jnp.sum(sizes > 0).astype(jnp.float32),
+        jnp.max(sizes) * G / jnp.maximum(pairs, 1.0),
+        jnp.sum(held).astype(jnp.float32)
+        - jnp.sum(placed).astype(jnp.float32)])
+    return y, counters

@@ -107,6 +107,9 @@ class BlockAllocator:
         self.tables = np.zeros((num_slots, page.max_blocks_per_seq),
                                np.int32)
         self._owned: List[List[int]] = [[] for _ in range(num_slots)]
+        # logical index of a slot's first owned block: 0 unless the
+        # blocks behind a window were given back (:meth:`drop_before`)
+        self._base = np.zeros(num_slots, np.int64)
         self._ref = np.zeros(page.num_blocks, np.int32)
         self._ref[0] = 1             # null block: pinned forever
         self._device_tables = None   # cache: re-upload only after changes
@@ -120,10 +123,30 @@ class BlockAllocator:
     def refcount(self, block: int) -> int:
         return int(self._ref[block])
 
+    def fits(self, tokens: int) -> bool:
+        """Whether a sequence of ``tokens`` could be held with the pool
+        otherwise idle."""
+        return self.blocks_for(tokens) <= min(self.page.num_blocks - 1,
+                                              self.page.max_blocks_per_seq)
+
+    def lacking(self, tokens: int, shared: int = 0, headroom: int = 0
+                ) -> int:
+        """Blocks the free list is short of for admitting a sequence of
+        ``tokens`` of which ``shared`` blocks are adopted, with
+        ``headroom`` blocks kept back (one growth block for each slot
+        already running). 0: it can be admitted."""
+        return max(0, self.blocks_for(tokens) - shared + headroom
+                   - len(self._free))
+
+    def need(self, slot: int, tokens: int) -> int:
+        """Blocks :meth:`ensure` would have to take for ``tokens``."""
+        return max(0, self.blocks_for(tokens) - int(self._base[slot])
+                   - len(self._owned[slot]))
+
     def ensure(self, slot: int, tokens: int) -> bool:
         """Grow ``slot``'s table to cover ``tokens`` cached tokens.
         Returns False (allocating nothing) if the pool can't cover it."""
-        need = self.blocks_for(tokens) - len(self._owned[slot])
+        need = self.need(slot, tokens)
         if need <= 0:
             return True
         if need > len(self._free) or self.blocks_for(tokens) > \
@@ -132,17 +155,44 @@ class BlockAllocator:
         for _ in range(need):
             b = self._free.pop()
             self._ref[b] = 1
-            self.tables[slot, len(self._owned[slot])] = b
+            self.tables[slot, int(self._base[slot])
+                        + len(self._owned[slot])] = b
             self._owned[slot].append(b)
         self._device_tables = None
         return True
+
+    def drop_before(self, slot: int, first_live_token: int) -> int:
+        """Give back the blocks of ``slot`` that lie wholly before
+        ``first_live_token`` (a sliding window has passed them): their
+        table entries become the null block, and a later :meth:`ensure`
+        starts after them. Returns how many went back to the pool."""
+        first = first_live_token // self.page.block_size
+        freed = 0
+        owned = self._owned[slot]
+        while self._base[slot] < first and owned:
+            b = owned.pop(0)
+            self.tables[slot, int(self._base[slot])] = 0
+            self._base[slot] += 1
+            self._ref[b] -= 1
+            if self._ref[b] == 0:
+                self._free.append(b)
+            freed += 1
+        if not owned and self._base[slot] < first:
+            self._base[slot] = first
+        if freed:
+            self._device_tables = None
+        return freed
+
+    def table_rows(self, slot: int):
+        """What a prefill of ``slot`` is handed."""
+        return self.tables[slot]
 
     def adopt(self, slot: int, blocks: List[int]) -> None:
         """Alias already-populated shared blocks (a cached prefix) into
         the next table positions of ``slot``. Each block's refcount is
         bumped; the slot releases them like its own, but the pool only
         reclaims a block when every reference is gone."""
-        base = len(self._owned[slot])
+        base = int(self._base[slot]) + len(self._owned[slot])
         if base + len(blocks) > self.page.max_blocks_per_seq:
             raise ValueError("adopt exceeds max_blocks_per_seq")
         for i, b in enumerate(blocks):
@@ -190,6 +240,7 @@ class BlockAllocator:
             if self._ref[b] == 0:
                 self._free.append(b)
         self._owned[slot] = []
+        self._base[slot] = 0
         self.tables[slot, :] = 0
         self._device_tables = None
 
@@ -219,6 +270,106 @@ class BlockAllocator:
         if self._device_tables is None:
             self._device_tables = jnp.asarray(self.tables)
         return self._device_tables
+
+
+class KVStateManager:
+    """Several kinds of KV state behind one allocator interface: a model
+    whose layers keep different state (full attention: the whole
+    sequence; sliding window: the blocks the window still touches) has
+    one pool, one :class:`BlockAllocator` and one block table a slot for
+    each kind. The engine drives it as it drives a single allocator;
+    a slot is admitted, grown, trimmed, preempted and released in every
+    kind together.
+
+    ``kinds``: name -> (PagedConfig, window). ``window`` None keeps the
+    whole sequence; a window of w keeps the blocks that positions
+    ``(tokens - 1 - w, tokens - 1]`` touch, gives the others back in
+    :meth:`trim`, and needs no growth headroom (a slot never holds more
+    than ``blocks_in_window`` of them)."""
+
+    def __init__(self, kinds: Dict[str, Tuple[PagedConfig, Optional[int]]],
+                 num_slots: int):
+        self.kinds = {name: BlockAllocator(page, num_slots)
+                      for name, (page, _) in kinds.items()}
+        self.windows = {name: w for name, (_, w) in kinds.items()}
+
+    def _first_live(self, name: str, tokens: int) -> int:
+        w = self.windows[name]
+        return 0 if w is None else max(0, tokens - w)
+
+    def _bounded(self, name: str, tokens: int) -> int:
+        """Blocks of this kind a sequence of ``tokens`` holds."""
+        a = self.kinds[name]
+        return (a.blocks_for(tokens)
+                - self._first_live(name, tokens) // a.page.block_size)
+
+    def fits(self, tokens: int) -> bool:
+        return all(
+            a.blocks_for(tokens) <= a.page.max_blocks_per_seq
+            and self._bounded(n, tokens) <= a.page.num_blocks - 1
+            for n, a in self.kinds.items())
+
+    def lacking(self, tokens: int, shared: int = 0, headroom: int = 0
+                ) -> int:
+        short = 0
+        for n, a in self.kinds.items():
+            grows = self.windows[n] is None
+            short = max(short, self._bounded(n, tokens)
+                        + (headroom if grows else 0) - a.free_blocks())
+        return max(0, short)
+
+    def free_blocks(self) -> int:
+        return min(a.free_blocks() for a in self.kinds.values())
+
+    def ensure(self, slot: int, tokens: int) -> bool:
+        """Every kind covers ``tokens`` for ``slot``, or none changes. A
+        windowed kind starts at the first block its window touches."""
+        for n, a in self.kinds.items():
+            a.drop_before(slot, self._first_live(n, tokens))
+        if any(a.need(slot, tokens) > a.free_blocks()
+               or a.blocks_for(tokens) > a.page.max_blocks_per_seq
+               for a in self.kinds.values()):
+            return False
+        for a in self.kinds.values():
+            a.ensure(slot, tokens)
+        return True
+
+    def trim(self, slot: int, tokens: int) -> int:
+        """Give back what the windows have passed once ``slot`` caches
+        ``tokens`` tokens. Returns the blocks freed."""
+        freed = 0
+        for n, a in self.kinds.items():
+            w = self.windows[n]
+            # called for every running slot on every engine turn: look at
+            # the table only when the window has passed a block's end
+            if w is not None and \
+                    (tokens - w) // a.page.block_size > a._base[slot]:
+                freed += a.drop_before(slot, tokens - w)
+        return freed
+
+    def release(self, slot: int) -> None:
+        for a in self.kinds.values():
+            a.release(slot)
+
+    def check_invariants(self) -> None:
+        for a in self.kinds.values():
+            a.check_invariants()
+
+    def table_rows(self, slot: int):
+        return {n: a.tables[slot] for n, a in self.kinds.items()}
+
+    def device_tables(self):
+        return {n: a.device_tables() for n, a in self.kinds.items()}
+
+    def pools(self, slot_lengths=()) -> Dict[str, dict]:
+        """Blocks of each kind, for ``stats()``, and the tokens a decode
+        step reads there when the running slots cache ``slot_lengths``."""
+        return {n: {"blocks_total": a.page.num_blocks - 1,
+                    "blocks_free": a.free_blocks(),
+                    "block_size": a.page.block_size,
+                    "live_tokens": sum(
+                        t - self._first_live(n, t) for t in slot_lengths)}
+                for n, a in self.kinds.items()}
 
 
 def _attend_paged(q, k_pool, v_pool, layer, tables, lengths, scale):

@@ -45,6 +45,78 @@ def mha_reference(q, k, v, causal: bool = True, scale: Optional[float] = None):
     return jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32)).astype(q.dtype)
 
 
+def _sink_softmax(s, sink):
+    """Softmax over the last axis of ``s`` (..., H-major as given) with an
+    optional per-head sink logit in the denominator only: the sink takes
+    probability mass and adds no value. ``sink`` broadcasts against
+    ``s[..., 0]``."""
+    m = jnp.max(s, axis=-1, keepdims=True)
+    if sink is not None:
+        m = jnp.maximum(m, sink[..., None])
+    p = jnp.exp(s - m)
+    den = jnp.sum(p, axis=-1, keepdims=True)
+    if sink is not None:
+        den = den + jnp.exp(sink[..., None] - m)
+    return p / den
+
+
+def hybrid_attention_reference(q, k, v, *, scale: float,
+                               window: Optional[int] = None, sink=None):
+    """Causal attention in XLA for one layer of a model that mixes full
+    and sliding-window layers: q (B, S, H, Dk), k (B, S, KV, Dk), v
+    (B, S, KV, Dv) with ``Dv`` free of ``Dk``; -> (B, S, H, Dv) in
+    q.dtype, accumulated in float32.
+
+    ``window`` w: query i attends keys j with ``i - w < j <= i``. The
+    scores are then computed in bands: queries in blocks of w against
+    their own key block and the one before, so key blocks wholly outside
+    the window are never read and the temporaries are (S, 2w), not
+    (S, S). ``sink`` (H,) float32: a learned per-head logit added to the
+    softmax's denominator."""
+    B, S, H, Dk = q.shape
+    KV, Dv = k.shape[2], v.shape[-1]
+    g = H // KV
+    qf = q.astype(jnp.float32).reshape(B, S, KV, g, Dk)
+    kf, vf = k.astype(jnp.float32), v.astype(jnp.float32)
+    sk = None if sink is None else sink.astype(jnp.float32).reshape(KV, g)
+    if window is None or window >= S:
+        s = jnp.einsum("bqkgd,bskd->bkgqs", qf, kf) * scale
+        row = jnp.arange(S)[:, None]
+        col = jnp.arange(S)[None, :]
+        ok = row >= col
+        if window is not None:
+            ok &= row - col < window
+        s = jnp.where(ok, s, NEG_INF)
+        p = _sink_softmax(s, None if sk is None else sk[None, :, :, None])
+        out = jnp.einsum("bkgqs,bskd->bqkgd", p, vf)
+        return out.reshape(B, S, H, Dv).astype(q.dtype)
+    w = window
+    nb = -(-S // w)
+    pad = nb * w - S
+    if pad:
+        qf = jnp.pad(qf, ((0, 0), (0, pad), (0, 0), (0, 0), (0, 0)))
+        kf = jnp.pad(kf, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        vf = jnp.pad(vf, ((0, 0), (0, pad), (0, 0), (0, 0)))
+    qb = qf.reshape(B, nb, w, KV, g, Dk)
+
+    def band(x):                       # (B, nb*w, KV, D) -> (B, nb, 2w, ...)
+        xb = x.reshape(B, nb, w, KV, x.shape[-1])
+        prev = jnp.pad(xb, ((0, 0), (1, 0), (0, 0), (0, 0), (0, 0)))[:, :-1]
+        return jnp.concatenate([prev, xb], axis=2)
+
+    s = jnp.einsum("bnqkgd,bnskd->bnkgqs", qb, band(kf)) * scale
+    row = jnp.arange(w)[:, None] + w          # position in the band
+    col = jnp.arange(2 * w)[None, :]
+    ok = (row >= col) & (row - col < w)
+    first = (jnp.arange(nb) == 0)[:, None, None] & (col < w)[None]
+    ok = ok[None] & ~first                    # block 0 has no block before
+    s = jnp.where(ok[None, :, None, None], s, NEG_INF)
+    p = _sink_softmax(
+        s, None if sk is None else sk[None, None, :, :, None])
+    out = jnp.einsum("bnkgqs,bnskd->bnqkgd", p, band(vf))
+    return out.reshape(B, nb * w, H, Dv)[:, :S].astype(q.dtype)
+
+
 def _fwd_xla(q, k, v, causal, scale):
     """Fused full-matrix forward returning (out, lse); (B, H, S, D) layout.
 

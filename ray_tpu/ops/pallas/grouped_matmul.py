@@ -1,0 +1,106 @@
+"""Grouped (ragged) matrix product for an expert layer (Pallas TPU):
+rows sorted by expert, each tile of ``tm`` rows belonging to ONE expert,
+against that expert's matrix out of a stacked (experts, K, N) tensor.
+
+    lhs         (M, K)      rows in tiles of tm; a tile holds rows of one
+                            group, padded with zero rows
+    rhs         (G, K, N)   one matrix a group
+    tile_group  (M / tm,)   int32, the group of each tile
+    n_active    ()          int32: the leading tiles that hold rows
+    -> out      (M, N)      rows of tiles at or past ``n_active`` are NOT
+                            written (the caller masks them)
+
+``M`` is static and sized for the worst case (every row routed here, no
+capacity, nothing dropped); a step usually fills a small part of it.
+``tile_group`` and ``n_active`` ride as scalar prefetch. The grid is
+(N / tn, M / tm) with the row tiles innermost: consecutive tiles of one
+expert name the same (K, tn) block of its matrix, which is then fetched
+once; tiles past ``n_active`` repeat the last active tile's indices and
+skip the product, so they move nothing. An expert that no row was routed
+to has no tile: its weights are never read. In a decode step the product
+is bound by the bytes of the experts hit, not by operations.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+NAME = "grouped_expert_matmul"
+_RHS_BLOCK_BYTES = 4 << 20
+
+
+def _kernel(group_ref, active_ref, lhs_ref, rhs_ref, o_ref):
+    del group_ref
+
+    @pl.when(pl.program_id(1) < active_ref[0])
+    def _():
+        o_ref[...] = jnp.dot(
+            lhs_ref[...], rhs_ref[...],
+            preferred_element_type=jnp.float32).astype(o_ref.dtype)
+
+
+def _tn(K: int, N: int, itemsize: int) -> int:
+    """Widest multiple of 128 that divides N with a (K, tn) block of at
+    most 4 MiB (double-buffered: half the default scoped VMEM)."""
+    tn = N
+    while tn > 128 and (tn * K * itemsize > _RHS_BLOCK_BYTES or N % tn):
+        tn -= 128
+    return tn if N % tn == 0 else N
+
+
+def grouped_matmul(lhs, rhs, tile_group, n_active, *, tm: int,
+                   out_dtype=None, name: str = NAME,
+                   interpret: bool = False):
+    """``name`` is the custom call's instruction name: a prefill's
+    products carry another than a decode step's, so a trace tells them
+    apart."""
+    M, K = lhs.shape
+    G, _, N = rhs.shape
+    if M % tm:
+        raise ValueError(f"rows {M} not a multiple of the tile {tm}")
+    out_dtype = out_dtype or lhs.dtype
+    tn = _tn(K, N, rhs.dtype.itemsize)
+
+    def tile(i, active_ref):
+        return jnp.minimum(i, jnp.maximum(active_ref[0] - 1, 0))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(N // tn, M // tm),
+        in_specs=[
+            pl.BlockSpec((tm, K), lambda n, i, g, a: (tile(i, a), 0)),
+            pl.BlockSpec((None, K, tn),
+                         lambda n, i, g, a: (g[tile(i, a)], 0, n)),
+        ],
+        out_specs=pl.BlockSpec((tm, tn), lambda n, i, g, a: (tile(i, a), n)),
+    )
+    with jax.named_scope(name):
+        return pl.pallas_call(
+            _kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary")),
+            interpret=interpret,
+            name=name,
+        )(tile_group.astype(jnp.int32),
+          jnp.asarray(n_active, jnp.int32).reshape(1), lhs, rhs)
+
+
+def grouped_matmul_reference(lhs, rhs, tile_group, n_active, *, tm: int,
+                             out_dtype=None, name: str = NAME):
+    """XLA path (and the kernel's oracle): each tile against a gathered
+    copy of its group's matrix; inactive tiles give zeros."""
+    M, K = lhs.shape
+    out_dtype = out_dtype or lhs.dtype
+    tiles = lhs.reshape(M // tm, tm, K)
+    out = jnp.einsum("itk,ikn->itn", tiles, rhs[tile_group],
+                     preferred_element_type=jnp.float32)
+    live = jnp.arange(M // tm) < n_active
+    return jnp.where(live[:, None, None], out, 0.0).reshape(
+        M, -1).astype(out_dtype)

@@ -98,6 +98,12 @@ class LLMEngine:
     (vLLM-style recompute preemption: its blocks are freed and it
     re-queues with prompt+generated-so-far as the new prompt).
     ``kv_cache="slot"`` keeps the flat per-slot ``max_seq`` reservation.
+
+    ``config`` is a model's config object. The engine takes the model
+    through :func:`ray_tpu.models.serving.serving_model`: its cache(s),
+    its prefill and decode programs and its allocator (one for each kind
+    of KV state it keeps). A mechanism the model has no builders for
+    raises ``ValueError`` here, naming it.
     """
 
     def __init__(self, config=None, params=None, *, num_slots: int = 8,
@@ -123,7 +129,22 @@ class LLMEngine:
 
         from ray_tpu.common.compile_cache import compile_cache_counts
 
+        from ray_tpu.models.serving import serving_model
+
         self.config = config or llama.CONFIGS[model]
+        self.model = serving_model(self.config)
+        asked = {"slot_cache": kv_cache == "slot",
+                 "speculation": speculation is not None,
+                 "prefix_cache": (
+                     prefix_cache not in (None, "off")
+                     or prefix_cache_size > 0
+                     or (prefix_cache_bytes or 0) > 0
+                     or (prefix_cache is None and os.environ.get(
+                         "RT_prefix_cache") not in (None, "off"))),
+                 "prefill_chunk": prefill_chunk is not None}
+        for mechanism in self.model.lacks:
+            if asked.get(mechanism):
+                self._refuse(mechanism)
         # the device this replica's process holds, as jax reports it —
         # stats() carries it so a driver that stays off jax can tell
         from ray_tpu.common import tpu_detect
@@ -134,7 +155,7 @@ class LLMEngine:
                         "granted_chips": tpu_detect.granted_chips}
         self._compile_cache = compile_cache_counts()
         if params is None:
-            params = llama.init_params(self.config, jax.random.key(seed))
+            params = self.model.init_params(jax.random.key(seed))
         self.params = params
         self.num_slots = num_slots
         self.max_seq = max_seq or self.config.max_seq
@@ -147,25 +168,19 @@ class LLMEngine:
             raise ValueError(
                 f"kv_block_size={kv_block_size} must divide 2048")
         self.kv_cache = kv_cache
+        self._counter_names = ()
         if kv_cache == "paged":
-            from ray_tpu.models.paged_cache import (
-                BlockAllocator, PagedConfig, init_paged_cache,
-                make_paged_decode_step, make_paged_inject,
-                make_paged_prefill)
-
-            pool_tokens = kv_pool_tokens or num_slots * self.max_seq
-            num_blocks = 1 + -(-pool_tokens // kv_block_size)  # +null
-            self._page = PagedConfig(num_blocks=num_blocks,
-                                     block_size=kv_block_size,
-                                     max_seq=self.max_seq)
-            self._alloc = BlockAllocator(self._page, num_slots)
-            self._cache = init_paged_cache(self.config, self._page,
-                                           num_slots)
-            self._decode = make_paged_decode_step(params, self.config,
-                                                  self._page)
-            self._prefill = make_paged_prefill(params, self.config,
-                                               self._page)
-            self._inject = make_paged_inject(self.config, self._page)
+            programs = self.model.paged(
+                params, num_slots=num_slots, max_seq=self.max_seq,
+                block_size=kv_block_size,
+                pool_tokens=kv_pool_tokens or num_slots * self.max_seq)
+            self._page = programs.page
+            self._alloc = programs.alloc
+            self._cache = programs.cache
+            self._decode = programs.decode
+            self._prefill = programs.prefill
+            self._inject = programs.inject
+            self._counter_names = programs.counters
         else:
             self._cache = init_cache(self.config, num_slots, self.max_seq)
             self._decode = make_decode_step(params, self.config)
@@ -234,6 +249,15 @@ class LLMEngine:
         self._spec_proposed = 0
         self._spec_accepted = 0
         self._key = jax.random.key(seed)
+        # a turn whose every sampled slot is greedy takes its tokens on
+        # the device: the fetch then moves 4 bytes a slot, not a row of
+        # the vocabulary, and the host's argmax over it goes
+        import jax.numpy as jnp
+
+        def greedy_ids(logits):
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+        self._greedy_ids = jax.jit(greedy_ids)
         # Prefix reuse across requests, OFF by default. Two modes behind
         # one knob (prefix_cache / RT_prefix_cache env):
         #   "radix"  — default when enabled on a paged engine: the radix
@@ -318,6 +342,12 @@ class LLMEngine:
         self._steps = 0
         self._tokens_generated = 0
         self._preemptions = 0
+        # the model's own counters (an expert layer's loads), summed
+        # over every decode step, and over every prefill, since the
+        # engine started
+        self._model_counters = np.zeros(len(self._counter_names))
+        self._model_counters_prefill = np.zeros(len(self._counter_names))
+        self._window_blocks_freed = 0
         # the loop's named phases (spans in a profiler capture, counters
         # in stats()) and the last finished requests' lifecycle records
         self._phases = profiling.Phases("rt.engine.")
@@ -329,6 +359,13 @@ class LLMEngine:
         self._thread.start()
 
     # ------------------------------------------------------------- public
+    def _refuse(self, mechanism: str):
+        from ray_tpu.models.serving import MECHANISMS
+
+        raise ValueError(
+            f"{type(self.config).__name__} is not served with "
+            f"{MECHANISMS[mechanism]}: the model has no builders for it")
+
     def _check_vocab(self, prompt: List[int]) -> None:
         """Reject out-of-vocab prompt token ids at submission. On device
         the embed gather would clamp silently, but host-side speculation
@@ -408,6 +445,8 @@ class LLMEngine:
         prompt position's logits."""
         import uuid
 
+        if "kv_transfer" in self.model.lacks:
+            self._refuse("kv_transfer")
         if len(prompt) == 0:
             raise ValueError("empty prompt")
         if len(prompt) + max_tokens > self.max_seq:
@@ -477,6 +516,18 @@ class LLMEngine:
                 kv_blocks_free=self._alloc.free_blocks(),
                 kv_blocks_total=self._page.num_blocks - 1,
                 kv_block_size=self._page.block_size)
+            if hasattr(self._alloc, "pools"):
+                # one row for each kind of KV state, with the tokens a
+                # decode step reads there now
+                out["kv_pools"] = self._alloc.pools(
+                    [int(self._slot_len[s]) for s in range(self.num_slots)
+                     if self._slots[s] is not None])
+                out["window_blocks_freed"] = self._window_blocks_freed
+        if self._counter_names:
+            out["model_counters"] = dict(zip(
+                self._counter_names, self._model_counters.tolist()))
+            out["model_counters_prefill"] = dict(zip(
+                self._counter_names, self._model_counters_prefill.tolist()))
         pc = {"mode": self._prefix_mode,
               "match_faults": self._prefix_match_faults,
               "insert_faults": self._prefix_insert_faults,
@@ -703,9 +754,7 @@ class LLMEngine:
                 # ensure plen + 1: this iteration's decode step writes
                 # the first generated token at position plen, which
                 # lives in a NEW block when the prompt is block-aligned.
-                total = self._alloc.blocks_for(plen + 1)
-                if total > min(self._page.num_blocks - 1,
-                               self._page.max_blocks_per_seq):
+                if not self._alloc.fits(plen + 1):
                     # can never fit, even with the pool idle: fail it
                     # rather than deadlock the queue
                     del self._waiting[idx]
@@ -720,17 +769,17 @@ class LLMEngine:
                 # watermark: beyond this request's blocks, keep one
                 # growth block of headroom per already-active slot, or
                 # admission starves running requests into preemption
-                need = (total - len(shared)
-                        + sum(s is not None for s in self._slots))
+                headroom = sum(s is not None for s in self._slots)
                 if shared:
                     # pin the matched blocks FIRST: the pool-pressure
                     # eviction below must never reclaim them
                     self._alloc.adopt(slot, shared)
-                if self._alloc.free_blocks() < need and \
-                        self._radix is not None:
-                    self._radix.evict_for(need - self._alloc.free_blocks())
-                if self._alloc.free_blocks() < need or not \
-                        self._alloc.ensure(slot, plen + 1):
+                lack = self._alloc.lacking(plen + 1, len(shared), headroom)
+                if lack and self._radix is not None:
+                    self._radix.evict_for(lack)
+                    lack = self._alloc.lacking(plen + 1, len(shared),
+                                               headroom)
+                if lack or not self._alloc.ensure(slot, plen + 1):
                     self._alloc.release(slot)  # un-pin the match
                     return  # picked request waits for blocks (no bypass)
                 if match is not None and match.cow is not None:
@@ -802,12 +851,12 @@ class LLMEngine:
                                   slot=slot):
                     if self.kv_cache == "paged":
                         self._cache, logits = self._prefill(
-                            self._cache, self._alloc.tables[slot],
+                            self._cache, self._alloc.table_rows(slot),
                             jnp.asarray(tokens), plen, slot)
                     else:
                         self._cache, logits = self._prefill(
                             self._cache, jnp.asarray(tokens), plen, slot)
-                    logits_np = np.asarray(logits)
+                    logits_np = self._fetch(logits, prefill=True)
                 self._legacy_insert(key, logits_np, slot, plen, resumed)
                 if self._radix is not None:
                     self._radix_insert(req, full_prompt, slot)
@@ -976,6 +1025,22 @@ class LLMEngine:
             self._proposer.admit(slot, toks)
         self._maybe_finish(slot)
 
+    def _fetch(self, logits, prefill: bool = False) -> np.ndarray:
+        """The logits on the host and, in the same transfer, the model's
+        counters of the program that made them (no further sync: they
+        are outputs of that program)."""
+        if not self._counter_names:
+            return np.asarray(logits)
+        import jax
+
+        logits_np, counters = jax.device_get(
+            (logits, self._cache["counters"]))
+        if prefill:
+            self._model_counters_prefill += counters
+        else:
+            self._model_counters += counters
+        return logits_np
+
     def _sample(self, logits: np.ndarray, temperature: float) -> np.ndarray:
         if temperature <= 0.0:
             return logits.argmax(-1).astype(np.int32)
@@ -1005,12 +1070,15 @@ class LLMEngine:
                 seq = (req.prompt + req.output)[:int(self._slot_len[slot])]
                 self._radix_insert(req, seq, slot)
             self._record_finish(req, "cancelled" if req.cancelled else "ok")
-            req.done.set()
             self._slots[slot] = None
-            if self._proposer is not None:
-                self._proposer.release(slot)
-            if self.kv_cache == "paged":
-                self._alloc.release(slot)
+            try:
+                if self._proposer is not None:
+                    self._proposer.release(slot)
+                if self.kv_cache == "paged":
+                    self._alloc.release(slot)
+            finally:
+                # last: a caller that wakes finds its slot and blocks back
+                req.done.set()
 
     def _record_finish(self, req: _Request, status: str) -> None:
         """One row for a request that finished, failed or was cancelled,
@@ -1058,8 +1126,11 @@ class LLMEngine:
         """Before a decode step each active slot needs its next token's
         block. On pool exhaustion, preempt the youngest other active
         slot; a slot alone in the pool preempts itself."""
+        bs = self._page.block_size
         for slot in range(self.num_slots):
-            if self._slots[slot] is None:
+            # the next token starts a block only at a block's multiple;
+            # otherwise the blocks that cover the cached tokens cover it
+            if self._slots[slot] is None or self._slot_len[slot] % bs:
                 continue
             while not self._alloc.ensure(slot, int(self._slot_len[slot]) + 1):
                 # pool pressure order: evict cold cached prefixes (LRU,
@@ -1135,6 +1206,14 @@ class LLMEngine:
         # head (paying its prefill), then immediately preempts it as the
         # youngest slot to feed an older slot's growth — prefill thrash
         if self.kv_cache == "paged":
+            if hasattr(self._alloc, "trim"):
+                # before growing: what a window has passed goes back to
+                # its pool, so the block the next token needs is there
+                with phase("window_free"):
+                    for slot in range(self.num_slots):
+                        if self._slots[slot] is not None:
+                            self._window_blocks_freed += self._alloc.trim(
+                                slot, int(self._slot_len[slot]) + 1)
             with phase("grow"):
                 self._grow_active_slots()
         with phase("admit",
@@ -1170,8 +1249,12 @@ class LLMEngine:
                 self._cache, logits = self._decode(
                     self._cache, jnp.asarray(self._last_token),
                     jnp.asarray(active))
+            greedy = all(self._slots[s].temperature <= 0.0
+                         for s in range(self.num_slots) if active[s])
+            if greedy:
+                logits = self._greedy_ids(logits)
         with phase("logits_fetch"):
-            logits_np = np.asarray(logits)
+            logits_np = self._fetch(logits)
         self._steps += 1
         # ONE span around the slots' loop, never one per slot
         with phase("sample", active=int(active.sum())):
@@ -1181,8 +1264,8 @@ class LLMEngine:
                     # mid-chunked-prefill slots were masked inactive in
                     # the decode; their logits row is garbage — no sampling
                     continue
-                tok = self._sample(logits_np[slot][None],
-                                   req.temperature)[0]
+                tok = (logits_np[slot] if greedy else self._sample(
+                    logits_np[slot][None], req.temperature)[0])
                 req.output.append(int(tok))
                 self._last_token[slot] = tok
                 self._slot_len[slot] += 1

@@ -95,6 +95,66 @@ class TestConfig:
         with pytest.raises(KeyError):
             c.get("nope")
 
+    @pytest.mark.parametrize("reader", ["finalizer", "other_thread"])
+    def test_a_first_read_holds_no_lock(self, reader):
+        """The collector may run while ``get`` reads a flag for the first
+        time (the env lookup allocates), and what it finalizes reads a flag
+        too: an ``ObjectRef`` frees through
+        ``get("lineage_pinning_enabled")``, on the same thread and under
+        the owner's other locks. That read has to return, and so has a
+        first read on another thread that holds such a lock meanwhile."""
+        import gc
+        import threading
+
+        c = Config()
+        c.declare("inner", int, 7)
+        seen = []
+
+        class Ref:
+            def __init__(self):
+                self.cycle = self            # only the collector frees it
+
+            def __del__(self):
+                seen.append(c.get("inner"))
+
+        def parse_inside_get(value):
+            if reader == "finalizer":
+                Ref()
+                gc.collect()
+            else:
+                t = threading.Thread(
+                    target=lambda: seen.append(c.get("inner")), daemon=True)
+                t.start()
+                t.join(5.0)
+                assert not t.is_alive(), "a read waits for another read"
+            return int(value)
+
+        c.declare("outer", parse_inside_get, 0)
+        c.initialize({"outer": 3})
+        got = []
+        t = threading.Thread(target=lambda: got.append(c.get("outer")),
+                             daemon=True)
+        t.start()
+        t.join(10.0)
+        assert not t.is_alive(), "a read waits for the read it runs inside"
+        assert got == [3] and seen == [7]
+
+    def test_a_write_is_seen_by_the_next_read(self):
+        c = Config()
+        c.declare("foo_ms", int, 100)
+        assert c.get("foo_ms") == 100
+        c.set_system_config_value("foo_ms", 5)
+        assert c.get("foo_ms") == 5
+        c.initialize({})
+        assert c.get("foo_ms") == 100
+        os.environ["RT_foo_ms"] = "9"
+        try:
+            assert c.get("foo_ms") == 100       # cached until reset
+            c.reset_cache()
+            assert c.get("foo_ms") == 9
+        finally:
+            del os.environ["RT_foo_ms"]
+
 
 class TestResources:
     def test_fractional_exact(self):

@@ -424,3 +424,27 @@ class TestPagedEngine:
             assert len(out) == 4
         finally:
             eng.shutdown()
+
+    def test_a_finished_request_has_given_its_blocks_back(self):
+        """``generate`` returns only once the slot's blocks are free again:
+        the engine announces a request done after the release, not before
+        (a release made slow here used to lose that race every time)."""
+        import time
+
+        eng = self._engine(num_slots=2, max_seq=128, kv_cache="paged",
+                           kv_block_size=16)
+        try:
+            release = eng._alloc.release
+
+            def slow_release(slot):
+                time.sleep(0.3)
+                release(slot)
+
+            eng._alloc.release = slow_release
+            whole = eng.stats()["kv_blocks_free"]
+            assert len(eng.generate([4, 5, 6], max_tokens=4,
+                                    timeout_s=120)) == 4
+            st = eng.stats()
+            assert st["kv_blocks_free"] == whole and st["active_slots"] == 0
+        finally:
+            eng.shutdown()
