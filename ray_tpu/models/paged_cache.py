@@ -372,16 +372,29 @@ class KVStateManager:
                 for n, a in self.kinds.items()}
 
 
-def _attend_paged(q, k_pool, v_pool, layer, tables, lengths, scale):
+def _decode_work(lengths, page: PagedConfig):
+    """The kernel's work list for a decode step's lengths, built before
+    the layer scan so that every layer shares it (None off the TPU, where
+    the oracle attends)."""
+    if not on_tpu():
+        return None
+    from ray_tpu.ops.pallas.paged_decode_attention import decode_work_list
+
+    return decode_work_list(lengths, page.block_size,
+                            page.max_blocks_per_seq)
+
+
+def _attend_paged(q, k_pool, v_pool, layer, tables, lengths, scale, work):
     """q (B,1,H,D); pools (L,NB,bs,KV,D), whole; layer () i32; tables
-    (B,MBS); lengths (B,). The pool is handed over as the layer scan
-    carries it: a per-layer slice here would be a copy of that layer."""
+    (B,MBS); lengths (B,); work from :func:`_decode_work` of the same
+    lengths. The pool is handed over as the layer scan carries it: a
+    per-layer slice here would be a copy of that layer."""
     if on_tpu():
         from ray_tpu.ops.pallas.paged_decode_attention import (
             paged_decode_attention)
 
         return paged_decode_attention(q, k_pool, v_pool, layer, tables,
-                                      lengths, scale=scale)
+                                      lengths, scale=scale, work=work)
     from ray_tpu.ops.pallas.paged_decode_attention import (
         paged_attention_reference)
 
@@ -520,6 +533,13 @@ def make_paged_decode_step(params: Params, config: LlamaConfig,
     already cover position ``length`` (the engine allocates between
     steps); inactive slots write into the null block.
 
+    The kernel is told which slots run: an inactive slot attends with
+    length 0 whatever ``cache["length"]`` holds for it (nothing resets
+    that when a slot is released, and a slot in a chunked prefill has one
+    too), so it costs the kernel no step, and its attention output is
+    zeros. Its row of the logits means nothing; the engine reads the rows
+    of active slots only (``LLMEngine._loop_once``).
+
     The pool is carried whole through the layer scan and updated in
     place (:func:`_scan_layers`): per layer a step writes one row for
     each slot and the kernel reads the blocks the tables name, out of
@@ -538,7 +558,8 @@ def make_paged_decode_step(params: Params, config: LlamaConfig,
         blk = jnp.where(active, blk, 0)                            # null
         off = lengths % bs
         positions = lengths[:, None]
-        att_len = lengths + 1
+        att_len = jnp.where(active, lengths + 1, 0)
+        work = _decode_work(att_len, page)
 
         def body(carry, scanned):
             x, kc, vc = carry                 # pools (L, NB, bs, KV, D)
@@ -552,7 +573,7 @@ def make_paged_decode_step(params: Params, config: LlamaConfig,
             kc = kc.at[l, blk, off].set(k[:, 0].astype(kc.dtype))
             vc = vc.at[l, blk, off].set(v[:, 0].astype(vc.dtype))
             out = _attend_paged(q, kc, vc, l, tables, att_len,
-                                c.head_dim ** -0.5)
+                                c.head_dim ** -0.5, work)
             x = x + jnp.einsum("bshd,hde->bse", out,
                                layer["wo"].astype(x.dtype))
             h2 = rmsnorm(x, layer["mlp_norm"], c.norm_eps)

@@ -7,18 +7,25 @@ Capability bar: vLLM's paged attention, which the reference delegates to
 The TPU shape of the idea: the pool is one static
 (layers, num_blocks, bs, KV, D) array for the whole model; each slot's
 logical cache in one layer is the sequence of that layer's pool blocks
-named by its block-table row. The layer index, the block tables and the
-lengths ride as SCALAR-PREFETCH operands, so the kernel's BlockSpec index
-maps translate (layer, slot, logical block) → physical pool block at
-grid-issue time — neither a layer's slice of the pool nor a contiguous
-per-slot cache is ever materialized in HBM. The caller (the decode step's
-layer scan) hands over the pool it carries, whole, and the kernel reads
-only the blocks the tables name.
+named by its block-table row. The layer index, the block tables, the
+lengths and the work list ride as SCALAR-PREFETCH operands, so the
+kernel's BlockSpec index maps translate (layer, slot, logical block) →
+physical pool block at grid-issue time — neither a layer's slice of the
+pool nor a contiguous per-slot cache is ever materialized in HBM. The
+caller (the decode step's layer scan) hands over the pool it carries,
+whole, and the kernel reads only the blocks the tables name.
 
-GQA is an unrolled static loop over kv heads inside each program (same
-rationale as ``decode_attention.py``: the KV axis is too small/unaligned
-to be a grid dimension, and looping in-program reads each cache block
-exactly once).
+The grid is the WORK LIST (:func:`decode_work_list`): one step for each
+(slot, logical block) pair that holds cached tokens, in slot order, and
+none for any other. Steps, copies and arithmetic are in proportion to
+``sum(ceil(length / bs))``, not to slots x max_seq / bs: a slot of length
+0 costs nothing, and its output row is zeros. The grid's bound is the
+list's length, a traced scalar; a slot's first pair resets the
+accumulators and its last pair writes the output row.
+
+GQA: a step multiplies all the heads' queries with the block's rows of
+every kv head at once and masks each head to its own kv head's rows; the
+block is read once and never re-laid-out head by head.
 
 Layout contract:
     q        (B, 1, H, D)    new-token queries
@@ -28,9 +35,11 @@ Layout contract:
                              (scalar prefetch; may be traced)
     tables   (B, MBS) int32  physical block id per logical block; entries
                              past the valid prefix MUST name a real block
-                             (conventionally the reserved null block 0) —
-                             they are masked out, but are still prefetched
-    lengths  (B,) int32      valid tokens per slot (incl. the new token)
+                             (conventionally the reserved null block 0):
+                             no step computes on them, but the one-step
+                             lookahead may name one
+    lengths  (B,) int32      valid tokens per slot (incl. the new token);
+                             0 for a slot that is not running
 
 Online-softmax recurrence identical to ``decode_attention.py``.
 """
@@ -49,13 +58,37 @@ NEG_INF = -1e30
 _LANES = 128
 
 
-def _paged_kernel(layer_ref, tables_ref, len_ref, q_ref, k_ref, v_ref,
-                  o_ref, acc_ref, m_ref, l_ref,
-                  *, scale: float, block_s: int, num_blocks: int,
+def decode_work_list(lengths, block_s: int, max_blocks: int):
+    """The (slot, logical block) pairs that hold cached tokens, in slot
+    order: ``{(s, j): j < ceil(lengths[s] / block_s)}``. Returns
+    ``(n_work () i32, work_slot (B * max_blocks + 1,) i32, work_block
+    (same))``. Entries from ``n_work`` on repeat the last pair: no step
+    computes on them, but the pipeline looks one step past the last (with
+    every table full too, hence the + 1; without it that run halted the
+    chip) and has to find valid indices there. A few fused integer
+    operations that depend on the lengths alone: a caller with many layers
+    builds the list once a decode step, not once a layer."""
+    B = lengths.shape[0]
+    nblk = jnp.clip((lengths.astype(jnp.int32) + block_s - 1) // block_s,
+                    0, max_blocks)
+    ends = jnp.cumsum(nblk)
+    n_work = ends[-1]
+    i = jnp.minimum(jnp.arange(B * max_blocks + 1, dtype=jnp.int32),
+                    jnp.maximum(n_work - 1, 0))
+    # pair i belongs to the first slot whose pairs end past i
+    slot = jnp.minimum(jnp.sum(i[:, None] >= ends[None, :], axis=1,
+                               dtype=jnp.int32), B - 1)
+    return n_work, slot, i - (ends - nblk)[slot]
+
+
+def _paged_kernel(layer_ref, tables_ref, len_ref, slot_ref, block_ref,
+                  q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
+                  *, scale: float, block_s: int, max_blocks: int,
                   num_kv: int, group: int):
-    del layer_ref                        # used by the index maps only
-    b = pl.program_id(0)
-    ib = pl.program_id(1)
+    del layer_ref, tables_ref            # used by the index maps only
+    i = pl.program_id(0)
+    ib = block_ref[i]
+    length = len_ref[slot_ref[i]]
 
     @pl.when(ib == 0)
     def _init():
@@ -63,37 +96,35 @@ def _paged_kernel(layer_ref, tables_ref, len_ref, q_ref, k_ref, v_ref,
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    length = len_ref[b]
+    # The block is (bs * KV, D): row t * KV + j is token t's key of kv head
+    # j, as the pool stores them. Every head meets every row in ONE product
+    # and keeps the rows of its own kv head; the rest are masked like the
+    # tokens past the length, so they add exact zeros to the sums below.
+    # (Slicing a head's (bs, D) keys out of the block instead costs a
+    # relayout of the whole block a head, several times the block's copy.)
+    s = jax.lax.dot_general(
+        q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale      # (H, bs * KV)
+    row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    mine = (col % num_kv == row // group) & \
+        (ib * block_s + col // num_kv < length)
+    s = jnp.where(mine, s, NEG_INF)
 
-    @pl.when(ib * block_s < length)
-    def _compute():
-        for j in range(num_kv):          # static unroll over kv heads
-            lo, hi = j * group, (j + 1) * group
-            q = q_ref[0, lo:hi, :]       # (group, D)
-            k = k_ref[0, :, j, :]        # (bs, D)
-            v = v_ref[0, :, j, :]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale  # (group, bs)
-            col = ib * block_s + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            s = jnp.where(col < length, s, NEG_INF)
+    m_prev = m_ref[:, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    alpha = jnp.exp(m_prev - m_new)
+    l_ref[:] = jnp.broadcast_to(
+        l_ref[:, :1] * alpha + jnp.sum(p, axis=1, keepdims=True),
+        l_ref.shape)
+    acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
+        p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
 
-            m_prev = m_ref[lo:hi, :1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            alpha = jnp.exp(m_prev - m_new)
-            l_ref[lo:hi, :] = jnp.broadcast_to(
-                l_ref[lo:hi, :1] * alpha + jnp.sum(p, axis=1,
-                                                   keepdims=True),
-                (group, _LANES))
-            acc_ref[lo:hi, :] = acc_ref[lo:hi, :] * alpha + \
-                jax.lax.dot_general(
-                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-            m_ref[lo:hi, :] = jnp.broadcast_to(m_new, (group, _LANES))
-
-    @pl.when(ib == num_blocks - 1)
+    # the slot's last pair: the block its length ends in, or the table's end
+    @pl.when(((ib + 1) * block_s >= length) | (ib == max_blocks - 1))
     def _finalize():
         l = l_ref[:, :1]
         l_safe = jnp.where(l == 0.0, 1.0, l)
@@ -101,9 +132,15 @@ def _paged_kernel(layer_ref, tables_ref, len_ref, q_ref, k_ref, v_ref,
 
 
 def paged_decode_attention(q, k_pool, v_pool, layer, tables, lengths, *,
-                           scale: float, interpret: bool = False):
+                           scale: float, interpret: bool = False,
+                           work=None):
     """q (B,1,H,D); k/v_pool (L,NB,bs,KV,D); layer () int32; tables
-    (B,MBS) int32; lengths (B,) int32. Returns (B, 1, H, D) in q.dtype."""
+    (B,MBS) int32; lengths (B,) int32. Returns (B, 1, H, D) in q.dtype;
+    the row of a slot of length 0 is zeros.
+
+    ``work`` is ``decode_work_list(lengths, bs, MBS)`` from a caller that
+    attends many layers over the same lengths and builds the list once
+    (XLA leaves it inside a layer scan's body); built here when absent."""
     B, _, H, D = q.shape
     bs, KV = k_pool.shape[2], k_pool.shape[3]
     MBS = tables.shape[1]
@@ -112,27 +149,34 @@ def paged_decode_attention(q, k_pool, v_pool, layer, tables, lengths, *,
     group = H // KV
 
     qh = q.reshape(B, H, D)
+    lengths = lengths.astype(jnp.int32)
+    n_work, work_slot, work_block = (
+        decode_work_list(lengths, bs, MBS) if work is None else work)
 
     kernel = functools.partial(
-        _paged_kernel, scale=scale, block_s=bs, num_blocks=MBS,
+        _paged_kernel, scale=scale, block_s=bs, max_blocks=MBS,
         num_kv=KV, group=group)
 
-    def kv_ix(b, ib, layer_ref, tables_ref, len_ref):
+    def kv_ix(i, layer_ref, tables_ref, len_ref, slot_ref, block_ref):
         del len_ref
-        return (layer_ref[0], tables_ref[b, ib], 0, 0, 0)
+        return (layer_ref[0], tables_ref[slot_ref[i], block_ref[i]], 0, 0)
 
-    # the layer axis is squeezed out of the block: the body sees the
-    # (1, bs, KV, D) block of one layer, as if the pool had no layer axis
-    kv_spec = pl.BlockSpec((None, 1, bs, KV, D), kv_ix)
+    def slot_ix(i, layer_ref, tables_ref, len_ref, slot_ref, block_ref):
+        del layer_ref, tables_ref, len_ref, block_ref
+        return (slot_ref[i], 0, 0)
+
+    # the layer axis is squeezed out of the block, and a block's tokens and
+    # kv heads are one axis of rows (the same bytes: no copy): the body
+    # sees the (1, bs * KV, D) block of one layer
+    L, NB = k_pool.shape[:2]
+    k_pool = k_pool.reshape(L, NB, bs * KV, D)
+    v_pool = v_pool.reshape(L, NB, bs * KV, D)
+    kv_spec = pl.BlockSpec((None, 1, bs * KV, D), kv_ix)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, MBS),
-        in_specs=[
-            pl.BlockSpec((1, H, D), lambda b, ib, *_: (b, 0, 0)),
-            kv_spec,
-            kv_spec,
-        ],
-        out_specs=pl.BlockSpec((1, H, D), lambda b, ib, *_: (b, 0, 0)),
+        num_scalar_prefetch=5,
+        grid=(n_work,),
+        in_specs=[pl.BlockSpec((1, H, D), slot_ix), kv_spec, kv_spec],
+        out_specs=pl.BlockSpec((1, H, D), slot_ix),
         scratch_shapes=[
             pltpu.VMEM((H, D), jnp.float32),
             pltpu.VMEM((H, _LANES), jnp.float32),
@@ -146,12 +190,14 @@ def paged_decode_attention(q, k_pool, v_pool, layer, tables, lengths, *,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary")),
+                dimension_semantics=("arbitrary",)),
             interpret=interpret,
             name="paged_decode_attention",
         )(jnp.asarray(layer, jnp.int32).reshape(1),
-          tables.astype(jnp.int32), lengths.astype(jnp.int32),
+          tables.astype(jnp.int32), lengths, work_slot, work_block,
           qh, k_pool, v_pool)
+        # no step visits an empty slot, so nothing wrote its row
+        out = jnp.where((lengths > 0)[:, None, None], out, 0)
 
     return out.reshape(B, 1, H, D)
 

@@ -140,21 +140,29 @@ def test_decode_attention_compiles_at_1b_widths(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("block_size", [64, 16])
-def test_paged_decode_attention_compiles_at_1b_widths(one_chip, block_size):
+# (slots, heads, kv heads, head dim, block, table columns, pool layers and
+# blocks): the 1b engine's two block sizes, then the dense serve cells' own
+# (mistral-7b-l16: 32 slots of a 4,096-token max_seq over a 49,152-token pool)
+PAGED_DECODE_SHAPES = {
+    "1b-block64": (8, H, KV, D, 64, 128, CFG.n_layers, 1025),
+    "1b-block16": (8, H, KV, D, 16, 512, CFG.n_layers, 4097),
+    "dense-serve-cells": (32, 32, 8, 128, 64, 64, 16, 769),
+}
+
+
+@pytest.mark.parametrize("shape", PAGED_DECODE_SHAPES)
+def test_paged_decode_attention_compiles_at_1b_widths(one_chip, shape):
     from ray_tpu.ops.pallas.paged_decode_attention import (
         paged_decode_attention)
 
-    slots, seq = 8, 8192
-    nb = 1 + slots * seq // block_size
-    q = _sds((slots, 1, H, D), jnp.bfloat16, one_chip)
-    pool = _sds((CFG.n_layers, nb, block_size, KV, D), jnp.bfloat16,
-                one_chip)
+    slots, h, kv, d, block_size, cols, layers, nb = PAGED_DECODE_SHAPES[shape]
+    q = _sds((slots, 1, h, d), jnp.bfloat16, one_chip)
+    pool = _sds((layers, nb, block_size, kv, d), jnp.bfloat16, one_chip)
     layer = _sds((), jnp.int32, one_chip)
-    tables = _sds((slots, seq // block_size), jnp.int32, one_chip)
+    tables = _sds((slots, cols), jnp.int32, one_chip)
     lens = _sds((slots,), jnp.int32, one_chip)
     fn = jax.jit(lambda q, k, v, l, t, n: paged_decode_attention(
-        q, k, v, l, t, n, scale=D ** -0.5))
+        q, k, v, l, t, n, scale=d ** -0.5))
     compiled = fn.lower(q, pool, pool, layer, tables, lens).compile()
     assert "tpu_custom_call" in compiled.as_text()
 

@@ -121,6 +121,97 @@ class TestKernelVsOracle:
         assert np.abs(outs[0] - outs[1]).max() > 1e-2   # layers differ
 
 
+# lengths of four slots over a table of three blocks, as multiples of the
+# block size ``bs`` plus a rest: (blocks, rest) -> blocks * bs + rest
+RAGGED = {
+    "empty-slot-between-running": [(1, 3), (0, 0), (2, 1), (0, 7)],
+    "length-one": [(0, 1), (1, 1), (0, 0), (2, 1)],
+    "whole-blocks": [(1, 0), (2, 0), (0, 0), (3, 0)],
+    "full-table": [(3, 0), (0, 5), (3, 0), (2, 15)],
+    "every-slot-empty": [(0, 0)] * 4,
+}
+
+
+def _ragged_case(case, bs):
+    """q, a one-layer pool, tables and lengths of a ragged case: each slot
+    owns distinct blocks for its live pairs, the null block past them."""
+    B, H, KV, D, MBS, NB = 4, 4, 2, 16, 3, 14
+    lengths = np.array([n * bs + r for n, r in RAGGED[case]], np.int32)
+    tables = np.zeros((B, MBS), np.int32)
+    ids = iter(np.random.default_rng(3).permutation(np.arange(1, NB)))
+    for s, n in enumerate(lengths):
+        for j in range(-(-int(n) // bs)):
+            tables[s, j] = next(ids)
+    k1, k2, k3 = jax.random.split(jax.random.key(5), 3)
+    q = jax.random.normal(k1, (B, 1, H, D), jnp.float32)
+    kp = jax.random.normal(k2, (1, NB, bs, KV, D), jnp.float32)
+    vp = jax.random.normal(k3, (1, NB, bs, KV, D), jnp.float32)
+    return q, kp, vp, jnp.asarray(tables), jnp.asarray(lengths)
+
+
+class TestKernelWalksLiveBlocksOnly:
+    """The kernel takes a step for a (slot, block) pair only if the block
+    holds cached tokens: the work list is exactly those pairs, a slot of
+    length 0 gets a row of zeros, and no other block is read."""
+
+    @pytest.mark.parametrize("bs", [16, 64])
+    @pytest.mark.parametrize("case", RAGGED)
+    def test_ragged_lengths_match_the_oracle(self, case, bs):
+        from ray_tpu.ops.pallas.paged_decode_attention import (
+            paged_attention_reference, paged_decode_attention)
+
+        q, kp, vp, tables, lengths = _ragged_case(case, bs)
+        want = np.asarray(paged_attention_reference(
+            q, kp, vp, 0, tables, lengths, scale=0.25))
+        got = np.asarray(paged_decode_attention(
+            q, kp, vp, 0, tables, lengths, scale=0.25, interpret=True))
+        live = np.asarray(lengths) > 0
+        np.testing.assert_allclose(got[live], want[live],
+                                   rtol=2e-3, atol=2e-3)
+        assert not got[~live].any()              # zeros, not garbage
+
+    @pytest.mark.parametrize("case", ["empty-slot-between-running",
+                                      "full-table", "every-slot-empty"])
+    def test_no_block_outside_the_work_list_is_read(self, case):
+        from ray_tpu.ops.pallas.paged_decode_attention import (
+            paged_decode_attention)
+
+        bs = 16
+        q, kp, vp, tables, lengths = _ragged_case(case, bs)
+        named = {int(tables[s, j]) for s, n in enumerate(np.asarray(lengths))
+                 for j in range(-(-int(n) // bs))}
+        dead = np.array([b not in named for b in range(kp.shape[1])])
+        assert dead[0]                           # the null block among them
+        poison = lambda pool: pool.at[:, dead].set(jnp.nan)  # noqa: E731
+        run = lambda k, v: np.asarray(paged_decode_attention(  # noqa: E731
+            q, k, v, 0, tables, lengths, scale=0.25, interpret=True))
+        got = run(poison(kp), poison(vp))
+        assert np.isfinite(got).all()
+        np.testing.assert_array_equal(got, run(kp, vp))
+
+    @pytest.mark.parametrize("lengths, bs, mbs", [
+        ([19, 0, 33, 7], 16, 3), ([0, 0, 0], 16, 4), ([64, 128, 1], 64, 2),
+        ([48, 48, 48, 48], 16, 3), ([0, 5], 64, 64), ([700, 9], 64, 4)])
+    def test_work_list_is_the_live_pairs_in_slot_order(self, lengths, bs,
+                                                       mbs):
+        from ray_tpu.ops.pallas.paged_decode_attention import (
+            decode_work_list)
+
+        n_work, slot, block = jax.jit(
+            decode_work_list, static_argnums=(1, 2))(
+                jnp.asarray(lengths, jnp.int32), bs, mbs)
+        want = [(s, j) for s, n in enumerate(lengths)
+                for j in range(min(-(-n // bs), mbs))]
+        assert int(n_work) == len(want)
+        assert slot.shape == block.shape == (len(lengths) * mbs + 1,)
+        got = list(zip(np.asarray(slot).tolist(), np.asarray(block).tolist()))
+        assert got[:len(want)] == want
+        # past the list: the last pair again (or (last slot, 0) of an empty
+        # list), so an index map that runs ahead names a real block
+        assert set(got[len(want):]) <= {want[-1] if want
+                                        else (len(lengths) - 1, 0)}
+
+
 class TestWritesInPlace:
     """The programs carry the whole pool and write into it: a step or a
     prefill changes the rows it is meant to, in every layer, and leaves
@@ -189,6 +280,43 @@ class TestWritesInPlace:
                         ).any(axis=(1, 2)).all()
         np.testing.assert_array_equal(np.asarray(cache["length"]),
                                       [6, 18, 3])
+
+    def test_inactive_slots_stale_length_is_not_attended(self, cfg, params):
+        """Nothing resets ``cache["length"]`` when a slot is released: two
+        of four slots are inactive with a stale length, and the step gives
+        the active slots' logits bit for bit as with those lengths at 0,
+        keeps the stale lengths, and writes for them only into the null
+        block."""
+        page = PagedConfig(num_blocks=12, block_size=16, max_seq=64)
+        al = BlockAllocator(page, num_slots=4)
+        active = np.array([True, False, True, False])
+        for slot, n in ((0, 21), (2, 6)):
+            al.ensure(slot, n + 1)
+        step = make_paged_decode_step(params, cfg, page)
+        tokens = jnp.asarray([7, 8, 9, 10], jnp.int32)
+        outs = {}
+        for name, lengths in (("stale", [21, 37, 6, 50]),
+                              ("zero", [21, 0, 6, 0])):
+            cache = self._noise_cache(cfg, page, 4)
+            cache["length"] = jnp.asarray(lengths, jnp.int32)
+            before = {n: np.asarray(cache[n]) for n in ("k", "v")}
+            cache, logits = step(cache, al.device_tables(), tokens,
+                                 jnp.asarray(active))
+            outs[name] = np.asarray(logits)
+            np.testing.assert_array_equal(
+                np.asarray(cache["length"]),
+                np.where(active, np.asarray(lengths) + 1, lengths))
+            changed = {n: (np.asarray(cache[n]) != before[n]).any(
+                axis=(0, 3, 4)) for n in ("k", "v")}       # (NB, bs)
+            live = {(int(al.tables[s, lengths[s] // 16]), lengths[s] % 16)
+                    for s in (0, 2)}
+            for n in ("k", "v"):
+                rows = {(int(b), int(o)) for b, o in np.argwhere(changed[n])
+                        if b != 0}
+                assert rows == live
+        assert np.isfinite(outs["stale"][active]).all()
+        np.testing.assert_array_equal(outs["stale"][active],
+                                      outs["zero"][active])
 
 
 class TestPagedEqualsSlot:
