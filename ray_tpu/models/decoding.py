@@ -13,21 +13,29 @@ design choices:
   ``ray_tpu.serve.llm`` admits/evicts slots between steps).
 - **Functional cache**: jitted steps take and return the cache arrays
   (donated), so XLA updates them in place on device.
+
+**Where the block lives.** The dense decoder's arithmetic is
+:mod:`ray_tpu.models.llama`'s (``qkv``, ``mlp``, ``embed``, ``logits_f32``).
+:func:`dense_block` strings it into one serving block around an ``attend``
+function; :func:`attend_rows` is the one attention over cached rows. A
+builder, here and in :mod:`ray_tpu.models.paged_cache`, adds its index
+arithmetic and its ``attend``: where this call's K and V rows are written,
+and what the queries attend over.
 """
 
 from __future__ import annotations
 
-import dataclasses
 import functools
-from typing import Dict, Optional, Tuple
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.llama import LlamaConfig, Params
-from ray_tpu.ops.attention import on_tpu
+from ray_tpu.models.llama import (LlamaConfig, Params, embed, logits_f32,
+                                  mlp, qkv)
+from ray_tpu.ops.attention import mha_reference, on_tpu
 from ray_tpu.ops.norms import rmsnorm
-from ray_tpu.ops.rope import apply_rope, rope_frequencies
+from ray_tpu.ops.rope import rope_frequencies
 
 Cache = Dict[str, jax.Array]
 
@@ -45,30 +53,55 @@ def init_cache(config: LlamaConfig, num_slots: int,
     }
 
 
+def dense_block(x, layer, c: LlamaConfig, cos, sin, positions, attend,
+                state):
+    """One block of the dense decoder in a serving program: norm, q / k /
+    v at ``positions``, ``out, state = attend(q, k, v, state)``, output
+    projection, norm, MLP. x (B, S, E) -> (x, state).
+
+    ``attend`` is what makes a program: it writes this call's k and v rows
+    into its cache and attends over what the queries may see; ``state`` is
+    what it threads through the layers (a layer's cache rows, or the whole
+    pool and the layer's index)."""
+    q, k, v = qkv(rmsnorm(x, layer["attn_norm"], c.norm_eps), layer,
+                  cos, sin, positions)
+    out, state = attend(q, k, v, state)
+    x = x + jnp.einsum("bshd,hde->bse", out, layer["wo"].astype(x.dtype))
+    x = x + mlp(rmsnorm(x, layer["mlp_norm"], c.norm_eps), layer)
+    return x, state
+
+
+def attend_rows(q, ks, vs, q_pos, scale):
+    """q (B, C, H, D) over the row sets ks / vs (B, S, KV, D): key ``j``
+    is visible to query ``i`` of row ``b`` iff ``j <= q_pos[b, i]``
+    (absolute positions, so rows past a slot's length, stale or zero,
+    are never seen).
+
+    A GROUPED einsum (q reshaped (B, C, KV, group, D)), so the rows are
+    never materialized head-repeated — on a (slots, S, KV, D) cache that
+    repeat was group x cache-size of wasted HBM traffic per step."""
+    B, C, H, D = q.shape
+    S, KV = ks.shape[1:3]
+    qg = q.astype(jnp.float32).reshape(B, C, KV, H // KV, D)
+    s = jnp.einsum("bckgd,bskd->bkgcs", qg, ks.astype(jnp.float32)) * scale
+    allowed = jnp.arange(S)[None, None, :] <= q_pos[:, :, None]   # (B,C,S)
+    s = jnp.where(allowed[:, None, None], s, -1e30)
+    p = jax.nn.softmax(s, axis=-1)
+    out = jnp.einsum("bkgcs,bskd->bckgd", p, vs.astype(jnp.float32))
+    return out.reshape(B, C, H, D).astype(q.dtype)
+
+
 def _attend_cached(q, k_cache, v_cache, lengths, scale):
     """q: (B, 1, H, D) new-token queries; k/v_cache: (B, S, KV, D);
     lengths: (B,) valid prefix per slot (incl. the new token).
 
-    Dispatches to the Pallas flash-decoding kernel on TPU; the XLA path
-    uses a GROUPED einsum (q reshaped (B,KV,group,D)) so the KV cache is
-    never materialized head-repeated — on a (slots, S, KV, D) cache that
-    repeat was group x cache-size of wasted HBM traffic per step."""
-    B, _, H, D = q.shape
-    KV = k_cache.shape[2]
-    group = H // KV
+    Dispatches to the Pallas flash-decoding kernel on TPU, to
+    :func:`attend_rows` elsewhere."""
     if on_tpu():
         from ray_tpu.ops.pallas.decode_attention import decode_attention
 
         return decode_attention(q, k_cache, v_cache, lengths, scale=scale)
-    qg = q.astype(jnp.float32).reshape(B, KV, group, D)
-    kf = k_cache.astype(jnp.float32)
-    vf = v_cache.astype(jnp.float32)
-    s = jnp.einsum("bkgd,bskd->bkgs", qg, kf) * scale     # (B,KV,group,S)
-    mask = (jnp.arange(s.shape[-1])[None, :] < lengths[:, None])
-    s = jnp.where(mask[:, None, None, :], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bkgs,bskd->bkgd", p, vf)            # (B,KV,group,D)
-    return out.reshape(B, 1, H, D).astype(q.dtype)
+    return attend_rows(q, k_cache, v_cache, lengths[:, None] - 1, scale)
 
 
 def _bind_params(jitted, params: Params):
@@ -85,37 +118,49 @@ def _bind_params(jitted, params: Params):
     return call
 
 
-def _decode_block(x, layer, k_cache, v_cache, lengths, cos, sin,
-                  config: LlamaConfig):
-    """One transformer block for one new token per slot, updating cache.
+def _bind_padded(jitted, params: Params, tokens_at: int, multiple_of: int = 1):
+    """:func:`_bind_params` for a program jitted per padded length:
+    ``call(cache, *args)`` where ``args[tokens_at]`` is the (B, P) token
+    array, whose P is the program's static ``pad_len`` (refused unless a
+    multiple of ``multiple_of``, the paged programs' block size), and
+    every other argument a scalar or vector made int32."""
 
-    x: (B, 1, E); k/v_cache: (B, S, KV, D); lengths: (B,) count BEFORE
-    this token. Returns (x, new_k_cache, new_v_cache).
-    """
-    c = config
-    h = rmsnorm(x, layer["attn_norm"], c.norm_eps)
-    q = jnp.einsum("bse,ehd->bshd", h, layer["wq"].astype(h.dtype))
-    k = jnp.einsum("bse,ehd->bshd", h, layer["wk"].astype(h.dtype))
-    v = jnp.einsum("bse,ehd->bshd", h, layer["wv"].astype(h.dtype))
-    positions = lengths[:, None]                           # (B, 1)
-    q = apply_rope(q, cos, sin, positions)
-    k = apply_rope(k, cos, sin, positions)
+    def call(cache, *args):
+        pad_len = args[tokens_at].shape[1]
+        if pad_len % multiple_of:
+            raise ValueError(f"padded length {pad_len} not a multiple of "
+                             f"block_size {multiple_of}")
+        args = [a if i == tokens_at else jnp.asarray(a, jnp.int32)
+                for i, a in enumerate(args)]
+        return jitted(params, cache, *args, pad_len=pad_len)
 
-    # write new k/v at each slot's current length
-    slot_ids = jnp.arange(x.shape[0])
-    k_cache = k_cache.at[slot_ids, lengths].set(k[:, 0])
-    v_cache = v_cache.at[slot_ids, lengths].set(v[:, 0])
+    call.jitted = jitted
+    return call
 
-    out = _attend_cached(q, k_cache, v_cache, lengths + 1,
-                         c.head_dim ** -0.5)
-    x = x + jnp.einsum("bshd,hde->bse", out,
-                       layer["wo"].astype(x.dtype))
-    h = rmsnorm(x, layer["mlp_norm"], c.norm_eps)
-    g = jnp.einsum("bse,em->bsm", h, layer["w_gate"].astype(h.dtype))
-    u = jnp.einsum("bse,em->bsm", h, layer["w_up"].astype(h.dtype))
-    x = x + jnp.einsum("bsm,me->bse", jax.nn.silu(g) * u,
-                       layer["w_down"].astype(h.dtype))
-    return x, k_cache, v_cache
+
+def _scan_cache(attend, x, params: Params, cache: Cache, c: LlamaConfig,
+                positions):
+    """:func:`dense_block` over the layers, each layer's cache rows
+    (slots, S, KV, D) through the scan's ``xs`` / ``ys`` as ``attend``'s
+    ``state = (k_rows, v_rows)``. -> (x, (new k, new v))."""
+    cos, sin = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta)
+
+    def body(x, scanned):
+        layer, kc, vc = scanned
+        return dense_block(x, layer, c, cos, sin, positions, attend,
+                           (kc, vc))
+
+    return jax.lax.scan(body, x,
+                        (params["layers"], cache["k"], cache["v"]))
+
+
+def _put_rows(rows_all, new, valid, slot, start):
+    """Write ``new`` (1, P, KV, D), zeroed where not ``valid`` (P,), into
+    rows [start, start + P) of ``slot`` in a layer's (slots, S, KV, D)."""
+    return jax.lax.dynamic_update_slice(
+        rows_all, jnp.where(valid[None, :, None, None], new,
+                            0.0).astype(rows_all.dtype),
+        (slot, start, 0, 0))
 
 
 def make_decode_step(params: Params, config: LlamaConfig):
@@ -129,21 +174,21 @@ def make_decode_step(params: Params, config: LlamaConfig):
 
     def step(params: Params, cache: Cache, tokens: jax.Array,
              active: jax.Array):
-        lengths = cache["length"]
-        cos, sin = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta)
-        x = params["embed"].astype(c.dtype)[tokens][:, None, :]  # (B,1,E)
+        lengths = cache["length"]              # count BEFORE this token
+        slot_ids = jnp.arange(tokens.shape[0])
 
-        def body(x, scanned):
-            layer, kc, vc = scanned
-            x, kc, vc = _decode_block(x, layer, kc, vc, lengths, cos, sin, c)
-            return x, (kc, vc)
+        def attend(q, k, v, state):
+            # write new k/v at each slot's current length
+            kc, vc = state                                 # (B, S, KV, D)
+            kc = kc.at[slot_ids, lengths].set(k[:, 0])
+            vc = vc.at[slot_ids, lengths].set(v[:, 0])
+            return (_attend_cached(q, kc, vc, lengths + 1,
+                                   c.head_dim ** -0.5), (kc, vc))
 
-        x, (new_k, new_v) = jax.lax.scan(
-            body, x, (params["layers"], cache["k"], cache["v"]))
-        x = rmsnorm(x, params["final_norm"], c.norm_eps)
-        head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
-        logits = jnp.einsum("be,ev->bv", x[:, 0].astype(jnp.float32),
-                            head.astype(jnp.float32))
+        x = embed(params, tokens, c)[:, None, :]                 # (B,1,E)
+        x, (new_k, new_v) = _scan_cache(attend, x, params, cache, c,
+                                        lengths[:, None])
+        logits = logits_f32(x, params, c, row=(slice(None), 0))
         # only active slots advance / keep their writes
         keep = active[:, None, None, None]
         new_k = jnp.where(keep[None], new_k, cache["k"])
@@ -167,59 +212,25 @@ def make_prefill(params: Params, config: LlamaConfig):
                        static_argnames=("pad_len",))
     def prefill(params: Params, cache: Cache, tokens: jax.Array,
                 true_len: jax.Array, slot: jax.Array, pad_len: int):
-        cos, sin = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta)
-        x = params["embed"].astype(c.dtype)[tokens]          # (1, P, E)
         positions = jnp.arange(pad_len)[None, :]
         mask_valid = positions[0] < true_len                 # (P,)
 
-        def body(x, scanned):
-            layer, kc_all, vc_all = scanned                  # (slots, S, …)
-            h = rmsnorm(x, layer["attn_norm"], c.norm_eps)
-            q = jnp.einsum("bse,ehd->bshd", h, layer["wq"].astype(h.dtype))
-            k = jnp.einsum("bse,ehd->bshd", h, layer["wk"].astype(h.dtype))
-            v = jnp.einsum("bse,ehd->bshd", h, layer["wv"].astype(h.dtype))
-            q = apply_rope(q, cos, sin, positions)
-            k = apply_rope(k, cos, sin, positions)
-            # causal attention within the prompt
-            from ray_tpu.ops.attention import mha_reference
+        def attend(q, k, v, state):
+            # causal within the prompt; its k/v go to the slot's rows [0, P)
+            kc_all, vc_all = state                           # (slots, S, …)
+            return mha_reference(q, k, v, causal=True), (
+                _put_rows(kc_all, k, mask_valid, slot, 0),
+                _put_rows(vc_all, v, mask_valid, slot, 0))
 
-            out = mha_reference(q, k, v, causal=True)
-            x = x + jnp.einsum("bshd,hde->bse", out,
-                               layer["wo"].astype(x.dtype))
-            h2 = rmsnorm(x, layer["mlp_norm"], c.norm_eps)
-            g = jnp.einsum("bse,em->bsm", h2,
-                           layer["w_gate"].astype(h2.dtype))
-            u = jnp.einsum("bse,em->bsm", h2, layer["w_up"].astype(h2.dtype))
-            x = x + jnp.einsum("bsm,me->bse", jax.nn.silu(g) * u,
-                               layer["w_down"].astype(h2.dtype))
-            # write prompt k/v into this slot's cache rows [0, P)
-            kc_all = jax.lax.dynamic_update_slice(
-                kc_all, jnp.where(mask_valid[None, :, None, None], k,
-                                  0.0).astype(kc_all.dtype),
-                (slot, 0, 0, 0))
-            vc_all = jax.lax.dynamic_update_slice(
-                vc_all, jnp.where(mask_valid[None, :, None, None], v,
-                                  0.0).astype(vc_all.dtype),
-                (slot, 0, 0, 0))
-            return x, (kc_all, vc_all)
-
-        x, (new_k, new_v) = jax.lax.scan(
-            body, x, (params["layers"], cache["k"], cache["v"]))
-        x = rmsnorm(x, params["final_norm"], c.norm_eps)
-        last = x[0, jnp.maximum(true_len - 1, 0)]
-        head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
-        logits = (last.astype(jnp.float32) @ head.astype(jnp.float32))
+        x = embed(params, tokens, c)                         # (1, P, E)
+        x, (new_k, new_v) = _scan_cache(attend, x, params, cache, c,
+                                        positions)
+        logits = logits_f32(x, params, c,
+                            row=(0, jnp.maximum(true_len - 1, 0)))
         new_len = cache["length"].at[slot].set(true_len)
         return ({"k": new_k, "v": new_v, "length": new_len}, logits)
 
-    def call(cache, tokens, true_len, slot):
-        pad_len = tokens.shape[1]
-        return prefill(params, cache, tokens,
-                       jnp.asarray(true_len, jnp.int32),
-                       jnp.asarray(slot, jnp.int32), pad_len=pad_len)
-
-    call.jitted = prefill
-    return call
+    return _bind_padded(prefill, params, tokens_at=0)
 
 
 def make_chunked_prefill(params: Params, config: LlamaConfig):
@@ -245,79 +256,33 @@ def make_chunked_prefill(params: Params, config: LlamaConfig):
     def chunk(params: Params, cache: Cache, tokens: jax.Array,
               true_len: jax.Array, start_pos: jax.Array, slot: jax.Array,
               pad_len: int):
-        S = cache["k"].shape[2]
-        cos, sin = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta)
-        x = params["embed"].astype(c.dtype)[tokens]          # (1, C, E)
         rel = jnp.arange(pad_len)                            # (C,)
         positions = (start_pos + rel)[None, :]               # (1, C)
         mask_valid = rel < true_len                          # (C,)
 
-        def body(x, scanned):
-            layer, kc_all, vc_all = scanned                  # (slots, S, …)
-            h = rmsnorm(x, layer["attn_norm"], c.norm_eps)
-            q = jnp.einsum("bse,ehd->bshd", h, layer["wq"].astype(h.dtype))
-            k = jnp.einsum("bse,ehd->bshd", h, layer["wk"].astype(h.dtype))
-            v = jnp.einsum("bse,ehd->bshd", h, layer["wv"].astype(h.dtype))
-            q = apply_rope(q, cos, sin, positions)
-            k = apply_rope(k, cos, sin, positions)
-            # write the chunk's k/v at rows [start_pos, start_pos + C)
-            kc_all = jax.lax.dynamic_update_slice(
-                kc_all, jnp.where(mask_valid[None, :, None, None], k,
-                                  0.0).astype(kc_all.dtype),
-                (slot, start_pos, 0, 0))
-            vc_all = jax.lax.dynamic_update_slice(
-                vc_all, jnp.where(mask_valid[None, :, None, None], v,
-                                  0.0).astype(vc_all.dtype),
-                (slot, start_pos, 0, 0))
-            # attend over the slot's FULL row set (prefix + this chunk):
-            # key j visible to query i iff j <= start_pos + i
-            ks = kc_all[slot]                                # (S, KV, D)
-            vs = vc_all[slot]
-            KV = ks.shape[1]
-            H = q.shape[2]
-            group = H // KV
-            qg = (q[0].astype(jnp.float32)
-                  .reshape(pad_len, KV, group, -1))          # (C,KV,g,D)
-            s = jnp.einsum("ckgd,skd->kgcs", qg,
-                           ks.astype(jnp.float32)) * (c.head_dim ** -0.5)
-            allowed = (jnp.arange(S)[None, :]
-                       <= (start_pos + rel)[:, None])        # (C, S)
-            s = jnp.where(allowed[None, None], s, -1e30)
-            p = jax.nn.softmax(s, axis=-1)
-            out = jnp.einsum("kgcs,skd->ckgd", p,
-                             vs.astype(jnp.float32))
-            out = out.reshape(1, pad_len, H, -1).astype(x.dtype)
-            x = x + jnp.einsum("bshd,hde->bse", out,
-                               layer["wo"].astype(x.dtype))
-            h2 = rmsnorm(x, layer["mlp_norm"], c.norm_eps)
-            g = jnp.einsum("bse,em->bsm", h2,
-                           layer["w_gate"].astype(h2.dtype))
-            u = jnp.einsum("bse,em->bsm", h2, layer["w_up"].astype(h2.dtype))
-            x = x + jnp.einsum("bsm,me->bse", jax.nn.silu(g) * u,
-                               layer["w_down"].astype(h2.dtype))
-            return x, (kc_all, vc_all)
+        def attend(q, k, v, state):
+            # write the chunk's k/v at rows [start_pos, start_pos + C),
+            # then attend over the slot's FULL row set (prefix + chunk)
+            kc_all, vc_all = state                           # (slots, S, …)
+            kc_all = _put_rows(kc_all, k, mask_valid, slot, start_pos)
+            vc_all = _put_rows(vc_all, v, mask_valid, slot, start_pos)
+            out = attend_rows(q, kc_all[slot][None], vc_all[slot][None],
+                              positions, c.head_dim ** -0.5)
+            return out, (kc_all, vc_all)
 
-        x, (new_k, new_v) = jax.lax.scan(
-            body, x, (params["layers"], cache["k"], cache["v"]))
-        x = rmsnorm(x, params["final_norm"], c.norm_eps)
-        last = x[0, jnp.maximum(true_len - 1, 0)]
-        head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
-        logits = (last.astype(jnp.float32) @ head.astype(jnp.float32))
+        x = embed(params, tokens, c)                         # (1, C, E)
+        x, (new_k, new_v) = _scan_cache(attend, x, params, cache, c,
+                                        positions)
+        logits = logits_f32(x, params, c,
+                            row=(0, jnp.maximum(true_len - 1, 0)))
         new_len = cache["length"].at[slot].set(start_pos + true_len)
         return ({"k": new_k, "v": new_v, "length": new_len}, logits)
 
-    def call(cache, tokens, true_len, start_pos, slot):
-        pad_len = tokens.shape[1]
-        return chunk(params, cache, tokens,
-                     jnp.asarray(true_len, jnp.int32),
-                     jnp.asarray(start_pos, jnp.int32),
-                     jnp.asarray(slot, jnp.int32), pad_len=pad_len)
-
-    call.jitted = chunk
-    return call
+    return _bind_padded(chunk, params, tokens_at=0)
 
 
-def make_batched_spec_verify(params: Params, config: LlamaConfig):
+def make_batched_spec_verify(params: Params, config: LlamaConfig,
+                             with_logits: bool = True):
     """Speculative-decoding verify: score K+1 candidate tokens for EVERY
     slot in ONE forward (the speculation subsystem's target-model step —
     :mod:`ray_tpu.models.speculation` owns proposers and acceptance).
@@ -336,8 +301,57 @@ def make_batched_spec_verify(params: Params, config: LlamaConfig):
     rows sit beyond the accepted length the caller installs afterwards
     (the engine overwrites ``cache["length"]`` wholesale) and are
     overwritten by later writes — attention masks by position, so they
-    are invisible."""
-    return _make_window_forward(params, config, with_logits=True)
+    are invisible.
+
+    ``with_logits=False`` is :func:`make_kv_ingest`'s program: the same
+    rows written, no head."""
+    c = config
+
+    @functools.partial(jax.jit, donate_argnums=(1,),
+                       static_argnames=("pad_len",))
+    def verify(params: Params, cache: Cache, tokens: jax.Array,
+               true_lens: jax.Array, start_pos: jax.Array, pad_len: int):
+        S = cache["k"].shape[2]
+        rel = jnp.arange(pad_len)                            # (C,)
+        positions = start_pos[:, None] + rel[None, :]        # (B, C)
+        sel = (rel[None, :] < true_lens[:, None])[..., None, None]
+        # gather-side clamp only: invalid rows may index past S. The
+        # scatter below uses the UNCLAMPED positions so out-of-range
+        # updates are dropped (jax scatter default) instead of clamping
+        # onto S-1 — a clamped duplicate would race the last valid row's
+        # write (scatter order with duplicate indices is undefined)
+        row_idx = jnp.minimum(positions, S - 1)
+        rope_pos = jnp.minimum(positions, c.max_seq - 1)
+        bidx = jnp.arange(tokens.shape[0])[:, None]          # (B, 1)
+
+        def attend(q, k, v, state):
+            # scatter each slot's window rows at its own offset; in-range
+            # invalid rows re-write their current contents, out-of-range
+            # rows are dropped (positions unclamped — no duplicates)
+            kc, vc = state                                   # (B, S, KV, D)
+            kc = kc.at[bidx, positions].set(
+                jnp.where(sel, k, kc[bidx, row_idx]).astype(kc.dtype))
+            vc = vc.at[bidx, positions].set(
+                jnp.where(sel, v, vc[bidx, row_idx]).astype(vc.dtype))
+            # attend over each slot's full row set: key j visible to
+            # window query i iff j <= start_pos + i
+            return (attend_rows(q, kc, vc, positions, c.head_dim ** -0.5),
+                    (kc, vc))
+
+        x = embed(params, tokens, c)                         # (B, C, E)
+        x, (new_k, new_v) = _scan_cache(attend, x, params, cache, c,
+                                        rope_pos)
+        # KV-ingest: the caller discards logits — skip the final norm
+        # and the (B, C, vocab) head projection entirely
+        all_logits = logits_f32(x, params, c) if with_logits else None
+        # provisional: start + window length for touched slots; the
+        # engine installs the accepted lengths right after
+        new_len = jnp.where(true_lens > 0,
+                            (start_pos + true_lens).astype(jnp.int32),
+                            cache["length"])
+        return ({"k": new_k, "v": new_v, "length": new_len}, all_logits)
+
+    return _bind_padded(verify, params, tokens_at=0)
 
 
 def make_kv_ingest(params: Params, config: LlamaConfig):
@@ -353,115 +367,12 @@ def make_kv_ingest(params: Params, config: LlamaConfig):
     every such round computed (and discarded) a full-vocab logits block
     (PERF_PLAN round 7, "known draft-path optimization, not yet taken").
     """
-    call = _make_window_forward(params, config, with_logits=False)
+    call = make_batched_spec_verify(params, config, with_logits=False)
 
     def ingest(cache, tokens, true_lens, start_pos):
-        cache, _ = call(cache, tokens, true_lens, start_pos)
-        return cache
+        return call(cache, tokens, true_lens, start_pos)[0]
 
     return ingest
-
-
-def _make_window_forward(params: Params, config: LlamaConfig,
-                         with_logits: bool):
-    """Shared builder: per-slot token windows scattered at per-slot
-    offsets through the full stack, with (``with_logits``) or without the
-    lm-head projection.  See :func:`make_batched_spec_verify` for the
-    window semantics."""
-    c = config
-
-    @functools.partial(jax.jit, donate_argnums=(1,),
-                       static_argnames=("pad_len",))
-    def verify(params: Params, cache: Cache, tokens: jax.Array,
-               true_lens: jax.Array, start_pos: jax.Array, pad_len: int):
-        S = cache["k"].shape[2]
-        B = tokens.shape[0]
-        cos, sin = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta)
-        x = params["embed"].astype(c.dtype)[tokens]          # (B, C, E)
-        rel = jnp.arange(pad_len)                            # (C,)
-        positions = start_pos[:, None] + rel[None, :]        # (B, C)
-        valid = rel[None, :] < true_lens[:, None]            # (B, C)
-        # gather-side clamp only: invalid rows may index past S. The
-        # scatter below uses the UNCLAMPED positions so out-of-range
-        # updates are dropped (jax scatter default) instead of clamping
-        # onto S-1 — a clamped duplicate would race the last valid row's
-        # write (scatter order with duplicate indices is undefined)
-        row_idx = jnp.minimum(positions, S - 1)
-        rope_pos = jnp.minimum(positions, cos.shape[0] - 1)
-        bidx = jnp.arange(B)[:, None]                        # (B, 1)
-
-        def body(x, scanned):
-            layer, kc, vc = scanned                          # (B, S, KV, D)
-            h = rmsnorm(x, layer["attn_norm"], c.norm_eps)
-            q = jnp.einsum("bse,ehd->bshd", h, layer["wq"].astype(h.dtype))
-            k = jnp.einsum("bse,ehd->bshd", h, layer["wk"].astype(h.dtype))
-            v = jnp.einsum("bse,ehd->bshd", h, layer["wv"].astype(h.dtype))
-            q = apply_rope(q, cos, sin, rope_pos)
-            k = apply_rope(k, cos, sin, rope_pos)
-            # scatter each slot's window rows at its own offset; in-range
-            # invalid rows re-write their current contents, out-of-range
-            # rows are dropped (positions unclamped — no duplicates)
-            old_k = kc[bidx, row_idx]                        # (B, C, KV, D)
-            old_v = vc[bidx, row_idx]
-            sel = valid[..., None, None]
-            kc = kc.at[bidx, positions].set(
-                jnp.where(sel, k, old_k).astype(kc.dtype))
-            vc = vc.at[bidx, positions].set(
-                jnp.where(sel, v, old_v).astype(vc.dtype))
-            # attend over the slot's full row set: key j visible to
-            # window query i iff j <= start_pos + i (grouped einsum, KV
-            # never head-repeated — same layout as _attend_cached)
-            KV = kc.shape[2]
-            H = q.shape[2]
-            group = H // KV
-            qg = (q.astype(jnp.float32)
-                  .reshape(B, pad_len, KV, group, -1))       # (B,C,KV,g,D)
-            s = jnp.einsum("bckgd,bskd->bkgcs", qg,
-                           kc.astype(jnp.float32)) * (c.head_dim ** -0.5)
-            allowed = (jnp.arange(S)[None, None, :]
-                       <= positions[:, :, None])             # (B, C, S)
-            s = jnp.where(allowed[:, None, None], s, -1e30)
-            p = jax.nn.softmax(s, axis=-1)
-            out = jnp.einsum("bkgcs,bskd->bckgd", p,
-                             vc.astype(jnp.float32))
-            out = out.reshape(B, pad_len, H, -1).astype(x.dtype)
-            x = x + jnp.einsum("bshd,hde->bse", out,
-                               layer["wo"].astype(x.dtype))
-            h2 = rmsnorm(x, layer["mlp_norm"], c.norm_eps)
-            g = jnp.einsum("bse,em->bsm", h2,
-                           layer["w_gate"].astype(h2.dtype))
-            u = jnp.einsum("bse,em->bsm", h2, layer["w_up"].astype(h2.dtype))
-            x = x + jnp.einsum("bsm,me->bse", jax.nn.silu(g) * u,
-                               layer["w_down"].astype(h2.dtype))
-            return x, (kc, vc)
-
-        x, (new_k, new_v) = jax.lax.scan(
-            body, x, (params["layers"], cache["k"], cache["v"]))
-        if with_logits:
-            x = rmsnorm(x, params["final_norm"], c.norm_eps)
-            head = (params["embed"].T if c.tie_embeddings
-                    else params["lm_head"])
-            all_logits = jnp.einsum("bce,ev->bcv", x.astype(jnp.float32),
-                                    head.astype(jnp.float32))
-        else:
-            # KV-ingest: the caller discards logits — skip the final norm
-            # and the (B, C, vocab) head projection entirely
-            all_logits = None
-        # provisional: start + window length for touched slots; the
-        # engine installs the accepted lengths right after
-        new_len = jnp.where(true_lens > 0,
-                            (start_pos + true_lens).astype(jnp.int32),
-                            cache["length"])
-        return ({"k": new_k, "v": new_v, "length": new_len}, all_logits)
-
-    def call(cache, tokens, true_lens, start_pos):
-        pad_len = tokens.shape[1]
-        return verify(params, cache, tokens,
-                      jnp.asarray(true_lens, jnp.int32),
-                      jnp.asarray(start_pos, jnp.int32), pad_len=pad_len)
-
-    call.jitted = verify
-    return call
 
 
 def make_inject(config: LlamaConfig):
@@ -499,18 +410,3 @@ def pad_to_bucket(n: int, buckets=(32, 64, 128, 256, 512, 1024, 2048)) -> int:
         if n <= b:
             return b
     return ((n + 511) // 512) * 512
-
-
-@dataclasses.dataclass
-class SamplingParams:
-    max_tokens: int = 64
-    temperature: float = 0.0        # 0 → greedy
-    eos_token: Optional[int] = None
-
-
-def sample_token(logits, temperature: float, key) -> Tuple[jax.Array, any]:
-    if temperature <= 0.0:
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), key
-    key, sub = jax.random.split(key)
-    tok = jax.random.categorical(sub, logits / temperature, axis=-1)
-    return tok.astype(jnp.int32), key

@@ -72,9 +72,9 @@ class LlamaConfig:
     def num_params(self) -> int:
         p = self.vocab_size * self.hidden                        # embed
         per_layer = (
-            self.hidden * self.q_dim                             # wq
-            + 2 * self.hidden * self.n_kv_heads * self.head_dim  # wk, wv
-            + self.q_dim * self.hidden                           # wo
+            self.hidden * self.q_dim                             # q
+            + 2 * self.hidden * self.n_kv_heads * self.head_dim  # k, v
+            + self.q_dim * self.hidden                           # out
             + 3 * self.hidden * self.mlp_dim                     # gate/up/down
             + 2 * self.hidden                                    # norms
         )
@@ -158,14 +158,53 @@ def init_params(config: LlamaConfig, key: jax.Array) -> Params:
     return params
 
 
+# The pieces of the block: the train forward below and the serving programs
+# (``models/decoding.py::dense_block``) are made of the same ones.
+def qkv(h, layer, cos, sin, positions=None):
+    """The three projections of a normed input ``h`` (B, S, E), queries
+    and keys rotated to ``positions`` ((B, S) or (1, S); None: 0..S-1).
+    -> q (B, S, H, D), k and v (B, S, KV, D)."""
+    q = jnp.einsum("bse,ehd->bshd", h, layer["wq"].astype(h.dtype))
+    k = jnp.einsum("bse,ehd->bshd", h, layer["wk"].astype(h.dtype))
+    v = jnp.einsum("bse,ehd->bshd", h, layer["wv"].astype(h.dtype))
+    return (apply_rope(q, cos, sin, positions),
+            apply_rope(k, cos, sin, positions), v)
+
+
+def mlp(h, layer):
+    """Gate / up / down of a normed input ``h`` (B, S, E)."""
+    g = jnp.einsum("bse,em->bsm", h, layer["w_gate"].astype(h.dtype))
+    u = jnp.einsum("bse,em->bsm", h, layer["w_up"].astype(h.dtype))
+    return jnp.einsum("bsm,me->bse", jax.nn.silu(g) * u,
+                      layer["w_down"].astype(h.dtype))
+
+
+def embed(params: Params, tokens, c: LlamaConfig):
+    """Serving's embedding: one device, no constraints (the train
+    forward places its own around the gather)."""
+    return params["embed"].astype(c.dtype)[tokens]
+
+
+def logits_f32(x, params: Params, c: LlamaConfig, row=None):
+    """Serving's head: final norm over all of ``x`` (..., E), then ``x[row]``
+    (a prefill's last valid position; None: every row) times the tied or
+    untied head -> (..., vocab) float32.
+
+    float32 OPERANDS, on purpose unlike :func:`forward`'s head (bfloat16
+    operands, float32 accumulation, for the MXU's rate over B x S rows):
+    serving multiplies one row a sequence, so the rate buys nothing and
+    the logits that are sampled from are not rounded."""
+    x = rmsnorm(x, params["final_norm"], c.norm_eps)
+    if row is not None:
+        x = x[row]
+    head = params["embed"].T if c.tie_embeddings else params["lm_head"]
+    return x.astype(jnp.float32) @ head.astype(jnp.float32)
+
+
 def _attention(x, layer, cos, sin, config: LlamaConfig,
                rules: ShardingRules, positions=None, mesh=None):
     c = config
-    q = jnp.einsum("bse,ehd->bshd", x, layer["wq"].astype(x.dtype))
-    kk = jnp.einsum("bse,ehd->bshd", x, layer["wk"].astype(x.dtype))
-    v = jnp.einsum("bse,ehd->bshd", x, layer["wv"].astype(x.dtype))
-    q = apply_rope(q, cos, sin, positions)
-    kk = apply_rope(kk, cos, sin, positions)
+    q, kk, v = qkv(x, layer, cos, sin, positions)
     q = with_logical_constraint(q, ("batch", "seq", "heads", "head_dim"), rules)
     if c.sp_axis is not None and mesh is not None:
         from ray_tpu.ops.ring_attention import ring_attention
@@ -209,13 +248,6 @@ def _flash_on_mesh(q, k, v, config: LlamaConfig, rules: ShardingRules):
                          out_specs=spec, check_vma=False)(q, k, v)
 
 
-def _mlp(x, layer):
-    g = jnp.einsum("bse,em->bsm", x, layer["w_gate"].astype(x.dtype))
-    u = jnp.einsum("bse,em->bsm", x, layer["w_up"].astype(x.dtype))
-    return jnp.einsum("bsm,me->bse", jax.nn.silu(g) * u,
-                      layer["w_down"].astype(x.dtype))
-
-
 def make_block(config: LlamaConfig, rules: ShardingRules, cos, sin,
                positions=None, mesh=None):
     """The scanned transformer block as a reusable closure — shared by the
@@ -229,7 +261,7 @@ def make_block(config: LlamaConfig, rules: ShardingRules, cos, sin,
                        layer, cos, sin, c, rules, positions, mesh)
         x = x + h
         x = with_logical_constraint(x, ("batch", "seq", "embed"), rules)
-        x = x + _mlp(rmsnorm(x, layer["mlp_norm"], c.norm_eps), layer)
+        x = x + mlp(rmsnorm(x, layer["mlp_norm"], c.norm_eps), layer)
         x = with_logical_constraint(x, ("batch", "seq", "embed"), rules)
         return x, None
 

@@ -36,6 +36,13 @@ Reference parity: the reference's serving engine gets this from vLLM
 here it is in-framework. The decode attention rides
 :mod:`ray_tpu.ops.pallas.paged_decode_attention` on TPU and its gather
 oracle elsewhere.
+
+**Where the block lives.** Its arithmetic is :mod:`ray_tpu.models.llama`'s,
+strung into one serving block by :func:`ray_tpu.models.decoding.dense_block`.
+A builder here adds which block and offset each row of its call lands at,
+and an ``attend``: write the call's K and V rows into the pool at layer
+``l``, then attend over the prompt itself (prefill), the blocks the tables
+name (decode) or one slot's gathered rows (chunk).
 """
 
 from __future__ import annotations
@@ -48,11 +55,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.models.decoding import _bind_params
-from ray_tpu.models.llama import LlamaConfig, Params
-from ray_tpu.ops.attention import on_tpu
-from ray_tpu.ops.norms import rmsnorm
-from ray_tpu.ops.rope import apply_rope, rope_frequencies
+from ray_tpu.models.decoding import (_bind_padded, _bind_params,
+                                      attend_rows, dense_block)
+from ray_tpu.models.llama import LlamaConfig, Params, embed, logits_f32
+from ray_tpu.ops.attention import mha_reference, on_tpu
+from ray_tpu.ops.rope import rope_frequencies
 
 PagedCache = Dict[str, jax.Array]
 
@@ -70,9 +77,6 @@ class PagedConfig:
     @property
     def max_blocks_per_seq(self) -> int:
         return -(-self.max_seq // self.block_size)
-
-    def tokens_capacity(self) -> int:
-        return (self.num_blocks - 1) * self.block_size
 
 
 def init_paged_cache(config: LlamaConfig, page: PagedConfig,
@@ -402,13 +406,24 @@ def _attend_paged(q, k_pool, v_pool, layer, tables, lengths, scale, work):
                                      lengths, scale=scale)
 
 
-def _scan_layers(body, x, params: Params, cache: PagedCache):
-    """Run ``body((x, k_pool, v_pool), (layer_weights, l))`` over the
-    layers with the whole pool in the scan's CARRY. A scan cannot alias
-    an ``xs`` input to a ``ys`` output, so a pool threaded through those
-    is sliced out, copied and written back layer by layer into a second
-    pool; a carry that the body only updates with ``.at[l, ...].set`` is
-    updated in place, and the donated argument becomes the result."""
+def _scan_layers(attend, x, params: Params, cache: PagedCache,
+                 c: LlamaConfig, positions):
+    """:func:`dense_block` over the layers with the whole pool in the
+    scan's CARRY, as ``attend``'s ``state = (k_pool, v_pool, l)`` in and
+    ``(k_pool, v_pool)`` out. A scan cannot alias an ``xs`` input to a
+    ``ys`` output, so a pool threaded through those is sliced out, copied
+    and written back layer by layer into a second pool; a carry that
+    ``attend`` only updates with ``.at[l, ...].set`` is updated in place,
+    and the donated argument becomes the result. -> (x, k_pool, v_pool)."""
+    cos, sin = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta)
+
+    def body(carry, scanned):
+        x, kc, vc = carry                      # pools (L, NB, bs, KV, D)
+        layer, l = scanned
+        x, (kc, vc) = dense_block(x, layer, c, cos, sin, positions, attend,
+                                  (kc, vc, l))
+        return (x, kc, vc), None
+
     n_layers = cache["k"].shape[0]
     (x, k_pool, v_pool), _ = jax.lax.scan(
         body, (x, cache["k"], cache["v"]),
@@ -440,90 +455,43 @@ def make_chunked_paged_prefill(params: Params, config: LlamaConfig,
     """
     c = config
     bs = page.block_size
-    MBS = page.max_blocks_per_seq
+    rows_shape = (1, page.max_blocks_per_seq * bs, c.n_kv_heads, c.head_dim)
 
     @functools.partial(jax.jit, donate_argnums=(1,),
                        static_argnames=("pad_len",))
     def chunk(params: Params, cache: PagedCache, table_row, tokens,
               true_len, start_pos, slot, pad_len: int):
-        cos, sin = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta)
-        x = params["embed"].astype(c.dtype)[tokens]           # (1, C, E)
         rel = jnp.arange(pad_len)
-        positions = (start_pos + rel)[None, :]
+        row_abs = start_pos + rel
         mask_valid = rel < true_len                           # (C,)
         # row-level scatter target: each chunk row lands at its exact
-        # (block, offset); invalid rows write into the null block. This
-        # supports a non-block-aligned start_pos (radix prefix hit with
-        # a copy-on-write divergence block) — rows cached before
-        # start_pos are never touched.
-        row_abs = start_pos + rel
+        # (block, offset), invalid rows in the null block; rows cached
+        # before start_pos are never touched
         row_blk = jnp.where(mask_valid, table_row[row_abs // bs], 0)
         row_off = row_abs % bs                                # (C,)
 
-        def body(carry, scanned):
-            x, kc, vc = carry                  # pools (L, NB, bs, KV, D)
-            layer, l = scanned
-            h = rmsnorm(x, layer["attn_norm"], c.norm_eps)
-            q = jnp.einsum("bse,ehd->bshd", h, layer["wq"].astype(h.dtype))
-            k = jnp.einsum("bse,ehd->bshd", h, layer["wk"].astype(h.dtype))
-            v = jnp.einsum("bse,ehd->bshd", h, layer["wv"].astype(h.dtype))
-            q = apply_rope(q, cos, sin, positions)
-            k = apply_rope(k, cos, sin, positions)
+        def attend(q, k, v, state):
+            kc, vc, l = state
             kb = jnp.where(mask_valid[:, None, None], k[0], 0.0)  # (C,KV,D)
             vb = jnp.where(mask_valid[:, None, None], v[0], 0.0)
             kc = kc.at[l, row_blk, row_off].set(kb.astype(kc.dtype))
             vc = vc.at[l, row_blk, row_off].set(vb.astype(vc.dtype))
             # gather the slot's full row set (prefix + this chunk) and
             # attend with absolute-position causal visibility
-            ks = kc[l, table_row].reshape(MBS * bs, c.n_kv_heads,
-                                          c.head_dim)
-            vs = vc[l, table_row].reshape(MBS * bs, c.n_kv_heads,
-                                          c.head_dim)
-            KV = c.n_kv_heads
-            H = q.shape[2]
-            group = H // KV
-            qg = (q[0].astype(jnp.float32)
-                  .reshape(pad_len, KV, group, -1))           # (C,KV,g,D)
-            s = jnp.einsum("ckgd,skd->kgcs", qg,
-                           ks.astype(jnp.float32)) * (c.head_dim ** -0.5)
-            allowed = (jnp.arange(MBS * bs)[None, :]
-                       <= (start_pos + rel)[:, None])         # (C, S)
-            s = jnp.where(allowed[None, None], s, -1e30)
-            p = jax.nn.softmax(s, axis=-1)
-            out = jnp.einsum("kgcs,skd->ckgd", p,
-                             vs.astype(jnp.float32))
-            out = out.reshape(1, pad_len, H, -1).astype(x.dtype)
-            x = x + jnp.einsum("bshd,hde->bse", out,
-                               layer["wo"].astype(x.dtype))
-            h2 = rmsnorm(x, layer["mlp_norm"], c.norm_eps)
-            g = jnp.einsum("bse,em->bsm", h2,
-                           layer["w_gate"].astype(h2.dtype))
-            u = jnp.einsum("bse,em->bsm", h2, layer["w_up"].astype(h2.dtype))
-            x = x + jnp.einsum("bsm,me->bse", jax.nn.silu(g) * u,
-                               layer["w_down"].astype(h2.dtype))
-            return (x, kc, vc), None
+            out = attend_rows(q, kc[l, table_row].reshape(rows_shape),
+                              vc[l, table_row].reshape(rows_shape),
+                              row_abs[None, :], c.head_dim ** -0.5)
+            return out, (kc, vc)
 
-        x, new_k, new_v = _scan_layers(body, x, params, cache)
-        x = rmsnorm(x, params["final_norm"], c.norm_eps)
-        last = x[0, jnp.maximum(true_len - 1, 0)]
-        head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
-        logits = (last.astype(jnp.float32) @ head.astype(jnp.float32))
+        x = embed(params, tokens, c)                          # (1, C, E)
+        x, new_k, new_v = _scan_layers(attend, x, params, cache, c,
+                                       row_abs[None, :])
+        logits = logits_f32(x, params, c,
+                            row=(0, jnp.maximum(true_len - 1, 0)))
         new_len = cache["length"].at[slot].set(start_pos + true_len)
         return ({"k": new_k, "v": new_v, "length": new_len}, logits)
 
-    def call(cache, table_row, tokens, true_len, start_pos, slot):
-        pad_len = tokens.shape[1]
-        if pad_len % bs:
-            raise ValueError(
-                f"chunk length {pad_len} must be a multiple of "
-                f"block_size {bs}")
-        return chunk(params, cache, jnp.asarray(table_row, jnp.int32),
-                     tokens, jnp.asarray(true_len, jnp.int32),
-                     jnp.asarray(start_pos, jnp.int32),
-                     jnp.asarray(slot, jnp.int32), pad_len=pad_len)
-
-    call.jitted = chunk
-    return call
+    return _bind_padded(chunk, params, tokens_at=1, multiple_of=bs)
 
 
 def make_paged_decode_step(params: Params, config: LlamaConfig,
@@ -549,46 +517,26 @@ def make_paged_decode_step(params: Params, config: LlamaConfig,
 
     def step(params: Params, cache: PagedCache, tables, tokens, active):
         lengths = cache["length"]
-        B = tokens.shape[0]
-        cos, sin = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta)
-        x = params["embed"].astype(c.dtype)[tokens][:, None, :]   # (B,1,E)
-        slot_rows = jnp.arange(B)
+        slot_rows = jnp.arange(tokens.shape[0])
         # physical write target of the new token per slot
         blk = tables[slot_rows, lengths // bs]                     # (B,)
         blk = jnp.where(active, blk, 0)                            # null
         off = lengths % bs
-        positions = lengths[:, None]
         att_len = jnp.where(active, lengths + 1, 0)
         work = _decode_work(att_len, page)
 
-        def body(carry, scanned):
-            x, kc, vc = carry                 # pools (L, NB, bs, KV, D)
-            layer, l = scanned
-            h = rmsnorm(x, layer["attn_norm"], c.norm_eps)
-            q = jnp.einsum("bse,ehd->bshd", h, layer["wq"].astype(h.dtype))
-            k = jnp.einsum("bse,ehd->bshd", h, layer["wk"].astype(h.dtype))
-            v = jnp.einsum("bse,ehd->bshd", h, layer["wv"].astype(h.dtype))
-            q = apply_rope(q, cos, sin, positions)
-            k = apply_rope(k, cos, sin, positions)
+        def attend(q, k, v, state):
+            kc, vc, l = state
             kc = kc.at[l, blk, off].set(k[:, 0].astype(kc.dtype))
             vc = vc.at[l, blk, off].set(v[:, 0].astype(vc.dtype))
             out = _attend_paged(q, kc, vc, l, tables, att_len,
                                 c.head_dim ** -0.5, work)
-            x = x + jnp.einsum("bshd,hde->bse", out,
-                               layer["wo"].astype(x.dtype))
-            h2 = rmsnorm(x, layer["mlp_norm"], c.norm_eps)
-            g = jnp.einsum("bse,em->bsm", h2,
-                           layer["w_gate"].astype(h2.dtype))
-            u = jnp.einsum("bse,em->bsm", h2, layer["w_up"].astype(h2.dtype))
-            x = x + jnp.einsum("bsm,me->bse", jax.nn.silu(g) * u,
-                               layer["w_down"].astype(h2.dtype))
-            return (x, kc, vc), None
+            return out, (kc, vc)
 
-        x, new_k, new_v = _scan_layers(body, x, params, cache)
-        x = rmsnorm(x, params["final_norm"], c.norm_eps)
-        head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
-        logits = jnp.einsum("be,ev->bv", x[:, 0].astype(jnp.float32),
-                            head.astype(jnp.float32))
+        x = embed(params, tokens, c)[:, None, :]                  # (B,1,E)
+        x, new_k, new_v = _scan_layers(attend, x, params, cache, c,
+                                       lengths[:, None])
+        logits = logits_f32(x, params, c, row=(slice(None), 0))
         new_len = jnp.where(active, lengths + 1, lengths)
         return ({"k": new_k, "v": new_v, "length": new_len}, logits)
 
@@ -612,61 +560,33 @@ def make_paged_prefill(params: Params, config: LlamaConfig,
     def prefill(params: Params, cache: PagedCache, table_row, tokens,
                 true_len, slot, pad_len: int):
         nblk = pad_len // bs
-        cos, sin = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta)
-        x = params["embed"].astype(c.dtype)[tokens]           # (1, P, E)
+        blocks_shape = (nblk, bs, c.n_kv_heads, c.head_dim)
         positions = jnp.arange(pad_len)[None, :]
         mask_valid = positions[0] < true_len                  # (P,)
         # rows past true_len write into the null block
         dest = jnp.where(jnp.arange(nblk) * bs < true_len,
                          table_row[:nblk], 0)                  # (nblk,)
 
-        def body(carry, scanned):
-            x, kc, vc = carry                  # pools (L, NB, bs, KV, D)
-            layer, l = scanned
-            h = rmsnorm(x, layer["attn_norm"], c.norm_eps)
-            q = jnp.einsum("bse,ehd->bshd", h, layer["wq"].astype(h.dtype))
-            k = jnp.einsum("bse,ehd->bshd", h, layer["wk"].astype(h.dtype))
-            v = jnp.einsum("bse,ehd->bshd", h, layer["wv"].astype(h.dtype))
-            q = apply_rope(q, cos, sin, positions)
-            k = apply_rope(k, cos, sin, positions)
-            from ray_tpu.ops.attention import mha_reference
-
-            out = mha_reference(q, k, v, causal=True)
-            x = x + jnp.einsum("bshd,hde->bse", out,
-                               layer["wo"].astype(x.dtype))
-            h2 = rmsnorm(x, layer["mlp_norm"], c.norm_eps)
-            g = jnp.einsum("bse,em->bsm", h2,
-                           layer["w_gate"].astype(h2.dtype))
-            u = jnp.einsum("bse,em->bsm", h2, layer["w_up"].astype(h2.dtype))
-            x = x + jnp.einsum("bsm,me->bse", jax.nn.silu(g) * u,
-                               layer["w_down"].astype(h2.dtype))
+        def attend(q, k, v, state):
+            # causal within the prompt; its k/v fill whole blocks
+            kc, vc, l = state
             kb = jnp.where(mask_valid[:, None, None], k[0],
-                           0.0).reshape(nblk, bs, c.n_kv_heads, c.head_dim)
+                           0.0).reshape(blocks_shape)
             vb = jnp.where(mask_valid[:, None, None], v[0],
-                           0.0).reshape(nblk, bs, c.n_kv_heads, c.head_dim)
+                           0.0).reshape(blocks_shape)
             kc = kc.at[l, dest].set(kb.astype(kc.dtype))
             vc = vc.at[l, dest].set(vb.astype(vc.dtype))
-            return (x, kc, vc), None
+            return mha_reference(q, k, v, causal=True), (kc, vc)
 
-        x, new_k, new_v = _scan_layers(body, x, params, cache)
-        x = rmsnorm(x, params["final_norm"], c.norm_eps)
-        last = x[0, jnp.maximum(true_len - 1, 0)]
-        head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
-        logits = (last.astype(jnp.float32) @ head.astype(jnp.float32))
+        x = embed(params, tokens, c)                          # (1, P, E)
+        x, new_k, new_v = _scan_layers(attend, x, params, cache, c,
+                                       positions)
+        logits = logits_f32(x, params, c,
+                            row=(0, jnp.maximum(true_len - 1, 0)))
         new_len = cache["length"].at[slot].set(true_len)
         return ({"k": new_k, "v": new_v, "length": new_len}, logits)
 
-    def call(cache, table_row, tokens, true_len, slot):
-        pad_len = tokens.shape[1]
-        if pad_len % bs:
-            raise ValueError(f"padded prompt {pad_len} not a multiple of "
-                             f"block_size {bs}")
-        return prefill(params, cache, jnp.asarray(table_row, jnp.int32),
-                       tokens, jnp.asarray(true_len, jnp.int32),
-                       jnp.asarray(slot, jnp.int32), pad_len=pad_len)
-
-    call.jitted = prefill
-    return call
+    return _bind_padded(prefill, params, tokens_at=1, multiple_of=bs)
 
 
 def make_paged_inject(config: LlamaConfig, page: PagedConfig):

@@ -27,8 +27,7 @@ from typing import Any, Callable, Optional, Tuple
 MECHANISMS = {
     "slot_cache": "kv_cache='slot'",
     "speculation": "speculation",
-    "prefix_cache": "a prefix cache (prefix_cache / prefix_cache_size / "
-                    "prefix_cache_bytes)",
+    "prefix_cache": "a prefix cache (prefix_cache / prefix_cache_bytes)",
     "prefill_chunk": "chunked prefill (prefill_chunk)",
     "kv_transfer": "KV inject / extract (llm_pd, submit_prefilled)",
 }

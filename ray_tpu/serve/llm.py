@@ -2,11 +2,25 @@
 
 Reference delegates this wholesale to vLLM
 (``python/ray/llm/_internal/serve/deployments/llm/vllm/vllm_engine.py``);
-here it's native: an Orca-style engine loop over the slot-based KV cache
-(:mod:`ray_tpu.models.decoding`) — admit waiting requests into free slots
-(bucketed prefill), then advance ALL active slots one token per jitted
-decode step. Batched decode keeps the MXU busy across requests; fixed
-shapes mean two compiled programs total (prefill per bucket + one decode).
+here it's native. One engine thread (:meth:`LLMEngine._loop_once`) turns
+an Orca-style loop over fixed slots: give back what a window has passed
+and grow the running slots' block tables, admit waiting requests into free
+slots (one bucketed prefill each; a long prompt or a prefix-cache suffix
+one chunk a turn), then advance ALL active slots one token in one jitted
+decode step and sample on the host (a turn whose slots are all greedy
+takes its argmax on the device).
+
+The KV cache is paged by default: a shared pool of fixed-size blocks with
+host-side block tables (:mod:`ray_tpu.models.paged_cache`). The model
+stands behind :mod:`ray_tpu.models.serving`: it brings its pool(s), its
+allocator and its two programs, prefill (one compile a padded-length
+bucket) and decode (one compile). For the dense decoder the engine itself
+adds, when asked: the chunked prefill, the radix prefix cache
+(:mod:`ray_tpu.models.prefix_cache`) with its block copy, KV inject for
+prefill/decode disaggregation, and, on ``kv_cache="slot"`` only (the flat
+per-slot cache of :mod:`ray_tpu.models.decoding`, with its own prefill,
+chunk and decode programs), speculation's batched verify. Shapes are
+fixed, so nothing compiles in steady state.
 """
 
 from __future__ import annotations
@@ -91,25 +105,30 @@ def _parse_req_spec(speculation) -> Optional[dict]:
 class LLMEngine:
     """Single-replica continuous-batching engine.
 
-    ``kv_cache="paged"`` (default) backs the slots with the block-table
-    pool of :mod:`ray_tpu.models.paged_cache`: HBM per request tracks
-    tokens actually cached, ``kv_pool_tokens`` bounds the total, and a
-    request that outgrows the pool preempts the youngest other slot
-    (vLLM-style recompute preemption: its blocks are freed and it
-    re-queues with prompt+generated-so-far as the new prompt).
-    ``kv_cache="slot"`` keeps the flat per-slot ``max_seq`` reservation.
-
     ``config`` is a model's config object. The engine takes the model
     through :func:`ray_tpu.models.serving.serving_model`: its cache(s),
     its prefill and decode programs and its allocator (one for each kind
     of KV state it keeps). A mechanism the model has no builders for
     raises ``ValueError`` here, naming it.
+
+    ``kv_cache="paged"`` (default) backs the slots with the model's
+    block-table pool: HBM per request tracks tokens actually cached,
+    ``kv_pool_tokens`` bounds the total, and a request that outgrows the
+    pool preempts the youngest other slot (vLLM-style recompute
+    preemption: its blocks are freed and it re-queues with
+    prompt+generated-so-far as the new prompt). ``kv_cache="slot"``, dense
+    decoder only, keeps the flat per-slot ``max_seq`` reservation;
+    ``speculation`` runs on it only.
+
+    ``prefill_chunk`` prefills prompts longer than it one chunk a turn;
+    ``prefix_cache="radix"`` (or a ``prefix_cache_bytes`` budget; paged
+    only) shares cached prompt blocks between requests and prefills only
+    the uncached suffix. Both are off by default.
     """
 
     def __init__(self, config=None, params=None, *, num_slots: int = 8,
                  max_seq: Optional[int] = None, model: str = "tiny",
-                 seed: int = 0, prefix_cache_size: int = 0,
-                 prefix_cache: Optional[str] = None,
+                 seed: int = 0, prefix_cache: Optional[str] = None,
                  prefix_cache_bytes: Optional[int] = None,
                  kv_cache: str = "paged",
                  kv_pool_tokens: Optional[int] = None,
@@ -118,7 +137,6 @@ class LLMEngine:
                  speculation=None,
                  spec_k: int = 4):
         import collections
-        import os
 
         import jax
 
@@ -135,12 +153,8 @@ class LLMEngine:
         self.model = serving_model(self.config)
         asked = {"slot_cache": kv_cache == "slot",
                  "speculation": speculation is not None,
-                 "prefix_cache": (
-                     prefix_cache not in (None, "off")
-                     or prefix_cache_size > 0
-                     or (prefix_cache_bytes or 0) > 0
-                     or (prefix_cache is None and os.environ.get(
-                         "RT_prefix_cache") not in (None, "off"))),
+                 "prefix_cache": (prefix_cache not in (None, "off")
+                                  or (prefix_cache_bytes or 0) > 0),
                  "prefill_chunk": prefill_chunk is not None}
         for mechanism in self.model.lacks:
             if asked.get(mechanism):
@@ -258,41 +272,21 @@ class LLMEngine:
             return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
         self._greedy_ids = jax.jit(greedy_ids)
-        # Prefix reuse across requests, OFF by default. Two modes behind
-        # one knob (prefix_cache / RT_prefix_cache env):
-        #   "radix"  — default when enabled on a paged engine: the radix
-        #              tree of ray_tpu.models.prefix_cache shares the
-        #              prompt's pool blocks read-only between requests
-        #              (block-level, zero-copy, copy-on-write divergence)
-        #              so a shared-system-prompt request prefills ONLY
-        #              its new tokens.
-        #   "legacy" — the old exact-match full-prompt host cache, kept
-        #              as a parity oracle: hits re-inject a device->host
-        #              KV copy. Only an identical prompt can ever hit.
-        # Both modes share ONE byte budget (prefix_cache_bytes); the
-        # legacy count cap (prefix_cache_size) additionally applies so
-        # old configs keep their behavior.
+        # Prefix reuse across requests, OFF by default: on with
+        # prefix_cache="radix" or when a byte budget is given. The radix
+        # tree of ray_tpu.models.prefix_cache shares the prompt's pool
+        # blocks read-only between requests (block-level, zero-copy,
+        # copy-on-write divergence), so a shared-system-prompt request
+        # prefills ONLY its new tokens.
         mode = prefix_cache
         if mode is None:
-            mode = os.environ.get("RT_prefix_cache")
-        if mode is None:
-            if prefix_cache_size > 0 or (prefix_cache_bytes or 0) > 0:
-                mode = "radix" if kv_cache == "paged" else "legacy"
-            else:
-                mode = "off"
-        if mode not in ("radix", "legacy", "off"):
-            raise ValueError(
-                f"prefix_cache={mode!r}: 'radix', 'legacy' or 'off'")
+            mode = "radix" if (prefix_cache_bytes or 0) > 0 else "off"
+        if mode not in ("radix", "off"):
+            raise ValueError(f"prefix_cache={mode!r}: 'radix' or 'off'")
         if mode == "radix" and kv_cache != "paged":
             raise ValueError("prefix_cache='radix' requires "
                              "kv_cache='paged' (it shares pool blocks)")
         self._prefix_mode = mode
-        self._prefix_cache: "collections.OrderedDict" = \
-            collections.OrderedDict()
-        self._prefix_cache_size = prefix_cache_size
-        self._prefix_cache_hostbytes = 0
-        self._prefix_hits = 0
-        self._prefix_misses = 0
         self._prefix_match_faults = 0
         self._prefix_insert_faults = 0
         self._fair_share_skips = 0
@@ -322,10 +316,6 @@ class LLMEngine:
                 # the engine wasn't configured for chunked prefill
                 self._chunk_prefill = make_chunked_paged_prefill(
                     params, self.config, self._page)
-        elif mode == "legacy" and prefix_cache_bytes is None:
-            prefix_cache_bytes = 64 << 20   # footgun fix: bytes, not
-            # just entry count — a handful of long prompts used to pin
-            # unbounded full k/v host arrays
         self._prefix_cache_bytes = prefix_cache_bytes or 0
 
         self._queue: "queue.Queue[_Request]" = queue.Queue()
@@ -497,8 +487,7 @@ class LLMEngine:
                "tokens_generated": self._tokens_generated,
                "active_slots": sum(s is not None for s in self._slots),
                "queued": self._queue.qsize() + len(self._waiting),
-               "prefix_hits": self._prefix_hits,
-               "prefix_misses": self._prefix_misses,
+               "prefix_hits": 0, "prefix_misses": 0,
                "prefill_chunks_run": self._chunks_run,
                "prefilling_slots": len(self._prefilling),
                "spec_proposed": self._spec_proposed,
@@ -536,9 +525,6 @@ class LLMEngine:
             pc.update(self._radix.stats())
             out["prefix_hits"] = pc["hits"]
             out["prefix_misses"] = pc["misses"]
-        else:
-            pc.update(entries=len(self._prefix_cache),
-                      cached_bytes=self._prefix_cache_hostbytes)
         out["prefix_cache"] = pc
         out["fair_share_skips"] = self._fair_share_skips
         mem = self._dev.memory_stats() or {}
@@ -559,13 +545,6 @@ class LLMEngine:
         try:
             if self._radix is not None:
                 return self._radix.digest()
-            if self._prefix_mode == "legacy":
-                from ray_tpu.serve.handle import _RouterState
-
-                out = set()
-                for key in list(self._prefix_cache):
-                    out.update(_RouterState._prefix_hashes(list(key)))
-                return sorted(out)[:128]
         except Exception:  # noqa: BLE001 — hint only, never a failure
             pass
         return []
@@ -607,18 +586,6 @@ class LLMEngine:
             else:
                 self._cache = self._inject(self._cache, jnp.asarray(k),
                                            jnp.asarray(v), true_len, slot)
-
-    def _extract_kv(self, slot: int, true_len: int):
-        """Device→host copy of one slot's prompt KV (rows [0, true_len))."""
-        import jax
-
-        if self.kv_cache == "paged":
-            from ray_tpu.models.paged_cache import extract_kv
-
-            return extract_kv(self._cache, self._alloc, slot, true_len)
-        k, v = jax.device_get((self._cache["k"][:, slot, :true_len],
-                               self._cache["v"][:, slot, :true_len]))
-        return np.asarray(k), np.asarray(v)
 
     def _free_slot(self) -> Optional[int]:
         for slot in range(self.num_slots):
@@ -703,29 +670,6 @@ class LLMEngine:
         self._radix.insert(toks[:nfull * bs], blocks, tenant=req.tenant,
                            max_new=max_new)
 
-    def _legacy_insert(self, key, logits_np, slot: int, plen: int,
-                       resumed: bool):
-        """Exact-match host cache insert (legacy parity oracle), now
-        under the SAME byte budget as the radix path: the old cache
-        capped entry count only, so a handful of long prompts could pin
-        unbounded full k/v host arrays."""
-        if self._prefix_mode != "legacy" or resumed:
-            return
-        self._prefix_misses += 1
-        with self._phases("kv_extract", slot=slot):
-            k, v = self._extract_kv(slot, plen)
-        self._prefix_cache[key] = {"k": k, "v": v, "logits": logits_np}
-        self._prefix_cache_hostbytes += k.nbytes + v.nbytes
-        while self._prefix_cache and (
-                (self._prefix_cache_size > 0
-                 and len(self._prefix_cache) > self._prefix_cache_size)
-                or (self._prefix_cache_bytes > 0
-                    and self._prefix_cache_hostbytes
-                    > self._prefix_cache_bytes)):
-            _, old = self._prefix_cache.popitem(last=False)
-            self._prefix_cache_hostbytes -= (old["k"].nbytes
-                                             + old["v"].nbytes)
-
     def _admit(self):
         import jax.numpy as jnp
 
@@ -797,24 +741,13 @@ class LLMEngine:
             del self._waiting[idx]
             if req.admitted_at is None:
                 req.admitted_at = time.monotonic()
-            resumed = bool(req.output)
             matched = match.matched if match is not None else 0
-            key = tuple(full_prompt)
-            cached = None
-            if (self._prefix_mode == "legacy" and req.preload is None
-                    and not resumed):
-                cached = self._prefix_cache.get(key)
             if req.preload is not None:
                 # PD handoff: prompt KV computed by a prefill replica
                 self._inject_kv(slot, req.preload["k"], req.preload["v"],
                                 plen)
                 logits_np = req.preload["logits"]
                 req.preload = None  # free the host copy
-            elif cached is not None:
-                self._prefix_hits += 1
-                self._prefix_cache.move_to_end(key)
-                self._inject_kv(slot, cached["k"], cached["v"], plen)
-                logits_np = cached["logits"]
             elif matched > 0:
                 # radix hit: the adopted blocks already hold the prefix
                 # KV — prefill ONLY the uncached suffix (TTFT tracks new
@@ -857,7 +790,6 @@ class LLMEngine:
                         self._cache, logits = self._prefill(
                             self._cache, jnp.asarray(tokens), plen, slot)
                     logits_np = self._fetch(logits, prefill=True)
-                self._legacy_insert(key, logits_np, slot, plen, resumed)
                 if self._radix is not None:
                     self._radix_insert(req, full_prompt, slot)
             tok = self._sample(logits_np.reshape(1, -1), req.temperature)[0]
@@ -1011,8 +943,6 @@ class LLMEngine:
         req = st["req"]
         del self._prefilling[slot]
         plen = len(toks)
-        resumed = bool(req.output)
-        self._legacy_insert(tuple(toks), logits_np, slot, plen, resumed)
         if self._radix is not None:
             self._radix_insert(req, toks, slot)
         tok = self._sample(logits_np.reshape(1, -1), req.temperature)[0]

@@ -80,13 +80,11 @@ class DecodeServer:
     """Decode-only replica: full slot engine, admits prefilled KV."""
 
     def __init__(self, model: str = "tiny", num_slots: int = 8,
-                 seed: int = 0, max_seq: Optional[int] = None,
-                 prefix_cache_size: int = 0):
+                 seed: int = 0, max_seq: Optional[int] = None):
         from ray_tpu.serve.llm import LLMEngine
 
         self.engine = LLMEngine(model=model, num_slots=num_slots, seed=seed,
-                                max_seq=max_seq,
-                                prefix_cache_size=prefix_cache_size)
+                                max_seq=max_seq)
 
     def submit_prefilled(self, prompt: List[int], kv: Any,
                          max_tokens: int = 64, temperature: float = 0.0,

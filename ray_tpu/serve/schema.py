@@ -131,10 +131,10 @@ def validate_config(config: Dict[str, Any]) -> Dict[str, Any]:
             # engine enforces these in __init__, but a typo'd mode
             # should fail the deploy call, not the replica boot
             pc = args["prefix_cache"]
-            if pc not in ("radix", "legacy", "off"):
+            if pc not in ("radix", "off"):
                 raise ServeConfigError(
-                    f"{where}.args.prefix_cache must be 'radix', "
-                    f"'legacy' or 'off', got {pc!r}")
+                    f"{where}.args.prefix_cache must be 'radix' or "
+                    f"'off', got {pc!r}")
         if args.get("prefix_cache_bytes") is not None:
             try:
                 pcb = int(args["prefix_cache_bytes"])

@@ -327,9 +327,9 @@ def test_preemption_returns_both_kinds_of_blocks(engine_parts):
     (dict(speculation="ngram", kv_cache="slot"), "kv_cache='slot'"),
     (dict(speculation="ngram"), "speculation"),
     (dict(prefix_cache="radix"), "prefix cache"),
-    (dict(prefix_cache_size=4), "prefix cache"),
+    (dict(prefix_cache_bytes=1 << 20), "prefix cache"),
     (dict(prefill_chunk=16), "chunked prefill")],
-    ids=["slot", "speculation-slot", "speculation", "radix", "legacy",
+    ids=["slot", "speculation-slot", "speculation", "radix", "budget",
          "chunked"])
 def test_what_the_model_lacks_raises_at_construction(engine_parts, kwargs,
                                                      names):

@@ -40,7 +40,7 @@ def test_forward_shapes_and_finite(cfg):
 def test_single_expert_equals_dense_mlp(cfg):
     """E=1, K=1, capacity ≥ tokens: MoE must reduce EXACTLY to the dense
     FFN (routing weight normalizes to 1, nothing dropped) — validates the
-    dispatch/combine einsum algebra against llama's _mlp."""
+    dispatch/combine einsum algebra against llama's mlp."""
     base = cfg.base
     one = moe.MoEConfig(base=base, n_experts=1, top_k=1,
                         capacity_factor=2.0)

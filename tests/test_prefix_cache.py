@@ -394,41 +394,28 @@ class TestChaos:
         assert st["prefix_cache"]["insert_faults"] >= 2
         assert st["prefix_cache"]["cached_blocks"] == 0
 
-    def test_legacy_parity_oracle(self):
-        """RT_prefix_cache=legacy on a paged engine: exact-match host
-        cache, same greedy tokens as radix and as off."""
-        p = PROMPTS[0]
-        want = _baseline([p, p], [8, 8])
-        eng = _engine(prefix_cache="legacy", prefix_cache_size=4)
+    def test_byte_budget_alone_turns_the_cache_on_and_bounds_it(self):
+        """A byte budget with no mode named is the radix cache, and the
+        engine's tree never holds more than the budget: two blocks'
+        worth here, under four prompts that would cache four each."""
+        itemsize = np.dtype(CFG.dtype).itemsize
+        block = 2 * CFG.n_layers * 8 * CFG.n_kv_heads * CFG.head_dim * itemsize
+        eng = _engine(prefix_cache_bytes=2 * block)
         try:
-            got = [eng.generate(p, max_tokens=8) for _ in range(2)]
+            got = [eng.generate(p, max_tokens=4) for p in PROMPTS[:4]]
             st = eng.stats()
+            eng._alloc.check_invariants()
         finally:
             eng.shutdown()
-        assert got == want
-        assert st["prefix_cache"]["mode"] == "legacy"
-        assert st["prefix_hits"] == 1
+        assert got == _baseline(PROMPTS[:4], [4] * 4)
+        pc = st["prefix_cache"]
+        assert pc["mode"] == "radix" and pc["budget_bytes"] == 2 * block
+        assert 0 < pc["cached_bytes"] <= 2 * block
+        assert st["prefix_hits"] >= 1               # SYSTEM's first blocks
 
-    def test_legacy_byte_budget(self):
-        """Footgun fix: the legacy cache is bounded by BYTES, not just
-        entry count — a budget sized for one entry holds one entry."""
-        eng = _engine(prefix_cache="legacy", prefix_cache_size=64,
-                      num_slots=2)
-        try:
-            eng.generate(PROMPTS[0], max_tokens=2)
-            one = eng._prefix_cache_hostbytes
-            assert one > 0
-        finally:
-            eng.shutdown()
-        eng = _engine(prefix_cache="legacy", prefix_cache_size=64,
-                      prefix_cache_bytes=int(one * 1.5), num_slots=2)
-        try:
-            for p in PROMPTS[:4]:
-                eng.generate(p, max_tokens=2)
-            assert len(eng._prefix_cache) == 1
-            assert eng._prefix_cache_hostbytes <= one * 1.5
-        finally:
-            eng.shutdown()
+    def test_a_mode_that_is_not_radix_or_off_is_refused(self):
+        with pytest.raises(ValueError, match="'radix' or 'off'"):
+            _engine(prefix_cache="legacy")
 
 
 class TestTenantFairShare:
@@ -572,10 +559,11 @@ class TestServeSurface:
         }]}
         out = schema.validate_config(cfg)
         assert out["applications"][0]["args"]["prefix_cache_bytes"] == 4096
-        cfg["applications"][0]["args"]["prefix_cache"] = "bogus"
-        with pytest.raises(schema.ServeConfigError,
-                           match=r"prefix_cache"):
-            schema.validate_config(cfg)
+        for refused in ("bogus", "legacy"):
+            cfg["applications"][0]["args"]["prefix_cache"] = refused
+            with pytest.raises(schema.ServeConfigError,
+                               match=r"prefix_cache"):
+                schema.validate_config(cfg)
         cfg["applications"][0]["args"]["prefix_cache"] = "off"
         cfg["applications"][0]["args"]["prefix_cache_bytes"] = -5
         with pytest.raises(schema.ServeConfigError,
