@@ -268,15 +268,31 @@ def _store(pool, where, k_rows, v_rows):
             vc.at[where].set(v_rows.astype(vc.dtype)))
 
 
-def _attend(q, kc, vc, li, tables, att_len, cfg, kind, sink):
-    """q (B, H, head_dim) -> (B, H, v_head_dim) over the paged pool."""
+def _window(cfg, kind):
+    return cfg.window if kind == "window" else None
+
+
+def _decode_work(att_len, cfg, page):
+    """The kernel's work list of each kind for a decode step's lengths,
+    built before the layer loop so that a kind's layers share it (None
+    off the TPU, where the oracle attends)."""
+    if not on_tpu():
+        return {kind: None for kind in KINDS}
+    return {kind: pha.hybrid_work_list(
+        att_len, page[kind].block_size, page[kind].max_blocks_per_seq,
+        _window(cfg, kind)) for kind in KINDS}
+
+
+def _attend(q, kc, vc, li, tables, att_len, cfg, kind, sink, work):
+    """q (B, H, head_dim) -> (B, H, v_head_dim) over the paged pool;
+    ``att_len`` 0 for a slot that is not running, ``work`` from
+    :func:`_decode_work` of the same lengths."""
     kw = dict(scale=cfg.head_dim ** -0.5, k_slices=key_slices(cfg, kind),
-              dv=cfg.v_head_dim, sink=sink,
-              window=cfg.window if kind == "window" else None)
+              dv=cfg.v_head_dim, sink=sink, window=_window(cfg, kind))
     qp = pack_queries(q, cfg, kind)
     if on_tpu():
         return pha.paged_hybrid_decode_attention(
-            qp, kc, vc, li, tables, att_len,
+            qp, kc, vc, li, tables, att_len, work=work,
             name=f"paged_hybrid_decode_{kind}", **kw)
     return pha.paged_hybrid_attention_reference(qp, kc, vc, li, tables,
                                                 att_len, **kw)
@@ -299,6 +315,10 @@ def make_decode_step(params: Params, cfg: MimoV2Config,
         blk = {kind: jnp.where(active, tables[kind][rows, lengths // bs], 0)
                for kind in KINDS}
         off = lengths % bs
+        # a slot that is not running attends nothing, whatever stale
+        # length it keeps
+        att_len = jnp.where(active, lengths + 1, 0)
+        work = _decode_work(att_len, cfg, page)
         pools = {kind: (cache[kind]["k"], cache[kind]["v"])
                  for kind in KINDS}
         index = {kind: 0 for kind in KINDS}
@@ -310,8 +330,8 @@ def make_decode_step(params: Params, cfg: MimoV2Config,
             kc, vc = pools[kind] = _store(
                 pools[kind], (li, blk[kind], off),
                 pack_keys(k[:, 0], cfg), v[:, 0].reshape(B, -1))
-            out = _attend(q[:, 0], kc, vc, li, tables[kind], lengths + 1,
-                          cfg, kind, layer.get("sink"))
+            out = _attend(q[:, 0], kc, vc, li, tables[kind], att_len,
+                          cfg, kind, layer.get("sink"), work[kind])
             x = x + jnp.einsum("bhd,hde->be", out,
                                layer["wo"].astype(x.dtype))[:, None, :]
             y, c = _mlp(rmsnorm(x[:, 0], layer["mlp_norm"], cfg.norm_eps),
@@ -358,7 +378,7 @@ def make_prefill(params: Params, cfg: MimoV2Config,
             q, k, v = _qkv(x, layer, cfg, *ropes[kind], None)
             out = hybrid_attention_reference(
                 q, k, v, scale=cfg.head_dim ** -0.5, sink=layer.get("sink"),
-                window=cfg.window if kind == "window" else None)
+                window=_window(cfg, kind))
             x = x + jnp.einsum("bshd,hde->bse", out,
                                layer["wo"].astype(x.dtype))
             kb = jnp.where(valid[:, None], pack_keys(k[0], cfg), 0.0)

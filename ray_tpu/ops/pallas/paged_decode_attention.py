@@ -58,7 +58,9 @@ NEG_INF = -1e30
 _LANES = 128
 
 
-def decode_work_list(lengths, block_s: int, max_blocks: int):
+def decode_work_list(lengths, block_s: int, max_blocks: int,
+                     first_block=None, max_pairs=None,
+                     blocks_per_step: int = 1):
     """The (slot, logical block) pairs that hold cached tokens, in slot
     order: ``{(s, j): j < ceil(lengths[s] / block_s)}``. Returns
     ``(n_work () i32, work_slot (B * max_blocks + 1,) i32, work_block
@@ -67,18 +69,39 @@ def decode_work_list(lengths, block_s: int, max_blocks: int):
     every table full too, hence the + 1; without it that run halted the
     chip) and has to find valid indices there. A few fused integer
     operations that depend on the lengths alone: a caller with many layers
-    builds the list once a decode step, not once a layer."""
+    builds the list once a decode step, not once a layer.
+
+    For a caller that reads only a slot's last blocks (a sliding window):
+    ``first_block`` (B,) i32 is the first logical block of each slot's
+    pairs, ``j >= first_block[s]``, and ``max_pairs`` the most pairs a
+    slot can then have (it sizes the lists in place of ``max_blocks``).
+    For a kernel that takes ``blocks_per_step`` = G blocks in a step: a
+    slot's every G-th pair, each standing for blocks ``j .. j + G - 1``
+    (the last may run past the slot's end), in lists of ``B *
+    ceil(pairs a slot / G) + 1``."""
     B = lengths.shape[0]
     nblk = jnp.clip((lengths.astype(jnp.int32) + block_s - 1) // block_s,
                     0, max_blocks)
+    per_slot = max_blocks
+    if first_block is not None:
+        nblk = jnp.clip(nblk - first_block, 0, max_pairs)
+        per_slot = max_pairs
+    if blocks_per_step != 1:
+        nblk = (nblk + blocks_per_step - 1) // blocks_per_step
+        per_slot = -(-per_slot // blocks_per_step)
     ends = jnp.cumsum(nblk)
     n_work = ends[-1]
-    i = jnp.minimum(jnp.arange(B * max_blocks + 1, dtype=jnp.int32),
+    i = jnp.minimum(jnp.arange(B * per_slot + 1, dtype=jnp.int32),
                     jnp.maximum(n_work - 1, 0))
     # pair i belongs to the first slot whose pairs end past i
     slot = jnp.minimum(jnp.sum(i[:, None] >= ends[None, :], axis=1,
                                dtype=jnp.int32), B - 1)
-    return n_work, slot, i - (ends - nblk)[slot]
+    block = i - (ends - nblk)[slot]
+    if blocks_per_step != 1:
+        block = block * blocks_per_step
+    if first_block is not None:
+        block = block + first_block[slot]
+    return n_work, slot, block
 
 
 def _paged_kernel(layer_ref, tables_ref, len_ref, slot_ref, block_ref,

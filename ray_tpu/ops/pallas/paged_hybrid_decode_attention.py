@@ -18,19 +18,34 @@ Here the pools are lane-dense, one row a token:
 
 so a 192-wide key needs no padding to 256 lanes: the model packs it into
 whole chunks (``ray_tpu.models.mimo_v2.pack_keys``) and every slice the
-kernel takes is aligned. ``layer``, ``tables`` and ``lengths`` ride as
-scalar prefetch, as in the dense kernel.
+kernel takes is aligned. ``layer``, ``tables``, ``lengths`` and the work
+list ride as scalar prefetch, as in the dense kernel.
 
-The grid is (slots, blocks visited). A full layer visits every logical
-block up to the slot's last (``tables.shape[1]`` steps, the ones past the
-last repeat its index, so nothing more is fetched). A window layer visits
-``(window - 2) // bs + 2`` blocks starting at the one that holds position
-``length - window``: blocks wholly behind the window are never named by
-the index map, so they are never read, and the allocator may have freed
-them (their table entries are then the null block).
+The grid is the WORK LIST (:func:`hybrid_work_list`, the dense kernel's
+``decode_work_list`` with a first block and G blocks a step): one step
+for each run of G logical blocks of a slot that the call must read, in
+slot order, and none for any other; its bound is the list's length, a
+traced scalar. A full layer's blocks are a slot's ``0 .. ceil(length /
+bs) - 1``, G = 4 of them a step; a window layer's start at the block
+that holds position ``length - window`` and are at most
+``blocks_in_window``, all in ONE step: blocks wholly behind the window
+are never named, so they are never read, and the allocator may have
+freed them (their table entries are then the null block). A slot of
+length 0 has no step and costs nothing; its output row is zeros. The
+pool is passed G times, with an index map for each block of a step; one
+past the slot's last block names the last again, and its keys are masked
+like any past the length.
 
-Online softmax as in ``decode_attention.py``; the sink joins the
-denominator once, at the end: ``l += exp(sink - m)``.
+A step is ONE scores product and ONE values product for all heads and
+all G blocks. A slot's first step lays each head's packed query out as
+wide as a key row (its chunks at its own kv head's, exact zeros
+elsewhere), so ``q' (H, Wk) . K^T (Wk, G * bs)`` is every head's scores
+with no slice of a block and no mask by head; one online-softmax update
+(as in ``decode_attention.py``) runs on the ``(H, G * bs)`` tile; ``p (H,
+G * bs) . V (G * bs, KV * Dv)`` goes into an ``(H, KV * Dv)`` float32
+accumulator, of which a head keeps its own kv head's ``Dv`` columns
+once, at the slot's last step. There the sink joins the denominator too:
+``l += exp(sink - m)``.
 """
 
 from __future__ import annotations
@@ -43,8 +58,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ray_tpu.ops.pallas.paged_decode_attention import decode_work_list
+
 NEG_INF = -1e30
 _LANES = 128
+# Blocks of a full layer in a step. On a v5e at 64 heads, 4 kv heads, keys
+# 192 and block 64 a block costs 0.65 / 0.44 / 0.39 / 0.35 / 0.33 / 0.34 us
+# at 1 / 2 / 3 / 4 / 6 / 8 a step (its copy: 0.20): a step's fixed cost is
+# shared, the products fill the MXU's tiles, and a slot's last step reads
+# up to G - 1 blocks for nothing.
+_FULL_BLOCKS_PER_STEP = 4
 
 
 def blocks_in_window(window: int, block_size: int) -> int:
@@ -58,129 +81,200 @@ def _first_block(length, window, block_s):
     return jnp.maximum(length - window, 0) // block_s
 
 
-def _kernel(layer_ref, tables_ref, len_ref, q_ref, k_ref, v_ref, *rest,
-            scale: float, block_s: int, n_visit: int, group: int, dv: int,
-            chunk: int, k_slices, window: Optional[int], has_sink: bool):
-    del layer_ref, tables_ref            # used by the index maps only
-    if has_sink:
-        sink_ref, o_ref, acc_ref, m_ref, l_ref = rest
-    else:
-        o_ref, acc_ref, m_ref, l_ref = rest
-    b = pl.program_id(0)
-    ib = pl.program_id(1)
+def _blocks_visited(window, block_s: int, max_blocks: int) -> int:
+    """The most blocks of a slot that a call reads."""
+    if window is None:
+        return max_blocks
+    return min(blocks_in_window(window, block_s), max_blocks)
 
-    @pl.when(ib == 0)
+
+def blocks_per_step(window, block_s: int, max_blocks: int) -> int:
+    """G: a window layer's blocks all in one step, a full layer's by
+    :data:`_FULL_BLOCKS_PER_STEP`."""
+    n_visit = _blocks_visited(window, block_s, max_blocks)
+    return n_visit if window is not None else min(_FULL_BLOCKS_PER_STEP,
+                                                  n_visit)
+
+
+def hybrid_work_list(lengths, block_s: int, max_blocks: int,
+                     window: Optional[int] = None):
+    """The work list of a call over ``lengths`` (B,) (the new token
+    included; 0 for a slot that is not running): :func:`decode_work_list`
+    from each slot's first block in the window, in this kernel's blocks a
+    step. It depends on the lengths alone, so a decode step builds one
+    for its full layers and one for its window layers."""
+    lengths = lengths.astype(jnp.int32)
+    return decode_work_list(
+        lengths, block_s, max_blocks,
+        first_block=(None if window is None
+                     else _first_block(lengths, window, block_s)),
+        max_pairs=_blocks_visited(window, block_s, max_blocks),
+        blocks_per_step=blocks_per_step(window, block_s, max_blocks))
+
+
+def _kernel(layer_ref, tables_ref, len_ref, slot_ref, block_ref, q_ref,
+            *rest, scale: float, block_s: int, max_blocks: int, G: int,
+            group: int, dv: int, chunk: int, k_slices,
+            window: Optional[int], has_sink: bool):
+    del layer_ref, tables_ref            # used by the index maps only
+    k_refs, v_refs, rest = rest[:G], rest[G:2 * G], rest[2 * G:]
+    if has_sink:
+        sink_ref, o_ref, qw_ref, acc_ref, m_ref, l_ref = rest
+    else:
+        o_ref, qw_ref, acc_ref, m_ref, l_ref = rest
+    i = pl.program_id(0)
+    ib = block_ref[i]                    # the step's first block
+    length = len_ref[slot_ref[i]]
+    first = _first_block(length, window, block_s)
+    H = q_ref.shape[1]
+
+    def kv_of_row(width):                # each query row's kv head
+        return jax.lax.broadcasted_iota(jnp.int32, (H, width), 0) // group
+
+    @pl.when(ib == first)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
+        # The query as wide as a key row: chunk t of a head's packed
+        # query at the start its kv head's key has it, zeros in every
+        # other chunk. Whole (H, chunk) tiles, selected by row.
+        for st in range(0, qw_ref.shape[1], chunk):
+            piece = jnp.zeros((H, chunk), qw_ref.dtype)
+            for j, starts in enumerate(k_slices):
+                for t, s_jt in enumerate(starts):
+                    if s_jt == st:
+                        piece = jnp.where(
+                            kv_of_row(chunk) == j,
+                            q_ref[0, :, t * chunk:(t + 1) * chunk], piece)
+            qw_ref[:, st:st + chunk] = piece
 
-    length = len_ref[b]
-    blk = _first_block(length, window, block_s) + ib
+    # the step's G blocks as one: token rows ib * bs .. (ib + G) * bs - 1
+    k_rows = jnp.concatenate([r[0] for r in k_refs], axis=0)
+    v_rows = jnp.concatenate([r[0] for r in v_refs], axis=0)
+    # Every head's scores in one product: the zeros of the wide query
+    # add nothing, so no head is masked and no key is sliced out.
+    s = jax.lax.dot_general(
+        qw_ref[:], k_rows, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale      # (H, G * bs)
+    col = ib * block_s + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    ok = col < length
+    if window is not None:
+        ok &= col >= length - window
+    s = jnp.where(ok, s, NEG_INF)
 
-    @pl.when(blk * block_s < length)
-    def _compute():
-        for j, starts in enumerate(k_slices):   # static unroll, kv heads
-            lo, hi = j * group, (j + 1) * group
-            q = q_ref[0, lo:hi, :]                          # (group, n*c)
-            k = jnp.concatenate(
-                [k_ref[0, :, st:st + chunk] for st in starts], axis=1)
-            v = v_ref[0, :, j * dv:(j + 1) * dv]            # (bs, dv)
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale  # (group, bs)
-            col = blk * block_s + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            ok = col < length
-            if window is not None:
-                ok &= col >= length - window
-            s = jnp.where(ok, s, NEG_INF)
+    m_prev = m_ref[:, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    alpha = jnp.exp(m_prev - m_new)
+    l_ref[:] = jnp.broadcast_to(
+        l_ref[:, :1] * alpha + jnp.sum(p, axis=1, keepdims=True),
+        l_ref.shape)
+    # every kv head's values at once: (H, KV * dv), a head's own slice
+    # is taken at the end
+    acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
+        p.astype(v_rows.dtype), v_rows, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
 
-            m_prev = m_ref[lo:hi, :1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            alpha = jnp.exp(m_prev - m_new)
-            l_ref[lo:hi, :] = jnp.broadcast_to(
-                l_ref[lo:hi, :1] * alpha + jnp.sum(p, axis=1,
-                                                   keepdims=True),
-                (group, _LANES))
-            acc_ref[lo:hi, :] = acc_ref[lo:hi, :] * alpha + \
-                jax.lax.dot_general(
-                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-            m_ref[lo:hi, :] = jnp.broadcast_to(m_new, (group, _LANES))
+    # the slot's last step: the one its length ends in, or the last the
+    # table or the window allows
+    n_visit = _blocks_visited(window, block_s, max_blocks)
 
-    @pl.when(ib == n_visit - 1)
+    @pl.when(((ib + G) * block_s >= length)
+             | (ib + G >= jnp.minimum(first + n_visit, max_blocks)))
     def _finalize():
         l = l_ref[:, :1]
         if has_sink:
             l = l + jnp.exp(sink_ref[:, :1] - m_ref[:, :1])
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
+        out = jnp.zeros((H, dv), jnp.float32)
+        for j in range(len(k_slices)):
+            out = jnp.where(kv_of_row(dv) == j,
+                            acc_ref[:, j * dv:(j + 1) * dv], out)
+        o_ref[0] = (out / l_safe).astype(o_ref.dtype)
 
 
 def paged_hybrid_decode_attention(
         q, k_pool, v_pool, layer, tables, lengths, *, scale: float,
         k_slices: Sequence[Tuple[int, ...]], dv: int,
-        window: Optional[int] = None, sink=None,
+        window: Optional[int] = None, sink=None, work=None,
         name: str = "paged_hybrid_decode", interpret: bool = False):
     """q (B, H, n*c) packed; k_pool (L, NB, bs, Wk); v_pool
     (L, NB, bs, KV*dv); layer () int32; tables (B, MBS) int32; lengths
-    (B,) int32, the new token included; ``sink`` (H,) float32 or None.
-    -> (B, H, dv) in q.dtype. ``name`` is the custom call's instruction
-    name, so a trace tells a full layer's calls from a window layer's."""
+    (B,) int32, the new token included, 0 for a slot that is not running;
+    ``sink`` (H,) float32 or None. -> (B, H, dv) in q.dtype; the row of a
+    slot of length 0 is zeros. ``name`` is the custom call's instruction
+    name, so a trace tells a full layer's calls from a window layer's.
+
+    ``work`` is ``hybrid_work_list(lengths, bs, MBS, window)`` from a
+    caller that attends many layers over the same lengths and builds the
+    list once; built here when absent."""
     B, H, qw = q.shape
-    bs = k_pool.shape[2]
+    bs, Wk = k_pool.shape[2], k_pool.shape[3]
+    MBS = tables.shape[1]
     KV = len(k_slices)
     if H % KV:
         raise ValueError(f"q heads {H} not a multiple of kv heads {KV}")
     chunk = qw // len(k_slices[0])
-    n_visit = (tables.shape[1] if window is None
-               else min(blocks_in_window(window, bs), tables.shape[1]))
+    G = blocks_per_step(window, bs, MBS)
+    lengths = lengths.astype(jnp.int32)
+    n_work, work_slot, work_block = (
+        hybrid_work_list(lengths, bs, MBS, window) if work is None else work)
     kernel = functools.partial(
-        _kernel, scale=scale, block_s=bs, n_visit=n_visit, group=H // KV,
-        dv=dv, chunk=chunk, k_slices=tuple(map(tuple, k_slices)),
-        window=window, has_sink=sink is not None)
+        _kernel, scale=scale, block_s=bs, max_blocks=MBS, G=G,
+        group=H // KV, dv=dv, chunk=chunk,
+        k_slices=tuple(map(tuple, k_slices)), window=window,
+        has_sink=sink is not None)
 
-    def kv_ix(b, ib, layer_ref, tables_ref, len_ref):
-        length = len_ref[b]
-        last = jnp.maximum(length - 1, 0) // bs
-        blk = jnp.minimum(_first_block(length, window, bs) + ib, last)
-        return (layer_ref[0], tables_ref[b, blk], 0, 0)
+    def kv_ix(g):
+        """Block g of a step; past the slot's last block, that one."""
+        def ix(i, layer_ref, tables_ref, len_ref, slot_ref, block_ref):
+            slot = slot_ref[i]
+            last = jnp.minimum(jnp.maximum(len_ref[slot] - 1, 0) // bs,
+                               MBS - 1)
+            blk = jnp.minimum(block_ref[i] + g, last)
+            return (layer_ref[0], tables_ref[slot, blk], 0, 0)
+        return ix
 
-    def row_ix(b, ib, *_):
-        return (b, 0, 0)
+    def slot_ix(i, layer_ref, tables_ref, len_ref, slot_ref, block_ref):
+        return (slot_ref[i], 0, 0)
 
-    in_specs = [pl.BlockSpec((1, H, qw), row_ix),
-                pl.BlockSpec((None, 1, bs, k_pool.shape[3]), kv_ix),
-                pl.BlockSpec((None, 1, bs, v_pool.shape[3]), kv_ix)]
-    args = [q, k_pool, v_pool]
+    in_specs = ([pl.BlockSpec((1, H, qw), slot_ix)]
+                + [pl.BlockSpec((None, 1, bs, Wk), kv_ix(g))
+                   for g in range(G)]
+                + [pl.BlockSpec((None, 1, bs, v_pool.shape[3]), kv_ix(g))
+                   for g in range(G)])
+    args = [q] + [k_pool] * G + [v_pool] * G
     if sink is not None:
-        in_specs.append(pl.BlockSpec((H, _LANES), lambda b, ib, *_: (0, 0)))
+        in_specs.append(pl.BlockSpec((H, _LANES), lambda i, *_: (0, 0)))
         args.append(jnp.broadcast_to(
             sink.astype(jnp.float32)[:, None], (H, _LANES)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, n_visit),
+        num_scalar_prefetch=5,
+        grid=(n_work,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, H, dv), row_ix),
+        out_specs=pl.BlockSpec((1, H, dv), slot_ix),
         scratch_shapes=[
-            pltpu.VMEM((H, dv), jnp.float32),
+            pltpu.VMEM((H, Wk), q.dtype),
+            pltpu.VMEM((H, KV * dv), jnp.float32),
             pltpu.VMEM((H, _LANES), jnp.float32),
             pltpu.VMEM((H, _LANES), jnp.float32),
         ],
     )
     with jax.named_scope(name):
-        return pl.pallas_call(
+        out = pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((B, H, dv), q.dtype),
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary")),
+                dimension_semantics=("arbitrary",)),
             interpret=interpret,
             name=name,
         )(jnp.asarray(layer, jnp.int32).reshape(1),
-          tables.astype(jnp.int32), lengths.astype(jnp.int32), *args)
+          tables.astype(jnp.int32), lengths, work_slot, work_block, *args)
+        # no step visits an empty slot, so nothing wrote its row
+        return jnp.where((lengths > 0)[:, None, None], out, 0)
 
 
 def paged_hybrid_attention_reference(

@@ -167,6 +167,48 @@ def test_paged_decode_attention_compiles_at_1b_widths(one_chip, shape):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("kind", ["full", "window"])
+def test_paged_hybrid_decode_attention_compiles_at_the_moe_cells_widths(
+        one_chip, kind):
+    """`serve-moe-window-decode`'s own shapes: 64 heads, keys 192 and
+    values 128 wide, 4 (full) or 8 (window) kv heads, block 64, 128
+    slots, 40 blocks a table; the work list built once outside the call,
+    as the decode step hands it over."""
+    from ray_tpu.models import mimo_v2
+    from ray_tpu.ops.pallas import paged_hybrid_decode_attention as pha
+
+    cfg = mimo_v2.MimoV2Config(
+        n_heads=64, n_kv_heads=4, swa_n_kv_heads=8, head_dim=192,
+        v_head_dim=128, rotary_dim=64, window=128)
+    slots, bs, cols, kv = 128, 64, 40, cfg.kv_heads(kind)
+    layers, nb = (2, 5121) if kind == "full" else (5, 385)
+    window = cfg.window if kind == "window" else None
+    q = _sds((slots, 64, cfg.head_dim), jnp.bfloat16, one_chip)
+    k_pool = _sds((layers, nb, bs, kv * cfg.head_dim), jnp.bfloat16,
+                  one_chip)
+    v_pool = _sds((layers, nb, bs, kv * cfg.v_head_dim), jnp.bfloat16,
+                  one_chip)
+    layer = _sds((), jnp.int32, one_chip)
+    tables = _sds((slots, cols), jnp.int32, one_chip)
+    lens = _sds((slots,), jnp.int32, one_chip)
+    sink = _sds((64,), jnp.float32, one_chip)
+
+    def attend(q, k, v, l, t, n, sink):
+        return pha.paged_hybrid_decode_attention(
+            mimo_v2.pack_queries(q, cfg, kind), k, v, l, t, n,
+            work=pha.hybrid_work_list(n, bs, cols, window),
+            scale=cfg.head_dim ** -0.5,
+            k_slices=mimo_v2.key_slices(cfg, kind), dv=cfg.v_head_dim,
+            window=window, sink=sink if window else None,
+            name=f"paged_hybrid_decode_{kind}")
+
+    compiled = jax.jit(attend).lower(
+        q, k_pool, v_pool, layer, tables, lens, sink).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert f"paged_hybrid_decode_{kind}" in text
+
+
 # ------------------------------------------------- the 1b serving programs
 def _engine_shapes(one_chip, num_slots=8, cfg=CFG):
     """What LLMEngine(model="1b") builds by default, as shapes."""

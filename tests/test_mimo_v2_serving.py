@@ -3,6 +3,7 @@ by share (``ray_tpu.models.mimo_v2``), at a small size on the CPU,
 against the benchmark's plain reference
 (``benchmark/reference/mimo_v2.py``) on seeded random weights."""
 
+import functools
 import os
 import sys
 
@@ -101,28 +102,170 @@ def test_window_blocks_are_given_back_and_reused():
 def test_paged_kernel_interpret_matches_its_oracle(kind):
     """Keys wider than values, packed rows, the sink, the window and a
     table whose blocks behind the window are the null block."""
+    got, want, _ = _run_kernel_case(kind, [1, 29, 48])
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+# ------------------------------------- the kernel walks live blocks only
+BS, MBS, WINDOW = 8, 6, 16          # MimoV2Config()'s window
+
+
+def _blocks_read(kind, n):
+    """Logical blocks of a slot of length n that a call must read."""
+    first = max(n - WINDOW, 0) // BS if kind == "window" else 0
+    last = min(-(-n // BS), MBS)
+    if kind == "window":
+        last = min(last, first + pha.blocks_in_window(WINDOW, BS))
+    return list(range(first, last))
+
+
+def _run_kernel_case(kind, lengths):
+    """The kernel (interpreted) on pools whose every block that the call
+    has no business reading is NaN, the null block among them, against
+    the oracle on the clean pools. A table names only the blocks
+    :func:`_blocks_read` lists: the rest are the null block, as after
+    the allocator gave back what the window passed.
+    -> (got, want, live rows)."""
     cfg = mimo_v2.MimoV2Config(n_heads=8)
-    KV, bs, MBS, B = cfg.kv_heads(kind), 8, 6, 3
+    assert cfg.window == WINDOW
+    KV, B = cfg.kv_heads(kind), len(lengths)
     ks = jax.random.split(jax.random.key(2), 5)
     q = jax.random.normal(ks[0], (B, 8, cfg.head_dim), jnp.float32)
-    kp = jax.random.normal(ks[1], (2, 20, bs, KV * cfg.head_dim))
-    vp = jax.random.normal(ks[2], (2, 20, bs, KV * cfg.v_head_dim))
+    NB = 1 + B * MBS
+    kp = jax.random.normal(ks[1], (2, NB, BS, KV * cfg.head_dim))
+    vp = jax.random.normal(ks[2], (2, NB, BS, KV * cfg.v_head_dim))
     sink = jax.random.normal(ks[3], (8,)) if kind == "window" else None
-    lengths = jnp.array([1, 29, 48], jnp.int32)
-    tables = np.arange(1, 1 + B * MBS, dtype=np.int32).reshape(B, MBS)
-    if kind == "window":
-        tables[1, :1] = 0               # behind the window of 16: freed
-        tables[2, :4] = 0
+    tables = np.zeros((B, MBS), np.int32)
+    for s, n in enumerate(lengths):
+        for j in _blocks_read(kind, n):
+            tables[s, j] = 1 + s * MBS + j
+    dead = np.ones(NB, bool)
+    dead[tables[tables > 0]] = False
+    poison = lambda pool: pool.at[:, dead].set(jnp.nan)  # noqa: E731
     kw = dict(scale=cfg.head_dim ** -0.5, dv=cfg.v_head_dim, sink=sink,
               k_slices=mimo_v2.key_slices(cfg, kind),
-              window=cfg.window if kind == "window" else None)
+              window=WINDOW if kind == "window" else None)
     qp = mimo_v2.pack_queries(q, cfg, kind)
+    lens = jnp.asarray(lengths, jnp.int32)
     got = pha.paged_hybrid_decode_attention(
-        qp, kp, vp, 1, jnp.asarray(tables), lengths, interpret=True, **kw)
+        qp, poison(kp), poison(vp), 1, jnp.asarray(tables), lens,
+        interpret=True, **kw)
     want = pha.paged_hybrid_attention_reference(
-        qp, kp, vp, 1, jnp.asarray(tables), lengths, **kw)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-5, atol=2e-5)
+        qp, kp, vp, 1, jnp.asarray(tables), lens, **kw)
+    return np.asarray(got), np.asarray(want), np.asarray(lengths) > 0
+
+
+KERNEL_LENGTHS = {
+    "empty-slot-between-running": [29, 0, 41],
+    "every-table-full": [MBS * BS] * 3,
+    "every-slot-empty": [0, 0, 0],
+    "on-a-block-boundary-and-one-past": [8, 9, 16, 17, 40, 41],
+    "shorter-than-the-window": [1, 5, 15, 16],
+    # keys (n - 16, n]: 24 -> blocks 1-2, 25 -> 1-3, 32 -> 2-3, 47 -> 3-5
+    "window-touches-two-and-three-blocks": [24, 25, 32, 33, 47],
+}
+
+
+@pytest.mark.parametrize("kind", ["full", "window"])
+@pytest.mark.parametrize("case", KERNEL_LENGTHS)
+def test_paged_kernel_reads_the_live_blocks_and_no_other(case, kind):
+    got, want, live = _run_kernel_case(kind, KERNEL_LENGTHS[case])
+    assert np.isfinite(got).all()                # no dead block was read
+    np.testing.assert_allclose(got[live], want[live], rtol=2e-5, atol=2e-5)
+    assert not got[~live].any()                  # zeros, not garbage
+
+
+@pytest.mark.parametrize("kind", ["full", "window"])
+@pytest.mark.parametrize("lengths, bs, mbs", [
+    ([29, 0, 41], 8, 6), ([0, 0, 0], 8, 6), ([48, 48], 8, 6),
+    ([8, 9, 16, 17], 8, 6), ([1, 15, 16, 24, 25, 47], 8, 6),
+    ([700, 64, 129], 64, 4), ([5, 100], 64, 2), ([300, 257, 600], 64, 10)])
+def test_work_list_is_the_steps_a_call_takes_in_slot_order(lengths, bs,
+                                                           mbs, kind):
+    """Against a plain enumeration: every G-th of the blocks a call
+    reads of each slot, their order, the bound, and the one entry more
+    that the pipeline's lookahead lands on."""
+    window = WINDOW if kind == "window" else None
+    n_work, slot, block = jax.jit(
+        pha.hybrid_work_list, static_argnums=(1, 2, 3))(
+            jnp.asarray(lengths, jnp.int32), bs, mbs, window)
+    most = min(pha.blocks_in_window(WINDOW, bs), mbs) if window else mbs
+    G = pha.blocks_per_step(window, bs, mbs)
+    assert G == (most if window else min(4, mbs))
+    want = []
+    for s, n in enumerate(lengths):
+        first = max(n - WINDOW, 0) // bs if window else 0
+        want += [(s, j) for j in range(
+            first, min(-(-n // bs), mbs, first + most), G)]
+    assert int(n_work) == len(want)
+    assert slot.shape == block.shape == (len(lengths) * -(-most // G) + 1,)
+    got = list(zip(np.asarray(slot).tolist(), np.asarray(block).tolist()))
+    assert got[:len(want)] == want
+    assert set(got[len(want):]) <= {want[-1] if want
+                                    else (len(lengths) - 1, 0)}
+
+
+@pytest.fixture
+def kernel_on_cpu(monkeypatch):
+    """The decode step asks ``on_tpu()`` whether to build the work lists
+    and call the kernel; here it says no. Steer it from the test: the
+    kernel, interpreted."""
+    monkeypatch.setattr(mimo_v2, "on_tpu", lambda: True)
+    monkeypatch.setattr(
+        pha, "paged_hybrid_decode_attention", functools.partial(
+            pha.paged_hybrid_decode_attention, interpret=True))
+
+
+def test_a_stale_slot_between_two_running_ones_is_not_attended(
+        kernel_on_cpu):
+    """Nothing resets ``cache["length"]`` when a slot is released: slot 1
+    keeps its 37 between two running slots, its blocks go to them as
+    they grow, and their greedy tokens are the reference's."""
+    cfg = mimo_v2.MimoV2Config(**dict(ARCH.program_kwargs(SPEC),
+                                      dtype=jnp.float32))
+    params = make_params(SPEC, 11, jnp.float32)
+    page = mimo_v2.pages(cfg, num_slots=3, max_seq=128, block_size=8,
+                         pool_tokens=12 * 8)
+    alloc = mimo_v2.make_manager(cfg, page, 3)
+    cache = mimo_v2.init_cache(cfg, page, 3)
+    prefill = mimo_v2.make_prefill(params, cfg, page)
+    decode = mimo_v2.make_decode_step(params, cfg, page)
+    seqs = {s: list(np.asarray(jax.random.randint(
+        jax.random.key(s), (n,), 0, 256))) for s, n in ((0, 21), (1, 37),
+                                                        (2, 6))}
+    prompt_len = {s: len(t) for s, t in seqs.items()}
+    for s in (1, 0, 2):
+        assert alloc.ensure(s, prompt_len[s] + 1)
+        padded = np.zeros((1, -(-prompt_len[s] // 8) * 8), np.int32)
+        padded[0, :prompt_len[s]] = seqs[s]
+        cache, lg = prefill(cache, alloc.table_rows(s), jnp.asarray(padded),
+                            prompt_len[s], s)
+        seqs[s].append(int(np.asarray(lg).argmax()))
+    alloc.release(1)
+    active = np.array([True, False, True])
+    step_logits = {0: [], 2: []}
+    for _ in range(14):
+        last = np.zeros(3, np.int32)
+        for s in (0, 2):
+            alloc.trim(s, len(seqs[s]))
+            assert alloc.ensure(s, len(seqs[s]))
+            last[s] = seqs[s][-1]
+        cache, lg = decode(cache, alloc.device_tables(), jnp.asarray(last),
+                           jnp.asarray(active))
+        for s in (0, 2):
+            step_logits[s].append(np.asarray(lg)[s])
+            seqs[s].append(int(step_logits[s][-1].argmax()))
+    assert np.asarray(cache["length"]).tolist() == [21 + 14, 37, 6 + 14]
+    # 5 + 3 blocks of 12, where 9 were taken before slot 1 left: its
+    # blocks were taken again by its neighbours
+    assert alloc.pools()["full"]["blocks_free"] == 12 - 5 - 3
+    for s in (0, 2):
+        want = np.asarray(REF.logits(
+            params, jnp.asarray(seqs[s][:-1]), SPEC,
+            list(range(prompt_len[s], len(seqs[s]) - 1))))
+        got = np.stack(step_logits[s])
+        assert REF.rel_err(got, want) < 2e-4
+        assert got.argmax(-1).tolist() == want.argmax(-1).tolist()
 
 
 def _plain_attention(q, k, v, scale, window, sink):
