@@ -227,7 +227,10 @@ def _flash_on_mesh(q, k, v, config: LlamaConfig, rules: ShardingRules):
     automatically partitioned"), so on a multi-device mesh each device
     runs the kernel on its own (batch, heads) shard under ``shard_map`` —
     attention mixes neither batch rows nor heads, so no collective is
-    needed. Shapes the mesh does not divide stay with the compiler."""
+    needed. An axis the mesh does not divide (one sequence on fsdp=2:
+    the benchmark's correctness check) is left whole on every device of
+    that mesh axis, still under ``shard_map``: the compiler refuses the
+    bare kernel on any mesh of more than one device."""
     attend = functools.partial(flash_attention, causal=True,
                                block=config.attn_block)
     mesh = jax.sharding.get_abstract_mesh()
@@ -238,10 +241,10 @@ def _flash_on_mesh(q, k, v, config: LlamaConfig, rules: ShardingRules):
         for a in rules.mesh_axes(("batch", "seq", "heads", "head_dim")))
     batch_axes = tuple(a for a in batch_axes or () if a in mesh.shape)
     heads_axis = tuple(a for a in heads_axis or () if a in mesh.shape)
-    n_batch = math.prod(mesh.shape[a] for a in batch_axes)
-    n_heads = math.prod(mesh.shape[a] for a in heads_axis)
-    if q.shape[0] % n_batch or k.shape[2] % n_heads:
-        return attend(q, k, v)
+    if q.shape[0] % math.prod(mesh.shape[a] for a in batch_axes):
+        batch_axes = ()
+    if k.shape[2] % math.prod(mesh.shape[a] for a in heads_axis):
+        heads_axis = ()
     spec = jax.sharding.PartitionSpec(batch_axes or None, None,
                                       heads_axis or None, None)
     return jax.shard_map(attend, in_specs=(spec, spec, spec),

@@ -175,14 +175,10 @@ def param_stds(cfg: MimoV2Config):
 
 
 def init_params(cfg: MimoV2Config, key: jax.Array) -> Params:
-    std, stds = param_stds(cfg)
-    leaves, treedef = jax.tree.flatten_with_path(
-        param_shapes(cfg), is_leaf=lambda t: isinstance(t, tuple))
-    keys = jax.random.split(key, len(leaves))
-    return jax.tree.unflatten(treedef, [
-        (jax.random.normal(k, shape, jnp.float32)
-         * stds.get(path[-1].key, std)).astype(cfg.dtype)
-        for k, (path, shape) in zip(keys, leaves)])
+    from ray_tpu.models.serving import init_from_shapes
+
+    return init_from_shapes(param_shapes(cfg), key, *param_stds(cfg),
+                            cfg.dtype)
 
 
 # ------------------------------------------------------------------- cache

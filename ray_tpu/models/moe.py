@@ -279,31 +279,66 @@ COUNTERS = ("expert_layer_calls", "expert_pairs", "experts_hit",
             "expert_load_max_over_mean", "expert_pairs_dropped")
 
 
-def route_sigmoid_topk(x, router, bias, top_k: int, scale: float = 1.0):
+def route_sigmoid_topk(x, router, bias, top_k: int, scale: float = 1.0,
+                       n_group: int = 1, topk_group: int = 1):
     """``noaux_tc`` routing: scores ``s = sigmoid(x_f32 @ W_r)`` in
     float32 over every expert; the ``top_k`` largest of ``s + b`` are
-    chosen (``b`` the stored correction bias, used for the choice only);
-    the weights are ``s`` at the chosen, divided by their sum, times
-    ``scale``. x (T, h) -> (idx (T, k) int32, weights (T, k) f32)."""
+    chosen (``b`` the stored correction bias, used for the choice only;
+    None where the model stores none); the weights are ``s`` at the
+    chosen, divided by their sum, times ``scale``. x (T, h) -> (idx
+    (T, k) int32, weights (T, k) f32).
+
+    Group-limited (``n_group`` > 1): the experts are ``n_group`` runs of
+    equal length; a group's score is the sum of its two largest ``s +
+    b``; only the ``topk_group`` best groups' experts stand for the
+    choice. 1 and 1 is the choice over all, as it was."""
     s = jax.nn.sigmoid(jnp.dot(
         x.astype(jnp.float32), router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
-    _, idx = jax.lax.top_k(s + bias.astype(jnp.float32)[None, :], top_k)
+    c = s if bias is None else s + bias.astype(jnp.float32)[None, :]
+    if n_group > 1:
+        T, E = c.shape
+        grouped = c.reshape(T, n_group, E // n_group)
+        score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+        _, best = jax.lax.top_k(score, topk_group)            # (T, kept)
+        kept = jnp.zeros((T, n_group), bool).at[
+            jnp.arange(T)[:, None], best].set(True)
+        c = jnp.where(kept[:, :, None], grouped, -jnp.inf).reshape(T, E)
+    _, idx = jax.lax.top_k(c, top_k)
     w = jnp.take_along_axis(s, idx, axis=-1)
     w = w / jnp.sum(w, axis=-1, keepdims=True) * scale
     return idx.astype(jnp.int32), w
 
 
+def swiglu(x, gate, up, down):
+    """``(silu(x gate) * (x up)) down`` in x.dtype: x (T, h), ``gate`` /
+    ``up`` (h, m), ``down`` (m, h)."""
+    return ((jax.nn.silu(x @ gate.astype(x.dtype)) * (x @ up.astype(x.dtype)))
+            @ down.astype(x.dtype))
+
+
+def shared_expert(x, layer: Params):
+    """The expert every token passes through, whole on every chip of an
+    expert-parallel deployment (the data-parallel part of the layer): a
+    SwiGLU ``ws_gate`` / ``ws_up`` (h, m), ``ws_down`` (m, h). x (T, h)
+    -> (T, h) in x.dtype."""
+    with jax.named_scope("shared_expert"):
+        return swiglu(x, layer["ws_gate"], layer["ws_up"], layer["ws_down"])
+
+
 def experts_by_share(x, layer: Params, *, experts_held: Tuple[int, int],
                      top_k: int, scale: float = 1.0, valid=None,
                      use_kernel: Optional[bool] = None,
-                     kernel_name: str = "grouped_expert_matmul"):
+                     kernel_name: str = "grouped_expert_matmul",
+                     n_group: int = 1, topk_group: int = 1):
     """The routed MLP of one layer on the chip that holds
     ``experts_held = (first, count)``: x (T, h) -> (y (T, h) float32,
     the partial sum over the experts held; counters (5,) float32 in the
     order of ``COUNTERS``).
 
-    ``layer``: ``router`` (h, E), ``router_bias`` (E,), and the HELD
+    ``layer``: ``router`` (h, E), ``router_bias`` (E,) where the model
+    stores one (``n_group`` / ``topk_group``: :func:`route_sigmoid_topk`'s
+    group limit), and the HELD
     experts' SwiGLU matrices ``we_gate``/``we_up`` (count, h, m),
     ``we_down`` (count, m, h). ``valid`` (T,) bool masks rows that are
     no token (a padded prompt, an idle slot): they are routed nowhere.
@@ -319,8 +354,8 @@ def experts_by_share(x, layer: Params, *, experts_held: Tuple[int, int],
     T, h = x.shape
     first, G = experts_held
     tm = ROW_TILE
-    idx, w = route_sigmoid_topk(x, layer["router"], layer["router_bias"],
-                                top_k, scale)
+    idx, w = route_sigmoid_topk(x, layer["router"], layer.get("router_bias"),
+                                top_k, scale, n_group, topk_group)
     local = idx - first
     held = (local >= 0) & (local < G)
     if valid is not None:

@@ -279,11 +279,15 @@ class BlockAllocator:
 class KVStateManager:
     """Several kinds of KV state behind one allocator interface: a model
     whose layers keep different state (full attention: the whole
-    sequence; sliding window: the blocks the window still touches) has
-    one pool, one :class:`BlockAllocator` and one block table a slot for
-    each kind. The engine drives it as it drives a single allocator;
+    sequence; sliding window: the blocks the window still touches;
+    latent attention: the whole sequence as ONE compressed row a token
+    and layer, which every head reads) has one pool, one
+    :class:`BlockAllocator` and one block table a slot for each kind. What
+    a row of a kind's pool holds is the model's own affair (per-head keys
+    and values, packed rows, a latent ``(c_kv, k_rope)``): the manager
+    counts blocks. The engine drives it as it drives a single allocator;
     a slot is admitted, grown, trimmed, preempted and released in every
-    kind together.
+    kind together, and ``stats()["kv_pools"]`` has a row a kind.
 
     ``kinds``: name -> (PagedConfig, window). ``window`` None keeps the
     whole sequence; a window of w keeps the blocks that positions

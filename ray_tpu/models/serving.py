@@ -83,6 +83,21 @@ class DenseDecoder:
             page=page, inject=make_paged_inject(self.config, page))
 
 
+def init_from_shapes(shapes, key, std: float, stds: dict, dtype):
+    """Seeded weights for a tree of shapes (tuples): each leaf a normal
+    draw at the standard deviation ``stds`` gives its name, else ``std``."""
+    import jax
+    import jax.numpy as jnp
+
+    leaves, treedef = jax.tree.flatten_with_path(
+        shapes, is_leaf=lambda t: isinstance(t, tuple))
+    keys = jax.random.split(key, len(leaves))
+    return jax.tree.unflatten(treedef, [
+        (jax.random.normal(k, shape, jnp.float32)
+         * stds.get(path[-1].key, std)).astype(dtype)
+        for k, (path, shape) in zip(keys, leaves)])
+
+
 def serving_model(config):
     """The serving model of a config object: its own
     (``config.serving_model()``), or the dense decoder's."""

@@ -209,6 +209,34 @@ def test_paged_hybrid_decode_attention_compiles_at_the_moe_cells_widths(
     assert f"paged_hybrid_decode_{kind}" in text
 
 
+@pytest.mark.parametrize("blocks_per_step", [1, 4, 8])
+def test_paged_mla_decode_compiles_at_the_latent_cells_widths(
+        one_chip, blocks_per_step, monkeypatch):
+    """`serve-mla-moe-decode`'s own shapes: 64 heads against latent rows
+    of 512 + 64 padded to 640 lanes, block 64, 192 slots, 68 blocks a
+    table; the work list built once outside the call, as the decode step
+    hands it over."""
+    from ray_tpu.ops.pallas import paged_mla_decode_attention as mla
+
+    monkeypatch.setattr(mla, "BLOCKS_PER_STEP", blocks_per_step)
+    slots, bs, cols, width = 192, 64, 68, mla.padded_row(512 + 64)
+    assert width == 640
+    q = _sds((slots, 64, width), jnp.bfloat16, one_chip)
+    pool = _sds((5, 1 + slots * cols, bs, width), jnp.bfloat16, one_chip)
+    layer = _sds((), jnp.int32, one_chip)
+    tables = _sds((slots, cols), jnp.int32, one_chip)
+    lens = _sds((slots,), jnp.int32, one_chip)
+
+    def attend(q, pool, l, t, n):
+        return mla.paged_mla_decode_kernel(
+            q, pool, l, t, n, scale=0.13086, rank=512,
+            work=mla.mla_work_list(n, bs, cols))
+
+    text = jax.jit(attend).lower(q, pool, layer, tables,
+                                 lens).compile().as_text()
+    assert "tpu_custom_call" in text and "paged_mla_decode" in text
+
+
 # ------------------------------------------------- the 1b serving programs
 def _engine_shapes(one_chip, num_slots=8, cfg=CFG):
     """What LLMEngine(model="1b") builds by default, as shapes."""
