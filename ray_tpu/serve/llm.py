@@ -7,8 +7,9 @@ an Orca-style loop over fixed slots: give back what a window has passed
 and grow the running slots' block tables, admit waiting requests into free
 slots (one bucketed prefill each; a long prompt or a prefix-cache suffix
 one chunk a turn), then advance ALL active slots one token in one jitted
-decode step and sample on the host (a turn whose slots are all greedy
-takes its argmax on the device).
+decode step and take their tokens on the device (:func:`greedy_ids` for a
+turn whose slots are all greedy, :func:`sample_ids` for any other): the
+host fetches one int32 a slot, never a row of the vocabulary.
 
 The KV cache is paged by default: a shared pool of fixed-size blocks with
 host-side block tables (:mod:`ray_tpu.models.paged_cache`). The model
@@ -73,6 +74,9 @@ class _Request:
     record: Optional[list] = None
     # the submitting task's span context, when its caller traces
     trace_ctx: Optional[Dict[str, str]] = None
+    # the request's place in the order the engine took requests from its
+    # queue: with a token's position, what that token's draw is keyed by
+    number: int = 0
 
 
 def _parse_req_spec(speculation) -> Optional[dict]:
@@ -102,6 +106,36 @@ def _parse_req_spec(speculation) -> Optional[dict]:
     raise ValueError("per-request speculation must be a bool or dict")
 
 
+def greedy_ids(logits):
+    import jax.numpy as jnp
+
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
+def sample_ids(logits, temperature, key, request, position):
+    """One token id a row of ``logits``, picked on the device. A row
+    whose ``temperature`` is <= 0 takes :func:`greedy_ids`' argmax; any
+    other takes ONE exact draw from ``softmax(logits / max(T, 1e-5))`` in
+    float32 (Gumbel-max: no top-k, no truncation). Each draw has a stream
+    of its own: ``key`` folded with the row's ``request`` number, then
+    with the ``position`` in its sequence of the token drawn, so a
+    token's stream does not depend on which slot its request sits in, on
+    which turn draws it, or on who shares the turn."""
+    import jax
+    import jax.numpy as jnp
+
+    logits = logits.reshape(temperature.shape[0], -1)
+
+    def draw(row, t, r, p):
+        stream = jax.random.fold_in(jax.random.fold_in(key, r), p)
+        return jax.random.categorical(
+            stream, row.astype(jnp.float32) / jnp.maximum(t, 1e-5))
+
+    drawn = jax.vmap(draw)(logits, temperature, request, position)
+    return jnp.where(temperature > 0.0, drawn,
+                     greedy_ids(logits)).astype(jnp.int32)
+
+
 class LLMEngine:
     """Single-replica continuous-batching engine.
 
@@ -124,6 +158,12 @@ class LLMEngine:
     ``prefix_cache="radix"`` (or a ``prefix_cache_bytes`` budget; paged
     only) shares cached prompt blocks between requests and prefills only
     the uncached suffix. Both are off by default.
+
+    ``seed`` makes the weights where ``params`` is None, and the key of
+    every draw at a temperature (:func:`sample_ids`): the same ``seed``
+    and the same requests in the same order are answered the same tokens,
+    another ``seed`` others. (Speculation's acceptance draws are the
+    host's and keyed by the step count.)
     """
 
     def __init__(self, config=None, params=None, *, num_slots: int = 8,
@@ -262,16 +302,24 @@ class LLMEngine:
         self.spec_k = spec_k
         self._spec_proposed = 0
         self._spec_accepted = 0
+        # a turn's tokens are taken on the device, so the fetch moves 4
+        # bytes a slot and not a row of the vocabulary: a turn whose
+        # every slot is greedy runs jit_greedy_ids, any other
+        # jit_sample_ids (neither is jit_step*: decode_step_dev_ms.*
+        # reads that name)
         self._key = jax.random.key(seed)
-        # a turn whose every sampled slot is greedy takes its tokens on
-        # the device: the fetch then moves 4 bytes a slot, not a row of
-        # the vocabulary, and the host's argmax over it goes
-        import jax.numpy as jnp
-
-        def greedy_ids(logits):
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
         self._greedy_ids = jax.jit(greedy_ids)
+        self._sample_ids = jax.jit(sample_ids)
+        # what sample_ids is given beside the logits, a row a slot: the
+        # occupant's temperature and number. The device's copy is made
+        # again only after a slot got a new occupant
+        self._draw_temp = np.zeros(num_slots, np.float32)
+        self._draw_request = np.zeros(num_slots, np.int32)
+        self._draw_rows = None
+        self._requests_taken = 0
+        self._greedy_turns = 0
+        self._sampled_turns = 0
+        self._sampled_tokens = 0
         # Prefix reuse across requests, OFF by default: on with
         # prefix_cache="radix" or when a byte budget is given. The radix
         # tree of ray_tpu.models.prefix_cache shares the prompt's pool
@@ -527,6 +575,11 @@ class LLMEngine:
             out["prefix_misses"] = pc["misses"]
         out["prefix_cache"] = pc
         out["fair_share_skips"] = self._fair_share_skips
+        # decode turns by the program that took their tokens, and the
+        # tokens drawn at a temperature (first tokens included)
+        out["sampling"] = {"greedy_turns": self._greedy_turns,
+                           "sampled_turns": self._sampled_turns,
+                           "sampled_tokens": self._sampled_tokens}
         mem = self._dev.memory_stats() or {}
         out["device"] = dict(self._device,
                              peak_bytes_in_use=mem.get("peak_bytes_in_use"))
@@ -676,9 +729,12 @@ class LLMEngine:
         # drain the thread-safe queue into the FIFO admission deque
         while True:
             try:
-                self._waiting.append(self._queue.get_nowait())
+                req = self._queue.get_nowait()
             except queue.Empty:
                 break
+            req.number = self._requests_taken
+            self._requests_taken = (self._requests_taken + 1) % 2 ** 31
+            self._waiting.append(req)
         while self._waiting:
             slot = self._free_slot()
             if slot is None:
@@ -746,7 +802,8 @@ class LLMEngine:
                 # PD handoff: prompt KV computed by a prefill replica
                 self._inject_kv(slot, req.preload["k"], req.preload["v"],
                                 plen)
-                logits_np = req.preload["logits"]
+                tok = np.asarray(self._draw_first(
+                    req, req.preload["logits"], plen)).item()
                 req.preload = None  # free the host copy
             elif matched > 0:
                 # radix hit: the adopted blocks already hold the prefix
@@ -754,10 +811,7 @@ class LLMEngine:
                 # tokens, not prompt length). Rides the chunked-prefill
                 # machinery so a long suffix still interleaves with the
                 # other slots' decode.
-                self._slots[slot] = req
-                self._slot_len[slot] = 0
-                self._admit_counter += 1
-                self._admit_seq[slot] = self._admit_counter
+                self._seat(slot, req, 0)
                 self._prefilling[slot] = {"req": req,
                                           "tokens": full_prompt,
                                           "pos": matched}
@@ -768,10 +822,7 @@ class LLMEngine:
                 # advance one chunk per iteration interleaved with other
                 # slots' decode; the slot starts decoding after the last
                 # chunk (see _advance_chunked_prefill)
-                self._slots[slot] = req
-                self._slot_len[slot] = 0
-                self._admit_counter += 1
-                self._admit_seq[slot] = self._admit_counter
+                self._seat(slot, req, 0)
                 self._prefilling[slot] = {"req": req,
                                           "tokens": full_prompt, "pos": 0}
                 continue
@@ -789,21 +840,43 @@ class LLMEngine:
                     else:
                         self._cache, logits = self._prefill(
                             self._cache, jnp.asarray(tokens), plen, slot)
-                    logits_np = self._fetch(logits, prefill=True)
+                    tok = self._fetch(self._draw_first(req, logits, plen),
+                                      prefill=True).item()
                 if self._radix is not None:
                     self._radix_insert(req, full_prompt, slot)
-            tok = self._sample(logits_np.reshape(1, -1), req.temperature)[0]
-            req.output.append(int(tok))
+            req.output.append(tok)
             if req.first_token_at is None:
                 req.first_token_at = time.monotonic()
-            self._slots[slot] = req
+            self._seat(slot, req, plen)
             self._last_token[slot] = tok
-            self._slot_len[slot] = plen
-            self._admit_counter += 1
-            self._admit_seq[slot] = self._admit_counter
             if self._proposer is not None:
                 self._proposer.admit(slot, full_prompt)
             self._maybe_finish(slot)
+
+    def _seat(self, slot: int, req: _Request, cached: int) -> None:
+        """``req`` takes ``slot`` with ``cached`` tokens of it in the KV
+        cache."""
+        self._slots[slot] = req
+        self._slot_len[slot] = cached
+        self._admit_counter += 1
+        self._admit_seq[slot] = self._admit_counter
+        self._draw_temp[slot] = req.temperature
+        self._draw_request[slot] = req.number
+        self._draw_rows = None
+
+    def _draw_first(self, req: _Request, logits, position: int):
+        """Dispatch the pick of a request's first token from the logits
+        of its prompt's last row: one int32, still on the device. As in
+        a turn, a greedy request's is the argmax program's: a
+        deployment that is never asked for a temperature never builds
+        the other."""
+        if req.temperature <= 0.0:
+            return self._greedy_ids(logits)
+        self._sampled_tokens += 1
+        return self._sample_ids(
+            logits, np.array([req.temperature], np.float32), self._key,
+            np.array([req.number], np.int32),
+            np.array([position], np.int32))
 
     def _spec_decode_step(self, active: np.ndarray) -> bool:
         """One speculative iteration for ALL active slots: collect
@@ -866,9 +939,8 @@ class LLMEngine:
             need_full = any(self._slots[s].temperature > 0.0
                             for s in infos)
             logits_np = np.asarray(all_logits) if need_full else None
-        # post-increment BEFORE seeding, like _sample: seeding first
-        # would reuse the stream the previous plain step sampled with,
-        # correlating accept/reject draws with the token just emitted
+        # the acceptance rule's draws are the host's, one generator a
+        # verify round, seeded by the step count after its increment
         self._steps += 1
         rng = np.random.default_rng(self._steps)
         accepted_map: Dict[int, int] = {}
@@ -939,14 +1011,12 @@ class LLMEngine:
             st["pos"] = pos + n
             if st["pos"] < len(toks):
                 return
-            logits_np = np.asarray(logits)
-        req = st["req"]
+            req, plen = st["req"], len(toks)
+            tok = np.asarray(self._draw_first(req, logits, plen)).item()
         del self._prefilling[slot]
-        plen = len(toks)
         if self._radix is not None:
             self._radix_insert(req, toks, slot)
-        tok = self._sample(logits_np.reshape(1, -1), req.temperature)[0]
-        req.output.append(int(tok))
+        req.output.append(tok)
         if req.first_token_at is None:
             req.first_token_at = time.monotonic()
         self._last_token[slot] = tok
@@ -955,32 +1025,20 @@ class LLMEngine:
             self._proposer.admit(slot, toks)
         self._maybe_finish(slot)
 
-    def _fetch(self, logits, prefill: bool = False) -> np.ndarray:
-        """The logits on the host and, in the same transfer, the model's
-        counters of the program that made them (no further sync: they
-        are outputs of that program)."""
+    def _fetch(self, ids, prefill: bool = False) -> np.ndarray:
+        """A program's token ids on the host and, in the same transfer,
+        the model's counters of that program (no further sync: they are
+        outputs of it)."""
         if not self._counter_names:
-            return np.asarray(logits)
+            return np.asarray(ids)
         import jax
 
-        logits_np, counters = jax.device_get(
-            (logits, self._cache["counters"]))
+        ids_np, counters = jax.device_get((ids, self._cache["counters"]))
         if prefill:
             self._model_counters_prefill += counters
         else:
             self._model_counters += counters
-        return logits_np
-
-    def _sample(self, logits: np.ndarray, temperature: float) -> np.ndarray:
-        if temperature <= 0.0:
-            return logits.argmax(-1).astype(np.int32)
-        z = logits / max(temperature, 1e-5)
-        z = z - z.max(-1, keepdims=True)
-        p = np.exp(z)
-        p /= p.sum(-1, keepdims=True)
-        rng = np.random.default_rng(self._steps)
-        return np.array([rng.choice(p.shape[-1], p=row) for row in p],
-                        np.int32)
+        return ids_np
 
     def _maybe_finish(self, slot: int):
         req = self._slots[slot]
@@ -1179,23 +1237,37 @@ class LLMEngine:
                 self._cache, logits = self._decode(
                     self._cache, jnp.asarray(self._last_token),
                     jnp.asarray(active))
-            greedy = all(self._slots[s].temperature <= 0.0
-                         for s in range(self.num_slots) if active[s])
-            if greedy:
-                logits = self._greedy_ids(logits)
+            # what the turn needs, from what it holds: the all-greedy
+            # turn's program is the argmax alone
+            sampled = int(np.count_nonzero(self._draw_temp[active] > 0.0))
+            if not sampled:
+                self._greedy_turns += 1
+                ids = self._greedy_ids(logits)
+            else:
+                self._sampled_turns += 1
+                self._sampled_tokens += sampled
+                if self._draw_rows is None:
+                    self._draw_rows = (jnp.asarray(self._draw_temp),
+                                       jnp.asarray(self._draw_request))
+                temperature, request = self._draw_rows
+                # a slot's next token stands after its cached tokens and
+                # the one this step feeds: the turn's one upload of its own
+                ids = self._sample_ids(
+                    logits, temperature, self._key, request,
+                    jnp.asarray((self._slot_len + 1).astype(np.int32)))
         with phase("logits_fetch"):
-            logits_np = self._fetch(logits)
+            ids = self._fetch(ids)
         self._steps += 1
-        # ONE span around the slots' loop, never one per slot
-        with phase("sample", active=int(active.sum())):
+        # ONE span around the slots' loop, never one per slot; the
+        # tokens are picked already, what is left is bookkeeping
+        with phase("sample", active=int(active.sum()), sampled=sampled):
             for slot in range(self.num_slots):
                 req = self._slots[slot]
                 if req is None or slot in self._prefilling:
                     # mid-chunked-prefill slots were masked inactive in
-                    # the decode; their logits row is garbage — no sampling
+                    # the decode; their row is garbage: no token
                     continue
-                tok = (logits_np[slot] if greedy else self._sample(
-                    logits_np[slot][None], req.temperature)[0])
+                tok = ids[slot]
                 req.output.append(int(tok))
                 self._last_token[slot] = tok
                 self._slot_len[slot] += 1

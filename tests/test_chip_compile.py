@@ -346,6 +346,29 @@ def test_1b_serving_program_holds_no_second_pool(one_chip, as_tpu, lower,
     assert mem.alias_size_in_bytes >= pool_bytes     # donated, and reused
 
 
+# ------------------------------------------- the engine's pick of a token
+@pytest.mark.parametrize("slots, vocab", [(32, 32768), (192, 20480)])
+def test_sample_ids_compiles_at_the_cells_shapes(one_chip, slots, vocab):
+    """The chat cell's turn and the widest cell's: the draw stays on the
+    device (no callback to the host) and fuses (no second copy of the
+    logits: what the Gumbel form is kept for)."""
+    from ray_tpu.serve.llm import sample_ids
+
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    logits = _sds((slots, vocab), jnp.float32, one_chip)
+    ints = _sds((slots,), jnp.int32, one_chip)
+    lowered = jax.jit(sample_ids).lower(
+        logits, _sds((slots,), jnp.float32, one_chip),
+        _sds(key.shape, key.dtype, one_chip), ints, ints)
+    assert "module @jit_sample_ids" in lowered.as_text()
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "callback" not in text and "custom-call" not in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 3 * _nbytes(logits)
+    assert mem.output_size_in_bytes <= 4096     # (slots,) int32, tiled
+
+
 # ----------------------------------------------------------------- CPU only
 def test_tiny_decode_module_holds_no_weights():
     """A closed-over array lowers to a literal. `tiny` has 89 MB of

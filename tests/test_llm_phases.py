@@ -90,6 +90,16 @@ class TestPhases:
         # before that phase's first exit had made its row
         assert steps <= runs("sample") <= steps + 1
 
+    def test_sampling_counts_the_turns_by_their_program(self, ran):
+        a, b = ran["before"]["sampling"], ran["after"]["sampling"]
+        # one answer of 6 at a temperature: its first token and 5 turns;
+        # then greedy answers of 5, 6 and 4: 4 + 5 + 3 turns
+        assert b["sampled_tokens"] - a["sampled_tokens"] == 6
+        assert b["sampled_turns"] - a["sampled_turns"] == 5
+        assert b["greedy_turns"] - a["greedy_turns"] == 12
+        assert (b["greedy_turns"] + b["sampled_turns"]
+                == ran["after"]["steps"])
+
     def test_leaf_self_times_cover_the_turn(self, ran):
         rows = ran["after"]["phases"]
         turn = rows["turn"][1]
@@ -206,6 +216,9 @@ def test_spans_land_in_a_capture_with_their_attributes(params, tmp_path):
     assert seen["prefill"]["prompt_len"] == 3
     assert {"pad_len", "slot"} <= set(seen["prefill"])
     assert "active" in seen["sample"] and "waiting" in seen["admit"]
+    # the slots at a temperature that turn: bookkeeping is all that is
+    # left inside the span
+    assert seen["sample"]["sampled"] == 1
     assert {"step_num", "active"} <= set(seen["turn"])
 
 
