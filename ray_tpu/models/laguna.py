@@ -1,43 +1,48 @@
-"""A decoder that mixes full and sliding-window attention layers with a
-routed expert MLP held by share (the language model of the ``mimo_v2``
-family), on the paged serving path.
+"""A decoder that mixes full and sliding-window attention layers whose
+QUERY heads differ by layer kind over one count of KV heads, gates each
+head's attention output, and has a shared expert beside a softmax-routed
+expert MLP (the language model of the ``laguna`` family), on the paged
+serving path.
 
 Layer ``l`` is a full-attention or a window layer by ``layer_kinds[l]``
-(0 / 1) and has a dense SwiGLU or a routed MLP by ``moe_layers[l]``. Both
-attention kinds: ``n_heads`` query heads, keys ``head_dim`` wide and
-values ``v_head_dim`` wide, scale ``head_dim ** -0.5``, rotary on the
-first ``rotary_dim`` dimensions only (half-split layout) at the kind's
-own theta, values multiplied by ``value_scale`` before the weighted sum.
-A window layer has its own number of KV heads, attends keys
-``i - window < j <= i`` and adds a learned per-head sink logit to the
-softmax's denominator. The routed MLP is
-:func:`ray_tpu.models.moe.experts_by_share`: sigmoid scores over all
-``n_experts``, ``top_k`` chosen with the correction bias, and the part
-of the sum that the ``experts_held`` here give.
+(0 / 1) and has a dense SwiGLU or a routed MLP by ``moe_layers[l]``.
+Attention, with ``H_l = heads[l]`` query heads, ``n_kv_heads`` KV heads
+and ``head_dim`` wide keys and values in both kinds::
 
-Two kinds of KV state, one manager (:class:`KVStateManager`): a pool for
-each kind, each with its own row shapes, a block table a slot and kind;
-full layers keep the whole sequence, window layers only the blocks the
-window still touches (the allocator gives the others back). The layers
-are not alike, so the programs unroll them; a pool is still ONE donated
-buffer that every layer of its kind updates in place
-(``pool.at[l, block, offset].set``).
+    q, k, v = x W_q, x W_k, x W_v          H_l x D, KV x D, KV x D
+    q, k    = rope_kind(q), rope_kind(k)
+    o_h     = softmax(q_h . k_g(h) / sqrt(D)) v_g(h)    g(h) = h // (H_l / KV)
+    o_h    <- sigmoid(x W_g)_h o_h          the gate: W_g (hidden, H_l)
+    out     = o W_o                          from H_l x D
 
-A pool row is lane-dense: one token's keys (or values) of every KV head.
-A key is ``head_dim`` = rotated + unrotated columns wide, which is no
-multiple of the 128 lanes at the published 64 + 128, so the row is
-packed, not padded (:func:`pack_keys`): the unrotated parts head after
-head, then the rotated parts of a PAIR of heads in one chunk. A query is
-packed to match (its rotated part in its head's half of the pair's
-chunk, zeros in the other), so ``q . k`` is unchanged and every slice
-the decode kernel takes is a whole aligned chunk.
+with ``x`` the normed input throughout, causal, a window layer's query i
+seeing keys ``i - window < j <= i``. Rope by kind: a window layer rotates
+``swa_rotary_dim`` dimensions at ``swa_rope_theta`` unscaled; a full
+layer the first ``rotary_dim`` at ``rope_theta`` under YaRN (``yarn``: a
+published ``attention_factor`` is the TABLES' factor, ``mscale_all_dim``
+0, so a rotated ``q . k`` carries its square and the unrotated
+dimensions do not).
+
+The MLP of a routed layer is ``shared(x) + routed_scale * sum over the
+top_k of w_e E_e(x)``: :func:`ray_tpu.models.moe.shared_expert` and
+:func:`ray_tpu.models.moe.experts_by_share` with ``score="softmax"``
+(``p = softmax(x_f32 W_r)``, the ``top_k`` largest, ``w = p / sum over
+the chosen``). ``experts_held`` may be the whole router's width: one
+chip then holds every expert and the layer's result is whole.
+
+The two kinds of KV state, their pools, the manager and the decode
+kernel's work lists are :mod:`ray_tpu.models.paged_cache`'s (shared with
+``mimo_v2.py``); a pool row is one token's keys (or values) of every KV
+head, head after head, which is lane-dense as it stands at a head of
+128, so nothing is packed. Decode attends through
+:mod:`ray_tpu.ops.pallas.paged_hybrid_decode_attention`.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -49,112 +54,89 @@ from ray_tpu.models.paged_cache import KVStateManager, PagedConfig
 from ray_tpu.ops.attention import hybrid_attention_reference, on_tpu
 from ray_tpu.ops.norms import rmsnorm
 from ray_tpu.ops.pallas import paged_hybrid_decode_attention as pha
-from ray_tpu.ops.rope import apply_rope, rope_frequencies
+from ray_tpu.ops.rope import YarnScaling, apply_rope, rope_frequencies
 
 Params = Dict[str, Any]
 KINDS = pc.HYBRID_KINDS             # layer_kinds 0, 1
 
 
 @dataclasses.dataclass(frozen=True)
-class MimoV2Config:
+class LagunaConfig:
     vocab_size: int = 256
     hidden: int = 64
-    n_layers: int = 3
-    n_heads: int = 4
-    n_kv_heads: int = 2             # full-attention layers
-    swa_n_kv_heads: int = 4         # window layers
-    head_dim: int = 24              # q . k width
-    v_head_dim: int = 16
-    rotary_dim: int = 8             # int(partial_rotary_factor * head_dim)
-    rope_theta: float = 1e7
+    n_layers: int = 5
+    heads: Tuple[int, ...] = (6, 8, 8, 8, 6)     # query heads of layer l
+    n_kv_heads: int = 2
+    head_dim: int = 16
+    rotary_dim: int = 8             # full layers: half a head
+    swa_rotary_dim: int = 16        # window layers: the whole head
+    rope_theta: float = 5e5
     swa_rope_theta: float = 1e4
-    window: int = 16
-    value_scale: float = 0.707
-    layer_kinds: Tuple[int, ...] = (0, 1, 1)     # 0 full, 1 window
-    moe_layers: Tuple[int, ...] = (0, 1, 1)      # 0 dense, 1 routed
+    yarn: Optional[YarnScaling] = YarnScaling(   # full layers only
+        factor=4.0, original_max_seq=32, beta_fast=4.0, beta_slow=1.0,
+        mscale=1.0, mscale_all_dim=0.0)
+    window: int = 24
+    layer_kinds: Tuple[int, ...] = (0, 1, 1, 1, 0)   # 0 full, 1 window
+    moe_layers: Tuple[int, ...] = (0, 1, 1, 1, 1)    # 0 dense, 1 routed
     mlp_dim: int = 128              # the dense layers' SwiGLU
-    expert_dim: int = 32
+    expert_dim: int = 16            # narrower than hidden
+    shared_dim: int = 16
     n_experts: int = 16             # the router's width
     top_k: int = 4
     experts_held: Tuple[int, int] = (0, 16)      # (first, count) here
-    routed_scale: float = 1.0
-    norm_eps: float = 1e-5
+    routed_scale: float = 2.5
+    norm_eps: float = 1e-6
     max_seq: int = 2048
     dtype: Any = jnp.bfloat16
 
     def __post_init__(self):
-        if len(self.layer_kinds) < self.n_layers or \
-                len(self.moe_layers) < self.n_layers:
-            raise ValueError("layer_kinds / moe_layers shorter than "
-                             "n_layers")
-        if 2 * self.rotary_dim != self.head_dim - self.rotary_dim:
-            raise ValueError(
-                "the packed key row needs rotary_dim = a third of "
-                f"head_dim, got {self.rotary_dim} of {self.head_dim}")
-        if self.n_kv_heads % 2 or self.swa_n_kv_heads % 2:
-            raise ValueError("KV heads are packed in pairs")
+        n = self.n_layers
+        if min(len(self.heads), len(self.layer_kinds),
+               len(self.moe_layers)) < n:
+            raise ValueError("heads / layer_kinds / moe_layers shorter "
+                             "than n_layers")
+        if any(H % self.n_kv_heads for H in self.heads[:n]):
+            raise ValueError(f"query heads {self.heads[:n]} not multiples "
+                             f"of the {self.n_kv_heads} KV heads")
 
     def kind(self, l: int) -> str:
         return KINDS[self.layer_kinds[l]]
-
-    def kv_heads(self, kind: str) -> int:
-        return self.n_kv_heads if kind == "full" else self.swa_n_kv_heads
 
     def layers_of(self, kind: str) -> Tuple[int, ...]:
         return tuple(l for l in range(self.n_layers)
                      if self.kind(l) == kind)
 
+    def window_of(self, kind: str) -> Optional[int]:
+        return self.window if kind == "window" else None
+
+    def scale(self, kind: str) -> float:
+        """The softmax scale: YaRN's own factor on it is 1 where the
+        published factor is the tables' (``mscale_all_dim`` 0)."""
+        s = self.head_dim ** -0.5
+        return s * self.yarn.attention_factor \
+            if kind == "full" and self.yarn else s
+
     def serving_model(self):
-        return MimoV2Serving(self)
-
-
-# ------------------------------------------------------------ packed rows
-def key_slices(cfg: MimoV2Config, kind: str):
-    """For each KV head, the starts of the chunks (each ``head_dim -
-    rotary_dim`` wide) of a packed key row that make up its key."""
-    KV, c = cfg.kv_heads(kind), cfg.head_dim - cfg.rotary_dim
-    return tuple((j * c, KV * c + (j // 2) * c) for j in range(KV))
-
-
-def pack_keys(k, cfg: MimoV2Config):
-    """(..., KV, head_dim) -> (..., KV * head_dim): the unrotated parts
-    head after head, then the rotated parts head after head (so a pair
-    of heads shares one chunk)."""
-    r = cfg.rotary_dim
-    lead = k.shape[:-2]
-    return jnp.concatenate([k[..., r:].reshape(*lead, -1),
-                            k[..., :r].reshape(*lead, -1)], axis=-1)
-
-
-def pack_queries(q, cfg: MimoV2Config, kind: str):
-    """(..., H, head_dim) -> (..., H, 2 * chunk): the unrotated part,
-    then the rotated part in the half of a chunk where the head's KV
-    head keeps its own (zeros in the other half)."""
-    r, H = cfg.rotary_dim, q.shape[-2]
-    odd = ((jnp.arange(H) // (H // cfg.kv_heads(kind))) % 2 == 1)[:, None]
-    rot, zero = q[..., :r], jnp.zeros_like(q[..., :r])
-    return jnp.concatenate([q[..., r:], jnp.where(odd, zero, rot),
-                            jnp.where(odd, rot, zero)], axis=-1)
+        return LagunaServing(self)
 
 
 # ----------------------------------------------------------------- weights
-def param_shapes(cfg: MimoV2Config) -> Params:
+def param_shapes(cfg: LagunaConfig) -> Params:
     """The tree the builders take, as shapes: ``layers`` is a LIST (the
     layers are not alike). A norm's stored weight ``w`` scales by
-    ``1 + w``; the router and its bias are read in float32."""
+    ``1 + w``; the router is read in float32."""
     c = cfg
-    h, H, D, Dv = c.hidden, c.n_heads, c.head_dim, c.v_head_dim
-    G = c.experts_held[1]
+    h, D, KV, G = c.hidden, c.head_dim, c.n_kv_heads, c.experts_held[1]
     layers = []
     for l in range(c.n_layers):
-        KV = c.kv_heads(c.kind(l))
+        H = c.heads[l]
         layer = {"attn_norm": (h,), "wq": (h, H, D), "wk": (h, KV, D),
-                 "wv": (h, KV, Dv), "wo": (H, Dv, h), "mlp_norm": (h,)}
-        if c.kind(l) == "window":
-            layer["sink"] = (H,)
+                 "wv": (h, KV, D), "w_out_gate": (h, H), "wo": (H, D, h),
+                 "mlp_norm": (h,)}
         if c.moe_layers[l]:
             layer.update(router=(h, c.n_experts),
-                         router_bias=(c.n_experts,),
+                         ws_gate=(h, c.shared_dim), ws_up=(h, c.shared_dim),
+                         ws_down=(c.shared_dim, h),
                          we_gate=(G, h, c.expert_dim),
                          we_up=(G, h, c.expert_dim),
                          we_down=(G, c.expert_dim, h))
@@ -166,16 +148,19 @@ def param_shapes(cfg: MimoV2Config) -> Params:
             "final_norm": (h,), "lm_head": (h, c.vocab_size)}
 
 
-def param_stds(cfg: MimoV2Config):
-    """(default standard deviation, {leaf name: its own})."""
+def param_stds(cfg: LagunaConfig):
+    """(default standard deviation, {leaf name: its own}): a matrix at
+    its fan-in ** -0.5, every projection back into the residual stream
+    scaled down by ``sqrt(2 L)`` (a routed expert's by ``routed_scale``
+    too, which multiplies the routed sum), norm weights at 0.1."""
     std = cfg.hidden ** -0.5
     out = std / (2 * cfg.n_layers) ** 0.5
     return std, {"attn_norm": 0.1, "mlp_norm": 0.1, "final_norm": 0.1,
-                 "wo": out, "w_down": out, "we_down": out,
-                 "sink": 1.0, "router_bias": 0.01}
+                 "wo": out, "w_down": out, "ws_down": out,
+                 "we_down": out / cfg.routed_scale}
 
 
-def init_params(cfg: MimoV2Config, key: jax.Array) -> Params:
+def init_params(cfg: LagunaConfig, key: jax.Array) -> Params:
     from ray_tpu.models.serving import init_from_shapes
 
     return init_from_shapes(param_shapes(cfg), key, *param_stds(cfg),
@@ -183,36 +168,59 @@ def init_params(cfg: MimoV2Config, key: jax.Array) -> Params:
 
 
 # ------------------------------------------------------------------- cache
-# the assembly is :mod:`ray_tpu.models.paged_cache`'s (every model of
-# full and window layers calls it); here is what this model's rows hold
-def pages(cfg: MimoV2Config, **geometry) -> Dict[str, PagedConfig]:
+# the assembly is :mod:`ray_tpu.models.paged_cache`'s; a row here is
+# every KV head's key (or value), head after head, in both kinds
+def key_slices(cfg: LagunaConfig):
+    """For each KV head, the start of the one ``head_dim`` wide chunk of
+    a key row that is its key."""
+    return tuple((j * cfg.head_dim,) for j in range(cfg.n_kv_heads))
+
+
+def pages(cfg: LagunaConfig, **geometry) -> Dict[str, PagedConfig]:
     return pc.hybrid_pages(cfg.window, **geometry)
 
 
-def init_cache(cfg: MimoV2Config, page: Dict[str, PagedConfig],
+def init_cache(cfg: LagunaConfig, page: Dict[str, PagedConfig],
                num_slots: int):
+    width = cfg.n_kv_heads * cfg.head_dim
     return pc.init_hybrid_cache(
         page, num_slots,
-        {kind: (len(cfg.layers_of(kind)),
-                cfg.kv_heads(kind) * cfg.head_dim,
-                cfg.kv_heads(kind) * cfg.v_head_dim) for kind in KINDS},
+        {kind: (len(cfg.layers_of(kind)), width, width) for kind in KINDS},
         len(moe.COUNTERS), cfg.dtype)
 
 
-def make_manager(cfg: MimoV2Config, page: Dict[str, PagedConfig],
+def make_manager(cfg: LagunaConfig, page: Dict[str, PagedConfig],
                  num_slots: int) -> KVStateManager:
     return pc.make_hybrid_manager(page, cfg.window, num_slots)
 
 
 # ------------------------------------------------------------------ blocks
+def _ropes(cfg, length):
+    return {"full": rope_frequencies(cfg.rotary_dim, length, cfg.rope_theta,
+                                     yarn=cfg.yarn),
+            "window": rope_frequencies(cfg.swa_rotary_dim, length,
+                                       cfg.swa_rope_theta)}
+
+
 def _qkv(x, layer, cfg, cos, sin, positions):
+    """x (B, S, h) -> the normed input, q (B, S, H_l, D), k, v
+    (B, S, KV, D), q and k rotated."""
     h = rmsnorm(x, layer["attn_norm"], cfg.norm_eps)
     q = jnp.einsum("bse,ehd->bshd", h, layer["wq"].astype(h.dtype))
     k = jnp.einsum("bse,ehd->bshd", h, layer["wk"].astype(h.dtype))
     v = jnp.einsum("bse,ehd->bshd", h, layer["wv"].astype(h.dtype))
-    v = (v.astype(jnp.float32) * cfg.value_scale).astype(v.dtype)
-    return (apply_rope(q, cos, sin, positions),
+    return (h, apply_rope(q, cos, sin, positions),
             apply_rope(k, cos, sin, positions), v)
+
+
+def gate_heads(out, h, layer):
+    """``o_h <- sigmoid(h W_g)_h o_h``: out (..., H, D) and the normed
+    input h (..., hidden) it was attended from; the sigmoid in float32."""
+    with jax.named_scope("attn_gate"):
+        g = jax.nn.sigmoid(jnp.einsum(
+            "...e,eh->...h", h, layer["w_out_gate"].astype(h.dtype),
+            preferred_element_type=jnp.float32))
+        return (out.astype(jnp.float32) * g[..., None]).astype(out.dtype)
 
 
 def _mlp(x, layer, cfg, valid, kernel_name="grouped_expert_matmul"):
@@ -220,11 +228,10 @@ def _mlp(x, layer, cfg, valid, kernel_name="grouped_expert_matmul"):
     if "router" in layer:
         y, counters = moe.experts_by_share(
             x, layer, experts_held=cfg.experts_held, top_k=cfg.top_k,
-            scale=cfg.routed_scale, valid=valid, kernel_name=kernel_name)
-        return y.astype(x.dtype), counters
-    g = x @ layer["w_gate"].astype(x.dtype)
-    u = x @ layer["w_up"].astype(x.dtype)
-    return ((jax.nn.silu(g) * u) @ layer["w_down"].astype(x.dtype),
+            scale=cfg.routed_scale, valid=valid, kernel_name=kernel_name,
+            score="softmax")
+        return (moe.shared_expert(x, layer) + y).astype(x.dtype), counters
+    return (moe.swiglu(x, layer["w_gate"], layer["w_up"], layer["w_down"]),
             jnp.zeros((len(moe.COUNTERS),), jnp.float32))
 
 
@@ -233,34 +240,23 @@ def _head(x, params, cfg):
     return x.astype(jnp.float32) @ params["lm_head"].astype(jnp.float32)
 
 
-def _ropes(cfg, length):
-    return {"full": rope_frequencies(cfg.rotary_dim, length, cfg.rope_theta),
-            "window": rope_frequencies(cfg.rotary_dim, length,
-                                       cfg.swa_rope_theta)}
-
-
-def _window(cfg, kind):
-    return cfg.window if kind == "window" else None
-
-
-def _attend(q, kc, vc, li, tables, att_len, cfg, kind, sink, work):
-    """q (B, H, head_dim) -> (B, H, v_head_dim) over the paged pool;
-    ``att_len`` 0 for a slot that is not running, ``work`` from
+def _attend(q, kc, vc, li, tables, att_len, cfg, kind, work):
+    """q (B, H_l, D) -> (B, H_l, D) over the paged pool; ``att_len`` 0
+    for a slot that is not running, ``work`` from
     :func:`ray_tpu.models.paged_cache.hybrid_decode_work` of the same
     lengths (None off the TPU, where the oracle attends)."""
-    kw = dict(scale=cfg.head_dim ** -0.5, k_slices=key_slices(cfg, kind),
-              dv=cfg.v_head_dim, sink=sink, window=_window(cfg, kind))
-    qp = pack_queries(q, cfg, kind)
+    kw = dict(scale=cfg.scale(kind), k_slices=key_slices(cfg),
+              dv=cfg.head_dim, window=cfg.window_of(kind))
     if on_tpu():
         return pha.paged_hybrid_decode_attention(
-            qp, kc, vc, li, tables, att_len, work=work,
+            q, kc, vc, li, tables, att_len, work=work,
             name=f"paged_hybrid_decode_{kind}", **kw)
-    return pha.paged_hybrid_attention_reference(qp, kc, vc, li, tables,
+    return pha.paged_hybrid_attention_reference(q, kc, vc, li, tables,
                                                 att_len, **kw)
 
 
 # ---------------------------------------------------------------- programs
-def make_decode_step(params: Params, cfg: MimoV2Config,
+def make_decode_step(params: Params, cfg: LagunaConfig,
                      page: Dict[str, PagedConfig]):
     """step(cache, tables {kind: (B, MBS) i32}, tokens (B,), active (B,)
     bool) -> (cache, logits (B, vocab) f32). ``cache["counters"]`` is
@@ -282,12 +278,13 @@ def make_decode_step(params: Params, cfg: MimoV2Config,
         for l, layer in enumerate(params["layers"]):
             kind = cfg.kind(l)
             li, index[kind] = index[kind], index[kind] + 1
-            q, k, v = _qkv(x, layer, cfg, *ropes[kind], lengths[:, None])
+            h, q, k, v = _qkv(x, layer, cfg, *ropes[kind], lengths[:, None])
             kc, vc = pools[kind] = pc.store_kv_rows(
                 pools[kind], (li, blk[kind], off),
-                pack_keys(k[:, 0], cfg), v[:, 0].reshape(B, -1))
-            out = _attend(q[:, 0], kc, vc, li, tables[kind], att_len,
-                          cfg, kind, layer.get("sink"), work[kind])
+                k[:, 0].reshape(B, -1), v[:, 0].reshape(B, -1))
+            out = _attend(q[:, 0], kc, vc, li, tables[kind], att_len, cfg,
+                          kind, work[kind])
+            out = gate_heads(out, h[:, 0], layer)
             x = x + jnp.einsum("bhd,hde->be", out,
                                layer["wo"].astype(x.dtype))[:, None, :]
             y, c = _mlp(rmsnorm(x[:, 0], layer["mlp_norm"], cfg.norm_eps),
@@ -301,7 +298,7 @@ def make_decode_step(params: Params, cfg: MimoV2Config,
     return _bind_params(jax.jit(step, donate_argnums=(1,)), params)
 
 
-def make_prefill(params: Params, cfg: MimoV2Config,
+def make_prefill(params: Params, cfg: LagunaConfig,
                  page: Dict[str, PagedConfig]):
     """prefill(cache, table_rows {kind: (MBS,) i32}, tokens (1, P)
     padded, true_len, slot) -> (cache, last_logits (vocab,) f32). P a
@@ -326,13 +323,13 @@ def make_prefill(params: Params, cfg: MimoV2Config,
         for l, layer in enumerate(params["layers"]):
             kind = cfg.kind(l)
             li, index[kind] = index[kind], index[kind] + 1
-            q, k, v = _qkv(x, layer, cfg, *ropes[kind], None)
+            h, q, k, v = _qkv(x, layer, cfg, *ropes[kind], None)
             out = hybrid_attention_reference(
-                q, k, v, scale=cfg.head_dim ** -0.5, sink=layer.get("sink"),
-                window=_window(cfg, kind))
+                q, k, v, scale=cfg.scale(kind), window=cfg.window_of(kind))
+            out = gate_heads(out, h, layer)
             x = x + jnp.einsum("bshd,hde->bse", out,
                                layer["wo"].astype(x.dtype))
-            kb = jnp.where(valid[:, None], pack_keys(k[0], cfg), 0.0)
+            kb = jnp.where(valid[:, None], k[0].reshape(pad_len, -1), 0.0)
             vb = jnp.where(valid[:, None], v[0].reshape(pad_len, -1), 0.0)
             pools[kind] = pc.store_kv_rows(pools[kind], (li, dest[kind]),
                                            kb.reshape(nblk, bs, -1),
@@ -350,7 +347,7 @@ def make_prefill(params: Params, cfg: MimoV2Config,
 
 
 # ------------------------------------------------- what the engine is given
-class MimoV2Serving:
+class LagunaServing:
     """The model as :class:`ray_tpu.serve.llm.LLMEngine` takes it
     (:mod:`ray_tpu.models.serving`)."""
 
@@ -358,7 +355,7 @@ class MimoV2Serving:
     lacks = ("slot_cache", "speculation", "prefix_cache", "prefill_chunk",
              "kv_transfer")
 
-    def __init__(self, config: MimoV2Config):
+    def __init__(self, config: LagunaConfig):
         self.config = config
 
     def init_params(self, key):

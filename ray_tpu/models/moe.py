@@ -310,6 +310,23 @@ def route_sigmoid_topk(x, router, bias, top_k: int, scale: float = 1.0,
     return idx.astype(jnp.int32), w
 
 
+def route_softmax_topk(x, router, top_k: int, scale: float = 1.0):
+    """Softmax routing with no capacity: ``p = softmax(x_f32 @ W_r)`` in
+    float32 over every expert; the ``top_k`` largest are chosen; the
+    weights are ``p`` at the chosen, divided by their sum, times
+    ``scale``. No correction bias, no groups. A sibling of
+    :func:`route_sigmoid_topk` and not a switch inside it: the two share
+    a product and a ``top_k`` and nothing else, and the sigmoid one's
+    outputs stay what they were. x (T, h) -> (idx (T, k) int32, weights
+    (T, k) f32)."""
+    p = jax.nn.softmax(jnp.dot(
+        x.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST), axis=-1)
+    w, idx = jax.lax.top_k(p, top_k)
+    w = w / jnp.sum(w, axis=-1, keepdims=True) * scale
+    return idx.astype(jnp.int32), w
+
+
 def swiglu(x, gate, up, down):
     """``(silu(x gate) * (x up)) down`` in x.dtype: x (T, h), ``gate`` /
     ``up`` (h, m), ``down`` (m, h)."""
@@ -330,15 +347,17 @@ def experts_by_share(x, layer: Params, *, experts_held: Tuple[int, int],
                      top_k: int, scale: float = 1.0, valid=None,
                      use_kernel: Optional[bool] = None,
                      kernel_name: str = "grouped_expert_matmul",
-                     n_group: int = 1, topk_group: int = 1):
+                     n_group: int = 1, topk_group: int = 1,
+                     score: str = "sigmoid"):
     """The routed MLP of one layer on the chip that holds
     ``experts_held = (first, count)``: x (T, h) -> (y (T, h) float32,
     the partial sum over the experts held; counters (5,) float32 in the
     order of ``COUNTERS``).
 
-    ``layer``: ``router`` (h, E), ``router_bias`` (E,) where the model
-    stores one (``n_group`` / ``topk_group``: :func:`route_sigmoid_topk`'s
-    group limit), and the HELD
+    ``layer``: ``router`` (h, E), scored by ``score``: ``"sigmoid"``
+    (:func:`route_sigmoid_topk`, with ``router_bias`` (E,) where the
+    model stores one and its group limit ``n_group`` / ``topk_group``)
+    or ``"softmax"`` (:func:`route_softmax_topk`); and the HELD
     experts' SwiGLU matrices ``we_gate``/``we_up`` (count, h, m),
     ``we_down`` (count, m, h). ``valid`` (T,) bool masks rows that are
     no token (a padded prompt, an idle slot): they are routed nowhere.
@@ -354,8 +373,14 @@ def experts_by_share(x, layer: Params, *, experts_held: Tuple[int, int],
     T, h = x.shape
     first, G = experts_held
     tm = ROW_TILE
-    idx, w = route_sigmoid_topk(x, layer["router"], layer.get("router_bias"),
-                                top_k, scale, n_group, topk_group)
+    if score == "softmax":
+        idx, w = route_softmax_topk(x, layer["router"], top_k, scale)
+    elif score != "sigmoid":
+        raise ValueError(f"score {score!r}: 'sigmoid' or 'softmax'")
+    else:
+        idx, w = route_sigmoid_topk(
+            x, layer["router"], layer.get("router_bias"), top_k, scale,
+            n_group, topk_group)
     local = idx - first
     held = (local >= 0) & (local < G)
     if valid is not None:

@@ -380,6 +380,134 @@ class KVStateManager:
                 for n, a in self.kinds.items()}
 
 
+# ---------------------------------------------------------------------
+# The cache of a model that mixes full and sliding-window layers: two
+# kinds of state (``HYBRID_KINDS``) under one :class:`KVStateManager`,
+# each kind ONE pair of lane-dense pools ``k`` / ``v`` (layers of the
+# kind, blocks, block size, row width) that every layer of its kind
+# updates in place. What a row holds (how many KV heads, how wide a key
+# and a value, packed or not) is the model's; the geometry, the
+# manager, the writes and the decode kernel's work lists are here, for
+# every such model (``mimo_v2.py``, ``laguna.py``) to call.
+
+HYBRID_KINDS = ("full", "window")
+
+
+def hybrid_pages(window: int, *, num_slots: int, max_seq: int,
+                 block_size: int, pool_tokens: int
+                 ) -> Dict[str, PagedConfig]:
+    """Pool geometry of each kind: the full pool holds ``pool_tokens``;
+    the window pool as many blocks a slot as a window can touch, which a
+    slot never exceeds."""
+    from ray_tpu.ops.pallas.paged_hybrid_decode_attention import (
+        blocks_in_window)
+
+    return {
+        "full": PagedConfig(num_blocks=1 + -(-pool_tokens // block_size),
+                            block_size=block_size, max_seq=max_seq),
+        "window": PagedConfig(
+            num_blocks=1 + num_slots * blocks_in_window(window, block_size),
+            block_size=block_size, max_seq=max_seq)}
+
+
+def init_hybrid_cache(page: Dict[str, PagedConfig], num_slots: int,
+                      rows: Dict[str, Tuple[int, int, int]],
+                      n_counters: int, dtype):
+    """``rows``: kind -> (layers of the kind, key row width, value row
+    width). ``counters`` is what a program reports of its step beside
+    the logits (the expert layer's, summed over the routed layers)."""
+    cache = {"length": jnp.zeros((num_slots,), jnp.int32),
+             "counters": jnp.zeros((n_counters,), jnp.float32)}
+    for kind, p in page.items():
+        L, wk, wv = rows[kind]
+        cache[kind] = {
+            "k": jnp.zeros((L, p.num_blocks, p.block_size, wk), dtype),
+            "v": jnp.zeros((L, p.num_blocks, p.block_size, wv), dtype)}
+    return cache
+
+
+def make_hybrid_manager(page: Dict[str, PagedConfig], window: int,
+                        num_slots: int) -> KVStateManager:
+    return KVStateManager({"full": (page["full"], None),
+                           "window": (page["window"], window)}, num_slots)
+
+
+def hybrid_pools(cache):
+    """{kind: (k pool, v pool)} of a cache, as a program carries them."""
+    return {kind: (cache[kind]["k"], cache[kind]["v"])
+            for kind in HYBRID_KINDS}
+
+
+def hybrid_cache(pools, length, counters):
+    """The cache a program returns: :func:`hybrid_pools` undone."""
+    new = {kind: {"k": pools[kind][0], "v": pools[kind][1]}
+           for kind in HYBRID_KINDS}
+    new["length"], new["counters"] = length, counters
+    return new
+
+
+def store_kv_rows(pool, where, k_rows, v_rows):
+    """Write key rows and value rows into one kind's (k, v) pools at
+    ``where`` (layer, blocks[, offsets]): in place, inside a jitted
+    program whose donated pools these are."""
+    kc, vc = pool
+    return (kc.at[where].set(k_rows.astype(kc.dtype)),
+            vc.at[where].set(v_rows.astype(vc.dtype)))
+
+
+def hybrid_decode_rows(tables, lengths, active, block_size: int):
+    """Where a decode step's new row of each slot goes and what the slot
+    attends: ({kind: block (B,)}, offset (B,), att_len (B,)). A slot that
+    is not running writes to the null block and attends nothing,
+    whatever stale length it keeps."""
+    rows = jnp.arange(lengths.shape[0])
+    blk = {kind: jnp.where(active, tables[kind][rows, lengths // block_size],
+                           0) for kind in HYBRID_KINDS}
+    return blk, lengths % block_size, jnp.where(active, lengths + 1, 0)
+
+
+def hybrid_prefill_blocks(table_rows, true_len, nblk: int, block_size: int):
+    """{kind: (nblk,)} the blocks a padded prompt's rows go to: what the
+    kind's table names, the null block past the prompt's end (and, for a
+    window layer, behind the window, where the table no longer holds
+    one)."""
+    return {kind: jnp.where(jnp.arange(nblk) * block_size < true_len,
+                            table_rows[kind][:nblk], 0)
+            for kind in HYBRID_KINDS}
+
+
+def hybrid_decode_work(att_len, page: Dict[str, PagedConfig], window: int):
+    """The hybrid decode kernel's work list of each kind for a decode
+    step's lengths, built before the layer loop so that a kind's layers
+    share it."""
+    from ray_tpu.ops.pallas.paged_hybrid_decode_attention import (
+        hybrid_work_list)
+
+    return {kind: hybrid_work_list(
+        att_len, page[kind].block_size, page[kind].max_blocks_per_seq,
+        window if kind == "window" else None) for kind in HYBRID_KINDS}
+
+
+def bind_hybrid_prefill(prefill, params: Params, block_size: int):
+    """``call(cache, table_rows {kind: (MBS,)}, tokens (1, P), true_len,
+    slot)`` around a prefill jitted per padded length (``params`` first,
+    ``pad_len`` static): P must be a multiple of the block size."""
+
+    def call(cache, table_rows, tokens, true_len, slot):
+        pad_len = tokens.shape[1]
+        if pad_len % block_size:
+            raise ValueError(f"padded prompt {pad_len} not a multiple of "
+                             f"block_size {block_size}")
+        rows = {kind: jnp.asarray(table_rows[kind], jnp.int32)
+                for kind in HYBRID_KINDS}
+        return prefill(params, cache, rows, tokens,
+                       jnp.asarray(true_len, jnp.int32),
+                       jnp.asarray(slot, jnp.int32), pad_len=pad_len)
+
+    call.jitted = prefill
+    return call
+
+
 def _decode_work(lengths, page: PagedConfig):
     """The kernel's work list for a decode step's lengths, built before
     the layer scan so that every layer shares it (None off the TPU, where

@@ -209,6 +209,41 @@ def test_paged_hybrid_decode_attention_compiles_at_the_moe_cells_widths(
     assert f"paged_hybrid_decode_{kind}" in text
 
 
+@pytest.mark.parametrize("kind, heads", [("full", 48), ("window", 64)])
+def test_paged_hybrid_decode_attention_compiles_at_the_whole_cells_widths(
+        one_chip, kind, heads):
+    """`serve-moe-whole-mixed-decode`'s own shapes: 48 (full) or 64
+    (window) query rows against 8 kv heads, keys and values 128 wide (a
+    row of 1,024 lanes, one chunk a kv head, nothing packed), no sink,
+    block 64, 128 slots, 72 blocks a table; a window of 512 is 9 blocks
+    in ONE step (4.7 MB of double-buffered blocks in VMEM)."""
+    from ray_tpu.models import laguna
+    from ray_tpu.ops.pallas import paged_hybrid_decode_attention as pha
+
+    cfg = laguna.LagunaConfig(heads=(48, 64, 64, 64, 48), n_kv_heads=8,
+                              head_dim=128, window=512)
+    slots, bs, cols = 128, 64, 72
+    layers, nb = (2, 9217) if kind == "full" else (3, 1153)
+    window = cfg.window_of(kind)
+    assert pha.blocks_per_step(window, bs, cols) == (9 if window else 4)
+    q = _sds((slots, heads, 128), jnp.bfloat16, one_chip)
+    pool = _sds((layers, nb, bs, 8 * 128), jnp.bfloat16, one_chip)
+    layer = _sds((), jnp.int32, one_chip)
+    tables = _sds((slots, cols), jnp.int32, one_chip)
+    lens = _sds((slots,), jnp.int32, one_chip)
+
+    def attend(q, k, v, l, t, n):
+        return pha.paged_hybrid_decode_attention(
+            q, k, v, l, t, n, work=pha.hybrid_work_list(n, bs, cols, window),
+            scale=cfg.scale(kind), k_slices=laguna.key_slices(cfg), dv=128,
+            window=window, name=f"paged_hybrid_decode_{kind}")
+
+    text = jax.jit(attend).lower(q, pool, pool, layer, tables,
+                                 lens).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert f"paged_hybrid_decode_{kind}" in text
+
+
 @pytest.mark.parametrize("blocks_per_step", [1, 4, 8])
 def test_paged_mla_decode_compiles_at_the_latent_cells_widths(
         one_chip, blocks_per_step, monkeypatch):
