@@ -270,9 +270,11 @@ class BlockAllocator:
         """Device copy of the tables, re-uploaded only after an
         ensure/release actually changed them — steady-state decode
         (most steps) reuses the cached buffer instead of paying a
-        host→device transfer per generated token."""
+        host→device transfer per generated token. Made from a COPY
+        (``asarray`` may alias the host's buffer): the engine changes the
+        tables while the step that was given these still runs."""
         if self._device_tables is None:
-            self._device_tables = jnp.asarray(self.tables)
+            self._device_tables = jnp.asarray(self.tables.copy())
         return self._device_tables
 
 

@@ -3,13 +3,17 @@
 Reference delegates this wholesale to vLLM
 (``python/ray/llm/_internal/serve/deployments/llm/vllm/vllm_engine.py``);
 here it's native. One engine thread (:meth:`LLMEngine._loop_once`) turns
-an Orca-style loop over fixed slots: give back what a window has passed
-and grow the running slots' block tables, admit waiting requests into free
-slots (one bucketed prefill each; a long prompt or a prefix-cache suffix
-one chunk a turn), then advance ALL active slots one token in one jitted
-decode step and take their tokens on the device (:func:`greedy_ids` for a
-turn whose slots are all greedy, :func:`sample_ids` for any other): the
-host fetches one int32 a slot, never a row of the vocabulary.
+an Orca-style loop over fixed slots: advance ALL active slots one token in
+one jitted decode step and take their tokens on the device
+(:func:`greedy_ids` for a turn whose slots are all greedy,
+:func:`sample_ids` for any other), then give back what a window has passed
+and grow the running slots' block tables, and admit waiting requests into
+free slots (one bucketed prefill each; a long prompt or a prefix-cache
+suffix one chunk a turn). The host fetches one int32 a slot, never a row
+of the vocabulary, and it works one step behind the device: step N+1 is
+dispatched from the ids step N left ON the device, and step N's fetch and
+bookkeeping, the freeing, the growing and the admitting run while step
+N+1 does (:meth:`LLMEngine._decode_turn`).
 
 The KV cache is paged by default: a shared pool of fixed-size blocks with
 host-side block tables (:mod:`ray_tpu.models.paged_cache`). The model
@@ -136,6 +140,28 @@ def sample_ids(logits, temperature, key, request, position):
                      greedy_ids(logits)).astype(jnp.int32)
 
 
+def merge_ids(host_ids, device_ids):
+    """A decode step's input ids when the step before it is still in
+    flight: a slot keeps the id that step left on the device, except
+    where the host seated a request since and knows its last token
+    (``host_ids`` >= 0; -1 elsewhere)."""
+    import jax.numpy as jnp
+
+    return jnp.where(host_ids >= 0, host_ids, device_ids)
+
+
+@dataclasses.dataclass
+class _Flight:
+    """A decode step the device has been given and the host has not
+    read: its number, its ids (and the model's counters of it) still on
+    the device, and who ran in it."""
+    step: int
+    ids: Any
+    counters: Any
+    reqs: List[Optional[_Request]]      # by slot; None: masked out
+    sampled: int
+
+
 class LLMEngine:
     """Single-replica continuous-batching engine.
 
@@ -235,6 +261,11 @@ class LLMEngine:
             self._prefill = programs.prefill
             self._inject = programs.inject
             self._counter_names = programs.counters
+            if self._counter_names:
+                # a program's counters are an output of it alone; the
+                # engine takes them out of the cache it hands on
+                # (_take_counters), so every program is given None there
+                self._cache = dict(self._cache, counters=None)
         else:
             self._cache = init_cache(self.config, num_slots, self.max_seq)
             self._decode = make_decode_step(params, self.config)
@@ -310,6 +341,7 @@ class LLMEngine:
         self._key = jax.random.key(seed)
         self._greedy_ids = jax.jit(greedy_ids)
         self._sample_ids = jax.jit(sample_ids)
+        self._merge_ids = jax.jit(merge_ids)
         # what sample_ids is given beside the logits, a row a slot: the
         # occupant's temperature and number. The device's copy is made
         # again only after a slot got a new occupant
@@ -377,7 +409,13 @@ class LLMEngine:
         self._admit_seq = np.zeros(num_slots, np.int64)  # preempt-victim age
         self._admit_counter = 0
         self._stop = threading.Event()
+        # decode steps whose ids the host has read, and the one the
+        # device was given last and the host has not read
         self._steps = 0
+        self._flight: Optional[_Flight] = None
+        self._turns_overlapped = 0
+        self._turns_drained = 0
+        self._surplus_dropped = 0
         self._tokens_generated = 0
         self._preemptions = 0
         # the model's own counters (an expert layer's loads), summed
@@ -580,6 +618,12 @@ class LLMEngine:
         out["sampling"] = {"greedy_turns": self._greedy_turns,
                            "sampled_turns": self._sampled_turns,
                            "sampled_tokens": self._sampled_tokens}
+        # decode steps dispatched behind one whose ids the host had not
+        # read, and with none in flight (the first, after a drain,
+        # speculation's); ids of a step that ran past its request's end
+        out["turns"] = {"overlapped": self._turns_overlapped,
+                        "drained": self._turns_drained,
+                        "surplus_dropped": self._surplus_dropped}
         mem = self._dev.memory_stats() or {}
         out["device"] = dict(self._device,
                              peak_bytes_in_use=mem.get("peak_bytes_in_use"))
@@ -841,17 +885,12 @@ class LLMEngine:
                         self._cache, logits = self._prefill(
                             self._cache, jnp.asarray(tokens), plen, slot)
                     tok = self._fetch(self._draw_first(req, logits, plen),
+                                      self._take_counters(),
                                       prefill=True).item()
                 if self._radix is not None:
                     self._radix_insert(req, full_prompt, slot)
-            req.output.append(tok)
-            if req.first_token_at is None:
-                req.first_token_at = time.monotonic()
             self._seat(slot, req, plen)
-            self._last_token[slot] = tok
-            if self._proposer is not None:
-                self._proposer.admit(slot, full_prompt)
-            self._maybe_finish(slot)
+            self._first_token(slot, tok, full_prompt)
 
     def _seat(self, slot: int, req: _Request, cached: int) -> None:
         """``req`` takes ``slot`` with ``cached`` tokens of it in the KV
@@ -863,6 +902,19 @@ class LLMEngine:
         self._draw_temp[slot] = req.temperature
         self._draw_request[slot] = req.number
         self._draw_rows = None
+
+    def _first_token(self, slot: int, tok: int, cached: List[int]) -> None:
+        """The occupant of ``slot`` has its prompt (``cached``) in the KV
+        cache and ``tok`` from its last row: it decodes from the next
+        step on, which takes ``tok`` from the host."""
+        req = self._slots[slot]
+        req.output.append(tok)
+        if req.first_token_at is None:
+            req.first_token_at = time.monotonic()
+        self._last_token[slot] = tok
+        if self._proposer is not None:
+            self._proposer.admit(slot, cached)
+        self._maybe_finish(slot)
 
     def _draw_first(self, req: _Request, logits, position: int):
         """Dispatch the pick of a request's first token from the logits
@@ -942,6 +994,7 @@ class LLMEngine:
         # the acceptance rule's draws are the host's, one generator a
         # verify round, seeded by the step count after its increment
         self._steps += 1
+        self._turns_drained += 1
         rng = np.random.default_rng(self._steps)
         accepted_map: Dict[int, int] = {}
         touched = np.zeros(self.num_slots, bool)
@@ -1016,24 +1069,29 @@ class LLMEngine:
         del self._prefilling[slot]
         if self._radix is not None:
             self._radix_insert(req, toks, slot)
-        req.output.append(tok)
-        if req.first_token_at is None:
-            req.first_token_at = time.monotonic()
-        self._last_token[slot] = tok
         self._slot_len[slot] = plen
-        if self._proposer is not None:
-            self._proposer.admit(slot, toks)
-        self._maybe_finish(slot)
+        self._first_token(slot, tok, toks)
 
-    def _fetch(self, ids, prefill: bool = False) -> np.ndarray:
-        """A program's token ids on the host and, in the same transfer,
-        the model's counters of that program (no further sync: they are
-        outputs of it)."""
+    def _take_counters(self):
+        """The counters the program dispatched last left in the cache,
+        taken out of it: the cache is donated to the next program, and a
+        step's counters are read after that one is dispatched."""
         if not self._counter_names:
+            return None
+        counters = self._cache["counters"]
+        self._cache = dict(self._cache, counters=None)
+        return counters
+
+    def _fetch(self, ids, counters=None, prefill: bool = False
+               ) -> np.ndarray:
+        """A program's token ids on the host and, in the same transfer,
+        the model's ``counters`` of that program (no further sync: they
+        are outputs of it)."""
+        if counters is None:
             return np.asarray(ids)
         import jax
 
-        ids_np, counters = jax.device_get((ids, self._cache["counters"]))
+        ids_np, counters = jax.device_get((ids, counters))
         if prefill:
             self._model_counters_prefill += counters
         else:
@@ -1110,15 +1168,37 @@ class LLMEngine:
         self._preemptions += 1
         req.preemptions += 1
 
+    def _runs_next(self, slot: int) -> bool:
+        """Whether ``slot`` takes part in the next decode step, by what
+        the host knows without the ids in flight: its occupant decodes,
+        is not cancelled, and the token it has coming from the step in
+        flight is not its last by ``max_tokens`` or ``max_seq``. (An
+        ``eos_token`` cannot be foreseen: such an occupant runs one step
+        past its end, and :meth:`_land` drops that id.)"""
+        req = self._slots[slot]
+        if req is None or req.cancelled or slot in self._prefilling:
+            return False
+        n = len(req.output) + self._in_flight(slot)
+        return n < req.max_tokens and len(req.prompt) + n < self.max_seq
+
+    def _in_flight(self, slot: int) -> bool:
+        """Whether the occupant of ``slot`` has a token coming from a
+        step the host has not read."""
+        req = self._slots[slot]
+        return (req is not None and self._flight is not None
+                and self._flight.reqs[slot] is req)
+
     def _grow_active_slots(self) -> None:
-        """Before a decode step each active slot needs its next token's
-        block. On pool exhaustion, preempt the youngest other active
-        slot; a slot alone in the pool preempts itself."""
+        """Before a decode step each slot that runs in it needs its next
+        token's block. On pool exhaustion, preempt the youngest other
+        active slot; a slot alone in the pool preempts itself. A victim
+        resumes from ``prompt + output``, so the step in flight lands
+        first, and what it finishes gives its blocks back."""
         bs = self._page.block_size
         for slot in range(self.num_slots):
             # the next token starts a block only at a block's multiple;
             # otherwise the blocks that cover the cached tokens cover it
-            if self._slots[slot] is None or self._slot_len[slot] % bs:
+            if self._slot_len[slot] % bs or not self._runs_next(slot):
                 continue
             while not self._alloc.ensure(slot, int(self._slot_len[slot]) + 1):
                 # pool pressure order: evict cold cached prefixes (LRU,
@@ -1126,6 +1206,11 @@ class LLMEngine:
                 # untouchable) BEFORE preempting a running request
                 if self._radix is not None and self._radix.evict_for(1):
                     continue
+                if self._flight is not None:
+                    self._land()
+                    if self._runs_next(slot):
+                        continue
+                    break
                 victims = [s for s in range(self.num_slots)
                            if s != slot and self._slots[s] is not None]
                 if victims:
@@ -1149,6 +1234,7 @@ class LLMEngine:
             except Exception as e:  # noqa: BLE001 — engine must survive
                 logging.getLogger(__name__).error(
                     "engine step failed:\n%s", traceback.format_exc())
+                self._land_quietly()
                 # fail every active request rather than hanging them
                 for slot in range(self.num_slots):
                     req = self._slots[slot]
@@ -1162,6 +1248,20 @@ class LLMEngine:
                             # _maybe_finish/_preempt release them
                             self._alloc.release(slot)
                 self._prefilling.clear()
+        self._land_quietly()
+
+    def _land_quietly(self) -> None:
+        """Outside a turn (the loop's end, a failed turn): what the step
+        in flight answered still goes to its requests, if it can be had."""
+        import logging
+        import traceback
+
+        try:
+            self._land()
+        except Exception:  # noqa: BLE001 — the step went with the failure
+            logging.getLogger(__name__).error(
+                "the decode step in flight was lost:\n%s",
+                traceback.format_exc())
 
     _PENDING_TTL_S = 180.0
 
@@ -1178,18 +1278,19 @@ class LLMEngine:
                 del self._pending[rid]
 
     def _loop_once(self):
-        """One turn of the engine loop. Every device dispatch and every
-        blocking fetch of a turn sits in a named phase (PERF.md section
-        3 lists them): a span in a profiler capture, a row of counters
-        in ``stats()["phases"]``."""
-        import jax.numpy as jnp
-
+        """One turn of the engine loop: a decode step goes to the device
+        (:meth:`_decode_turn`), and while it runs there the host reads
+        the step before it, gives back, grows and admits for the step
+        after it. Every device dispatch and every blocking fetch of a
+        turn sits in a named phase (PERF.md section 3 lists them): a span
+        in a profiler capture, a row of counters in ``stats()["phases"]``."""
         phase = self._phases
 
         self._steps_since_sweep = getattr(self, "_steps_since_sweep", 0) + 1
         if self._steps_since_sweep >= 500:
             self._steps_since_sweep = 0
             self._sweep_pending()
+        ran = self._decode_turn()
         # grow BEFORE admitting: otherwise a tight pool admits the queue
         # head (paying its prefill), then immediately preempts it as the
         # youngest slot to feed an older slot's growth — prefill thrash
@@ -1199,7 +1300,7 @@ class LLMEngine:
                 # its pool, so the block the next token needs is there
                 with phase("window_free"):
                     for slot in range(self.num_slots):
-                        if self._slots[slot] is not None:
+                        if self._runs_next(slot):
                             self._window_blocks_freed += self._alloc.trim(
                                 slot, int(self._slot_len[slot]) + 1)
             with phase("grow"):
@@ -1211,32 +1312,80 @@ class LLMEngine:
         # decode of already-active slots (vLLM-class chunked prefill)
         if self._prefilling:
             self._advance_chunked_prefill()
-        active = np.array([
-            self._slots[s] is not None and s not in self._prefilling
-            for s in range(self.num_slots)])
+        elif not ran and all(r is None for r in self._slots):
+            with phase("idle_wait"):
+                time.sleep(0.002)
+
+    def _decode_turn(self) -> bool:
+        """Give the device its next decode step, THEN read the one it
+        was given before: step N+1 takes from step N only the ids that
+        step left on the device, and everything else of it the host
+        knows without them (lengths advance at dispatch, tokens are
+        appended at the fetch), so the fetch's round trip and the
+        bookkeeping of step N run under step N+1. Returns whether a
+        step was dispatched or read.
+
+        A proposer reads the host's token history, so an engine with
+        one reads each step before it builds the next."""
+        for slot, req in enumerate(self._slots):
+            # it runs in no step again, and nothing of it is in flight:
+            # no bookkeeping would meet it (a prefill sees to its own)
+            if req is not None and req.cancelled \
+                    and slot not in self._prefilling \
+                    and not self._in_flight(slot):
+                self._maybe_finish(slot)
+        active = np.array([self._runs_next(s)
+                           for s in range(self.num_slots)])
         if not active.any():
-            if not self._prefilling:
-                with phase("idle_wait"):
-                    time.sleep(0.002)
-            return
+            landed = self._flight is not None
+            self._land()
+            return landed
+        # speculation replaces the decode step wholesale: every active
+        # slot gets a verify window (1-token windows for slots without
+        # proposals), per-slot under continuous batching —
+        # mid-chunked-prefill slots stay masked out. When NO slot has a
+        # proposal this iteration, the plain (cheaper) decode program
+        # runs instead.
+        if self._proposer is not None and self._spec_decode_step(active):
+            return True
+        ahead = self._dispatch(active)
+        self._land()
+        self._flight = ahead
         if self._proposer is not None:
-            # speculation replaces the decode step wholesale: every
-            # active slot gets a verify window (1-token windows for
-            # slots without proposals), per-slot under continuous
-            # batching — mid-chunked-prefill slots stay masked out.
-            # When NO slot has a proposal this iteration, fall through
-            # to the plain (cheaper) decode program below instead.
-            if self._spec_decode_step(active):
-                return
-        with phase("decode_dispatch"):
+            self._land()
+        return True
+
+    def _dispatch(self, active: np.ndarray) -> _Flight:
+        """One decode step of the ``active`` slots and the program that
+        takes its tokens, both left on the device."""
+        import jax.numpy as jnp
+
+        before = self._flight
+        step = self._steps + (before is not None)
+        with self._phases("decode_dispatch", step=step):
+            reqs = [r if a else None for r, a in zip(self._slots, active)]
+            if before is None:
+                self._turns_drained += 1
+                # of a copy (asarray may alias the host's buffer): the
+                # host writes a seated slot's token while this step runs
+                tokens = jnp.asarray(self._last_token.copy())
+            else:
+                self._turns_overlapped += 1
+                tokens = before.ids
+                seated = np.array([r is not None and r is not b
+                                   for r, b in zip(reqs, before.reqs)])
+                if seated.any():
+                    tokens = self._merge_ids(
+                        jnp.asarray(np.where(seated, self._last_token, -1)),
+                        tokens)
             if self.kv_cache == "paged":
                 self._cache, logits = self._decode(
-                    self._cache, self._alloc.device_tables(),
-                    jnp.asarray(self._last_token), jnp.asarray(active))
+                    self._cache, self._alloc.device_tables(), tokens,
+                    jnp.asarray(active))
             else:
                 self._cache, logits = self._decode(
-                    self._cache, jnp.asarray(self._last_token),
-                    jnp.asarray(active))
+                    self._cache, tokens, jnp.asarray(active))
+            counters = self._take_counters()
             # what the turn needs, from what it holds: the all-greedy
             # turn's program is the argmax alone
             sampled = int(np.count_nonzero(self._draw_temp[active] > 0.0))
@@ -1247,30 +1396,43 @@ class LLMEngine:
                 self._sampled_turns += 1
                 self._sampled_tokens += sampled
                 if self._draw_rows is None:
-                    self._draw_rows = (jnp.asarray(self._draw_temp),
-                                       jnp.asarray(self._draw_request))
+                    # of copies too: _seat writes the rows under this step
+                    self._draw_rows = (jnp.asarray(self._draw_temp.copy()),
+                                       jnp.asarray(self._draw_request.copy()))
                 temperature, request = self._draw_rows
                 # a slot's next token stands after its cached tokens and
                 # the one this step feeds: the turn's one upload of its own
                 ids = self._sample_ids(
                     logits, temperature, self._key, request,
                     jnp.asarray((self._slot_len + 1).astype(np.int32)))
-        with phase("logits_fetch"):
-            ids = self._fetch(ids)
+            self._slot_len[active] += 1
+        return _Flight(step, ids, counters, reqs, sampled)
+
+    def _land(self) -> None:
+        """Read the ids of the step in flight, if there is one, and do
+        its bookkeeping: a token a slot, and the finishes they bring."""
+        flight, self._flight = self._flight, None
+        if flight is None:
+            return
+        with self._phases("logits_fetch", step=flight.step):
+            ids = self._fetch(flight.ids, flight.counters)
         self._steps += 1
+        ran = [slot for slot, r in enumerate(flight.reqs) if r is not None]
         # ONE span around the slots' loop, never one per slot; the
         # tokens are picked already, what is left is bookkeeping
-        with phase("sample", active=int(active.sum()), sampled=sampled):
-            for slot in range(self.num_slots):
-                req = self._slots[slot]
-                if req is None or slot in self._prefilling:
-                    # mid-chunked-prefill slots were masked inactive in
-                    # the decode; their row is garbage: no token
+        with self._phases("sample", active=len(ran),
+                          sampled=flight.sampled):
+            for slot in ran:
+                req = flight.reqs[slot]
+                if self._slots[slot] is not req:
+                    # it ended (an EOS, a cancel) with the token of the
+                    # step before this one, which ran before the host
+                    # had read that token
+                    self._surplus_dropped += 1
                     continue
                 tok = ids[slot]
                 req.output.append(int(tok))
                 self._last_token[slot] = tok
-                self._slot_len[slot] += 1
                 self._tokens_generated += 1
                 self._maybe_finish(slot)
 

@@ -89,6 +89,14 @@ class TestPhases:
         # the answer that `before` waited for left from inside `sample`,
         # before that phase's first exit had made its row
         assert steps <= runs("sample") <= steps + 1
+        # a step is dispatched behind the one the host has not read, or
+        # with none in flight (a request's first): each step is one
+        turns = {k: b["turns"][k] - a["turns"][k] for k in b["turns"]}
+        assert turns["overlapped"] + turns["drained"] == steps
+        # four answers one after the other: each one's first step finds
+        # the device empty, every other is dispatched ahead of the read
+        assert turns == {"overlapped": steps - 4, "drained": 4,
+                         "surplus_dropped": 0}
 
     def test_sampling_counts_the_turns_by_their_program(self, ran):
         a, b = ran["before"]["sampling"], ran["after"]["sampling"]
@@ -220,6 +228,22 @@ def test_spans_land_in_a_capture_with_their_attributes(params, tmp_path):
     # left inside the span
     assert seen["sample"]["sampled"] == 1
     assert {"step_num", "active"} <= set(seen["turn"])
+    # a program and its fetch pair by number, not by place: step N+1 is
+    # dispatched before step N is fetched
+    assert "step" in seen["decode_dispatch"] and "step" in seen["logits_fetch"]
+    order = [(ev.name[len("rt.engine."):], dict(ev.stats)["step"], ev.start_ns)
+             for line in host.lines for ev in line.events
+             if ev.name in ("rt.engine.decode_dispatch",
+                            "rt.engine.logits_fetch")]
+    order.sort(key=lambda e: e[2])
+    names = [(n, k) for n, k, _ in order]
+    first = names[0][1]
+    # the answer's three steps: dispatch, dispatch, fetch, dispatch,
+    # fetch, fetch
+    assert names == [
+        ("decode_dispatch", first), ("decode_dispatch", first + 1),
+        ("logits_fetch", first), ("decode_dispatch", first + 2),
+        ("logits_fetch", first + 1), ("logits_fetch", first + 2)], names
 
 
 def test_profiling_imports_without_jax():

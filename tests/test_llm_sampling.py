@@ -151,9 +151,9 @@ def test_the_host_fetches_ids_never_a_row_of_the_vocabulary(params):
     eng = _engine(params)
     fetched, fetch = [], eng._fetch
 
-    def spy(ids, prefill=False):
+    def spy(ids, counters=None, prefill=False):
         fetched.append((ids.ndim, ids.dtype, ids.nbytes, prefill))
-        return fetch(ids, prefill)
+        return fetch(ids, counters, prefill)
 
     eng._fetch = spy
     try:

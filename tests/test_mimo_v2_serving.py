@@ -433,6 +433,50 @@ def test_the_engine_serves_it_and_counts_its_experts(engine_parts):
         eng.shutdown()
 
 
+def test_the_counters_of_a_run_are_those_of_the_host_fed_steps(engine_parts):
+    """The engine reads a step's counters after the next program has
+    taken the cache (the step after it, or a prefill): each step's must
+    still be its own, and none read twice or lost."""
+    from ray_tpu.serve.llm import LLMEngine
+    from test_llm import _drain_polls, _wait_for_tokens, host_fed
+
+    cfg, params = engine_parts
+    geometry = dict(num_slots=3, max_seq=128, block=8)
+    requests = [(list(range(1, 41)), 24, 0.0, None),
+                (list(range(3, 20)), 30, 0.0, None),
+                (list(range(7, 30)), 12, 0.0, None)]
+    eng = LLMEngine(config=cfg, params=params, num_slots=3, max_seq=128,
+                    kv_block_size=8, kv_pool_tokens=3 * 128)
+    try:
+        # alone, the steps are the host-fed ones: all five counters
+        want, counters = host_fed(cfg, params, requests[:1], **geometry)
+        assert eng.generate(*requests[0][:2]) == want[0]
+        alone = eng.stats()
+        assert list(alone["model_counters"].values()) == pytest.approx(
+            list(counters))
+        assert alone["turns"] == {"overlapped": 22, "drained": 1,
+                                  "surplus_dropped": 0}
+        # together, two of them admitted while a step is in flight: a
+        # step's rows are other requests' too, so only what adds up
+        # token by token can be compared (the pairs), and the calls
+        want, counters = host_fed(cfg, params, requests, **geometry)
+        rids = [eng.submit(*requests[0][:2])]
+        _wait_for_tokens(eng, rids[0], 3)
+        rids += [eng.submit(*r[:2]) for r in requests[1:]]
+        assert _drain_polls(eng, rids) == want
+    finally:
+        eng.shutdown()
+    st = eng.stats()
+    names = list(st["model_counters"])
+    got = {n: st["model_counters"][n] - alone["model_counters"][n]
+           for n in names}
+    assert got["expert_pairs"] == counters[names.index("expert_pairs")]
+    assert got["expert_layer_calls"] == 4 * (st["steps"] - alone["steps"])
+    assert got["expert_pairs_dropped"] == 0
+    assert st["model_counters_prefill"]["expert_layer_calls"] == 4 * 4
+    assert st["turns"]["surplus_dropped"] == 0
+
+
 def test_preemption_returns_both_kinds_of_blocks(engine_parts):
     """A full pool too small for three growing answers: the youngest is
     preempted, recomputed and finishes; afterwards both pools are whole."""
