@@ -56,6 +56,7 @@ from ray_tpu.ops.attention import hybrid_attention_reference, on_tpu
 from ray_tpu.ops.norms import rmsnorm
 from ray_tpu.ops.pallas import paged_mla_decode_attention as mla
 from ray_tpu.ops.rope import YarnScaling, apply_rope, rope_frequencies
+from ray_tpu.util.profiling import part
 
 Params = Dict[str, Any]
 KIND = "latent"
@@ -188,6 +189,7 @@ def _rope_tables(cfg: AxK1Config, length: int):
                             yarn=cfg.yarn)
 
 
+@part("attn_proj")
 def _latents(x, layer, cfg, cos, sin, positions):
     """x (B, S, h) -> q_nope (B, S, H, nope), q_rope (B, S, H, rope),
     c_kv (B, S, kv_rank), k_rope (B, S, rope): what both forms of the
@@ -210,6 +212,7 @@ def _rows(c_kv, k_rope, cfg):
         [c_kv, k_rope, jnp.zeros((*c_kv.shape[:-1], pad), c_kv.dtype)], -1)
 
 
+@part("kv_store")
 def _store(pool, where, rows):
     """Write latent rows into the pool at ``where`` (layer, blocks[,
     offsets]): in place, inside a jitted program whose donated pool this
@@ -217,18 +220,17 @@ def _store(pool, where, rows):
     return pool.at[where].set(rows.astype(pool.dtype))
 
 
+@part("mla_expand")
 def attend_expanded(q_nope, q_rope, c_kv, k_rope, layer, cfg):
     """Causal attention over the sequence itself with keys and values
     made from its latents: (B, S, H, v_dim)."""
-    with jax.named_scope("mla_expand"):
-        dt = c_kv.dtype
-        k_nope = jnp.einsum("bsr,rhd->bshd", c_kv,
-                            layer["w_kvb_k"].astype(dt))
-        v = jnp.einsum("bsr,rhd->bshd", c_kv, layer["w_kvb_v"].astype(dt))
-        k = jnp.concatenate([k_nope, jnp.broadcast_to(
-            k_rope[:, :, None, :], (*k_nope.shape[:-1], cfg.rope_dim))], -1)
-        q = jnp.concatenate([q_nope, q_rope], -1)
-        return hybrid_attention_reference(q, k, v, scale=cfg.scale)
+    dt = c_kv.dtype
+    k_nope = jnp.einsum("bsr,rhd->bshd", c_kv, layer["w_kvb_k"].astype(dt))
+    v = jnp.einsum("bsr,rhd->bshd", c_kv, layer["w_kvb_v"].astype(dt))
+    k = jnp.concatenate([k_nope, jnp.broadcast_to(
+        k_rope[:, :, None, :], (*k_nope.shape[:-1], cfg.rope_dim))], -1)
+    q = jnp.concatenate([q_nope, q_rope], -1)
+    return hybrid_attention_reference(q, k, v, scale=cfg.scale)
 
 
 def absorbed_queries(q_nope, q_rope, layer, cfg):
@@ -239,14 +241,14 @@ def absorbed_queries(q_nope, q_rope, layer, cfg):
     return _rows(q_abs, q_rope, cfg)
 
 
+@part("mla_absorb")
 def attend_absorbed(q_nope, q_rope, pool, li, tables, att_len, layer, cfg,
                     work=None):
     """One query a slot over the paged latent rows: (B, H, v_dim)."""
-    with jax.named_scope("mla_absorb"):
-        u = mla.paged_mla_decode(
-            absorbed_queries(q_nope, q_rope, layer, cfg), pool, li, tables,
-            att_len, scale=cfg.scale, rank=cfg.kv_rank, work=work)
-        return jnp.einsum("bhr,rhd->bhd", u, layer["w_kvb_v"].astype(u.dtype))
+    u = mla.paged_mla_decode(
+        absorbed_queries(q_nope, q_rope, layer, cfg), pool, li, tables,
+        att_len, scale=cfg.scale, rank=cfg.kv_rank, work=work)
+    return jnp.einsum("bhr,rhd->bhd", u, layer["w_kvb_v"].astype(u.dtype))
 
 
 def _mlp(x, layer, cfg, valid, kernel_name="grouped_expert_matmul"):
@@ -257,10 +259,13 @@ def _mlp(x, layer, cfg, valid, kernel_name="grouped_expert_matmul"):
             scale=cfg.routed_scale, valid=valid, kernel_name=kernel_name,
             n_group=cfg.n_group, topk_group=cfg.topk_group)
         return (moe.shared_expert(x, layer) + y).astype(x.dtype), counters
-    return (moe.swiglu(x, layer["w_gate"], layer["w_up"], layer["w_down"]),
-            jnp.zeros((len(moe.COUNTERS),), jnp.float32))
+    with part("mlp"):
+        return (moe.swiglu(x, layer["w_gate"], layer["w_up"],
+                           layer["w_down"]),
+                jnp.zeros((len(moe.COUNTERS),), jnp.float32))
 
 
+@part("head")
 def _head(x, params, cfg):
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     return x.astype(jnp.float32) @ params["lm_head"].astype(jnp.float32)
@@ -278,27 +283,32 @@ def make_decode_step(params: Params, cfg: AxK1Config, page: PagedConfig):
         table = tables[KIND]
         B = tokens.shape[0]
         cos, sin = _rope_tables(cfg, page.max_seq)
-        x = params["embed"].astype(cfg.dtype)[tokens][:, None, :]
-        blk = jnp.where(active, table[jnp.arange(B), lengths // bs], 0)
-        off = lengths % bs
-        # a slot that is not running attends nothing, whatever stale
-        # length it keeps
-        att_len = jnp.where(active, lengths + 1, 0)
-        work = (mla.mla_work_list(att_len, bs, page.max_blocks_per_seq)
-                if on_tpu() else None)     # once for every layer
+        with part("embed"):
+            x = params["embed"].astype(cfg.dtype)[tokens][:, None, :]
+        with part("kv_store"):
+            blk = jnp.where(active, table[jnp.arange(B), lengths // bs], 0)
+            off = lengths % bs
+            # a slot that is not running attends nothing, whatever stale
+            # length it keeps
+            att_len = jnp.where(active, lengths + 1, 0)
+            work = (mla.mla_work_list(att_len, bs, page.max_blocks_per_seq)
+                    if on_tpu() else None)     # once for every layer
         pool = cache[KIND]
         counters = jnp.zeros((len(moe.COUNTERS),), jnp.float32)
         for l, layer in enumerate(params["layers"]):
             q_nope, q_rope, c_kv, k_rope = _latents(
                 x, layer, cfg, cos, sin, lengths[:, None])
-            pool = _store(pool, (l, blk, off),
-                          _rows(c_kv[:, 0], k_rope[:, 0], cfg))
+            with part("kv_store"):
+                rows = _rows(c_kv[:, 0], k_rope[:, 0], cfg)
+            pool = _store(pool, (l, blk, off), rows)
             out = attend_absorbed(q_nope[:, 0], q_rope[:, 0], pool, l, table,
                                   att_len, layer, cfg, work)
-            x = x + jnp.einsum("bhd,hde->be", out,
-                               layer["wo"].astype(x.dtype))[:, None, :]
-            y, c = _mlp(rmsnorm(x[:, 0], layer["mlp_norm"], cfg.norm_eps),
-                        layer, cfg, active)
+            with part("attn_proj"):
+                x = x + jnp.einsum("bhd,hde->be", out,
+                                   layer["wo"].astype(x.dtype))[:, None, :]
+            with part("mlp"):
+                normed = rmsnorm(x[:, 0], layer["mlp_norm"], cfg.norm_eps)
+            y, c = _mlp(normed, layer, cfg, active)
             x = x + y[:, None, :]
             counters = counters + c
         new = {KIND: pool, "counters": counters,
@@ -321,23 +331,29 @@ def make_prefill(params: Params, cfg: AxK1Config, page: PagedConfig):
                 pad_len: int):
         nblk = pad_len // bs
         cos, sin = _rope_tables(cfg, pad_len)
-        x = params["embed"].astype(cfg.dtype)[tokens]          # (1, P, h)
+        with part("embed"):
+            x = params["embed"].astype(cfg.dtype)[tokens]      # (1, P, h)
         valid = jnp.arange(pad_len) < true_len
-        dest = jnp.where(jnp.arange(nblk) * bs < true_len,
-                         table_rows[KIND][:nblk], 0)
+        with part("kv_store"):
+            dest = jnp.where(jnp.arange(nblk) * bs < true_len,
+                             table_rows[KIND][:nblk], 0)
         pool = cache[KIND]
         counters = jnp.zeros((len(moe.COUNTERS),), jnp.float32)
         for l, layer in enumerate(params["layers"]):
             q_nope, q_rope, c_kv, k_rope = _latents(x, layer, cfg, cos, sin,
                                                     None)
             out = attend_expanded(q_nope, q_rope, c_kv, k_rope, layer, cfg)
-            x = x + jnp.einsum("bshd,hde->bse", out,
-                               layer["wo"].astype(x.dtype))
-            rows = jnp.where(valid[:, None], _rows(c_kv[0], k_rope[0], cfg),
-                             0.0)
+            with part("attn_proj"):
+                x = x + jnp.einsum("bshd,hde->bse", out,
+                                   layer["wo"].astype(x.dtype))
+            with part("kv_store"):
+                rows = jnp.where(valid[:, None],
+                                 _rows(c_kv[0], k_rope[0], cfg), 0.0)
             pool = _store(pool, (l, dest), rows.reshape(nblk, bs, -1))
-            y, c = _mlp(rmsnorm(x[0], layer["mlp_norm"], cfg.norm_eps),
-                        layer, cfg, valid, "grouped_expert_matmul_prefill")
+            with part("mlp"):
+                normed = rmsnorm(x[0], layer["mlp_norm"], cfg.norm_eps)
+            y, c = _mlp(normed, layer, cfg, valid,
+                        "grouped_expert_matmul_prefill")
             x = x + y[None]
             counters = counters + c
         new = {KIND: pool, "counters": counters,

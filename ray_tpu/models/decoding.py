@@ -36,6 +36,7 @@ from ray_tpu.models.llama import (LlamaConfig, Params, embed, logits_f32,
 from ray_tpu.ops.attention import mha_reference, on_tpu
 from ray_tpu.ops.norms import rmsnorm
 from ray_tpu.ops.rope import rope_frequencies
+from ray_tpu.util.profiling import part
 
 Cache = Dict[str, jax.Array]
 
@@ -63,14 +64,19 @@ def dense_block(x, layer, c: LlamaConfig, cos, sin, positions, attend,
     into its cache and attends over what the queries may see; ``state`` is
     what it threads through the layers (a layer's cache rows, or the whole
     pool and the layer's index)."""
-    q, k, v = qkv(rmsnorm(x, layer["attn_norm"], c.norm_eps), layer,
-                  cos, sin, positions)
+    with part("attn_proj"):
+        q, k, v = qkv(rmsnorm(x, layer["attn_norm"], c.norm_eps), layer,
+                      cos, sin, positions)
     out, state = attend(q, k, v, state)
-    x = x + jnp.einsum("bshd,hde->bse", out, layer["wo"].astype(x.dtype))
-    x = x + mlp(rmsnorm(x, layer["mlp_norm"], c.norm_eps), layer)
+    with part("attn_proj"):
+        x = x + jnp.einsum("bshd,hde->bse", out,
+                           layer["wo"].astype(x.dtype))
+    with part("mlp"):
+        x = x + mlp(rmsnorm(x, layer["mlp_norm"], c.norm_eps), layer)
     return x, state
 
 
+@part("attention")
 def attend_rows(q, ks, vs, q_pos, scale):
     """q (B, C, H, D) over the row sets ks / vs (B, S, KV, D): key ``j``
     is visible to query ``i`` of row ``b`` iff ``j <= q_pos[b, i]``
@@ -154,6 +160,7 @@ def _scan_cache(attend, x, params: Params, cache: Cache, c: LlamaConfig,
                         (params["layers"], cache["k"], cache["v"]))
 
 
+@part("kv_store")
 def _put_rows(rows_all, new, valid, slot, start):
     """Write ``new`` (1, P, KV, D), zeroed where not ``valid`` (P,), into
     rows [start, start + P) of ``slot`` in a layer's (slots, S, KV, D)."""
@@ -180,8 +187,9 @@ def make_decode_step(params: Params, config: LlamaConfig):
         def attend(q, k, v, state):
             # write new k/v at each slot's current length
             kc, vc = state                                 # (B, S, KV, D)
-            kc = kc.at[slot_ids, lengths].set(k[:, 0])
-            vc = vc.at[slot_ids, lengths].set(v[:, 0])
+            with part("kv_store"):
+                kc = kc.at[slot_ids, lengths].set(k[:, 0])
+                vc = vc.at[slot_ids, lengths].set(v[:, 0])
             return (_attend_cached(q, kc, vc, lengths + 1,
                                    c.head_dim ** -0.5), (kc, vc))
 

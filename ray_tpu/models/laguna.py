@@ -55,6 +55,7 @@ from ray_tpu.ops.attention import hybrid_attention_reference, on_tpu
 from ray_tpu.ops.norms import rmsnorm
 from ray_tpu.ops.pallas import paged_hybrid_decode_attention as pha
 from ray_tpu.ops.rope import YarnScaling, apply_rope, rope_frequencies
+from ray_tpu.util.profiling import part
 
 Params = Dict[str, Any]
 KINDS = pc.HYBRID_KINDS             # layer_kinds 0, 1
@@ -202,6 +203,7 @@ def _ropes(cfg, length):
                                        cfg.swa_rope_theta)}
 
 
+@part("attn_proj")
 def _qkv(x, layer, cfg, cos, sin, positions):
     """x (B, S, h) -> the normed input, q (B, S, H_l, D), k, v
     (B, S, KV, D), q and k rotated."""
@@ -213,14 +215,14 @@ def _qkv(x, layer, cfg, cos, sin, positions):
             apply_rope(k, cos, sin, positions), v)
 
 
+@part("attn_gate")
 def gate_heads(out, h, layer):
     """``o_h <- sigmoid(h W_g)_h o_h``: out (..., H, D) and the normed
     input h (..., hidden) it was attended from; the sigmoid in float32."""
-    with jax.named_scope("attn_gate"):
-        g = jax.nn.sigmoid(jnp.einsum(
-            "...e,eh->...h", h, layer["w_out_gate"].astype(h.dtype),
-            preferred_element_type=jnp.float32))
-        return (out.astype(jnp.float32) * g[..., None]).astype(out.dtype)
+    g = jax.nn.sigmoid(jnp.einsum(
+        "...e,eh->...h", h, layer["w_out_gate"].astype(h.dtype),
+        preferred_element_type=jnp.float32))
+    return (out.astype(jnp.float32) * g[..., None]).astype(out.dtype)
 
 
 def _mlp(x, layer, cfg, valid, kernel_name="grouped_expert_matmul"):
@@ -231,10 +233,13 @@ def _mlp(x, layer, cfg, valid, kernel_name="grouped_expert_matmul"):
             scale=cfg.routed_scale, valid=valid, kernel_name=kernel_name,
             score="softmax")
         return (moe.shared_expert(x, layer) + y).astype(x.dtype), counters
-    return (moe.swiglu(x, layer["w_gate"], layer["w_up"], layer["w_down"]),
-            jnp.zeros((len(moe.COUNTERS),), jnp.float32))
+    with part("mlp"):
+        return (moe.swiglu(x, layer["w_gate"], layer["w_up"],
+                           layer["w_down"]),
+                jnp.zeros((len(moe.COUNTERS),), jnp.float32))
 
 
+@part("head")
 def _head(x, params, cfg):
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     return x.astype(jnp.float32) @ params["lm_head"].astype(jnp.float32)
@@ -267,7 +272,8 @@ def make_decode_step(params: Params, cfg: LagunaConfig,
         lengths = cache["length"]
         B = tokens.shape[0]
         ropes = _ropes(cfg, page["full"].max_seq)
-        x = params["embed"].astype(cfg.dtype)[tokens][:, None, :]
+        with part("embed"):
+            x = params["embed"].astype(cfg.dtype)[tokens][:, None, :]
         blk, off, att_len = pc.hybrid_decode_rows(tables, lengths, active,
                                                   bs)
         work = (pc.hybrid_decode_work(att_len, page, cfg.window)
@@ -285,10 +291,12 @@ def make_decode_step(params: Params, cfg: LagunaConfig,
             out = _attend(q[:, 0], kc, vc, li, tables[kind], att_len, cfg,
                           kind, work[kind])
             out = gate_heads(out, h[:, 0], layer)
-            x = x + jnp.einsum("bhd,hde->be", out,
-                               layer["wo"].astype(x.dtype))[:, None, :]
-            y, c = _mlp(rmsnorm(x[:, 0], layer["mlp_norm"], cfg.norm_eps),
-                        layer, cfg, active)
+            with part("attn_proj"):
+                x = x + jnp.einsum("bhd,hde->be", out,
+                                   layer["wo"].astype(x.dtype))[:, None, :]
+            with part("mlp"):
+                normed = rmsnorm(x[:, 0], layer["mlp_norm"], cfg.norm_eps)
+            y, c = _mlp(normed, layer, cfg, active)
             x = x + y[:, None, :]
             counters = counters + c
         new = pc.hybrid_cache(
@@ -314,7 +322,8 @@ def make_prefill(params: Params, cfg: LagunaConfig,
                 pad_len: int):
         nblk = pad_len // bs
         ropes = _ropes(cfg, pad_len)
-        x = params["embed"].astype(cfg.dtype)[tokens]          # (1, P, h)
+        with part("embed"):
+            x = params["embed"].astype(cfg.dtype)[tokens]      # (1, P, h)
         valid = jnp.arange(pad_len) < true_len
         dest = pc.hybrid_prefill_blocks(table_rows, true_len, nblk, bs)
         pools = pc.hybrid_pools(cache)
@@ -327,15 +336,21 @@ def make_prefill(params: Params, cfg: LagunaConfig,
             out = hybrid_attention_reference(
                 q, k, v, scale=cfg.scale(kind), window=cfg.window_of(kind))
             out = gate_heads(out, h, layer)
-            x = x + jnp.einsum("bshd,hde->bse", out,
-                               layer["wo"].astype(x.dtype))
-            kb = jnp.where(valid[:, None], k[0].reshape(pad_len, -1), 0.0)
-            vb = jnp.where(valid[:, None], v[0].reshape(pad_len, -1), 0.0)
+            with part("attn_proj"):
+                x = x + jnp.einsum("bshd,hde->bse", out,
+                                   layer["wo"].astype(x.dtype))
+            with part("kv_store"):
+                kb = jnp.where(valid[:, None], k[0].reshape(pad_len, -1),
+                               0.0)
+                vb = jnp.where(valid[:, None], v[0].reshape(pad_len, -1),
+                               0.0)
             pools[kind] = pc.store_kv_rows(pools[kind], (li, dest[kind]),
                                            kb.reshape(nblk, bs, -1),
                                            vb.reshape(nblk, bs, -1))
-            y, c = _mlp(rmsnorm(x[0], layer["mlp_norm"], cfg.norm_eps),
-                        layer, cfg, valid, "grouped_expert_matmul_prefill")
+            with part("mlp"):
+                normed = rmsnorm(x[0], layer["mlp_norm"], cfg.norm_eps)
+            y, c = _mlp(normed, layer, cfg, valid,
+                        "grouped_expert_matmul_prefill")
             x = x + y[None]
             counters = counters + c
         new = pc.hybrid_cache(

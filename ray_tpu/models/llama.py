@@ -31,6 +31,7 @@ from ray_tpu.ops.attention import flash_attention
 from ray_tpu.ops.norms import rmsnorm
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
 from ray_tpu.parallel.sharding import ShardingRules, with_logical_constraint
+from ray_tpu.util.profiling import part
 
 Params = Dict[str, Any]
 
@@ -160,6 +161,7 @@ def init_params(config: LlamaConfig, key: jax.Array) -> Params:
 
 # The pieces of the block: the train forward below and the serving programs
 # (``models/decoding.py::dense_block``) are made of the same ones.
+@part("attn_proj")
 def qkv(h, layer, cos, sin, positions=None):
     """The three projections of a normed input ``h`` (B, S, E), queries
     and keys rotated to ``positions`` ((B, S) or (1, S); None: 0..S-1).
@@ -171,6 +173,7 @@ def qkv(h, layer, cos, sin, positions=None):
             apply_rope(k, cos, sin, positions), v)
 
 
+@part("mlp")
 def mlp(h, layer):
     """Gate / up / down of a normed input ``h`` (B, S, E)."""
     g = jnp.einsum("bse,em->bsm", h, layer["w_gate"].astype(h.dtype))
@@ -179,12 +182,14 @@ def mlp(h, layer):
                       layer["w_down"].astype(h.dtype))
 
 
+@part("embed")
 def embed(params: Params, tokens, c: LlamaConfig):
     """Serving's embedding: one device, no constraints (the train
     forward places its own around the gather)."""
     return params["embed"].astype(c.dtype)[tokens]
 
 
+@part("head")
 def logits_f32(x, params: Params, c: LlamaConfig, row=None):
     """Serving's head: final norm over all of ``x`` (..., E), then ``x[row]``
     (a prefill's last valid position; None: every row) times the tied or
@@ -218,7 +223,9 @@ def _attention(x, layer, cos, sin, config: LlamaConfig,
         out = _flash_on_mesh(q, kk, v, c, rules)
     out = with_logical_constraint(
         out, ("batch", "seq", "heads", "head_dim"), rules)
-    return jnp.einsum("bshd,hde->bse", out, layer["wo"].astype(x.dtype))
+    with part("attn_proj"):
+        return jnp.einsum("bshd,hde->bse", out,
+                          layer["wo"].astype(x.dtype))
 
 
 def _flash_on_mesh(q, k, v, config: LlamaConfig, rules: ShardingRules):
@@ -260,11 +267,14 @@ def make_block(config: LlamaConfig, rules: ShardingRules, cos, sin,
     c = config
 
     def block(x, layer):
-        h = _attention(rmsnorm(x, layer["attn_norm"], c.norm_eps),
-                       layer, cos, sin, c, rules, positions, mesh)
-        x = x + h
+        with part("attn_proj"):
+            h = rmsnorm(x, layer["attn_norm"], c.norm_eps)
+        h = _attention(h, layer, cos, sin, c, rules, positions, mesh)
+        with part("attn_proj"):
+            x = x + h
         x = with_logical_constraint(x, ("batch", "seq", "embed"), rules)
-        x = x + mlp(rmsnorm(x, layer["mlp_norm"], c.norm_eps), layer)
+        with part("mlp"):
+            x = x + mlp(rmsnorm(x, layer["mlp_norm"], c.norm_eps), layer)
         x = with_logical_constraint(x, ("batch", "seq", "embed"), rules)
         return x, None
 
@@ -293,20 +303,24 @@ def forward(params: Params, tokens: jax.Array, config: LlamaConfig,
     # involuntary-full-remat path a sharded-table gather triggers.)
     table = with_logical_constraint(
         params["embed"], ("embed_vocab", "embed"), rules)
-    x = table.astype(c.dtype)[tokens]
+    with part("embed"):
+        x = table.astype(c.dtype)[tokens]
     x = with_logical_constraint(x, ("batch", "seq", "embed"), rules)
     cos, sin = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta)
 
     block = make_block(c, rules, cos, sin, positions, mesh)
     x, _ = jax.lax.scan(block, x, params["layers"])
 
-    x = rmsnorm(x, params["final_norm"], c.norm_eps)
-    head = (params["embed"].T if c.tie_embeddings else params["lm_head"])
-    # bf16 operands + f32 accumulation: full MXU rate with f32-exact logits.
-    # An f32×f32 einsum here runs the MXU at a fraction of bf16 peak and the
-    # head matmul is ~6% of total FLOPs — measurable at the step level.
-    logits = jnp.einsum("bse,ev->bsv", x, head.astype(x.dtype),
-                        preferred_element_type=jnp.float32)
+    with part("head"):
+        x = rmsnorm(x, params["final_norm"], c.norm_eps)
+        head = (params["embed"].T if c.tie_embeddings
+                else params["lm_head"])
+        # bf16 operands + f32 accumulation: full MXU rate with f32-exact
+        # logits. An f32×f32 einsum here runs the MXU at a fraction of
+        # bf16 peak and the head matmul is ~6% of total FLOPs —
+        # measurable at the step level.
+        logits = jnp.einsum("bse,ev->bsv", x, head.astype(x.dtype),
+                            preferred_element_type=jnp.float32)
     return with_logical_constraint(logits, ("batch", "seq", "vocab"), rules)
 
 
@@ -321,9 +335,15 @@ def loss_fn(params: Params, batch: Dict[str, jax.Array], config: LlamaConfig,
     """
     tokens = batch["tokens"]
     logits = forward(params, tokens, config, rules, mesh=mesh)  # (B,S,V) f32
+    return next_token_loss(logits, tokens, batch.get("mask"))
+
+
+@part("loss")
+def next_token_loss(logits, tokens, mask=None):
+    """Cross entropy of ``logits`` (B, S, V) against the next token, and
+    the metrics :func:`loss_fn` returns."""
     targets = tokens[:, 1:]
     logits = logits[:, :-1]
-    mask = batch.get("mask")
     # mask[i] gates the loss term at step i (predicting token i+1), so the
     # last position's mask value is unused.
     mask = (jnp.ones_like(targets, jnp.float32) if mask is None

@@ -50,6 +50,7 @@ from ray_tpu.ops.attention import hybrid_attention_reference, on_tpu
 from ray_tpu.ops.norms import rmsnorm
 from ray_tpu.ops.pallas import paged_hybrid_decode_attention as pha
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
+from ray_tpu.util.profiling import part
 
 Params = Dict[str, Any]
 KINDS = pc.HYBRID_KINDS             # layer_kinds 0, 1
@@ -205,6 +206,7 @@ def make_manager(cfg: MimoV2Config, page: Dict[str, PagedConfig],
 
 
 # ------------------------------------------------------------------ blocks
+@part("attn_proj")
 def _qkv(x, layer, cfg, cos, sin, positions):
     h = rmsnorm(x, layer["attn_norm"], cfg.norm_eps)
     q = jnp.einsum("bse,ehd->bshd", h, layer["wq"].astype(h.dtype))
@@ -222,12 +224,14 @@ def _mlp(x, layer, cfg, valid, kernel_name="grouped_expert_matmul"):
             x, layer, experts_held=cfg.experts_held, top_k=cfg.top_k,
             scale=cfg.routed_scale, valid=valid, kernel_name=kernel_name)
         return y.astype(x.dtype), counters
-    g = x @ layer["w_gate"].astype(x.dtype)
-    u = x @ layer["w_up"].astype(x.dtype)
-    return ((jax.nn.silu(g) * u) @ layer["w_down"].astype(x.dtype),
-            jnp.zeros((len(moe.COUNTERS),), jnp.float32))
+    with part("mlp"):
+        g = x @ layer["w_gate"].astype(x.dtype)
+        u = x @ layer["w_up"].astype(x.dtype)
+        return ((jax.nn.silu(g) * u) @ layer["w_down"].astype(x.dtype),
+                jnp.zeros((len(moe.COUNTERS),), jnp.float32))
 
 
+@part("head")
 def _head(x, params, cfg):
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     return x.astype(jnp.float32) @ params["lm_head"].astype(jnp.float32)
@@ -250,7 +254,8 @@ def _attend(q, kc, vc, li, tables, att_len, cfg, kind, sink, work):
     lengths (None off the TPU, where the oracle attends)."""
     kw = dict(scale=cfg.head_dim ** -0.5, k_slices=key_slices(cfg, kind),
               dv=cfg.v_head_dim, sink=sink, window=_window(cfg, kind))
-    qp = pack_queries(q, cfg, kind)
+    with part("attn_proj"):
+        qp = pack_queries(q, cfg, kind)
     if on_tpu():
         return pha.paged_hybrid_decode_attention(
             qp, kc, vc, li, tables, att_len, work=work,
@@ -271,7 +276,8 @@ def make_decode_step(params: Params, cfg: MimoV2Config,
         lengths = cache["length"]
         B = tokens.shape[0]
         ropes = _ropes(cfg, page["full"].max_seq)
-        x = params["embed"].astype(cfg.dtype)[tokens][:, None, :]
+        with part("embed"):
+            x = params["embed"].astype(cfg.dtype)[tokens][:, None, :]
         blk, off, att_len = pc.hybrid_decode_rows(tables, lengths, active,
                                                   bs)
         work = (pc.hybrid_decode_work(att_len, page, cfg.window)
@@ -283,15 +289,19 @@ def make_decode_step(params: Params, cfg: MimoV2Config,
             kind = cfg.kind(l)
             li, index[kind] = index[kind], index[kind] + 1
             q, k, v = _qkv(x, layer, cfg, *ropes[kind], lengths[:, None])
+            with part("kv_store"):
+                k_rows = pack_keys(k[:, 0], cfg)
             kc, vc = pools[kind] = pc.store_kv_rows(
-                pools[kind], (li, blk[kind], off),
-                pack_keys(k[:, 0], cfg), v[:, 0].reshape(B, -1))
+                pools[kind], (li, blk[kind], off), k_rows,
+                v[:, 0].reshape(B, -1))
             out = _attend(q[:, 0], kc, vc, li, tables[kind], att_len,
                           cfg, kind, layer.get("sink"), work[kind])
-            x = x + jnp.einsum("bhd,hde->be", out,
-                               layer["wo"].astype(x.dtype))[:, None, :]
-            y, c = _mlp(rmsnorm(x[:, 0], layer["mlp_norm"], cfg.norm_eps),
-                        layer, cfg, active)
+            with part("attn_proj"):
+                x = x + jnp.einsum("bhd,hde->be", out,
+                                   layer["wo"].astype(x.dtype))[:, None, :]
+            with part("mlp"):
+                normed = rmsnorm(x[:, 0], layer["mlp_norm"], cfg.norm_eps)
+            y, c = _mlp(normed, layer, cfg, active)
             x = x + y[:, None, :]
             counters = counters + c
         new = pc.hybrid_cache(
@@ -317,7 +327,8 @@ def make_prefill(params: Params, cfg: MimoV2Config,
                 pad_len: int):
         nblk = pad_len // bs
         ropes = _ropes(cfg, pad_len)
-        x = params["embed"].astype(cfg.dtype)[tokens]          # (1, P, h)
+        with part("embed"):
+            x = params["embed"].astype(cfg.dtype)[tokens]      # (1, P, h)
         valid = jnp.arange(pad_len) < true_len
         dest = pc.hybrid_prefill_blocks(table_rows, true_len, nblk, bs)
         pools = pc.hybrid_pools(cache)
@@ -330,15 +341,20 @@ def make_prefill(params: Params, cfg: MimoV2Config,
             out = hybrid_attention_reference(
                 q, k, v, scale=cfg.head_dim ** -0.5, sink=layer.get("sink"),
                 window=_window(cfg, kind))
-            x = x + jnp.einsum("bshd,hde->bse", out,
-                               layer["wo"].astype(x.dtype))
-            kb = jnp.where(valid[:, None], pack_keys(k[0], cfg), 0.0)
-            vb = jnp.where(valid[:, None], v[0].reshape(pad_len, -1), 0.0)
+            with part("attn_proj"):
+                x = x + jnp.einsum("bshd,hde->bse", out,
+                                   layer["wo"].astype(x.dtype))
+            with part("kv_store"):
+                kb = jnp.where(valid[:, None], pack_keys(k[0], cfg), 0.0)
+                vb = jnp.where(valid[:, None], v[0].reshape(pad_len, -1),
+                               0.0)
             pools[kind] = pc.store_kv_rows(pools[kind], (li, dest[kind]),
                                            kb.reshape(nblk, bs, -1),
                                            vb.reshape(nblk, bs, -1))
-            y, c = _mlp(rmsnorm(x[0], layer["mlp_norm"], cfg.norm_eps),
-                        layer, cfg, valid, "grouped_expert_matmul_prefill")
+            with part("mlp"):
+                normed = rmsnorm(x[0], layer["mlp_norm"], cfg.norm_eps)
+            y, c = _mlp(normed, layer, cfg, valid,
+                        "grouped_expert_matmul_prefill")
             x = x + y[None]
             counters = counters + c
         new = pc.hybrid_cache(

@@ -36,6 +36,7 @@ from ray_tpu.ops.attention import on_tpu
 from ray_tpu.ops.norms import rmsnorm
 from ray_tpu.ops.rope import rope_frequencies
 from ray_tpu.parallel.sharding import ShardingRules, with_logical_constraint
+from ray_tpu.util.profiling import part
 
 Params = Dict[str, Any]
 
@@ -279,6 +280,7 @@ COUNTERS = ("expert_layer_calls", "expert_pairs", "experts_hit",
             "expert_load_max_over_mean", "expert_pairs_dropped")
 
 
+@part("router")
 def route_sigmoid_topk(x, router, bias, top_k: int, scale: float = 1.0,
                        n_group: int = 1, topk_group: int = 1):
     """``noaux_tc`` routing: scores ``s = sigmoid(x_f32 @ W_r)`` in
@@ -310,6 +312,7 @@ def route_sigmoid_topk(x, router, bias, top_k: int, scale: float = 1.0,
     return idx.astype(jnp.int32), w
 
 
+@part("router")
 def route_softmax_topk(x, router, top_k: int, scale: float = 1.0):
     """Softmax routing with no capacity: ``p = softmax(x_f32 @ W_r)`` in
     float32 over every expert; the ``top_k`` largest are chosen; the
@@ -334,13 +337,13 @@ def swiglu(x, gate, up, down):
             @ down.astype(x.dtype))
 
 
+@part("shared_expert")
 def shared_expert(x, layer: Params):
     """The expert every token passes through, whole on every chip of an
     expert-parallel deployment (the data-parallel part of the layer): a
     SwiGLU ``ws_gate`` / ``ws_up`` (h, m), ``ws_down`` (m, h). x (T, h)
     -> (T, h) in x.dtype."""
-    with jax.named_scope("shared_expert"):
-        return swiglu(x, layer["ws_gate"], layer["ws_up"], layer["ws_down"])
+    return swiglu(x, layer["ws_gate"], layer["ws_up"], layer["ws_down"])
 
 
 def experts_by_share(x, layer: Params, *, experts_held: Tuple[int, int],
@@ -381,37 +384,40 @@ def experts_by_share(x, layer: Params, *, experts_held: Tuple[int, int],
         idx, w = route_sigmoid_topk(
             x, layer["router"], layer.get("router_bias"), top_k, scale,
             n_group, topk_group)
-    local = idx - first
-    held = (local >= 0) & (local < G)
-    if valid is not None:
-        held &= valid[:, None]
-    key = jnp.where(held, local, G).reshape(-1)               # (T*k,)
-    order = jnp.argsort(key, stable=True)
-    sizes = jnp.zeros(G + 1, jnp.int32).at[key].add(1)[:G]
-    padded = -(-sizes // tm) * tm
-    pend = jnp.cumsum(padded)
-    ustart = jnp.cumsum(sizes) - sizes
     n_tiles = -(-(T * top_k + G * (tm - 1)) // tm)
     M = n_tiles * tm
-    skey = key[order]
-    g_of = jnp.minimum(skey, G - 1)
-    row_sorted = jnp.where(
-        skey < G, (pend - padded)[g_of] + jnp.arange(T * top_k)
-        - ustart[g_of], M)
-    token_of_row = jnp.full((M,), T, jnp.int32).at[row_sorted].set(
-        (order // top_k).astype(jnp.int32), mode="drop")
-    x_rows = jnp.concatenate([x, jnp.zeros((1, h), x.dtype)])[token_of_row]
-    n_active = pend[-1] // tm
-    tile = jnp.minimum(jnp.arange(n_tiles), jnp.maximum(n_active - 1, 0))
-    tile_group = jnp.minimum(
-        jnp.searchsorted(pend, tile * tm, side="right"), G - 1)
+    with part("expert_dispatch"):
+        local = idx - first
+        held = (local >= 0) & (local < G)
+        if valid is not None:
+            held &= valid[:, None]
+        key = jnp.where(held, local, G).reshape(-1)           # (T*k,)
+        order = jnp.argsort(key, stable=True)
+        sizes = jnp.zeros(G + 1, jnp.int32).at[key].add(1)[:G]
+        padded = -(-sizes // tm) * tm
+        pend = jnp.cumsum(padded)
+        ustart = jnp.cumsum(sizes) - sizes
+        skey = key[order]
+        g_of = jnp.minimum(skey, G - 1)
+        row_sorted = jnp.where(
+            skey < G, (pend - padded)[g_of] + jnp.arange(T * top_k)
+            - ustart[g_of], M)
+        token_of_row = jnp.full((M,), T, jnp.int32).at[row_sorted].set(
+            (order // top_k).astype(jnp.int32), mode="drop")
+        x_rows = jnp.concatenate(
+            [x, jnp.zeros((1, h), x.dtype)])[token_of_row]
+        n_active = pend[-1] // tm
+        tile = jnp.minimum(jnp.arange(n_tiles),
+                           jnp.maximum(n_active - 1, 0))
+        tile_group = jnp.minimum(
+            jnp.searchsorted(pend, tile * tm, side="right"), G - 1)
 
     if use_kernel is None:
         use_kernel = on_tpu()
     mm = functools.partial(
         gm.grouped_matmul if use_kernel else gm.grouped_matmul_reference,
         name=kernel_name)
-    with jax.named_scope("expert_layer"):
+    with part("expert_layer"):
         gate = mm(x_rows, layer["we_gate"], tile_group, n_active, tm=tm,
                   out_dtype=jnp.float32)
         up = mm(x_rows, layer["we_up"], tile_group, n_active, tm=tm,
@@ -419,18 +425,22 @@ def experts_by_share(x, layer: Params, *, experts_held: Tuple[int, int],
         act = (jax.nn.silu(gate) * up).astype(x.dtype)
         y_rows = mm(act, layer["we_down"], tile_group, n_active, tm=tm,
                     out_dtype=jnp.float32)
-        row_pair = jnp.zeros(T * top_k, jnp.int32).at[order].set(
-            row_sorted.astype(jnp.int32)).reshape(T, top_k)
-        placed = held & (row_pair < M)
-        # rows of tiles past n_active were never written: select, never
-        # multiply, or what lies there leaks through a zero weight
-        y_pairs = jnp.where(placed[..., None],
-                            y_rows[jnp.minimum(row_pair, M - 1)], 0.0)
-        y = jnp.sum(y_pairs * w[..., None], axis=1)
-    pairs = jnp.sum(sizes).astype(jnp.float32)
-    counters = jnp.stack([
-        jnp.float32(1.0), pairs, jnp.sum(sizes > 0).astype(jnp.float32),
-        jnp.max(sizes) * G / jnp.maximum(pairs, 1.0),
-        jnp.sum(held).astype(jnp.float32)
-        - jnp.sum(placed).astype(jnp.float32)])
+        with part("expert_combine"):
+            row_pair = jnp.zeros(T * top_k, jnp.int32).at[order].set(
+                row_sorted.astype(jnp.int32)).reshape(T, top_k)
+            placed = held & (row_pair < M)
+            # rows of tiles past n_active were never written: select,
+            # never multiply, or what lies there leaks through a zero
+            # weight
+            y_pairs = jnp.where(placed[..., None],
+                                y_rows[jnp.minimum(row_pair, M - 1)], 0.0)
+            y = jnp.sum(y_pairs * w[..., None], axis=1)
+    with part("expert_dispatch"):       # the step's counters: group sizes
+        pairs = jnp.sum(sizes).astype(jnp.float32)
+        counters = jnp.stack([
+            jnp.float32(1.0), pairs,
+            jnp.sum(sizes > 0).astype(jnp.float32),
+            jnp.max(sizes) * G / jnp.maximum(pairs, 1.0),
+            jnp.sum(held).astype(jnp.float32)
+            - jnp.sum(placed).astype(jnp.float32)])
     return y, counters

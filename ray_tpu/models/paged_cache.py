@@ -60,6 +60,7 @@ from ray_tpu.models.decoding import (_bind_padded, _bind_params,
 from ray_tpu.models.llama import LlamaConfig, Params, embed, logits_f32
 from ray_tpu.ops.attention import mha_reference, on_tpu
 from ray_tpu.ops.rope import rope_frequencies
+from ray_tpu.util.profiling import part
 
 PagedCache = Dict[str, jax.Array]
 
@@ -448,6 +449,7 @@ def hybrid_cache(pools, length, counters):
     return new
 
 
+@part("kv_store")
 def store_kv_rows(pool, where, k_rows, v_rows):
     """Write key rows and value rows into one kind's (k, v) pools at
     ``where`` (layer, blocks[, offsets]): in place, inside a jitted
@@ -457,6 +459,7 @@ def store_kv_rows(pool, where, k_rows, v_rows):
             vc.at[where].set(v_rows.astype(vc.dtype)))
 
 
+@part("kv_store")
 def hybrid_decode_rows(tables, lengths, active, block_size: int):
     """Where a decode step's new row of each slot goes and what the slot
     attends: ({kind: block (B,)}, offset (B,), att_len (B,)). A slot that
@@ -468,6 +471,7 @@ def hybrid_decode_rows(tables, lengths, active, block_size: int):
     return blk, lengths % block_size, jnp.where(active, lengths + 1, 0)
 
 
+@part("kv_store")
 def hybrid_prefill_blocks(table_rows, true_len, nblk: int, block_size: int):
     """{kind: (nblk,)} the blocks a padded prompt's rows go to: what the
     kind's table names, the null block past the prompt's end (and, for a
@@ -478,6 +482,7 @@ def hybrid_prefill_blocks(table_rows, true_len, nblk: int, block_size: int):
             for kind in HYBRID_KINDS}
 
 
+@part("kv_store")
 def hybrid_decode_work(att_len, page: Dict[str, PagedConfig], window: int):
     """The hybrid decode kernel's work list of each kind for a decode
     step's lengths, built before the layer loop so that a kind's layers
@@ -510,6 +515,7 @@ def bind_hybrid_prefill(prefill, params: Params, block_size: int):
     return call
 
 
+@part("kv_store")
 def _decode_work(lengths, page: PagedConfig):
     """The kernel's work list for a decode step's lengths, built before
     the layer scan so that every layer shares it (None off the TPU, where
@@ -601,20 +607,22 @@ def make_chunked_paged_prefill(params: Params, config: LlamaConfig,
         # row-level scatter target: each chunk row lands at its exact
         # (block, offset), invalid rows in the null block; rows cached
         # before start_pos are never touched
-        row_blk = jnp.where(mask_valid, table_row[row_abs // bs], 0)
-        row_off = row_abs % bs                                # (C,)
+        with part("kv_store"):
+            row_blk = jnp.where(mask_valid, table_row[row_abs // bs], 0)
+            row_off = row_abs % bs                            # (C,)
 
         def attend(q, k, v, state):
             kc, vc, l = state
-            kb = jnp.where(mask_valid[:, None, None], k[0], 0.0)  # (C,KV,D)
-            vb = jnp.where(mask_valid[:, None, None], v[0], 0.0)
-            kc = kc.at[l, row_blk, row_off].set(kb.astype(kc.dtype))
-            vc = vc.at[l, row_blk, row_off].set(vb.astype(vc.dtype))
+            with part("kv_store"):
+                kb = jnp.where(mask_valid[:, None, None], k[0], 0.0)
+                vb = jnp.where(mask_valid[:, None, None], v[0], 0.0)
+            kc, vc = store_kv_rows((kc, vc), (l, row_blk, row_off), kb, vb)
             # gather the slot's full row set (prefix + this chunk) and
             # attend with absolute-position causal visibility
-            out = attend_rows(q, kc[l, table_row].reshape(rows_shape),
-                              vc[l, table_row].reshape(rows_shape),
-                              row_abs[None, :], c.head_dim ** -0.5)
+            with part("attention"):
+                out = attend_rows(q, kc[l, table_row].reshape(rows_shape),
+                                  vc[l, table_row].reshape(rows_shape),
+                                  row_abs[None, :], c.head_dim ** -0.5)
             return out, (kc, vc)
 
         x = embed(params, tokens, c)                          # (1, C, E)
@@ -652,17 +660,18 @@ def make_paged_decode_step(params: Params, config: LlamaConfig,
     def step(params: Params, cache: PagedCache, tables, tokens, active):
         lengths = cache["length"]
         slot_rows = jnp.arange(tokens.shape[0])
-        # physical write target of the new token per slot
-        blk = tables[slot_rows, lengths // bs]                     # (B,)
-        blk = jnp.where(active, blk, 0)                            # null
-        off = lengths % bs
-        att_len = jnp.where(active, lengths + 1, 0)
+        with part("kv_store"):
+            # physical write target of the new token per slot
+            blk = tables[slot_rows, lengths // bs]                 # (B,)
+            blk = jnp.where(active, blk, 0)                        # null
+            off = lengths % bs
+            att_len = jnp.where(active, lengths + 1, 0)
         work = _decode_work(att_len, page)
 
         def attend(q, k, v, state):
             kc, vc, l = state
-            kc = kc.at[l, blk, off].set(k[:, 0].astype(kc.dtype))
-            vc = vc.at[l, blk, off].set(v[:, 0].astype(vc.dtype))
+            kc, vc = store_kv_rows((kc, vc), (l, blk, off), k[:, 0],
+                                   v[:, 0])
             out = _attend_paged(q, kc, vc, l, tables, att_len,
                                 c.head_dim ** -0.5, work)
             return out, (kc, vc)
@@ -698,19 +707,20 @@ def make_paged_prefill(params: Params, config: LlamaConfig,
         positions = jnp.arange(pad_len)[None, :]
         mask_valid = positions[0] < true_len                  # (P,)
         # rows past true_len write into the null block
-        dest = jnp.where(jnp.arange(nblk) * bs < true_len,
-                         table_row[:nblk], 0)                  # (nblk,)
+        with part("kv_store"):
+            dest = jnp.where(jnp.arange(nblk) * bs < true_len,
+                             table_row[:nblk], 0)              # (nblk,)
 
         def attend(q, k, v, state):
             # causal within the prompt; its k/v fill whole blocks
             kc, vc, l = state
-            kb = jnp.where(mask_valid[:, None, None], k[0],
-                           0.0).reshape(blocks_shape)
-            vb = jnp.where(mask_valid[:, None, None], v[0],
-                           0.0).reshape(blocks_shape)
-            kc = kc.at[l, dest].set(kb.astype(kc.dtype))
-            vc = vc.at[l, dest].set(vb.astype(vc.dtype))
-            return mha_reference(q, k, v, causal=True), (kc, vc)
+            with part("kv_store"):
+                kb = jnp.where(mask_valid[:, None, None], k[0],
+                               0.0).reshape(blocks_shape)
+                vb = jnp.where(mask_valid[:, None, None], v[0],
+                               0.0).reshape(blocks_shape)
+            pools = store_kv_rows((kc, vc), (l, dest), kb, vb)
+            return mha_reference(q, k, v, causal=True), pools
 
         x = embed(params, tokens, c)                          # (1, P, E)
         x, new_k, new_v = _scan_layers(attend, x, params, cache, c,

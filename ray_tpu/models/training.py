@@ -24,6 +24,7 @@ from ray_tpu.parallel.sharding import (
     logical_sharding,
     logical_spec,
 )
+from ray_tpu.util.profiling import part
 
 Pytree = Any
 
@@ -141,11 +142,12 @@ def make_train_step(loss_fn: Callable[[Pytree, Dict[str, jax.Array]],
             if x.ndim == 2 else x, batch)
         (loss, metrics), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(state.params, batch)
-        updates, new_opt = optimizer.update(grads, state.opt_state,
-                                            state.params)
-        new_params = optax.apply_updates(state.params, updates)
-        metrics = dict(metrics)
-        metrics["grad_norm"] = optax.global_norm(grads)
+        with part("optimizer"):
+            updates, new_opt = optimizer.update(grads, state.opt_state,
+                                                state.params)
+            new_params = optax.apply_updates(state.params, updates)
+            metrics = dict(metrics)
+            metrics["grad_norm"] = optax.global_norm(grads)
         metrics["lr_step"] = state.step
         return TrainState(step=state.step + 1, params=new_params,
                           opt_state=new_opt), metrics

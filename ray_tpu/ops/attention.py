@@ -18,6 +18,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.util.profiling import part
+
 NEG_INF = -1e30
 
 
@@ -27,6 +29,7 @@ def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+@part("attention")
 def mha_reference(q, k, v, causal: bool = True, scale: Optional[float] = None):
     """Naive O(S²)-memory attention, (B, S, H, D) layout. Test oracle."""
     scale = scale if scale is not None else q.shape[-1] ** -0.5
@@ -60,6 +63,7 @@ def _sink_softmax(s, sink):
     return p / den
 
 
+@part("attention")
 def hybrid_attention_reference(q, k, v, *, scale: float,
                                window: Optional[int] = None, sink=None):
     """Causal attention in XLA for one layer of a model that mixes full
@@ -283,6 +287,7 @@ def _flash_bwd_xla(causal, scale, block, res, cotangents):
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 
+@part("attention")
 def flash_attention(q, k, v, causal: bool = True,
                     scale: Optional[float] = None, block: int = 512):
     """Differentiable flash attention, (B, S, H, D) layout (GQA-aware)."""

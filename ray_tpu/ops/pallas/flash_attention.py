@@ -25,8 +25,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
 from ray_tpu.ops.attention import NEG_INF
+from ray_tpu.util.profiling import part
 
 _LANES = 128
 
@@ -122,7 +122,7 @@ def flash_attention_fwd_pallas(q, k, v, *, causal: bool, scale: float,
         _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
         block_kv=block_kv, kv_len=skv, num_kv_blocks=nk)
 
-    with jax.named_scope("flash_attention_fwd"):
+    with part("flash_attention_fwd"):
         out, lse = pl.pallas_call(
             kernel,
             grid=(b * hq, nq, nk),
@@ -325,7 +325,7 @@ def flash_attention_bwd_pallas(q, k, v, lse, delta, dout, *,
         _bwd_dq_kernel, scale=scale, causal=causal, block_q=block_q,
         block_kv=block_kv, q_len=sq, kv_len=skv, num_kv_blocks=nk)
 
-    with jax.named_scope("flash_attention_dq"):
+    with part("flash_attention_dq"):
         dq = pl.pallas_call(
             dq_kernel,
             grid=(b * hq, nq, nk),
@@ -364,7 +364,7 @@ def flash_attention_bwd_pallas(q, k, v, lse, delta, dout, *,
         block_kv=block_kv, q_len=sq, kv_len=skv, num_q_blocks=nq,
         num_inner=num_inner)
 
-    with jax.named_scope("flash_attention_dkv"):
+    with part("flash_attention_dkv"):
         dk, dv = pl.pallas_call(
             dkv_kernel,
             grid=(b * hkv, nk, num_inner),

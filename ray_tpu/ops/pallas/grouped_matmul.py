@@ -30,6 +30,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ray_tpu.util.profiling import part
+
 NAME = "grouped_expert_matmul"
 _RHS_BLOCK_BYTES = 4 << 20
 
@@ -79,7 +81,7 @@ def grouped_matmul(lhs, rhs, tile_group, n_active, *, tm: int,
         ],
         out_specs=pl.BlockSpec((tm, tn), lambda n, i, g, a: (tile(i, a), n)),
     )
-    with jax.named_scope(name):
+    with part(name):
         return pl.pallas_call(
             _kernel,
             grid_spec=grid_spec,
@@ -99,8 +101,9 @@ def grouped_matmul_reference(lhs, rhs, tile_group, n_active, *, tm: int,
     M, K = lhs.shape
     out_dtype = out_dtype or lhs.dtype
     tiles = lhs.reshape(M // tm, tm, K)
-    out = jnp.einsum("itk,ikn->itn", tiles, rhs[tile_group],
-                     preferred_element_type=jnp.float32)
-    live = jnp.arange(M // tm) < n_active
-    return jnp.where(live[:, None, None], out, 0.0).reshape(
-        M, -1).astype(out_dtype)
+    with part(name):
+        out = jnp.einsum("itk,ikn->itn", tiles, rhs[tile_group],
+                         preferred_element_type=jnp.float32)
+        live = jnp.arange(M // tm) < n_active
+        return jnp.where(live[:, None, None], out, 0.0).reshape(
+            M, -1).astype(out_dtype)

@@ -53,6 +53,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ray_tpu.util.profiling import part
+
 
 NEG_INF = -1e30
 _LANES = 128
@@ -207,7 +209,7 @@ def paged_decode_attention(q, k_pool, v_pool, layer, tables, lengths, *,
         ],
     )
 
-    with jax.named_scope("paged_decode_attention"):
+    with part("paged_decode_attention"):
         out = pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
@@ -225,6 +227,7 @@ def paged_decode_attention(q, k_pool, v_pool, layer, tables, lengths, *,
     return out.reshape(B, 1, H, D)
 
 
+@part("attention")
 def paged_attention_reference(q, k_pool, v_pool, layer, tables, lengths, *,
                               scale: float):
     """XLA path (and the kernel's correctness oracle), same arguments as

@@ -59,6 +59,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops.pallas.paged_decode_attention import decode_work_list
+from ray_tpu.util.profiling import part
 
 NEG_INF = -1e30
 _LANES = 128
@@ -199,17 +200,22 @@ def paged_hybrid_decode_attention(
         q, k_pool, v_pool, layer, tables, lengths, *, scale: float,
         k_slices: Sequence[Tuple[int, ...]], dv: int,
         window: Optional[int] = None, sink=None, work=None,
-        name: str = "paged_hybrid_decode", interpret: bool = False):
+        name: Optional[str] = None, interpret: bool = False):
     """q (B, H, n*c) packed; k_pool (L, NB, bs, Wk); v_pool
     (L, NB, bs, KV*dv); layer () int32; tables (B, MBS) int32; lengths
     (B,) int32, the new token included, 0 for a slot that is not running;
     ``sink`` (H,) float32 or None. -> (B, H, dv) in q.dtype; the row of a
     slot of length 0 is zeros. ``name`` is the custom call's instruction
-    name, so a trace tells a full layer's calls from a window layer's.
+    name, so a trace tells a full layer's calls from a window layer's
+    (None: ``paged_hybrid_decode_window`` with a window, ``_full``
+    without).
 
     ``work`` is ``hybrid_work_list(lengths, bs, MBS, window)`` from a
     caller that attends many layers over the same lengths and builds the
     list once; built here when absent."""
+    if name is None:
+        name = ("paged_hybrid_decode_full" if window is None
+                else "paged_hybrid_decode_window")
     B, H, qw = q.shape
     bs, Wk = k_pool.shape[2], k_pool.shape[3]
     MBS = tables.shape[1]
@@ -262,7 +268,7 @@ def paged_hybrid_decode_attention(
             pltpu.VMEM((H, _LANES), jnp.float32),
         ],
     )
-    with jax.named_scope(name):
+    with part(name):
         out = pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
@@ -277,6 +283,7 @@ def paged_hybrid_decode_attention(
         return jnp.where((lengths > 0)[:, None, None], out, 0)
 
 
+@part("attention")
 def paged_hybrid_attention_reference(
         q, k_pool, v_pool, layer, tables, lengths, *, scale: float,
         k_slices: Sequence[Tuple[int, ...]], dv: int,

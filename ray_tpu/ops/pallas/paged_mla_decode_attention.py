@@ -41,6 +41,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops.attention import on_tpu
 from ray_tpu.ops.pallas.paged_decode_attention import decode_work_list
+from ray_tpu.util.profiling import part
 
 NEG_INF = -1e30
 _LANES = 128
@@ -154,7 +155,7 @@ def paged_mla_decode_kernel(q, pool, layer, tables, lengths, *, scale: float,
             pltpu.VMEM((H, _LANES), jnp.float32),
         ],
     )
-    with jax.named_scope(name):
+    with part(name):
         out = pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
@@ -170,6 +171,7 @@ def paged_mla_decode_kernel(q, pool, layer, tables, lengths, *, scale: float,
         return jnp.where((lengths > 0)[:, None, None], out, 0)
 
 
+@part("attention")
 def paged_mla_attention_reference(q, pool, layer, tables, lengths, *,
                                   scale: float, rank: int):
     """XLA path (and the kernel's oracle), same arguments: gather every

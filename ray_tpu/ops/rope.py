@@ -34,6 +34,8 @@ from typing import Optional
 
 import jax.numpy as jnp
 
+from ray_tpu.util.profiling import part
+
 
 @dataclasses.dataclass(frozen=True)
 class YarnScaling:
@@ -78,6 +80,7 @@ class YarnScaling:
         return (1.0 / (self.factor * f)) * ramp + (1.0 / f) * (1.0 - ramp)
 
 
+@part("attn_proj")
 def rope_frequencies(head_dim: int, max_seq: int, theta: float = 500000.0,
                      dtype=jnp.float32, yarn: Optional[YarnScaling] = None):
     """(max_seq, head_dim/2) cos/sin tables."""

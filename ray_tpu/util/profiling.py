@@ -3,7 +3,7 @@ profilers via runtime hooks; the TPU-native equivalent is the XLA/jax
 profiler, whose traces open in TensorBoard/Perfetto and show per-kernel
 MXU/HBM utilization).
 
-Three entry points:
+Four entry points:
 
 - :func:`profile` — context manager around a training/serving region;
   writes an XLA profiler trace directory (the evidence artifact for
@@ -16,6 +16,11 @@ Three entry points:
   counters (count, wall seconds and, sampled, the thread's CPU
   seconds), so the same names read the same work with and without a
   capture.
+
+- :func:`part` — the named part of a block that a device operation
+  belongs to (``jax.named_scope`` under ONE vocabulary, :data:`PARTS`):
+  metadata of the compiled instructions, read from a capture by
+  ``benchmark/layer_metrics/_dev_ms_by_part.py``.
 
 ``profile`` and ``annotate`` degrade to no-ops when jax's profiler is
 unavailable (e.g. a worker without jax initialized), so library code
@@ -84,6 +89,40 @@ def annotate(name: str, **attrs):
     """Named region inside a capture (shows as a host-side bar above the
     device kernels it launched); ``attrs`` become the event's stats."""
     return _annotations()[0](name, **attrs)
+
+
+# The parts of a block, for the DEVICE's operations: every operation of
+# the decode step, a prefill and the train step is traced under one of
+# these names, which becomes a component of its ``op_name`` in the
+# compiled program and of the ``tf_op`` stat of its event in a capture.
+# The innermost name wins, so a kernel inside ``expert_layer`` reads as
+# the kernel. PERF.md section 3 lists where each is opened and which
+# metric reads it; the benchmark's reader holds a copy that a test holds
+# equal to this one.
+PARTS = (
+    # the block's own arithmetic
+    "embed", "attn_proj", "kv_store", "attention", "mlp", "router",
+    "expert_dispatch", "expert_combine", "head", "loss", "optimizer",
+    # what a model adds
+    "expert_layer", "shared_expert", "attn_gate", "mla_expand",
+    "mla_absorb",
+    # the kernels, each under its custom call's own name
+    "paged_decode_attention", "paged_hybrid_decode_full",
+    "paged_hybrid_decode_window", "paged_mla_decode",
+    "grouped_expert_matmul", "grouped_expert_matmul_prefill",
+    "flash_attention_fwd", "flash_attention_dq", "flash_attention_dkv",
+)
+
+
+def part(name: str):
+    """``jax.named_scope(name)`` for a ``name`` of :data:`PARTS`: what
+    is traced inside belongs to that part of the block. Checked while
+    tracing; nothing runs for it on the device or in a loop's turn."""
+    if name not in PARTS:
+        raise ValueError(f"{name!r} is no part of a block: one of {PARTS}")
+    import jax
+
+    return jax.named_scope(name)
 
 
 class _Phase:

@@ -1,0 +1,254 @@
+"""Every device operation of the decode step, a prefill and the train step
+lies under a named part of the block (``ray_tpu.util.profiling.PARTS``).
+
+The programs of the four serving models and the train step are lowered
+here on the CPU at tiny sizes, and the ``op_name`` that every instruction
+carries (``metadata={op_name="jit(step)/.../mlp/dot_general"}``; on a chip
+the same path is the ``tf_op`` stat of the operation's event in a capture)
+is read as the benchmark's reader reads it: the innermost component that
+is a name of ``PARTS`` is the part, a transform's wrapper
+(``transpose(jvp(mlp))``) peeled off. What is read is the module the
+compiler is GIVEN: what the CPU's compiler makes of it (a fusion of its
+own around a widened operand, an expanded cumulative sum) carries no path
+and says nothing of the chip's, whose unnamed share the benchmark measures
+(``decode_unnamed_dev_ms``). The kernels do not run on the CPU: their XLA
+oracles stand under ``attention`` and the kernels' own names."""
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from ray_tpu.models import axk1, laguna, llama, mimo_v2
+from ray_tpu.models.serving import serving_model
+from ray_tpu.models.training import (OptimizerConfig, init_train_state,
+                                     make_train_step)
+from ray_tpu.parallel.mesh import MeshConfig, make_mesh
+from ray_tpu.parallel.sharding import ShardingRules
+from ray_tpu.util import profiling
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SLOTS, MAX_SEQ, BLOCK, PAD = 3, 64, 8, 32
+HEAVY = ("dot", "convolution", "sort", "gather", "scatter", "reduce",
+         "reduce-window", "dynamic-update-slice", "custom-call")
+MODELS = {
+    "dense": lambda: llama.CONFIGS["debug"],
+    "mimo_v2": mimo_v2.MimoV2Config,
+    "axk1": axk1.AxK1Config,
+    "laguna": laguna.LagunaConfig,
+}
+ROUTED = ("mimo_v2", "axk1", "laguna")
+
+_INSTR = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = (.*?) ([a-z][a-z\-]*)\(")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLED = re.compile(r"(?:body|condition|to_apply|true_computation|"
+                     r"false_computation)=%?([\w.\-]+)|"
+                     r"branch_computations=\{([^}]*)\}")
+_PEEL = re.compile(r"^(?:\w+\()*([^()]*)\)*$")
+
+
+def part_of(op_name):
+    """The innermost component of an ``op_name`` path that is a part."""
+    for comp in reversed(op_name.split("/")):
+        inner = _PEEL.match(comp)
+        if inner and inner.group(1) in profiling.PARTS:
+            return inner.group(1)
+    return None
+
+
+def hlo_text(lowered):
+    """The module as the compiler is given it, with every instruction's
+    ``metadata``."""
+    from jax._src.lib import _jax
+
+    options = _jax.HloPrintOptions()
+    options.print_metadata = True
+    return lowered.compiler_ir(dialect="hlo").get_hlo_module().to_string(
+        options)
+
+
+def operations(text):
+    """[(opcode, result type, op_name)] of the entry computation and of
+    the computations its loops, calls and conditionals run (a reducer is
+    its reduction's own business). An instruction of a called function
+    names itself from the call on (``mul``); the call's own path is put
+    before it, as the compiler does when it inlines the call."""
+    comps, name, entry = {}, None, None
+    for line in text.splitlines():
+        head = re.match(r"^(ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            name = head.group(2)
+            comps[name] = []
+            if head.group(1):
+                entry = name
+        elif name is not None and line.startswith("}"):
+            name = None
+        elif name is not None:
+            comps[name].append(line)
+    root = next(m.group(1).split("/")[0] for m in map(
+        _OP_NAME.search, comps[entry]) if m and m.group(1).startswith("jit("))
+    out, todo, seen = [], [(entry, "")], set()
+    while todo:
+        comp, prefix = todo.pop()
+        if (comp, prefix) in seen:
+            continue
+        seen.add((comp, prefix))
+        for line in comps[comp]:
+            m = _INSTR.match(line)
+            if not m:
+                continue
+            opcode = m.group(3)
+            path = _OP_NAME.search(line)
+            path = path.group(1) if path else ""
+            if path and path.split("/")[0] != root:
+                path = prefix + "/" + path
+            if opcode in ("while", "call", "conditional"):
+                for one, many in _CALLED.findall(line):
+                    todo += [(c.strip().lstrip("%"), path)
+                             for c in ([one] if one else many.split(","))]
+                continue
+            if 'custom_call_target="Sharding"' not in line:  # no operation
+                out.append((opcode, m.group(2), path))
+    return out
+
+
+@pytest.fixture(scope="module")
+def programs():
+    """model -> {"decode": compiled text, "prefill": ..., "cfg": ...},
+    compiled once for all the cases."""
+    out = {}
+
+    def get(model):
+        if model not in out:
+            cfg = MODELS[model]()
+            served = serving_model(cfg)
+            params = served.init_params(jax.random.key(0))
+            p = served.paged(params, num_slots=SLOTS, max_seq=MAX_SEQ,
+                             block_size=BLOCK, pool_tokens=SLOTS * MAX_SEQ)
+            decode = p.decode.jitted.lower(
+                params, p.cache, p.alloc.device_tables(),
+                jnp.zeros((SLOTS,), jnp.int32), jnp.ones((SLOTS,), bool))
+            rows = jax.tree.map(jnp.asarray, p.alloc.table_rows(0))
+            prefill = p.prefill.jitted.lower(
+                params, p.cache, rows, jnp.zeros((1, PAD), jnp.int32),
+                jnp.int32(PAD - 3), jnp.int32(0), pad_len=PAD)
+            out[model] = {"cfg": cfg, "cache": p.cache,
+                          "decode": hlo_text(decode),
+                          "prefill": hlo_text(prefill)}
+        return out[model]
+
+    return get
+
+
+@pytest.fixture(scope="module")
+def train_text():
+    cfg = llama.LlamaConfig(vocab_size=256, hidden=64, n_layers=2, n_heads=4,
+                            n_kv_heads=2, head_dim=16, mlp_dim=128,
+                            max_seq=64, dtype=jnp.float32, remat=True)
+    rules = ShardingRules()
+    opt = OptimizerConfig(warmup_steps=1).make()
+    mesh = make_mesh(MeshConfig(dp=1), devices=jax.devices()[:1])
+    with jax.sharding.set_mesh(mesh):
+        state, _ = init_train_state(
+            lambda key: llama.init_params(cfg, key),
+            llama.param_logical_axes(cfg), opt, mesh, rules,
+            jax.random.key(0))
+        step = make_train_step(lambda p, b: llama.loss_fn(p, b, cfg, rules),
+                               opt, mesh, rules, donate=False)
+        return hlo_text(step.lower(
+            state, {"tokens": jnp.zeros((2, 64), jnp.int32)}))
+
+
+def _named_share(text):
+    """(heavy operations, those of them under no part). A layer scan's
+    own stacking of what its body returns (``.../while/body/
+    dynamic_update_slice``: a train step's saved activations and stacked
+    gradients) is ``lax.scan``'s, written under the scan's path and not
+    the block's: left out here, and ``unnamed`` on the chip."""
+    heavy = [(op, path) for op, _, path in operations(text) if op in HEAVY
+             and not path.endswith("/while/body/dynamic_update_slice")]
+    bare = [(op, path) for op, path in heavy if part_of(path) is None]
+    return len(heavy), bare
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("model", sorted(MODELS))
+def test_the_serving_programs_operations_lie_under_a_part(programs, model,
+                                                          program):
+    n, bare = _named_share(programs(model)[program])
+    assert n > 10
+    assert len(bare) <= 0.05 * n, (n, bare)
+
+
+def test_the_train_steps_operations_lie_under_a_part(train_text):
+    n, bare = _named_share(train_text)
+    assert n > 30
+    assert len(bare) <= 0.05 * n, (n, bare)
+
+
+@pytest.mark.parametrize("model", ROUTED)
+def test_sort_under_dispatch_and_topk_under_router(programs, model):
+    ops = operations(programs(model)["decode"])
+    sorts = [path for op, _, path in ops if op == "sort"
+             and "top_k" not in path]
+    assert sorts and {part_of(p) for p in sorts} == {"expert_dispatch"}
+    topk = [path for _, _, path in ops if "top_k" in path]
+    assert topk and {part_of(p) for p in topk} == {"router"}, topk
+    assert any(part_of(p) == "expert_combine" for _, _, p in ops)
+
+
+@pytest.mark.parametrize("model", sorted(MODELS))
+def test_vocabulary_dot_under_head_and_pool_writes_under_kv_store(programs,
+                                                                 model):
+    got = programs(model)
+    ops = operations(got["decode"])
+    vocab = got["cfg"].vocab_size
+    wide = [path for op, result, path in ops
+            if op == "dot" and re.search(rf"\[(\d+,)*{vocab}\]", result)]
+    assert wide and {part_of(p) for p in wide} == {"head"}, wide
+    pools = {tuple(a.shape) for a in jax.tree.leaves(got["cache"])
+             if a.ndim >= 4}
+    writes = [path for op, result, path in ops
+              if op in ("scatter", "dynamic-update-slice") and any(
+                  "[" + ",".join(map(str, s)) + "]" in result for s in pools)]
+    assert writes and {part_of(p) for p in writes} == {"kv_store"}, writes
+
+
+def test_the_train_step_splits_into_forward_recompute_and_backward(
+        train_text):
+    paths = [path for op, _, path in operations(train_text) if op in HEAVY]
+    recompute = {part_of(p) for p in paths if "rematted_computation" in p}
+    backward = {part_of(p) for p in paths if "transpose(jvp" in p
+                and "rematted_computation" not in p}
+    forward = {part_of(p) for p in paths if "transpose(jvp" not in p
+               and "rematted_computation" not in p}
+    block = {"attn_proj", "attention", "mlp"}
+    assert block <= recompute and block <= backward and block <= forward
+    assert {"embed", "head", "loss", "optimizer"} <= forward
+    assert "optimizer" not in backward | recompute
+
+
+def test_a_name_outside_the_vocabulary_raises_at_trace_time():
+    def f(x):
+        with profiling.part("nonsense"):
+            return x + 1
+
+    with pytest.raises(ValueError, match="nonsense"):
+        jax.jit(f).lower(jnp.zeros(2))
+    assert len(set(profiling.PARTS)) == len(profiling.PARTS)
+
+
+def test_named_scope_is_spelled_once():
+    """``jax.named_scope`` is called in ``util/profiling.part`` and
+    nowhere under ``models/`` or ``ops/``."""
+    hits = []
+    for sub in ("models", "ops"):
+        for base, _, files in os.walk(os.path.join(ROOT, "ray_tpu", sub)):
+            for name in files:
+                if name.endswith(".py"):
+                    with open(os.path.join(base, name)) as f:
+                        if "named_scope" in f.read():
+                            hits.append(os.path.join(base, name))
+    assert hits == []
