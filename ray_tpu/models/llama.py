@@ -54,7 +54,9 @@ class LlamaConfig:
     # "nothing" (max recompute, min HBM), "dots" (save matmul outputs —
     # fewer recomputed FLOPs, more HBM), "none" alias of remat=False
     remat_policy: str = "nothing"
-    attn_block: int = 512           # flash attention tile size
+    # tile of the blockwise XLA flash backward (off-TPU); the Pallas kernels
+    # choose their blocks from the call's shapes
+    attn_block: int = 512
     # Ring/sequence-parallel attention: set by the trainer when sp > 1.
     sp_axis: Optional[str] = None
 

@@ -150,21 +150,19 @@ def _flash_lse(q, k, v, causal, scale, block):
     """Joint (out, lse) primitive so downstream consumers of lse (ring
     attention merges) stay differentiable: bwd handles the dlse cotangent
     via the extra ``P·dlse`` term in dS."""
-    return _flash_fwd_dispatch(q, k, v, causal, scale, block)
+    return _flash_fwd_dispatch(q, k, v, causal, scale)
 
 
-def _flash_fwd_dispatch(q, k, v, causal, scale, block):
+def _flash_fwd_dispatch(q, k, v, causal, scale):
     if on_tpu():
         from ray_tpu.ops.pallas.flash_attention import flash_attention_fwd_pallas
 
-        return flash_attention_fwd_pallas(
-            q, k, v, causal=causal, scale=scale,
-            block_q=block, block_kv=block)
+        return flash_attention_fwd_pallas(q, k, v, causal=causal, scale=scale)
     return _fwd_xla(q, k, v, causal, scale)
 
 
 def _flash_lse_fwd(q, k, v, causal, scale, block):
-    out, lse = _flash_fwd_dispatch(q, k, v, causal, scale, block)
+    out, lse = _flash_fwd_dispatch(q, k, v, causal, scale)
     return (out, lse), (q, k, v, out, lse)
 
 
@@ -179,8 +177,7 @@ def _flash_lse_bwd(causal, scale, block, res, cotangents):
         delta = (jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32),
                          axis=-1) - dlse.astype(jnp.float32))
         dq, dk, dv = flash_attention_bwd_pallas(
-            q, k, v, lse, delta, dout, causal=causal, scale=scale,
-            block_q=block, block_kv=block)
+            q, k, v, lse, delta, dout, causal=causal, scale=scale)
         return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
     return _flash_bwd_xla(causal, scale, block, res, cotangents)
 
@@ -290,7 +287,10 @@ _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 @part("attention")
 def flash_attention(q, k, v, causal: bool = True,
                     scale: Optional[float] = None, block: int = 512):
-    """Differentiable flash attention, (B, S, H, D) layout (GQA-aware)."""
+    """Differentiable flash attention, (B, S, H, D) layout (GQA-aware).
+
+    ``block`` tiles the blockwise XLA backward used off-TPU; the Pallas
+    kernels choose their blocks from the call's shapes."""
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)

@@ -1,10 +1,19 @@
-"""Flash-attention forward kernel (Pallas TPU).
+"""Flash-attention kernels (Pallas TPU): forward, dq, dkv.
 
-Online-softmax tiling: grid = (batch·heads, q blocks, kv blocks) with the kv
-dimension innermost ("arbitrary" = sequential), carrying the running max /
-normalizer / accumulator in VMEM scratch so the S×S score matrix never touches
-HBM. Causal blocks strictly above the diagonal are skipped with ``pl.when``
-(compute is elided; the scratch state is carried through unchanged).
+Online-softmax tiling: the S×S score matrix never touches HBM. The forward
+and dq walk a work list, ``grid = (batch·heads, live block pairs)``: two
+scalar-prefetched tables name the (q block, kv block) pair of every step, so
+a causal call takes a step, and fetches a K / V block, only for a pair on or
+below the diagonal, and a non-causal call for the whole rectangle. The steps
+of one q block are consecutive ("arbitrary" = sequential) and carry its
+running max / normalizer / accumulator in VMEM scratch.
+
+All three kernels keep the scores *transposed*, s_t = K·Qᵀ of shape
+(block_kv, block_q): with q as the lane (minor) dimension the per-query
+vectors (running max and sum, lse, delta) are (1, block_q) rows that
+broadcast against s_t over sublanes, and the forward's two reductions run
+over sublanes, not along lanes. A mask is built only in a block it can
+change: one the diagonal crosses, or the padded last kv block.
 
 Layout contract: inputs are (B, H, S, D); GQA kv heads are resolved in the kv
 BlockSpec index map (no materialized head repeat). Matmuls run on the MXU in
@@ -22,6 +31,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -31,68 +41,130 @@ from ray_tpu.util.profiling import part
 _LANES = 128
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                acc_ref, m_ref, l_ref,
-                *, scale: float, causal: bool, block_q: int, block_kv: int,
-                kv_len: int, num_kv_blocks: int):
-    iq = pl.program_id(1)
-    ik = pl.program_id(2)
+def _blocks(block, length: int, row_bytes: int) -> tuple[int, int]:
+    """(block, padded length) of one sequence axis. A sequence of at most
+    one block is one block of whole lanes. ``block=None`` chooses: 1024 or
+    512, whichever pads less. 1024² is the largest score tile whose f32
+    temporaries (dq holds four) fit the VMEM a kernel may scope beside
+    (block, D) operands of at most 256 bytes a row; on a v5e at 4,096 × 128
+    it is the fastest for all three kernels (forward 4.40 ms a call against
+    4.88 at 512², dq 4.51 / 5.31, dkv 6.03 / 6.55), and 2048 on either side
+    is slower again (PERF.md section 6, PR 37)."""
+    if block is None:
+        block = 512
+        if row_bytes <= 256 and -length % 1024 <= -length % 512:
+            block = 1024
+    block = min(block, math.ceil(length / _LANES) * _LANES)
+    return block, math.ceil(length / block) * block
+
+
+def _live(iq, ik, *, causal: bool, block_q: int, block_kv: int):
+    """Whether block pair (iq, ik) holds work: always, or in a causal call
+    when the kv block starts at or before the q block's last row."""
+    return ik * block_kv < (iq + 1) * block_q if causal else True
+
+
+def _work_list(nq: int, nk: int, **blocks):
+    """The block pairs that hold work, q-major, as two int32 tables for
+    scalar prefetch: ``(iq[t], ik[t])`` is step t's pair."""
+    iq, ik = np.nonzero(np.broadcast_to(
+        _live(np.arange(nq)[:, None], np.arange(nk)[None, :], **blocks),
+        (nq, nk)))
+    return jnp.asarray(iq, jnp.int32), jnp.asarray(ik, jnp.int32)
+
+
+def _is_last(iq, ik, *, num_kv_blocks: int, **blocks):
+    """Whether (iq, ik) is q block iq's last pair in the work list."""
+    return jnp.logical_or(ik == num_kv_blocks - 1,
+                          jnp.logical_not(_live(iq, ik + 1, **blocks)))
+
+
+def _when_masked(step, iq, ik, *, causal: bool, block_q: int, block_kv: int,
+                 kv_len: int, num_kv_blocks: int):
+    """Run ``step(mask)`` once. ``mask`` is a function of s_t (block_kv,
+    block_q) where a mask can change a score: the diagonal crosses the block
+    (its last key lies after its first query), or it is the padded last kv
+    block. Elsewhere it is None and the body builds no iota or compare."""
+
+    def mask(s_t):
+        kpos = ik * block_kv + jax.lax.broadcasted_iota(
+            jnp.int32, s_t.shape, 0)
+        valid = kpos < kv_len
+        if causal:
+            qpos = iq * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, s_t.shape, 1)
+            valid = jnp.logical_and(valid, qpos >= kpos)
+        return valid
+
+    conds = []
+    if causal:
+        conds.append((ik + 1) * block_kv - 1 > iq * block_q)
+    if kv_len != num_kv_blocks * block_kv:
+        conds.append(ik == num_kv_blocks - 1)
+    if not conds:
+        step(None)
+        return
+    needs_mask = functools.reduce(jnp.logical_or, conds)
+    pl.when(needs_mask)(lambda: step(mask))
+    pl.when(jnp.logical_not(needs_mask))(lambda: step(None))
+
+
+def _fwd_kernel(iq_ref, ik_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
+                qs_ref, acc_ref, m_ref, l_ref,
+                *, scale: float, kv_len: int, num_kv_blocks: int, **blocks):
+    t = pl.program_id(1)
+    iq = iq_ref[t]
+    ik = ik_ref[t]
 
     @pl.when(ik == 0)
     def _init():
+        # the scale goes once on the (Bq, D) queries, not on every
+        # (Bkv, Bq) tile of scores
+        qs_ref[:] = (q_ref[0].astype(jnp.float32) * scale).astype(
+            qs_ref.dtype)
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    # Causal: q rows [iq·Bq, iq·Bq+Bq) never see kv cols >= (iq+1)·Bq, so
-    # blocks strictly above the diagonal are skipped entirely.
-    should_run = (ik * block_kv < (iq + 1) * block_q) if causal else True
-
-    @pl.when(should_run)
-    def _compute():
-        q = q_ref[0]                      # (Bq, D)
-        k = k_ref[0]                      # (Bkv, D)
+    def _step(mask):
+        k = k_ref[0]                       # (Bkv, D)
         v = v_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # (Bq, Bkv) f32
-
-        col = ik * block_kv + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = col < kv_len               # padded kv tail
-        if causal:
-            row = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            mask = jnp.logical_and(mask, row >= col)
-        s = jnp.where(mask, s, NEG_INF)
-
-        m_prev = m_ref[:, :1]                               # (Bq, 1)
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)                              # (Bq, Bkv)
-        alpha = jnp.exp(m_prev - m_new)                     # (Bq, 1)
-        l_new = l_ref[:, :1] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        s_t = jax.lax.dot_general(         # (Bkv, Bq) = K·(scale·Q)ᵀ
+            k, qs_ref[:], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+        if mask is not None:
+            s_t = jnp.where(mask(s_t), s_t, NEG_INF)
+        m_prev = m_ref[:]                                   # (1, Bq)
+        m_new = jnp.maximum(m_prev, jnp.max(s_t, axis=0, keepdims=True))
+        p_t = jnp.exp(s_t - m_new)                          # (Bkv, Bq)
+        alpha = jnp.exp(m_prev - m_new)                     # (1, Bq)
+        l_ref[:] = l_ref[:] * alpha + jnp.sum(p_t, axis=0, keepdims=True)
+        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
+            v, p_t.astype(v.dtype), (((0,), (0,)), ((), ())),   # (D, Bq)
+            preferred_element_type=jnp.float32)
+        m_ref[:] = m_new
 
-    @pl.when(ik == num_kv_blocks - 1)
+    _when_masked(_step, iq, ik, kv_len=kv_len, num_kv_blocks=num_kv_blocks,
+                 **blocks)
+
+    @pl.when(_is_last(iq, ik, num_kv_blocks=num_kv_blocks, **blocks))
     def _finalize():
-        l = l_ref[:, :1]
+        l = l_ref[:]
         # Fully-masked rows (padding) would divide by zero; keep them finite.
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
-        lse = jnp.where(l == 0.0, NEG_INF, m_ref[:, :1] + jnp.log(l_safe))
-        lse_ref[0] = jnp.broadcast_to(lse, lse_ref.shape[1:])
+        o_ref[0] = (acc_ref[:] / l_safe).T.astype(o_ref.dtype)
+        lse_ref[0] = jnp.where(l == 0.0, NEG_INF, m_ref[:] + jnp.log(l_safe))
 
 
 def flash_attention_fwd_pallas(q, k, v, *, causal: bool, scale: float,
-                               block_q: int = 512, block_kv: int = 512,
+                               block_q: int | None = None,
+                               block_kv: int | None = None,
                                interpret: bool = False):
     """q: (B, Hq, Sq, D); k, v: (B, Hkv, Skv, D).
 
     Returns ``(out, lse)``: out (B, Hq, Sq, D) in q.dtype, lse (B, Hq, Sq)
     f32 where ``lse[i] = log(sum_j exp(scale·q_i·k_j))`` over unmasked j.
+    The blocks are chosen from the shapes (``_blocks``) unless given.
     """
     b, hq, sq, d = q.shape
     _, hkv, skv, _ = k.shape
@@ -100,97 +172,96 @@ def flash_attention_fwd_pallas(q, k, v, *, causal: bool, scale: float,
         raise ValueError(f"q heads {hq} not a multiple of kv heads {hkv}")
     group = hq // hkv
 
-    block_q = max(16, min(block_q, sq))
-    block_kv = max(16, min(block_kv, skv))
-    sq_p = math.ceil(sq / block_q) * block_q
-    skv_p = math.ceil(skv / block_kv) * block_kv
+    block_q, sq_p = _blocks(block_q, sq, d * q.dtype.itemsize)
+    block_kv, skv_p = _blocks(block_kv, skv, d * q.dtype.itemsize)
     if sq_p != sq:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, sq_p - sq), (0, 0)))
     if skv_p != skv:
         k = jnp.pad(k, ((0, 0), (0, 0), (0, skv_p - skv), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, skv_p - skv), (0, 0)))
-    nq = sq_p // block_q
     nk = skv_p // block_kv
+    blocks = dict(causal=causal, block_q=block_q, block_kv=block_kv)
+    iq_tab, ik_tab = _work_list(sq_p // block_q, nk, **blocks)
 
-    def q_index(bh, iq, ik):
-        return (bh, iq, 0)
+    def q_index(bh, t, iq_ref, ik_ref):
+        return (bh, iq_ref[t], 0)
 
-    def kv_index(bh, iq, ik):
-        return (bh // hq * hkv + (bh % hq) // group, ik, 0)
+    def kv_index(bh, t, iq_ref, ik_ref):
+        return (bh // hq * hkv + (bh % hq) // group, ik_ref[t], 0)
+
+    def lse_index(bh, t, iq_ref, ik_ref):
+        return (bh, 0, iq_ref[t])
 
     kernel = functools.partial(
-        _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
-        block_kv=block_kv, kv_len=skv, num_kv_blocks=nk)
+        _fwd_kernel, scale=scale, kv_len=skv, num_kv_blocks=nk, **blocks)
 
     with part("flash_attention_fwd"):
         out, lse = pl.pallas_call(
             kernel,
-            grid=(b * hq, nq, nk),
-            in_specs=[
-                pl.BlockSpec((1, block_q, d), q_index),
-                pl.BlockSpec((1, block_kv, d), kv_index),
-                pl.BlockSpec((1, block_kv, d), kv_index),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, block_q, d), q_index),
-                pl.BlockSpec((1, block_q, _LANES),
-                             lambda bh, iq, ik: (bh, iq, 0)),
-            ],
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(b * hq, iq_tab.shape[0]),
+                in_specs=[
+                    pl.BlockSpec((1, block_q, d), q_index),
+                    pl.BlockSpec((1, block_kv, d), kv_index),
+                    pl.BlockSpec((1, block_kv, d), kv_index),
+                ],
+                out_specs=[
+                    pl.BlockSpec((1, block_q, d), q_index),
+                    pl.BlockSpec((1, 1, block_q), lse_index),
+                ],
+                scratch_shapes=[
+                    pltpu.VMEM((block_q, d), q.dtype),
+                    pltpu.VMEM((d, block_q), jnp.float32),
+                    pltpu.VMEM((1, block_q), jnp.float32),
+                    pltpu.VMEM((1, block_q), jnp.float32),
+                ]),
             out_shape=[
                 jax.ShapeDtypeStruct((b * hq, sq_p, d), q.dtype),
-                jax.ShapeDtypeStruct((b * hq, sq_p, _LANES), jnp.float32),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((block_q, d), jnp.float32),
-                pltpu.VMEM((block_q, _LANES), jnp.float32),
-                pltpu.VMEM((block_q, _LANES), jnp.float32),
+                jax.ShapeDtypeStruct((b * hq, 1, sq_p), jnp.float32),
             ],
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
+                dimension_semantics=("parallel", "arbitrary")),
             interpret=interpret,
             name="flash_attention_fwd",
-        )(q.reshape(b * hq, sq_p, d),
+        )(iq_tab, ik_tab,
+          q.reshape(b * hq, sq_p, d),
           k.reshape(b * hkv, skv_p, d),
           v.reshape(b * hkv, skv_p, d))
 
     out = out.reshape(b, hq, sq_p, d)[:, :, :sq]
-    lse = lse[:, :, 0].reshape(b, hq, sq_p)[:, :, :sq]
+    lse = lse.reshape(b, hq, sq_p)[:, :, :sq]
     return out, lse
 
 
 # ---------------------------------------------------------------------------
 # Backward kernels.
 #
-# Both kernels keep the score matrix *transposed* relative to the forward:
-# s_t = K·Qᵀ of shape (block_kv, block_q). With q as the lane (minor)
-# dimension, the per-q-row vectors lse and delta — stored as (1, block_q)
-# tiles — broadcast against s_t without any in-kernel transpose; every
-# contraction is a plain MXU dot_general.
-#
 # Standard recompute formulation (P recomputed from q, k, lse):
 #   P   = exp(S·scale − lse)
 #   dV  = Pᵀ·dO
 #   dS  = P ∘ (dO·Vᵀ − Δ)   with Δ = Σ_d dO·O − dlse (precomputed, f32)
 #   dQ  = scale·dS·K          dK = scale·dSᵀ·Q
+# lse and delta come as (1, block_q) tiles and broadcast against s_t without
+# any in-kernel transpose; every contraction is a plain MXU dot_general.
+# Only key positions are masked: a padded query row is zeros in q and dO
+# with lse = delta = 0, so its P is 1, its dS 0, and it adds nothing.
 # ---------------------------------------------------------------------------
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   dq_ref, dq_acc_ref,
-                   *, scale: float, causal: bool, block_q: int,
-                   block_kv: int, q_len: int, kv_len: int,
-                   num_kv_blocks: int):
-    iq = pl.program_id(1)
-    ik = pl.program_id(2)
+def _bwd_dq_kernel(iq_ref, ik_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                   delta_ref, dq_ref, dq_acc_ref,
+                   *, scale: float, kv_len: int, num_kv_blocks: int,
+                   **blocks):
+    t = pl.program_id(1)
+    iq = iq_ref[t]
+    ik = ik_ref[t]
 
     @pl.when(ik == 0)
     def _init():
         dq_acc_ref[:] = jnp.zeros_like(dq_acc_ref)
 
-    should_run = (ik * block_kv < (iq + 1) * block_q) if causal else True
-
-    @pl.when(should_run)
-    def _compute():
+    def _step(mask):
         q = q_ref[0]                       # (Bq, D)
         k = k_ref[0]                       # (Bkv, D)
         v = v_ref[0]
@@ -201,12 +272,9 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         s_t = jax.lax.dot_general(         # (Bkv, Bq) = K·Qᵀ
             k, q, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
-        qpos = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, s_t.shape, 1)
-        kpos = ik * block_kv + jax.lax.broadcasted_iota(jnp.int32, s_t.shape, 0)
-        mask = jnp.logical_and(qpos < q_len, kpos < kv_len)
-        if causal:
-            mask = jnp.logical_and(mask, qpos >= kpos)
-        p_t = jnp.where(mask, jnp.exp(s_t - lse), 0.0)        # (Bkv, Bq)
+        p_t = jnp.exp(s_t - lse)                              # (Bkv, Bq)
+        if mask is not None:
+            p_t = jnp.where(mask(s_t), p_t, 0.0)
         dp_t = jax.lax.dot_general(        # (Bkv, Bq) = V·dOᵀ
             v, do, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -215,16 +283,18 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             ds_t, k.astype(jnp.float32), (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
 
-    @pl.when(ik == num_kv_blocks - 1)
+    _when_masked(_step, iq, ik, kv_len=kv_len, num_kv_blocks=num_kv_blocks,
+                 **blocks)
+
+    @pl.when(_is_last(iq, ik, num_kv_blocks=num_kv_blocks, **blocks))
     def _finalize():
         dq_ref[0] = dq_acc_ref[:].astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_acc_ref, dv_acc_ref,
-                    *, scale: float, causal: bool, block_q: int,
-                    block_kv: int, q_len: int, kv_len: int,
-                    num_q_blocks: int, num_inner: int):
+                    *, scale: float, kv_len: int, num_kv_blocks: int,
+                    num_q_blocks: int, num_inner: int, **blocks):
     ik = pl.program_id(1)
     e = pl.program_id(2)                   # enumerates (gqa group, q block)
     iq = e % num_q_blocks
@@ -234,11 +304,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc_ref[:] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[:] = jnp.zeros_like(dv_acc_ref)
 
-    # Causal: the q block must reach at least the first kv row of this block.
-    should_run = ((iq + 1) * block_q > ik * block_kv) if causal else True
-
-    @pl.when(should_run)
-    def _compute():
+    def _step(mask):
         q = q_ref[0]                       # (Bq, D)
         k = k_ref[0]                       # (Bkv, D)
         v = v_ref[0]
@@ -249,12 +315,9 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         s_t = jax.lax.dot_general(
             k, q, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale       # (Bkv, Bq)
-        qpos = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, s_t.shape, 1)
-        kpos = ik * block_kv + jax.lax.broadcasted_iota(jnp.int32, s_t.shape, 0)
-        mask = jnp.logical_and(qpos < q_len, kpos < kv_len)
-        if causal:
-            mask = jnp.logical_and(mask, qpos >= kpos)
-        p_t = jnp.where(mask, jnp.exp(s_t - lse), 0.0)
+        p_t = jnp.exp(s_t - lse)
+        if mask is not None:
+            p_t = jnp.where(mask(s_t), p_t, 0.0)
         dv_acc_ref[:] += jax.lax.dot_general(   # (Bkv, D) = P_t·dO
             p_t, do.astype(jnp.float32), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -266,6 +329,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             ds_t, q.astype(jnp.float32), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
 
+    # a q block above the diagonal is a step with nothing to do
+    pl.when(_live(iq, ik, **blocks))(lambda: _when_masked(
+        _step, iq, ik, kv_len=kv_len, num_kv_blocks=num_kv_blocks, **blocks))
+
     @pl.when(e == num_inner - 1)
     def _finalize():
         dk_ref[0] = dk_acc_ref[:].astype(dk_ref.dtype)
@@ -274,7 +341,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def flash_attention_bwd_pallas(q, k, v, lse, delta, dout, *,
                                causal: bool, scale: float,
-                               block_q: int = 512, block_kv: int = 512,
+                               block_q: int | None = None,
+                               block_kv: int | None = None,
                                interpret: bool = False):
     """Backward pass. q/dout: (B, Hq, Sq, D); k, v: (B, Hkv, Skv, D);
     lse, delta: (B, Hq, Sq) f32 with delta = Σ_d dO·O − dlse.
@@ -288,10 +356,8 @@ def flash_attention_bwd_pallas(q, k, v, lse, delta, dout, *,
     _, hkv, skv, _ = k.shape
     group = hq // hkv
 
-    block_q = max(16, min(block_q, sq))
-    block_kv = max(16, min(block_kv, skv))
-    sq_p = math.ceil(sq / block_q) * block_q
-    skv_p = math.ceil(skv / block_kv) * block_kv
+    block_q, sq_p = _blocks(block_q, sq, d * q.dtype.itemsize)
+    block_kv, skv_p = _blocks(block_kv, skv, d * q.dtype.itemsize)
     if sq_p != sq:
         pad = ((0, 0), (0, 0), (0, sq_p - sq), (0, 0))
         q = jnp.pad(q, pad)
@@ -304,6 +370,8 @@ def flash_attention_bwd_pallas(q, k, v, lse, delta, dout, *,
         v = jnp.pad(v, pad)
     nq = sq_p // block_q
     nk = skv_p // block_kv
+    blocks = dict(causal=causal, block_q=block_q, block_kv=block_kv)
+    iq_tab, ik_tab = _work_list(nq, nk, **blocks)
 
     qf = q.reshape(b * hq, sq_p, d)
     doutf = dout.reshape(b * hq, sq_p, d)
@@ -312,57 +380,67 @@ def flash_attention_bwd_pallas(q, k, v, lse, delta, dout, *,
     lsef = lse.reshape(b * hq, 1, sq_p).astype(jnp.float32)
     deltaf = delta.reshape(b * hq, 1, sq_p).astype(jnp.float32)
 
-    def q_ix(bh, iq, ik):
-        return (bh, iq, 0)
+    def q_ix(bh, t, iq_ref, ik_ref):
+        return (bh, iq_ref[t], 0)
 
-    def kv_ix(bh, iq, ik):
-        return (bh // hq * hkv + (bh % hq) // group, ik, 0)
+    def kv_ix(bh, t, iq_ref, ik_ref):
+        return (bh // hq * hkv + (bh % hq) // group, ik_ref[t], 0)
 
-    def vec_ix(bh, iq, ik):
-        return (bh, 0, iq)
+    def vec_ix(bh, t, iq_ref, ik_ref):
+        return (bh, 0, iq_ref[t])
 
     dq_kernel = functools.partial(
-        _bwd_dq_kernel, scale=scale, causal=causal, block_q=block_q,
-        block_kv=block_kv, q_len=sq, kv_len=skv, num_kv_blocks=nk)
+        _bwd_dq_kernel, scale=scale, kv_len=skv, num_kv_blocks=nk, **blocks)
 
     with part("flash_attention_dq"):
         dq = pl.pallas_call(
             dq_kernel,
-            grid=(b * hq, nq, nk),
-            in_specs=[
-                pl.BlockSpec((1, block_q, d), q_ix),
-                pl.BlockSpec((1, block_kv, d), kv_ix),
-                pl.BlockSpec((1, block_kv, d), kv_ix),
-                pl.BlockSpec((1, block_q, d), q_ix),
-                pl.BlockSpec((1, 1, block_q), vec_ix),
-                pl.BlockSpec((1, 1, block_q), vec_ix),
-            ],
-            out_specs=pl.BlockSpec((1, block_q, d), q_ix),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(b * hq, iq_tab.shape[0]),
+                in_specs=[
+                    pl.BlockSpec((1, block_q, d), q_ix),
+                    pl.BlockSpec((1, block_kv, d), kv_ix),
+                    pl.BlockSpec((1, block_kv, d), kv_ix),
+                    pl.BlockSpec((1, block_q, d), q_ix),
+                    pl.BlockSpec((1, 1, block_q), vec_ix),
+                    pl.BlockSpec((1, 1, block_q), vec_ix),
+                ],
+                out_specs=pl.BlockSpec((1, block_q, d), q_ix),
+                scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)]),
             out_shape=jax.ShapeDtypeStruct((b * hq, sq_p, d), q.dtype),
-            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
+                dimension_semantics=("parallel", "arbitrary")),
             interpret=interpret,
             name="flash_attention_dq",
-        )(qf, kf, vf, doutf, lsef, deltaf)
+        )(iq_tab, ik_tab, qf, kf, vf, doutf, lsef, deltaf)
 
     # dkv: grid minor axis sweeps (group, q block) pairs while one kv tile
-    # and its dk/dv accumulators stay resident in VMEM.
+    # and its dk/dv accumulators stay resident in VMEM. A q block above the
+    # diagonal is named as the first one below it, which the sweep reads
+    # next: the step that does nothing fetches nothing of its own.
     num_inner = group * nq
 
+    def q_block(ik, e):
+        iq = e % nq
+        if causal:
+            iq = jnp.maximum(iq, jnp.minimum(ik * block_kv // block_q, nq - 1))
+        return iq
+
     def q_ix2(bh, ik, e):
-        return (bh // hkv * hq + (bh % hkv) * group + e // nq, e % nq, 0)
+        return (bh // hkv * hq + (bh % hkv) * group + e // nq,
+                q_block(ik, e), 0)
 
     def kv_ix2(bh, ik, e):
         return (bh, ik, 0)
 
     def vec_ix2(bh, ik, e):
-        return (bh // hkv * hq + (bh % hkv) * group + e // nq, 0, e % nq)
+        return (bh // hkv * hq + (bh % hkv) * group + e // nq, 0,
+                q_block(ik, e))
 
     dkv_kernel = functools.partial(
-        _bwd_dkv_kernel, scale=scale, causal=causal, block_q=block_q,
-        block_kv=block_kv, q_len=sq, kv_len=skv, num_q_blocks=nq,
-        num_inner=num_inner)
+        _bwd_dkv_kernel, scale=scale, kv_len=skv, num_kv_blocks=nk,
+        num_q_blocks=nq, num_inner=num_inner, **blocks)
 
     with part("flash_attention_dkv"):
         dk, dv = pl.pallas_call(

@@ -99,32 +99,44 @@ def _total_bytes(mem) -> int:
 # ------------------------------------------------------------------ kernels
 B, S, H, KV, D = 4, 2048, CFG.n_heads, CFG.n_kv_heads, CFG.head_dim
 
+# (batch, query heads, kv heads, sequence, head dimension, block): the 1b
+# widths at two given blocks, and the per-device calls of the two train
+# cells, whose blocks are the kernels' own choice
+FLASH_CALLS = {
+    "1b-512": (B, H, KV, S, D, 512),
+    "1b-1024": (B, H, KV, S, D, 1024),
+    "train-1chip": (6, 16, 16, 4096, 128, None),
+    "train-fsdp2tp2": (8, 16, 4, 4096, 128, None),
+}
 
-@pytest.mark.parametrize("block", [512, 1024])
-def test_flash_fwd_compiles_at_1b_widths(one_chip, block):
+
+@pytest.mark.parametrize("call", FLASH_CALLS)
+def test_flash_fwd_compiles(one_chip, call):
     from ray_tpu.ops.pallas.flash_attention import flash_attention_fwd_pallas
 
-    q = _sds((B, H, S, D), jnp.bfloat16, one_chip)
-    kv = _sds((B, KV, S, D), jnp.bfloat16, one_chip)
+    b, hq, hkv, s, d, block = FLASH_CALLS[call]
+    q = _sds((b, hq, s, d), jnp.bfloat16, one_chip)
+    kv = _sds((b, hkv, s, d), jnp.bfloat16, one_chip)
     fn = jax.jit(lambda q, k, v: flash_attention_fwd_pallas(
-        q, k, v, causal=True, scale=D ** -0.5, block_q=block,
+        q, k, v, causal=True, scale=d ** -0.5, block_q=block,
         block_kv=block))
     compiled = fn.lower(q, kv, kv).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("block", [512, 1024])
-def test_flash_bwd_compiles_at_1b_widths(one_chip, block):
+@pytest.mark.parametrize("call", FLASH_CALLS)
+def test_flash_bwd_compiles(one_chip, call):
     from ray_tpu.ops.pallas.flash_attention import flash_attention_bwd_pallas
 
-    q = _sds((B, H, S, D), jnp.bfloat16, one_chip)
-    kv = _sds((B, KV, S, D), jnp.bfloat16, one_chip)
-    vec = _sds((B, H, S), jnp.float32, one_chip)
+    b, hq, hkv, s, d, block = FLASH_CALLS[call]
+    q = _sds((b, hq, s, d), jnp.bfloat16, one_chip)
+    kv = _sds((b, hkv, s, d), jnp.bfloat16, one_chip)
+    vec = _sds((b, hq, s), jnp.float32, one_chip)
     fn = jax.jit(lambda q, k, v, lse, delta, do: flash_attention_bwd_pallas(
-        q, k, v, lse, delta, do, causal=True, scale=D ** -0.5,
+        q, k, v, lse, delta, do, causal=True, scale=d ** -0.5,
         block_q=block, block_kv=block))
     compiled = fn.lower(q, kv, kv, vec, vec, q).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.as_text().count("tpu_custom_call") >= 2
 
 
 def test_decode_attention_compiles_at_1b_widths(one_chip):

@@ -76,51 +76,95 @@ def test_ring_attention_grad():
         np.testing.assert_allclose(a, b, atol=5e-4, rtol=5e-4)
 
 
-def test_pallas_kernel_interpret_mode():
+def _bhsd(key, sq, skv, hq, hkv, d=32):
+    kq, kk, kv = jax.random.split(key, 3)
+    return (jax.random.normal(kq, (1, hq, sq, d)),
+            jax.random.normal(kk, (1, hkv, skv, d)),
+            jax.random.normal(kv, (1, hkv, skv, d)))
+
+
+# (sq, skv, block_q, block_kv): the first is the case the kernel tests began as
+FLASH_SHAPES = {
+    "tail_q_and_kv": (80, 80, 32, 32),
+    "tail_kv_only": (64, 72, 32, 32),
+    "one_block": (32, 32, 32, 32),
+    "one_block_of_lanes": (80, 80, 512, 512),
+    "several_blocks": (128, 128, 32, 32),
+    "wide_q": (128, 128, 64, 32),
+    "wide_kv": (128, 128, 32, 64),
+    "more_keys_than_queries": (32, 96, 32, 32),
+}
+
+
+@pytest.mark.parametrize("shape", FLASH_SHAPES)
+@pytest.mark.parametrize("hkv", [1, 2, 4])
+@pytest.mark.parametrize("causal", [True, False])
+def test_pallas_kernel_interpret_mode(causal, hkv, shape):
     """Validate the TPU kernel logic itself via the pallas interpreter."""
+    from ray_tpu.ops.attention import _fwd_xla
     from ray_tpu.ops.pallas.flash_attention import flash_attention_fwd_pallas
 
-    q, k, v = _qkv(jax.random.PRNGKey(4), b=1, s=80, hq=2, hkv=1, d=32)
-    qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+    sq, skv, block_q, block_kv = FLASH_SHAPES[shape]
+    qt, kt, vt = _bhsd(jax.random.PRNGKey(4), sq, skv, 4, hkv)
     out, lse = flash_attention_fwd_pallas(
-        qt, kt, vt, causal=True, scale=32 ** -0.5, block_q=32, block_kv=32,
-        interpret=True)
-    ref = mha_reference(q, k, v, causal=True).transpose(0, 2, 1, 3)
+        qt, kt, vt, causal=causal, scale=32 ** -0.5, block_q=block_q,
+        block_kv=block_kv, interpret=True)
+    ref = mha_reference(*(x.transpose(0, 2, 1, 3) for x in (qt, kt, vt)),
+                        causal=causal).transpose(0, 2, 1, 3)
     np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
-    assert lse.shape == (1, 2, 80)
-    assert np.all(np.isfinite(lse))
+    assert lse.shape == (1, 4, sq)
+    _, lse_ref = _fwd_xla(qt, kt, vt, causal, 32 ** -0.5)
+    np.testing.assert_allclose(lse, lse_ref, atol=1e-5, rtol=1e-5)
 
 
+def _inner_grid(fn, *args):
+    """Inner grid steps a head of the one pallas_call ``fn`` traces."""
+    eqns = [e for e in jax.make_jaxpr(fn)(*args).eqns
+            if e.primitive.name == "pallas_call"]
+    assert len(eqns) == 1
+    heads, *inner = eqns[0].params["grid_mapping"].grid
+    return int(np.prod(inner))
+
+
+@pytest.mark.parametrize("causal,steps", [(True, 36), (False, 64)])
+def test_pallas_fwd_grid_is_the_live_blocks(causal, steps):
+    """8 x 8 blocks: a causal call takes a grid step for the 36 pairs on or
+    below the diagonal only, a non-causal one for the whole rectangle."""
+    from ray_tpu.ops.pallas.flash_attention import flash_attention_fwd_pallas
+
+    qt, kt, vt = _bhsd(jax.random.PRNGKey(8), 256, 256, 2, 1)
+    assert _inner_grid(lambda q, k, v: flash_attention_fwd_pallas(
+        q, k, v, causal=causal, scale=1.0, block_q=32, block_kv=32,
+        interpret=True), qt, kt, vt) == steps
+
+
+@pytest.mark.parametrize("shape", FLASH_SHAPES)
 @pytest.mark.parametrize("causal", [True, False])
 @pytest.mark.parametrize("hkv", [2, 1])
-def test_pallas_bwd_kernel_interpret_mode(causal, hkv):
+def test_pallas_bwd_kernel_interpret_mode(causal, hkv, shape):
     """Backward kernels (dq + fused-GQA dkv) vs autodiff of the oracle."""
+    from ray_tpu.ops.attention import _fwd_xla
     from ray_tpu.ops.pallas.flash_attention import flash_attention_bwd_pallas
 
-    q, k, v = _qkv(jax.random.PRNGKey(7), b=1, s=80, hq=2, hkv=hkv, d=32)
+    sq, skv, block_q, block_kv = FLASH_SHAPES[shape]
+    qt, kt, vt = _bhsd(jax.random.PRNGKey(7), sq, skv, 2, hkv)
     scale = 32 ** -0.5
 
-    def loss_ref(q, k, v):
+    def loss_ref(qt, kt, vt):
+        q, k, v = (x.transpose(0, 2, 1, 3) for x in (qt, kt, vt))
         return jnp.sum(mha_reference(q, k, v, causal=causal) ** 2)
 
-    dq_ref, dk_ref, dv_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    refs = jax.grad(loss_ref, argnums=(0, 1, 2))(qt, kt, vt)
 
     # Oracle forward in (B,H,S,D) layout for out/lse/dout residuals.
-    qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
-    from ray_tpu.ops.attention import _fwd_xla
-
     out, lse = _fwd_xla(qt, kt, vt, causal, scale)
     dout = 2.0 * out  # d/dx of sum(out²)
     delta = jnp.sum(dout * out, axis=-1)
-    dq, dk, dv = flash_attention_bwd_pallas(
+    grads = flash_attention_bwd_pallas(
         qt, kt, vt, lse, delta, dout, causal=causal, scale=scale,
-        block_q=32, block_kv=32, interpret=True)
-    np.testing.assert_allclose(dq.transpose(0, 2, 1, 3), dq_ref,
-                               atol=3e-4, rtol=3e-4)
-    np.testing.assert_allclose(dk.transpose(0, 2, 1, 3), dk_ref,
-                               atol=3e-4, rtol=3e-4)
-    np.testing.assert_allclose(dv.transpose(0, 2, 1, 3), dv_ref,
-                               atol=3e-4, rtol=3e-4)
+        block_q=block_q, block_kv=block_kv, interpret=True)
+    for got, ref in zip(grads, refs):
+        np.testing.assert_allclose(got, ref, atol=3e-4, rtol=3e-4)
 
 
 def test_rmsnorm_layernorm():
