@@ -212,13 +212,26 @@ def make_manager(cfg: NemotronHConfig, page: PagedConfig,
 @part("ssm_proj")
 def _in_proj(x, layer, cfg):
     """x (T, h) -> z (T, d_in), raw xBC (T, d_c) in cfg.dtype, dt_raw
-    (T, H) float32."""
+    (T, H) float32: ``u W_in`` as THREE products, one against each run
+    of the stored matrix's columns. Made as one product and split, the
+    parts are read at four places of a decode layer on both sides of the
+    state update's kernel, and the chip's compiler, rather than keep the
+    (192, 18,560) result alive, computed the whole product again for
+    each of them: 18 reads of a 152 MB ``w_in`` a step where 5 are
+    needed (my chip run, PR 40). With a product a part, each fuses into
+    its own consumer and reads its own columns only; do not fuse them
+    back."""
     u = rmsnorm(x, layer["norm"], cfg.norm_eps)
-    zxbcdt = jnp.dot(u, layer["w_in"].astype(u.dtype),
-                     preferred_element_type=F32)
-    z, xbc, dt = jnp.split(
-        zxbcdt, [cfg.d_inner, cfg.d_inner + cfg.conv_dim], axis=-1)
-    return z.astype(x.dtype), xbc.astype(x.dtype), dt
+    w = layer["w_in"]
+    z_end, xbc_end = cfg.d_inner, cfg.d_inner + cfg.conv_dim
+
+    def proj(lo, hi):
+        return jnp.dot(u, w[:, lo:hi].astype(u.dtype),
+                       preferred_element_type=F32)
+
+    return (proj(0, z_end).astype(x.dtype),
+            proj(z_end, xbc_end).astype(x.dtype),
+            proj(xbc_end, w.shape[1]))
 
 
 @part("ssm_conv")

@@ -9,6 +9,8 @@ on-chip-measurement guide, section 2).
 """
 
 import dataclasses
+import json
+import os
 import re
 
 import jax
@@ -415,6 +417,95 @@ def test_1b_serving_program_holds_no_second_pool(one_chip, as_tpu, lower,
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < pool_bytes // 2
     assert mem.alias_size_in_bytes >= pool_bytes     # donated, and reused
+
+
+# ------------------------------ a weight is read once by the program made
+# what the compiler itself puts between a weight and the product that
+# reads it: a prefetch into fast memory, whole or in slices, put together
+# again by a bitcast
+_PREFETCH = {"copy-start", "copy-done", "slice-start", "slice-done",
+             "bitcast", "custom-call:ConcatBitcast"}
+_CALLED = re.compile(r"\w+=(%[\w.\-]+|\{[^}]*\})")
+
+
+def _entry_users(text: str) -> dict:
+    """{operand: [(instruction, opcode, its HLO line)]} of a compiled
+    module's entry computation; the computations a line calls are not
+    among its operands."""
+    from benchmark.trace_reduce import parse_op
+
+    users = {}
+    for line in text[text.index("\nENTRY "):].splitlines()[2:]:
+        name, opcode, _ = parse_op(line.strip().removeprefix("ROOT "))
+        rhs = _CALLED.sub("", line.partition(" = ")[2])
+        for operand in re.findall(r"%([\w.\-]+)", rhs):
+            users.setdefault(operand, []).append((name, opcode, line))
+    return users
+
+
+def _readers(users: dict, name: str) -> list:
+    """The instructions that read ``name``, the compiler's prefetches of
+    it followed to what they feed."""
+    out = []
+    for user, opcode, line in users.get(name, []):
+        out += (_readers(users, user) if opcode in _PREFETCH
+                else [(user, opcode, line)])
+    return out
+
+
+def _columns_made(text: str, line: str) -> int:
+    """Output columns of the products (``convolution``) inside the
+    computation a fusion calls."""
+    called = re.search(r"calls=(%[\w.\-]+)", line).group(1)
+    body = text[text.index(f"\n{called} ("):]
+    body = body[:body.index("\n}")]
+    return sum(int(w) for w in re.findall(
+        r"= \w+\[[\d,]*?(\d+)\]\S* convolution\(", body))
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill64"])
+def test_state_space_cells_program_reads_each_weight_once(
+        topo, one_chip, as_tpu, program):
+    """`serve-ssm-latent-moe-chat`'s decode step (192 slots) and its
+    smallest prefill bucket at the published widths. The in-projection
+    made as ONE product and split was computed again by the chip's
+    compiler for each of its four consumers in a decode layer
+    (``fusion.465``, ``.465.remat``, ``.remat2``, ``.remat3``: 18 reads
+    of a 152 MB ``w_in`` a step where 5 are needed; PERF.md section 6,
+    PR 40). Held here: every column of every ``w_in`` is made once, by
+    fusions that take the stored matrix itself (no copy or slice of it
+    beside them, none a rematerialisation), and each other large matrix
+    of the step has one reader."""
+    from benchmark import model_spec, sizing
+
+    spec = model_spec.load_config("nemotron3-super-ep4-l11")
+    with open(os.path.join(model_spec.HERE, "cells",
+                           "serve-ssm-latent-moe-chat.json")) as f:
+        deployment = json.load(f)["deployment"]
+    assert deployment["num_slots"] == 192
+    decode, bucket = sizing.serve_programs(spec, deployment, topo.devices[0])
+    text = (decode if program == "decode" else bucket(64)).compile().as_text()
+    users = _entry_users(text)
+    weights = {}
+    for name in users:
+        m = re.match(r"params__(?:layers___\d+___)?([a-z_0-9]+?)__\.\d+$",
+                     name)
+        if m:
+            weights.setdefault(m.group(1), []).append(name)
+    assert len(weights["w_in"]) == 5
+    for w_in in weights["w_in"]:
+        direct = users[w_in]
+        assert {opcode for _, opcode, _ in direct} == {"fusion"}, direct
+        assert not [n for n, _, _ in direct if "remat" in n], direct
+        assert sum(_columns_made(text, line)
+                   for _, _, line in direct) == 18560, direct
+    for kind, count in [("w_out", 5), ("w_fc1", 5), ("w_fc2", 5),
+                        ("ws_up", 5), ("ws_down", 5), ("lm_head", 1)]:
+        assert len(weights[kind]) == count
+        for weight in weights[kind]:
+            readers = {n for n, _, _ in _readers(users, weight)}
+            assert len(readers) == 1, (weight, readers)
+            assert not [n for n in readers if "remat" in n], readers
 
 
 # ------------------------------------------- the engine's pick of a token

@@ -26,6 +26,7 @@ if ROOT not in sys.path:
 from benchmark import model_spec  # noqa: E402
 from ray_tpu.models import moe, nemotron_h  # noqa: E402
 from ray_tpu.models.paged_cache import PagedConfig  # noqa: E402
+from ray_tpu.ops.norms import rmsnorm  # noqa: E402
 from ray_tpu.ops.pallas import ssm_decode_update as ssm_kernel  # noqa: E402
 
 SPEC = dict(
@@ -115,6 +116,40 @@ def test_the_chunked_recurrence_is_the_step_by_step_one(true_len, T):
     # the cache's layout: (G, N, heads a group x P)
     S = S.reshape(G, N, H // G, P).transpose(0, 2, 3, 1).reshape(H, P, N)
     assert REF.rel_err(S, want_S) < 2e-5
+
+
+# ------------------------------------------------------- the in-projection
+@pytest.mark.parametrize("cfg", [
+    nemotron_h.NemotronHConfig(),
+    nemotron_h.NemotronHConfig(hidden=40, ssm_heads=6, ssm_head_dim=12,
+                               ssm_groups=3, ssm_state=5)],
+    ids=["tiny-defaults", "widths-no-multiples-of-each-other"])
+def test_the_in_projection_is_the_single_product_split(cfg):
+    """``_in_proj``'s three products against column runs of the stored
+    matrix are ``u W_in`` split at ``[d_inner, d_inner + conv_dim]`` (64
+    | 128 | 8 of 200 columns, then 72 | 102 | 6 of 180), column for
+    column: a column's sum does not know which product it was made in.
+    ``z`` and ``xBC`` come in the model's dtype, ``dt_raw`` in float32."""
+    width = cfg.d_inner + cfg.conv_dim + cfg.ssm_heads
+    assert nemotron_h.param_shapes(cfg)["layers"][0]["w_in"] == (
+        cfg.hidden, width)
+    kx, kn, kw = jax.random.split(jax.random.key(5), 3)
+    x = jax.random.normal(kx, (7, cfg.hidden), cfg.dtype)
+    layer = {"norm": 0.1 * jax.random.normal(kn, (cfg.hidden,), cfg.dtype),
+             "w_in": jax.random.normal(kw, (cfg.hidden, width), cfg.dtype)
+             * cfg.hidden ** -0.5}
+    u = rmsnorm(x, layer["norm"], cfg.norm_eps)
+    want = jnp.split(jnp.dot(u, layer["w_in"], preferred_element_type=F32),
+                     [cfg.d_inner, cfg.d_inner + cfg.conv_dim], axis=-1)
+    # a sum in another order is an ulp of float32, a cast's tie one of
+    # bfloat16 (2 ** -8); a split one column off is of order one
+    for got, part, dtype, rtol in zip(
+            nemotron_h._in_proj(x, layer, cfg), want,
+            (cfg.dtype, cfg.dtype, F32), (2 ** -7, 2 ** -7, 1e-5)):
+        assert (got.dtype, got.shape) == (dtype, part.shape)
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32),
+            np.asarray(part.astype(dtype), np.float32), rtol=rtol, atol=1e-5)
 
 
 # ------------------------------------------- the program and the reference
