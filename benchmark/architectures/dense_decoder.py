@@ -301,7 +301,10 @@ def train_setup(spec: dict, job: dict, mesh):
     cfg = program_config(spec)
     rules = FSDP_TP_RULES
     axes = llama.param_logical_axes(cfg)
-    opt = OptimizerConfig(warmup_steps=1).make()
+    # the schedule is the traffic mix's (``"optimizer"``: keywords of the
+    # program's ``OptimizerConfig``); a caller that only lowers the step
+    # from shapes gives none, and the step is the same program
+    opt = OptimizerConfig(**job.get("optimizer", {"warmup_steps": 1})).make()
     init = weights.init_fn(spec)
 
     def build(key):

@@ -358,10 +358,12 @@ def _programs(params, spec: dict, deployment: dict):
 
 
 def _engine_serving(params):
-    """The engine of this process that serves ``params``, if one does.
-    ``worker_serve.check`` hands an adapter the engine's weights and not
-    the engine, so it is looked up among the live objects (a limit of
-    the harness's interface: PERF.md section 7)."""
+    """The engine of this process that serves ``params``, if one does,
+    for a caller that hands ``serve_program_logits`` no engine. No run
+    of the benchmark comes here since PR 41 (``worker_serve.check``
+    hands the replica's engine over); it stays for
+    ``tests/test_nemotron_h.py``, a tier-1 file that calls it by name
+    and that a ``benchmark`` PR may not edit (PERF.md section 7)."""
     import gc
 
     from ray_tpu.serve.llm import LLMEngine
@@ -380,7 +382,7 @@ def _neighbours(slots: int) -> list:
 
 
 def serve_program_logits(params, spec: dict, tokens, deployment: dict, *,
-                         prefill: int):
+                         prefill: int, engine=None):
     """Prefill of the first ``prefill`` tokens, then one teacher-forced
     decode step for each token after them, through the KV pool AND the
     recurrent state AT THE CELL'S SLOTS: the sequence sits in the LAST
@@ -388,10 +390,11 @@ def serve_program_logits(params, spec: dict, tokens, deployment: dict, *,
     prompts of other tokens and run in every step beside it. ->
     (1 + steps, vocab) float32.
 
-    Where an engine serves these weights in this process (a run of the
-    cell), the programs, the manager and the cache are THE ENGINE'S OWN,
-    borrowed while it is idle: the compiled decode step and prefills the
-    window times, on the buffers it times them on. The engine's
+    Where the caller hands over the ``engine`` that serves these weights
+    (a run of the cell: ``worker_serve.check``), the programs, the
+    manager and the cache are THE ENGINE'S OWN, borrowed while it is
+    idle: the compiled decode step and prefills the window times, on the
+    buffers it times them on. The engine's
     recurrent state (21 MB a slot, 4.1 GB) is most of what the chip has
     left beside the weights and a second one of its size does not fit,
     so a scratch copy at these slots cannot stand beside it. The slots
@@ -405,7 +408,10 @@ def serve_program_logits(params, spec: dict, tokens, deployment: dict, *,
     from ray_tpu.models.paged_cache import pad_to_block_bucket
 
     bs, slots = deployment["kv_block_size"], deployment["num_slots"]
-    engine = _engine_serving(params)
+    if engine is None:
+        engine = _engine_serving(params)
+    elif engine.params is not params:
+        raise RuntimeError("the engine handed over serves other weights")
     if engine is None:
         cfg, page, prefill_fn, decode = _programs(params, spec, deployment)
         alloc = nemotron_h.make_manager(cfg, page, slots)

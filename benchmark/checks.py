@@ -11,6 +11,8 @@ the two readings each was set from.
 
 from __future__ import annotations
 
+import inspect
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -34,14 +36,22 @@ def serve_reference_logits(params, spec: dict, tokens, *, quant=None):
         params, jnp.asarray(tokens)[:total], spec, rows, quant=quant))
 
 
-def serve_check(params, spec: dict, seed: int, deployment: dict) -> dict:
+def serve_check(params, spec: dict, seed: int, deployment: dict,
+                engine=None) -> dict:
     """Logits of a SERVE_PREFILL-token prefill and SERVE_DECODE
     teacher-forced decode steps, by the adapter's
-    ``serve_program_logits`` at the cell's deployment."""
+    ``serve_program_logits`` at the cell's deployment. ``engine``: the
+    replica's idle engine, handed to an adapter whose
+    ``serve_program_logits`` takes ``engine=`` and then compares the
+    engine's own programs on its own cache; the others build a scratch
+    copy beside it."""
     ref = model_spec.reference(spec)
     tokens = sample_tokens(spec, seed, SERVE_PREFILL + SERVE_DECODE)
-    got = model_spec.adapter(spec).serve_program_logits(
-        params, spec, tokens, deployment, prefill=SERVE_PREFILL)
+    program_logits = model_spec.adapter(spec).serve_program_logits
+    takes_engine = "engine" in inspect.signature(program_logits).parameters
+    got = program_logits(params, spec, tokens, deployment,
+                         prefill=SERVE_PREFILL,
+                         **({"engine": engine} if takes_engine else {}))
     want = serve_reference_logits(params, spec, tokens)
     lim = model_spec.limits(spec)
     return {

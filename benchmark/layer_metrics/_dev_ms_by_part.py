@@ -2,10 +2,15 @@
 each operation belongs to.
 
 The program opens ``ray_tpu.util.profiling.part(name)`` (one vocabulary,
-``PARTS`` there; ``PARTS`` here is a copy, held equal by
-``benchmark/tests/test_dev_ms_by_part.py``, because a reader imports
-nothing of the program) around the block's arithmetic, so the name is a
-component of every instruction's ``op_name``:
+the tuples there whose names end in ``PARTS``) around the block's
+arithmetic. ``PARTS`` here is a copy, because a reader imports nothing of
+the program, and it is DATA: the union of the lists in
+``layer_metrics/parts/*.json`` (``base.json`` the block's own,
+``state_space.json`` what a state-space model adds), so a PR that adds a
+model adds ``parts/<architecture>.json`` beside the names it appends in
+``profiling.py`` and edits nothing here;
+``benchmark/tests/test_dev_ms_by_part.py`` holds the two unions equal. The
+name is a component of every instruction's ``op_name``:
 ``jit(step)/while/body/closed_call/mlp/dot_general``. In a v5e capture
 that path is the ``tf_op`` stat (the path and a trailing ``:``) of the
 operation's EVENT METADATA on the ``XLA Ops`` line. jaxlib's
@@ -41,6 +46,7 @@ were there). The whole table of every program it saw is printed once a
 run, ``[dev_ms_by_part] ...``, with the longest unnamed operations."""
 
 import bisect
+import json
 import os
 import re
 import time
@@ -50,16 +56,21 @@ from collective_exposed_pct import COLLECTIVE as COLLECTIVE_OP
 from benchmark.trace_reduce import (CONTAINERS, DEVICE_PLANE, MODULES_LINE,
                                     OPS_LINE, find_xplane, parse_op)
 
-PARTS = (
-    "embed", "attn_proj", "kv_store", "attention", "mlp", "router",
-    "expert_dispatch", "expert_combine", "head", "loss", "optimizer",
-    "expert_layer", "shared_expert", "attn_gate", "mla_expand",
-    "mla_absorb",
-    "paged_decode_attention", "paged_hybrid_decode_full",
-    "paged_hybrid_decode_window", "paged_mla_decode",
-    "grouped_expert_matmul", "grouped_expert_matmul_prefill",
-    "flash_attention_fwd", "flash_attention_dq", "flash_attention_dkv",
-)
+PARTS_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)), "parts")
+
+
+def load_parts(folder=PARTS_DIR):
+    """The vocabulary: every name of every ``<folder>/*.json``'s
+    ``"parts"`` list, files in name order, a name once."""
+    names = []
+    for entry in sorted(os.listdir(folder)):
+        if entry.endswith(".json"):
+            with open(os.path.join(folder, entry)) as f:
+                names += [n for n in json.load(f)["parts"] if n not in names]
+    return tuple(names)
+
+
+PARTS = load_parts()
 UNNAMED, COLLECTIVE = "unnamed", "collective"
 PATH_STAT = "tf_op"     # the event metadata's stat that holds ``op_name``
 PHASES = ("forward", "recompute", "backward")

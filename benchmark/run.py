@@ -295,16 +295,36 @@ def train_cell(args, cell, spec, mix, cellfile, out_dir) -> dict:
     return m
 
 
+LOSS_ENDS = 3   # steps at each end of a job whose mean loss is compared
+
+
+def loss_fall(losses: list) -> float:
+    """How far the job's loss fell: the mean of its first ``LOSS_ENDS``
+    steps less the mean of its last. One step's batch moves a loss by a
+    few hundredths, which a single first loss against a single last one
+    (how this was read until PR 41) cannot tell from an optimizer that
+    does nothing; the means over three steps can."""
+    k = min(LOSS_ENDS, len(losses) // 2)
+    if not k:
+        return float("nan")
+    return sum(losses[:k]) / k - sum(losses[-k:]) / k
+
+
 def train_results(args, cell, spec, mix, m) -> dict:
     losses = m["losses"]
     say(f"steps {m['steps']} window {m['window_s']:.2f}s times {m['times']} "
-        f"losses first {losses[:3]} last {losses[-2:]}")
+        f"losses {json.dumps(losses)}")
     numbers = dict(m["check"])
     numbers["losses_not_finite"] = {
         "value": sum(1 for x in losses if not (x == x and abs(x) < 1e4)),
         "limit": 0}
+    fall = loss_fall(losses)
+    numbers["loss_fall"] = {"value": fall, "limit": None}
+    # the margin of the configuration's own limits file where it has one
+    least = (model_spec.limits(spec).get("loss_fall_min")
+             or load_json(HERE, "limits.json")["limits"]["loss_fall_min"])
     numbers["loss_did_not_fall"] = {
-        "value": 0 if losses[-1] < losses[0] else 1, "limit": 0}
+        "value": 0 if fall > least["limit"] else 1, "limit": 0}
     if cell["chips"] > 1:
         numbers["parameters_not_spanning_all_chips"] = {
             "value": 0 if m["param_device_span"] == cell["chips"] else 1,
@@ -427,6 +447,16 @@ def main() -> int:
     line["metrics"] = {k: {"value": v, "unit": units[k]}
                        for k, v in metrics.items()}
     line["device"] = out_device
+    # every number that was compared beside its limit: last in the line,
+    # and as the last lines of standard error (what a record of a run
+    # that is not correct keeps)
+    line["compared"] = {
+        name: dict(n, value=n["value"] if abs(n["value"]) < float("inf")
+                   else repr(n["value"]))       # a NaN is no JSON number
+        for name, n in res["numbers"].items() if n["limit"] is not None}
+    for name, n in line["compared"].items():
+        print(f"compared {name}: {n['value']!r} limit {n['limit']!r}",
+              file=sys.stderr, flush=True)
     print(json.dumps(line), flush=True)
     return 0
 

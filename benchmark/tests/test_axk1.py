@@ -242,9 +242,9 @@ TINY = dict(
     router_width=32, experts_first=8, max_position_embeddings=1024,
     rope_scaling=dict(SPEC["rope_scaling"], factor=4,
                       original_max_position_embeddings=64), reduced=[])
-MLA_METRICS = ("expert_pairs_per_step.mla", "expert_load_max_over_mean.mla",
+MLA_METRICS = ("expert_pairs_per_step", "expert_load_max_over_mean",
                "expert_pairs_dropped", "latent_pool_live_pct",
-               "engine_step_ms.mla", "slot_occupancy_pct.mla")
+               "engine_step_ms", "slot_occupancy_pct")
 
 
 def test_a_tiny_configuration_of_the_block_runs_through_the_harness(
@@ -301,7 +301,7 @@ def test_a_tiny_configuration_of_the_block_runs_through_the_harness(
     assert set(MLA_METRICS) <= set(got), sorted(got)
     assert got["expert_pairs_dropped"]["value"] == 0
     # 3 slots x 8 choices x 8 of 32 experts held: 6 pairs a full step
-    assert 0 < got["expert_pairs_per_step.mla"]["value"] <= 24
+    assert 0 < got["expert_pairs_per_step"]["value"] <= 24
     assert 0 < got["latent_pool_live_pct"]["value"] <= 100
     assert "paged_mla_decode_roofline" not in got          # no kernel here
     assert "read router_choices_flipped_by_bf16_activations" in proc.stdout

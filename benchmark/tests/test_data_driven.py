@@ -1,6 +1,7 @@
 """A configuration, a traffic mix, a cell, a per-layer metric and an
-ARCHITECTURE (adapter, reference, limits, a deployment key of its own)
-are each added as NEW files and entries: in a temp copy of the
+ARCHITECTURE (adapter, reference, limits, a deployment key of its own, a
+part of its block with a name of its own) are each added as NEW files and
+entries: in a temp copy of the
 benchmark, nothing that was there is edited, and ``run.py`` finds all of
 them by name. The run is a rehearsal on the CPU at a tiny size (pretend
 chip, the line names the cpu). A configuration whose adapter, reference
@@ -86,13 +87,16 @@ def _dense(tmp_path):
     """A configuration of the block that is there."""
     _put(tmp_path, "configs/new-tiny.json", TINY)
     _put(tmp_path, "cells/new-cell.json", {"deployment": DEPLOYMENT})
-    return {"configs/new-tiny.json", "cells/new-cell.json"}, "limit 0.06 ok"
+    return ({"configs/new-tiny.json", "cells/new-cell.json"},
+            "limit 0.06 ok", [])
 
 
 def _other_block(tmp_path):
     """A new ARCHITECTURE: its adapter (a copy of the dense one under
     another name: the test is of the lookup, not of a block) with a
-    deployment key of its own, its reference, its limits."""
+    deployment key of its own, its reference, its limits, and a part of
+    its block that no model before it had (``other_mix``): a file under
+    ``layer_metrics/parts/`` and an alias of the by-part reader."""
     with open(os.path.join(BENCH, "architectures", "dense_decoder.py")) as f:
         _put(tmp_path, "architectures/other_block.py", f.read() + OTHER_ENGINE)
     with open(os.path.join(BENCH, "reference", "dense_decoder.py")) as f:
@@ -109,16 +113,67 @@ def _other_block(tmp_path):
     deployment = dict(DEPLOYMENT, pool_blocks=16)
     del deployment["kv_pool_tokens"]
     _put(tmp_path, "cells/new-cell.json", {"deployment": deployment})
-    return {"configs/new-tiny.json", "cells/new-cell.json",
-            "architectures/other_block.py", "reference/other_block.py",
-            "limits/new-tiny.json"}, "limit 0.03 ok"
+    _put(tmp_path, "layer_metrics/parts/other_block.json",
+         {"why": "test", "parts": ["other_mix"]})
+    _put(tmp_path, "layer_metrics/decode_other_mix_dev_ms.json",
+         {"reader": "_dev_ms_by_part",
+          "args": {"program": "^jit_step", "parts": ["other_mix"]}})
+    return ({"configs/new-tiny.json", "cells/new-cell.json",
+             "architectures/other_block.py", "reference/other_block.py",
+             "limits/new-tiny.json", "layer_metrics/parts/other_block.json",
+             "layer_metrics/decode_other_mix_dev_ms.json"},
+            "limit 0.03 ok", ["decode_other_mix_dev_ms"])
+
+
+# one run of the decode step whose one operation lies under the new part
+OTHER_MIX_CAPTURE = '''
+planes {
+  name: "/device:TPU:0"
+  lines { name: "XLA Modules" timestamp_ns: 1000
+          events { metadata_id: 1 offset_ps: 0 duration_ps: 100000000 } }
+  lines { name: "XLA Ops" timestamp_ns: 1000
+          events { metadata_id: 2 offset_ps: 0 duration_ps: 60000000 } }
+  event_metadata { key: 1 value { id: 1 name: "jit_step(11)" } }
+  event_metadata { key: 2 value {
+    id: 2 name: "%fusion.1 = f32[8]{0} fusion(f32[8]{0} %x), kind=kLoop"
+    stats { metadata_id: 9 str_value: "jit(step)/other_mix/dot_general:" } } }
+  stat_metadata { key: 9 value { id: 9 name: "tf_op" } }
+}
+'''
+READ_THE_NEW_PART = '''
+import sys
+sys.path.insert(0, ".")
+from benchmark import run
+run_ = {"trace": {"xplane": sys.argv[1]}, "cell": {"name": "none"}}
+print("READ", run.load_reader("decode_other_mix_dev_ms")(run_),
+      run.load_reader("decode_dense_mlp_dev_ms")(run_))
+'''
+
+
+def _reads_the_new_part(tmp_path):
+    """On the CPU a capture has no device plane and the alias reads
+    nothing; on a hand-made capture of a chip the copy's reader files
+    the operation under the name its new file brought."""
+    from jaxlib._profile_data import ProfileData
+
+    capture = tmp_path / "other_mix.xplane.pb"
+    capture.write_bytes(ProfileData.text_proto_to_serialized_xspace(
+        OTHER_MIX_CAPTURE))
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("PYTHONPATH", None)
+    proc = subprocess.run(
+        [sys.executable, "-c", READ_THE_NEW_PART, str(capture)],
+        cwd=tmp_path, env=env, capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    said = [ln for ln in proc.stdout.splitlines() if ln.startswith("READ")]
+    assert said == ["READ 0.06 None"], proc.stdout[-2000:]
 
 
 @pytest.mark.parametrize("block", [_dense, _other_block])
 def test_a_new_cell_is_files_and_entries_only(tmp_path, block):
     bench = _copy(tmp_path)
     before = _hashes(tmp_path / "benchmark")
-    added, compared = block(tmp_path)
+    added, compared, own_metrics = block(tmp_path)
     _put(tmp_path, "traffic/new-mix.json", {
         "kind": "closed_loop_handle", "clients": 6, "block": 16,
         "prompt_len": {"dist": "uniform", "min": 40, "max": 100},
@@ -140,7 +195,7 @@ def test_a_new_cell_is_files_and_entries_only(tmp_path, block):
     for m in bench["end_to_end"]:
         if m["name"] == "output_tokens_per_s":
             m["workloads"].append("new-cell")
-    for name in ("new_metric", "new_alias"):
+    for name in ["new_metric", "new_alias"] + own_metrics:
         bench["per_layer"].append({
             "name": name, "unit": "count", "better": "higher",
             "source": "program_counter", "layer": "engine loop",
@@ -165,6 +220,9 @@ def test_a_new_cell_is_files_and_entries_only(tmp_path, block):
     assert traced["metrics"]["new_alias"]["unit"] == "count"
     assert "compiles_in_window" in traced["metrics"]    # no `workloads` key
     assert "ttft_p95_ms" not in traced["metrics"]
+    if own_metrics:
+        assert not set(own_metrics) & set(traced["metrics"])   # a CPU
+        _reads_the_new_part(tmp_path)
     after = _hashes(tmp_path / "benchmark")
     assert {k: v for k, v in after.items() if k in before} == before
     assert set(after) - set(before) == added | {

@@ -258,10 +258,37 @@ def test_a_file_that_is_no_capture_reads_none_and_does_not_raise(
 
 
 def test_the_readers_vocabulary_is_the_programs():
+    """The union of ``layer_metrics/parts/*.json`` is the union of every
+    tuple of ``profiling`` whose name ends in ``PARTS``: a PR that adds
+    a model appends a tuple there and a file here."""
     from ray_tpu.util import profiling
 
-    assert by_part.PARTS == profiling.PARTS
+    programs = [name for tup_name in sorted(vars(profiling))
+                if tup_name.endswith("PARTS")
+                for name in getattr(profiling, tup_name)]
+    assert len(programs) == len(set(programs))
+    assert set(by_part.PARTS) == set(programs)
+    assert len(by_part.PARTS) == len(set(by_part.PARTS))
+    assert by_part._KNOWN == frozenset(by_part.PARTS)
     assert not {by_part.UNNAMED, by_part.COLLECTIVE} & set(by_part.PARTS)
+    # each tuple of the program has a file of its own
+    files = {}
+    for entry in os.listdir(by_part.PARTS_DIR):
+        with open(os.path.join(by_part.PARTS_DIR, entry)) as f:
+            files[entry] = tuple(json.load(f)["parts"])
+    assert files["base.json"] == profiling.PARTS
+    assert files["state_space.json"] == profiling.SSM_PARTS
+
+
+def test_a_part_of_a_file_of_its_own_is_filed_under_its_name(tmp_path):
+    (tmp_path / "a.json").write_text(json.dumps({"parts": ["mlp", "mix"]}))
+    (tmp_path / "b.json").write_text(json.dumps({"parts": ["gate", "mlp"]}))
+    (tmp_path / "notes.txt").write_text("no vocabulary")
+    assert by_part.load_parts(str(tmp_path)) == ("mlp", "mix", "gate")
+    assert by_part.part_of("jit(step)/ssm_update/mul:") == "ssm_update"
+    assert by_part.part_of(
+        "jit(step)/ssm_update/ssm_decode_update:") == "ssm_update"
+    assert by_part.part_of("jit(step)/mix/mul:") == by_part.UNNAMED
 
 
 ROOT = os.path.dirname(os.path.dirname(FOLDER))
@@ -274,18 +301,19 @@ ALIASES = sorted(name[:-5] for name in os.listdir(FOLDER) if name.endswith(
 
 @pytest.mark.parametrize("name", ALIASES)
 def test_every_alias_resolves_to_the_reader_and_is_declared(name, tmp_path):
-    assert len(ALIASES) == 12
     entry = next(m for m in BENCH["per_layer"] if m["name"] == name)
     assert entry["source"] == "device_trace" and entry["workloads"]
-    assert not name.startswith("idle_")
     with open(os.path.join(FOLDER, name + ".json")) as f:
         args = json.load(f)["args"]
     assert set(args.get("parts", [])) <= set(by_part.PARTS) | {
         by_part.UNNAMED, by_part.COLLECTIVE}
     assert args.get("phase") in (None,) + by_part.PHASES
-    train = args["program"] == "^jit_train_step"
-    assert entry["moves"] == ("train_tokens_per_s" if train
-                              else "output_tokens_per_s")
+    # the one end-to-end metric (setup apart) of every cell it lists
+    moved = {m["name"] for m in BENCH["end_to_end"] if m["name"] != "setup_s"
+             and set(entry["workloads"]) & set(m["workloads"])}
+    assert moved == {entry["moves"]}
+    assert (entry["moves"] == "train_tokens_per_s") == (
+        args["program"] == "^jit_train_step")
     assert entry["unit"] == ("%" if args.get("share") else "ms")
     # the parent's program under this reader: nothing named, no failure
     run = {"trace": {"xplane": _capture(tmp_path, HAND_MADE)},

@@ -164,9 +164,9 @@ def test_the_file_keeps_every_published_key_but_the_depth():
              for m in bench["end_to_end"] + bench["per_layer"]}
     for name in ("output_tokens_per_s", "replica_ready_s",
                  "expert_pairs_dropped", "window_pool_live_pct",
-                 "experts_hit_pct", "grouped_expert_matmul_roofline.whole",
-                 "paged_hybrid_decode_full_roofline.whole",
-                 "paged_hybrid_decode_window_roofline.whole"):
+                 "experts_hit_pct", "grouped_expert_matmul_roofline",
+                 "paged_hybrid_decode_full_roofline",
+                 "paged_hybrid_decode_window_roofline"):
         assert CELL in lists[name], name
 
 
@@ -301,10 +301,10 @@ TINY = dict(
         original_max_position_embeddings=32, beta_fast=4,
         attention_factor=0.1 * math.log(4) + 1)))
 TINY.pop("published")
-WHOLE_METRICS = ("experts_hit_pct", "expert_pairs_per_step.whole",
-                 "expert_load_max_over_mean.whole", "expert_pairs_dropped",
-                 "window_pool_live_pct", "engine_step_ms.whole",
-                 "slot_occupancy_pct.whole")
+WHOLE_METRICS = ("experts_hit_pct", "expert_pairs_per_step",
+                 "expert_load_max_over_mean", "expert_pairs_dropped",
+                 "window_pool_live_pct", "engine_step_ms",
+                 "slot_occupancy_pct")
 
 
 def test_a_tiny_configuration_of_the_block_runs_through_the_harness(
@@ -361,8 +361,8 @@ def test_a_tiny_configuration_of_the_block_runs_through_the_harness(
     assert set(WHOLE_METRICS) <= set(got), sorted(got)
     assert got["expert_pairs_dropped"]["value"] == 0
     # every expert is held: 4 pairs for each running slot, at most 3 slots
-    assert 0 < got["expert_pairs_per_step.whole"]["value"] <= 12
+    assert 0 < got["expert_pairs_per_step"]["value"] <= 12
     assert 0 < got["experts_hit_pct"]["value"] <= 100 * 12 / 16
     assert 0 < got["window_pool_live_pct"]["value"] <= 100
-    assert "paged_hybrid_decode_full_roofline.whole" not in got  # no kernel
+    assert "paged_hybrid_decode_full_roofline" not in got  # no kernel
     assert "read router_choices_flipped_by_bf16_activations" in proc.stdout

@@ -219,9 +219,9 @@ TINY = dict(
     experts_first=4, num_experts_per_tok=4, layernorm_epsilon=1e-5,
     max_position_embeddings=1024, tie_word_embeddings=False,
     routed_scaling_factor=None, torch_dtype="bfloat16", reduced=[])
-MOE_METRICS = ("expert_pairs_per_step.moe", "expert_load_max_over_mean.moe",
+MOE_METRICS = ("expert_pairs_per_step", "expert_load_max_over_mean",
                "expert_pairs_dropped", "window_pool_live_pct",
-               "engine_step_ms.moe", "slot_occupancy_pct.moe")
+               "engine_step_ms", "slot_occupancy_pct")
 
 
 def test_a_tiny_configuration_of_the_block_runs_through_the_harness(
@@ -278,7 +278,7 @@ def test_a_tiny_configuration_of_the_block_runs_through_the_harness(
     assert set(MOE_METRICS) <= set(got), sorted(got)
     assert got["expert_pairs_dropped"]["value"] == 0
     # 3 slots x 4 choices x 4 of 16 experts held: 3 pairs a full step
-    assert 0 < got["expert_pairs_per_step.moe"]["value"] <= 12
+    assert 0 < got["expert_pairs_per_step"]["value"] <= 12
     assert 0 < got["window_pool_live_pct"]["value"] <= 100
     assert "grouped_expert_matmul_roofline" not in got     # no kernel here
     assert "read router_choices_flipped_by_bf16_activations" in proc.stdout

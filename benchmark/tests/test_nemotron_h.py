@@ -2,9 +2,9 @@
 adapter's counts against hand counts, the configuration's file against
 its published keys, the ``chat-turnover`` mix, the cell's programs
 compiled for a described v5e, the readers of the recurrent state and of
-the widened part vocabulary, a checkout without the program's module,
-and a rehearsal of a tiny configuration of the block through ``run.py``
-with the cell's per-layer metrics."""
+the state-space parts, the check on an engine handed over, a checkout
+without the program's module, and a rehearsal of a tiny configuration of
+the block through ``run.py`` with the cell's per-layer metrics."""
 
 import json
 import os
@@ -278,55 +278,56 @@ def test_the_cells_programs_fit_one_chip(device, monkeypatch):
 
 
 # ------------------------------------------------------------- the readers
-def test_the_widened_readers_vocabulary_is_the_programs():
-    from ray_tpu.util import profiling
-
+def test_the_state_space_parts_are_read_by_the_one_reader():
+    """Since PR 41 the part names are data (``layer_metrics/parts/``) and
+    this cell's by-part metrics run the reader every cell runs."""
+    with open(os.path.join(BENCH, "layer_metrics",
+                           "decode_ssm_update_dev_ms.json")) as f:
+        assert json.load(f) == {"reader": "_dev_ms_by_part", "args": {
+            "program": "^jit_step", "parts": ["ssm_update"]}}
     bench_run.load_reader("decode_ssm_update_dev_ms")    # as run.py does
-    wide = model_spec.load_module(os.path.join(
-        BENCH, "layer_metrics", "_dev_ms_by_part_ssm.py"))
-    assert wide.SSM_PARTS == profiling.SSM_PARTS
-    assert wide.PARTS == profiling.PARTS + profiling.SSM_PARTS
-    assert wide._base.part_of(
-        "jit(step)/ssm_update/mul:") == "ssm_update"
-    assert wide._base.part_of(
-        "jit(step)/ssm_update/ssm_decode_update:") == "ssm_update"
-    # the accepted reader's own module is not the copy that was widened
-    base = model_spec.load_module(os.path.join(
+    reader = model_spec.load_module(os.path.join(
         BENCH, "layer_metrics", "_dev_ms_by_part.py"))
-    assert base.part_of("jit(step)/ssm_update/mul:") == base.UNNAMED
-    assert base.PARTS == profiling.PARTS
-    # what the widened copy patches is there under these names: a rename
-    # in the accepted reader fails here, not silently in a traced run
-    assert base._KNOWN == frozenset(base.PARTS)
-    assert wide._base._KNOWN == frozenset(wide.PARTS)
+    with open(os.path.join(reader.PARTS_DIR, "state_space.json")) as f:
+        own = json.load(f)["parts"]
+    assert set(own) <= set(reader.PARTS)
+    for name in own:
+        assert reader.part_of(f"jit(step)/{name}/mul:") == name
+    assert reader.part_of(
+        "jit(step)/ssm_update/ssm_decode_update:") == "ssm_update"
 
 
-NEW = [m for m in BENCHMARK["per_layer"] if m.get("workloads") == [CELL]]
+OF_CELL = [m for m in BENCHMARK["per_layer"]
+           if CELL in m.get("workloads", [])]
+OWN = [m for m in OF_CELL if m["workloads"] == [CELL]]
 
 
-def test_the_new_entries_name_this_cell_only_and_stand_at_the_end():
-    names = [m["name"] for m in NEW]
-    assert len(names) == 25 and BENCHMARK["per_layer"][-25:] == NEW
-    for m in NEW:
-        assert m["moves"] == "output_tokens_per_s", m["name"]
-        assert os.path.exists(os.path.join(
-            BENCH, "layer_metrics", m["name"] + ".json")) or os.path.exists(
-            os.path.join(BENCH, "layer_metrics", m["name"] + ".py"))
-        if m["name"].endswith("_roofline") or "_roofline." in m["name"]:
-            assert m["unit"] == "%"
-    shared = [m["name"] for m in BENCHMARK["end_to_end"]
-              + BENCHMARK["per_layer"]
-              if CELL in m.get("workloads", []) and m not in NEW]
-    assert shared == ["output_tokens_per_s", "replica_ready_s",
-                      "expert_pairs_dropped"]
+@pytest.mark.parametrize("m", OF_CELL, ids=lambda m: m["name"])
+def test_an_entry_that_lists_the_cell_moves_its_metric_and_has_a_reader(m):
+    assert m["moves"] in ("output_tokens_per_s", "setup_s"), m["name"]
+    assert callable(bench_run.load_reader(m["name"]))
+    if m["name"].endswith("_roofline") or "_roofline." in m["name"]:
+        assert m["unit"] == "%"
 
 
-# the readers of what THIS PR adds to the program (a part, a kernel, a
-# counter, a pool); the others are the accepted readers under this cell's
-# name and read what every serve cell has
-OF_THIS_PR = [m["name"] for m in NEW if not m["name"].startswith((
-    "engine_step_ms", "slot_occupancy_pct", "device_idle_pct",
-    "decode_step_dev_ms", "prefill_dev_share_pct", "overlapped_turn_pct"))]
+def test_the_cells_own_entries_read_what_its_model_added():
+    """A part, a kernel, a pool of this model alone; what it shares with
+    the other serve cells stands under their folded names."""
+    names = {m["name"] for m in OF_CELL}
+    assert {m["name"] for m in OWN} >= {
+        "ssm_decode_update_roofline", "decode_ssm_update_dev_ms",
+        "decode_ssm_proj_dev_ms", "prefill_ssm_scan_dev_ms",
+        "ssm_state_pool_live_pct"}
+    assert {"engine_step_ms", "slot_occupancy_pct", "device_idle_pct",
+            "decode_step_dev_ms", "overlapped_turn_pct",
+            "expert_layer_dev_ms", "grouped_expert_matmul_roofline",
+            "paged_decode_roofline", "replica_ready_s",
+            "expert_pairs_dropped"} <= names
+
+
+# the readers of what PR 39 added to the program (a part, a kernel, a
+# counter, a pool): the entries that list this cell alone
+OF_THIS_PR = [m["name"] for m in OWN]
 
 
 @pytest.mark.parametrize("name", OF_THIS_PR)
@@ -348,7 +349,6 @@ def test_a_new_reader_finds_nothing_on_another_program_and_does_not_raise(
            "raw": {"open": stats, "close": dict(stats, now=4.0)},
            "trace": {"xplane": str(empty), "programs": {}, "ops": {},
                      "busy_s": 1.0, "window_s": 2.0}}
-    assert len(OF_THIS_PR) == 19
     assert bench_run.load_reader(name)(run) is None
 
 
@@ -378,10 +378,10 @@ TINY = dict(
     moe_intermediate_size=24, moe_shared_expert_intermediate_size=48,
     max_position_embeddings=1024, reduced=[])
 TINY.pop("published")
-SSM_METRICS = ("ssm_state_pool_live_pct", "expert_pairs_per_step.ssm",
-               "expert_load_max_over_mean.ssm", "expert_pairs_dropped",
-               "engine_step_ms.ssm", "slot_occupancy_pct.ssm",
-               "overlapped_turn_pct.ssm")
+SSM_METRICS = ("ssm_state_pool_live_pct", "expert_pairs_per_step",
+               "expert_load_max_over_mean", "expert_pairs_dropped",
+               "engine_step_ms", "slot_occupancy_pct",
+               "overlapped_turn_pct")
 
 
 def test_a_tiny_configuration_of_the_block_runs_through_the_harness(
@@ -438,7 +438,7 @@ def test_a_tiny_configuration_of_the_block_runs_through_the_harness(
     assert set(SSM_METRICS) <= set(got), sorted(got)
     assert got["expert_pairs_dropped"]["value"] == 0
     # every expert is held: 4 pairs for each running slot, at most 3 slots
-    assert 0 < got["expert_pairs_per_step.ssm"]["value"] <= 12
+    assert 0 < got["expert_pairs_per_step"]["value"] <= 12
     assert 0 < got["ssm_state_pool_live_pct"]["value"] <= 100
     assert "ssm_decode_update_roofline" not in got       # no kernel here
     assert "read router_choices_flipped_by_bf16_activations" in proc.stdout

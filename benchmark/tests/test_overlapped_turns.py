@@ -8,8 +8,6 @@ import pytest
 from benchmark import run as bench_run
 
 NAME = "overlapped_turn_pct"
-CLOSED_LOOP = ["serve-batch-decode", "serve-moe-window-decode",
-               "serve-mla-moe-decode", "serve-moe-whole-mixed-decode"]
 
 
 def serve_run(open_turns, close_turns):
@@ -45,14 +43,24 @@ def test_nothing_to_read_gives_no_number(run):
     assert bench_run.load_reader(NAME)(run) is None
 
 
-def test_the_entry_names_the_closed_loop_cells():
+def test_the_entries_name_closed_loop_cells():
+    """Whichever entries run this reader (a later PR appends
+    ``overlapped_turn_pct.<tag>`` for its cell): each lists closed-loop
+    cells alone, whose callers wait for their answers."""
     with open(os.path.join(bench_run.ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    entry = next(m for m in bench["per_layer"] if m["name"] == NAME)
-    assert entry == {
-        "name": NAME, "unit": "%", "better": "higher",
-        "source": "program_counter",
-        "layer": "engine loop: ray_tpu/serve/llm.py",
-        "moves": "output_tokens_per_s", "workloads": CLOSED_LOOP}
-    cells = {w["name"] for w in bench["workloads"]}
-    assert set(CLOSED_LOOP) <= cells
+    cells = {w["name"]: w for w in bench["workloads"]}
+    entries = [m for m in bench["per_layer"]
+               if m["name"].split(".")[0] == NAME]
+    assert entries
+    for entry in entries:
+        assert {k: v for k, v in entry.items()
+                if k not in ("name", "workloads")} == {
+            "unit": "%", "better": "higher", "source": "program_counter",
+            "layer": "engine loop: ray_tpu/serve/llm.py",
+            "moves": "output_tokens_per_s"}
+        assert callable(bench_run.load_reader(entry["name"]))
+        for name in entry["workloads"]:
+            with open(os.path.join(bench_run.HERE, "traffic",
+                                   cells[name]["traffic"] + ".json")) as f:
+                assert json.load(f)["kind"] == "closed_loop_handle", name

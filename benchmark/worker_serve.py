@@ -70,7 +70,8 @@ class BenchServer(LLMServer):
 
         t0 = time.monotonic()
         self._check = checks.serve_check(
-            self.engine.params, self._spec, self._seed, self._deployment)
+            self.engine.params, self._spec, self._seed, self._deployment,
+            engine=self.engine)
         self._times["check_s"] = time.monotonic() - t0
         return self._check
 

@@ -19,7 +19,8 @@ def train_loop(config):
 
     t_loop = time.monotonic()
     counts = compile_cache_counts()
-    spec, mix, job = config["spec"], config["mix"], config["job"]
+    spec, mix = config["spec"], config["mix"]
+    job = dict(config["job"], optimizer=mix["optimizer"])
     seed, seconds = config["seed"], config["seconds"]
     devices = jax.devices()
     dev = devices[0]
