@@ -52,7 +52,7 @@ import jax.numpy as jnp
 from ray_tpu.models import moe
 from ray_tpu.models.decoding import _bind_params
 from ray_tpu.models.paged_cache import KVStateManager, PagedConfig
-from ray_tpu.ops.attention import hybrid_attention_reference, on_tpu
+from ray_tpu.ops.attention import on_tpu, prompt_attention
 from ray_tpu.ops.norms import rmsnorm
 from ray_tpu.ops.pallas import paged_mla_decode_attention as mla
 from ray_tpu.ops.rope import YarnScaling, apply_rope, rope_frequencies
@@ -230,7 +230,7 @@ def attend_expanded(q_nope, q_rope, c_kv, k_rope, layer, cfg):
     k = jnp.concatenate([k_nope, jnp.broadcast_to(
         k_rope[:, :, None, :], (*k_nope.shape[:-1], cfg.rope_dim))], -1)
     q = jnp.concatenate([q_nope, q_rope], -1)
-    return hybrid_attention_reference(q, k, v, scale=cfg.scale)
+    return prompt_attention(q, k, v, scale=cfg.scale)
 
 
 def absorbed_queries(q_nope, q_rope, layer, cfg):

@@ -51,7 +51,7 @@ from ray_tpu.models import moe
 from ray_tpu.models import paged_cache as pc
 from ray_tpu.models.decoding import _bind_params
 from ray_tpu.models.paged_cache import KVStateManager, PagedConfig
-from ray_tpu.ops.attention import hybrid_attention_reference, on_tpu
+from ray_tpu.ops.attention import on_tpu, prompt_attention
 from ray_tpu.ops.norms import rmsnorm
 from ray_tpu.ops.pallas import paged_hybrid_decode_attention as pha
 from ray_tpu.ops.rope import YarnScaling, apply_rope, rope_frequencies
@@ -333,7 +333,7 @@ def make_prefill(params: Params, cfg: LagunaConfig,
             kind = cfg.kind(l)
             li, index[kind] = index[kind], index[kind] + 1
             h, q, k, v = _qkv(x, layer, cfg, *ropes[kind], None)
-            out = hybrid_attention_reference(
+            out = prompt_attention(
                 q, k, v, scale=cfg.scale(kind), window=cfg.window_of(kind))
             out = gate_heads(out, h, layer)
             with part("attn_proj"):

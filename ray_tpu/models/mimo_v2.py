@@ -46,7 +46,7 @@ from ray_tpu.models import moe
 from ray_tpu.models import paged_cache as pc
 from ray_tpu.models.decoding import _bind_params
 from ray_tpu.models.paged_cache import KVStateManager, PagedConfig
-from ray_tpu.ops.attention import hybrid_attention_reference, on_tpu
+from ray_tpu.ops.attention import on_tpu, prompt_attention
 from ray_tpu.ops.norms import rmsnorm
 from ray_tpu.ops.pallas import paged_hybrid_decode_attention as pha
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
@@ -338,7 +338,7 @@ def make_prefill(params: Params, cfg: MimoV2Config,
             kind = cfg.kind(l)
             li, index[kind] = index[kind], index[kind] + 1
             q, k, v = _qkv(x, layer, cfg, *ropes[kind], None)
-            out = hybrid_attention_reference(
+            out = prompt_attention(
                 q, k, v, scale=cfg.head_dim ** -0.5, sink=layer.get("sink"),
                 window=_window(cfg, kind))
             with part("attn_proj"):

@@ -121,6 +121,28 @@ def hybrid_attention_reference(q, k, v, *, scale: float,
     return out.reshape(B, nb * w, H, Dv)[:, :S].astype(q.dtype)
 
 
+@part("attention")
+def prompt_attention(q, k, v, *, scale: float, window: Optional[int] = None,
+                     sink=None):
+    """Causal attention of a served prompt over itself, one layer:
+    :func:`hybrid_attention_reference`'s arguments and result. A full
+    layer without a sink (``window`` None or not shorter than the
+    prompt) goes through the flash forward kernel on a TPU: operands in
+    their own dtype on the MXU, float32 running maximum, sum and
+    accumulator, no (S, S) array. A window or a sink, which the kernel
+    has not, and every other backend take the XLA reference."""
+    if (on_tpu() and sink is None
+            and (window is None or window >= q.shape[1])):
+        from ray_tpu.ops.pallas.flash_attention import flash_attention_fwd_pallas
+
+        out, _ = flash_attention_fwd_pallas(
+            q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+            v.transpose(0, 2, 1, 3), causal=True, scale=scale)
+        return out.transpose(0, 2, 1, 3)
+    return hybrid_attention_reference(q, k, v, scale=scale, window=window,
+                                      sink=sink)
+
+
 def _fwd_xla(q, k, v, causal, scale):
     """Fused full-matrix forward returning (out, lse); (B, H, S, D) layout.
 

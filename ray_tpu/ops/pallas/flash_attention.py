@@ -17,7 +17,10 @@ change: one the diagonal crosses, or the padded last kv block.
 
 Layout contract: inputs are (B, H, S, D); GQA kv heads are resolved in the kv
 BlockSpec index map (no materialized head repeat). Matmuls run on the MXU in
-the input dtype with f32 accumulation (``preferred_element_type``).
+the input dtype with f32 accumulation (``preferred_element_type``). The
+forward takes value rows of another width than the key rows (a served
+prompt of a model with keys 192 and values 128 wide); the backward kernels
+take one width, as the train step has.
 
 The reference framework has no kernel layer (its attention lives in torch /
 vLLM, outside the repo); this file is net-new TPU-first work (SURVEY.md §5
@@ -49,7 +52,10 @@ def _blocks(block, length: int, row_bytes: int) -> tuple[int, int]:
     (block, D) operands of at most 256 bytes a row; on a v5e at 4,096 × 128
     it is the fastest for all three kernels (forward 4.40 ms a call against
     4.88 at 512², dq 4.51 / 5.31, dkv 6.03 / 6.55), and 2048 on either side
-    is slower again (PERF.md section 6, PR 37)."""
+    is slower again (PERF.md section 6, PR 37). A row of 192 x 2 bytes (the
+    forward alone, a served prompt) fits 1024² too and is no faster for it:
+    1.414 ms against 1.394 at 512² for 64 heads x 2,048, 0.472 against 0.426
+    for 64 x 1,024 (PERF.md section 6, PR 44)."""
     if block is None:
         block = 512
         if row_bytes <= 256 and -length % 1024 <= -length % 512:
@@ -118,7 +124,7 @@ def _fwd_kernel(iq_ref, ik_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
     @pl.when(ik == 0)
     def _init():
-        # the scale goes once on the (Bq, D) queries, not on every
+        # the scale goes once on the (Bq, Dk) queries, not on every
         # (Bkv, Bq) tile of scores
         qs_ref[:] = (q_ref[0].astype(jnp.float32) * scale).astype(
             qs_ref.dtype)
@@ -127,8 +133,8 @@ def _fwd_kernel(iq_ref, ik_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         l_ref[:] = jnp.zeros_like(l_ref)
 
     def _step(mask):
-        k = k_ref[0]                       # (Bkv, D)
-        v = v_ref[0]
+        k = k_ref[0]                       # (Bkv, Dk)
+        v = v_ref[0]                       # (Bkv, Dv)
         s_t = jax.lax.dot_general(         # (Bkv, Bq) = K·(scale·Q)ᵀ
             k, qs_ref[:], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -140,7 +146,7 @@ def _fwd_kernel(iq_ref, ik_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         alpha = jnp.exp(m_prev - m_new)                     # (1, Bq)
         l_ref[:] = l_ref[:] * alpha + jnp.sum(p_t, axis=0, keepdims=True)
         acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            v, p_t.astype(v.dtype), (((0,), (0,)), ((), ())),   # (D, Bq)
+            v, p_t.astype(v.dtype), (((0,), (0,)), ((), ())),   # (Dv, Bq)
             preferred_element_type=jnp.float32)
         m_ref[:] = m_new
 
@@ -160,20 +166,22 @@ def flash_attention_fwd_pallas(q, k, v, *, causal: bool, scale: float,
                                block_q: int | None = None,
                                block_kv: int | None = None,
                                interpret: bool = False):
-    """q: (B, Hq, Sq, D); k, v: (B, Hkv, Skv, D).
+    """q: (B, Hq, Sq, Dk); k: (B, Hkv, Skv, Dk); v: (B, Hkv, Skv, Dv).
 
-    Returns ``(out, lse)``: out (B, Hq, Sq, D) in q.dtype, lse (B, Hq, Sq)
+    Returns ``(out, lse)``: out (B, Hq, Sq, Dv) in q.dtype, lse (B, Hq, Sq)
     f32 where ``lse[i] = log(sum_j exp(scale·q_i·k_j))`` over unmasked j.
-    The blocks are chosen from the shapes (``_blocks``) unless given.
+    The blocks are chosen from the shapes (``_blocks``, by the wider of
+    the two rows) unless given.
     """
-    b, hq, sq, d = q.shape
-    _, hkv, skv, _ = k.shape
+    b, hq, sq, dk = q.shape
+    _, hkv, skv, dv = v.shape
     if hq % hkv:
         raise ValueError(f"q heads {hq} not a multiple of kv heads {hkv}")
     group = hq // hkv
 
-    block_q, sq_p = _blocks(block_q, sq, d * q.dtype.itemsize)
-    block_kv, skv_p = _blocks(block_kv, skv, d * q.dtype.itemsize)
+    row_bytes = max(dk, dv) * q.dtype.itemsize
+    block_q, sq_p = _blocks(block_q, sq, row_bytes)
+    block_kv, skv_p = _blocks(block_kv, skv, row_bytes)
     if sq_p != sq:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, sq_p - sq), (0, 0)))
     if skv_p != skv:
@@ -202,22 +210,22 @@ def flash_attention_fwd_pallas(q, k, v, *, causal: bool, scale: float,
                 num_scalar_prefetch=2,
                 grid=(b * hq, iq_tab.shape[0]),
                 in_specs=[
-                    pl.BlockSpec((1, block_q, d), q_index),
-                    pl.BlockSpec((1, block_kv, d), kv_index),
-                    pl.BlockSpec((1, block_kv, d), kv_index),
+                    pl.BlockSpec((1, block_q, dk), q_index),
+                    pl.BlockSpec((1, block_kv, dk), kv_index),
+                    pl.BlockSpec((1, block_kv, dv), kv_index),
                 ],
                 out_specs=[
-                    pl.BlockSpec((1, block_q, d), q_index),
+                    pl.BlockSpec((1, block_q, dv), q_index),
                     pl.BlockSpec((1, 1, block_q), lse_index),
                 ],
                 scratch_shapes=[
-                    pltpu.VMEM((block_q, d), q.dtype),
-                    pltpu.VMEM((d, block_q), jnp.float32),
+                    pltpu.VMEM((block_q, dk), q.dtype),
+                    pltpu.VMEM((dv, block_q), jnp.float32),
                     pltpu.VMEM((1, block_q), jnp.float32),
                     pltpu.VMEM((1, block_q), jnp.float32),
                 ]),
             out_shape=[
-                jax.ShapeDtypeStruct((b * hq, sq_p, d), q.dtype),
+                jax.ShapeDtypeStruct((b * hq, sq_p, dv), q.dtype),
                 jax.ShapeDtypeStruct((b * hq, 1, sq_p), jnp.float32),
             ],
             compiler_params=pltpu.CompilerParams(
@@ -225,11 +233,11 @@ def flash_attention_fwd_pallas(q, k, v, *, causal: bool, scale: float,
             interpret=interpret,
             name="flash_attention_fwd",
         )(iq_tab, ik_tab,
-          q.reshape(b * hq, sq_p, d),
-          k.reshape(b * hkv, skv_p, d),
-          v.reshape(b * hkv, skv_p, d))
+          q.reshape(b * hq, sq_p, dk),
+          k.reshape(b * hkv, skv_p, dk),
+          v.reshape(b * hkv, skv_p, dv))
 
-    out = out.reshape(b, hq, sq_p, d)[:, :, :sq]
+    out = out.reshape(b, hq, sq_p, dv)[:, :, :sq]
     lse = lse.reshape(b, hq, sq_p)[:, :, :sq]
     return out, lse
 

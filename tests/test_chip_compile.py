@@ -101,14 +101,19 @@ def _total_bytes(mem) -> int:
 # ------------------------------------------------------------------ kernels
 B, S, H, KV, D = 4, 2048, CFG.n_heads, CFG.n_kv_heads, CFG.head_dim
 
-# (batch, query heads, kv heads, sequence, head dimension, block): the 1b
-# widths at two given blocks, and the per-device calls of the two train
-# cells, whose blocks are the kernels' own choice
+# (batch, query heads, kv heads, sequence, key width, value width, block
+# given, block the call must end up with): the 1b widths at two given
+# blocks; the per-device calls of the two train cells and a prompt's full
+# layers in the three routed serve cells' largest buckets, whose blocks are
+# the kernels' own choice from the shapes
 FLASH_CALLS = {
-    "1b-512": (B, H, KV, S, D, 512),
-    "1b-1024": (B, H, KV, S, D, 1024),
-    "train-1chip": (6, 16, 16, 4096, 128, None),
-    "train-fsdp2tp2": (8, 16, 4, 4096, 128, None),
+    "1b-512": (B, H, KV, S, D, D, 512, 512),
+    "1b-1024": (B, H, KV, S, D, D, 1024, 1024),
+    "train-1chip": (6, 16, 16, 4096, 128, 128, None, 1024),
+    "train-fsdp2tp2": (8, 16, 4, 4096, 128, 128, None, 1024),
+    "mla-prefill-2048": (1, 64, 64, 2048, 192, 128, None, 512),
+    "whole-prefill-2048": (1, 48, 8, 2048, 128, 128, None, 1024),
+    "window-prefill-1024": (1, 64, 4, 1024, 192, 128, None, 512),
 }
 
 
@@ -116,21 +121,27 @@ FLASH_CALLS = {
 def test_flash_fwd_compiles(one_chip, call):
     from ray_tpu.ops.pallas.flash_attention import flash_attention_fwd_pallas
 
-    b, hq, hkv, s, d, block = FLASH_CALLS[call]
-    q = _sds((b, hq, s, d), jnp.bfloat16, one_chip)
-    kv = _sds((b, hkv, s, d), jnp.bfloat16, one_chip)
+    b, hq, hkv, s, dk, dv, block, chosen = FLASH_CALLS[call]
+    q = _sds((b, hq, s, dk), jnp.bfloat16, one_chip)
+    k = _sds((b, hkv, s, dk), jnp.bfloat16, one_chip)
+    v = _sds((b, hkv, s, dv), jnp.bfloat16, one_chip)
     fn = jax.jit(lambda q, k, v: flash_attention_fwd_pallas(
-        q, k, v, causal=True, scale=d ** -0.5, block_q=block,
+        q, k, v, causal=True, scale=dk ** -0.5, block_q=block,
         block_kv=block))
-    compiled = fn.lower(q, kv, kv).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = fn.lower(q, k, v).compile().as_text()
+    assert "tpu_custom_call" in text
+    # the work list is the causal triangle of (s / block)^2 block pairs
+    n = s // chosen
+    pairs = f"s32[{n * (n + 1) // 2}]{{0}}"
+    assert f"operand_layout_constraints={{{pairs}, {pairs}, " in text
 
 
-@pytest.mark.parametrize("call", FLASH_CALLS)
+@pytest.mark.parametrize(
+    "call", [c for c, shape in FLASH_CALLS.items() if shape[4] == shape[5]])
 def test_flash_bwd_compiles(one_chip, call):
     from ray_tpu.ops.pallas.flash_attention import flash_attention_bwd_pallas
 
-    b, hq, hkv, s, d, block = FLASH_CALLS[call]
+    b, hq, hkv, s, d, _, block, _ = FLASH_CALLS[call]
     q = _sds((b, hq, s, d), jnp.bfloat16, one_chip)
     kv = _sds((b, hkv, s, d), jnp.bfloat16, one_chip)
     vec = _sds((b, hq, s), jnp.float32, one_chip)
@@ -506,6 +517,33 @@ def test_state_space_cells_program_reads_each_weight_once(
             readers = {n for n, _, _ in _readers(users, weight)}
             assert len(readers) == 1, (weight, readers)
             assert not [n for n in readers if "remat" in n], readers
+
+
+def test_latent_cells_largest_bucket_attends_in_the_flash_kernel(
+        topo, as_tpu):
+    """`serve-mla-moe-decode`'s 2,048 bucket, where every prompt of the
+    cell lands: each of the five layers' expanded attention is ONE
+    ``flash_attention_fwd`` call, and no float32 array of a layer's
+    whole scores (64 x 2,048 x 2,048 x 4 B = 1.07 GB in XLA) is left in
+    the program. What peaks in the bucket's 1.00 GiB of temporaries is
+    then the expert layer's float32 rows (the grouped product's (16,576 x
+    7,168) output beside the combine's gather): held as the ceiling."""
+    from benchmark import model_spec, sizing
+
+    spec = model_spec.load_config("axk1-ep16-l5")
+    with open(os.path.join(model_spec.HERE, "cells",
+                           "serve-mla-moe-decode.json")) as f:
+        deployment = json.load(f)["deployment"]
+    _, bucket = sizing.serve_programs(spec, deployment, topo.devices[0])
+    compiled = bucket(2048).compile()
+    text = compiled.as_text()
+    kernels = re.findall(r"= \([^=]*\) custom-call\([^\n]*"
+                         r"flash_attention_fwd/pallas_call", text)
+    assert len(kernels) == 5
+    assert all(k.startswith("= (bf16[64,2048,128]") for k in kernels)
+    # a layer's scores whole: any float32 (..., 2048, 2048) of several heads
+    assert not re.findall(r"= f32\[(?:\d+,)+2048,2048\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.05 * 1024 ** 3
 
 
 # ------------------------------------------- the engine's pick of a token

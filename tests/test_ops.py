@@ -1,5 +1,7 @@
 """Kernel correctness vs the naive oracle, on the 8-device CPU mesh."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -136,6 +138,76 @@ def test_pallas_fwd_grid_is_the_live_blocks(causal, steps):
     assert _inner_grid(lambda q, k, v: flash_attention_fwd_pallas(
         q, k, v, causal=causal, scale=1.0, block_q=32, block_kv=32,
         interpret=True), qt, kt, vt) == steps
+
+
+# (key width, value width, query heads, kv heads): the latent model's
+# widths, the window model's under grouped heads, the whole model's grouping
+SERVED_WIDTHS = {
+    "keys_wider": (192, 128, 4, 4),
+    "keys_wider_grouped": (192, 128, 8, 2),
+    "equal_grouped": (128, 128, 6, 2),
+}
+# (prompt length, block)
+SERVED_LENGTHS = {
+    "one_block": (64, 64),
+    "several_blocks": (192, 64),
+    "padded_last_block": (168, 64),
+}
+
+
+@pytest.mark.parametrize("length", SERVED_LENGTHS)
+@pytest.mark.parametrize("widths", SERVED_WIDTHS)
+def test_pallas_fwd_takes_values_narrower_than_keys(widths, length):
+    """The forward kernel at a served prompt's shapes against the XLA
+    form the prefills ran before: keys ``dk`` and values ``dv`` wide."""
+    from ray_tpu.ops.attention import _fwd_xla, hybrid_attention_reference
+    from ray_tpu.ops.pallas.flash_attention import flash_attention_fwd_pallas
+
+    dk, dv, h, kv = SERVED_WIDTHS[widths]
+    s, block = SERVED_LENGTHS[length]
+    kq, kk, kvv = jax.random.split(jax.random.PRNGKey(11), 3)
+    q = jax.random.normal(kq, (1, s, h, dk))
+    k = jax.random.normal(kk, (1, s, kv, dk))
+    v = jax.random.normal(kvv, (1, s, kv, dv))
+    scale = 1.3 * dk ** -0.5
+    qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+    out, lse = flash_attention_fwd_pallas(
+        qt, kt, vt, causal=True, scale=scale, block_q=block, block_kv=block,
+        interpret=True)
+    assert out.shape == (1, h, s, dv) and lse.shape == (1, h, s)
+    ref = hybrid_attention_reference(q, k, v, scale=scale)
+    np.testing.assert_allclose(out.transpose(0, 2, 1, 3), ref, atol=2e-5,
+                               rtol=2e-5)
+    _, lse_ref = _fwd_xla(qt, kt, vt, True, scale)
+    np.testing.assert_allclose(lse, lse_ref, atol=1e-5, rtol=1e-5)
+
+
+# what a prefill hands the front -> whether the kernel is what is traced
+PROMPT_CALLS = {
+    "full": (dict(), True),
+    "window_not_shorter": (dict(window=64), True),
+    "window": (dict(window=16), False),
+    "sink": (dict(sink=np.zeros((4,), np.float32)), False),
+}
+
+
+@pytest.mark.parametrize("backend", ["tpu", "cpu"])
+@pytest.mark.parametrize("call", PROMPT_CALLS)
+def test_prompt_attention_chooses_by_its_arguments(call, backend,
+                                                   monkeypatch):
+    """A full layer without a sink traces the flash forward on a TPU
+    backend; a window, a sink or another backend the XLA reference."""
+    from ray_tpu.ops.attention import prompt_attention
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    kwargs, full = PROMPT_CALLS[call]
+    q, k, v = _qkv(jax.random.PRNGKey(3), b=1, s=64, hq=4, hkv=2, d=32)
+    jaxpr = jax.make_jaxpr(functools.partial(
+        prompt_attention, scale=0.2, **kwargs))(q, k, v[..., :16])
+    assert jaxpr.out_avals[0].shape == (1, 64, 4, 16)
+    kernels = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert len(kernels) == (1 if full and backend == "tpu" else 0)
+    assert all(e.params["name"] == "flash_attention_fwd" for e in kernels)
 
 
 @pytest.mark.parametrize("shape", FLASH_SHAPES)
