@@ -275,9 +275,37 @@ def loss_fn(params: Params, batch: Dict[str, jax.Array], config: MoEConfig,
 # here) and a step fills what the routing sends. The partial sum is the
 # layer's output; on one chip nothing stands in for the absent experts.
 
-ROW_TILE = 16                 # rows of one expert a tile (bf16 sublanes)
+# rows of one expert a tile: from the bf16 sublanes to the MXU's 128 rows
+ROW_TILES = (16, 32, 64, 128)
 COUNTERS = ("expert_layer_calls", "expert_pairs", "experts_hit",
             "expert_load_max_over_mean", "expert_pairs_dropped")
+
+
+def row_tile(T: int, top_k: int, n_experts: int, held: int) -> int:
+    """Rows of one expert in a tile of the grouped products, from the
+    call's static shapes alone: the smallest of ``ROW_TILES`` that holds
+    MORE than ``T * top_k / n_experts`` rows, what an expert of the
+    router's ``n_experts`` expects from ``T`` tokens whether the chip
+    holds all of them or a share (an expert's rows scatter around that
+    mean: a tile of just the mean is two tiles for half the experts);
+    then halved while the padding it can add to the worst case, ``held *
+    (tm - 1)`` rows, is more than the ``T * top_k`` pairs themselves
+    (the XLA around the products works on every row of the buffer, and
+    a chip that holds the whole expert set pads 256 last tiles).
+
+    The product loads each 128 x 128 tile of an expert's matrix into
+    the MXU once per row tile, so 16 rows a tile pay a weight load for
+    16 rows of work. That is right where an expert HAS no more: a decode
+    step sends it 4-8 rows, the tile is 16 in every cell and the call is
+    bound by the experts' bytes. A prefill's bucket sends it 16-85: one
+    tile an expert in place of two to six (the kernel alone and the
+    layer at each tile at four models' widths: PERF.md section 6,
+    PR 45)."""
+    pairs = T * top_k
+    tm = next((t for t in ROW_TILES if t * n_experts > pairs), ROW_TILES[-1])
+    while tm > ROW_TILES[0] and held * (tm - 1) > pairs:
+        tm //= 2
+    return tm
 
 
 @part("router")
@@ -385,17 +413,22 @@ def experts_by_share(x, layer: Params, *, experts_held: Tuple[int, int],
     no token (a padded prompt, an idle slot): they are routed nowhere.
 
     The (token, expert) pairs whose expert is held are sorted by expert
-    and laid out in tiles of ``ROW_TILE`` rows, one expert a tile; three
+    and laid out in tiles of ``tm`` rows, one expert a tile; three
     grouped products (``ops/pallas/grouped_matmul.py``) run over the
     tiles that hold rows; the weighted rows are gathered back by token.
-    ``kernel_name`` names the products' custom calls in a trace.
+    ``tm`` is chosen HERE, by :func:`row_tile` from ``T``, ``top_k``,
+    the router's width and ``G``: 16 for a decode step, up to 128 for a
+    prefill's bucket; no caller and no option names it. The row buffer
+    stays the worst case at any tile, ``T * top_k + G * (tm - 1)`` rows
+    rounded to tiles. ``kernel_name`` names the products' custom calls
+    in a trace.
     """
     from ray_tpu.ops.pallas import grouped_matmul as gm
 
     T = x.shape[0]
     xe = x if x_experts is None else x_experts
     first, G = experts_held
-    tm = ROW_TILE
+    tm = row_tile(T, top_k, layer["router"].shape[1], G)
     if score == "softmax":
         idx, w = route_softmax_topk(x, layer["router"], top_k, scale)
     elif score != "sigmoid":

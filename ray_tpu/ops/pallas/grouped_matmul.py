@@ -17,8 +17,20 @@ capacity, nothing dropped); a step usually fills a small part of it.
 expert name the same (K, tn) block of its matrix, which is then fetched
 once; tiles past ``n_active`` repeat the last active tile's indices and
 skip the product, so they move nothing. An expert that no row was routed
-to has no tile: its weights are never read. In a decode step the product
-is bound by the bytes of the experts hit, not by operations.
+to has no tile: its weights are never read.
+
+``tm`` is the caller's: ``models/moe.py::row_tile`` chooses it for each
+program from the call's static shapes (the rows an expert expects), and
+this file takes any multiple of 16. A live step loads every 128 x 128
+tile of the (K, tn) block into the MXU for ``tm`` rows of work, so a
+decode step, whose experts get 4-8 rows, keeps 16 and is bound by the
+bytes of the experts hit; a prefill's bucket, whose experts get 16-85,
+takes 32-128 so that an expert is one step a column block and not six.
+At 128 rows the blocks are lhs (128, 7,168) 1.8 MB and rhs (7,168, 256)
+3.7 MB, both double-buffered, beside a float32 (128, 256) output: 11.3
+of the 16 MiB of scoped VMEM, the largest of the four models' calls
+(``_tn`` bounds the rhs block alone: rows much wider than 7,168 would
+have to narrow it, never the precision).
 """
 
 from __future__ import annotations

@@ -474,6 +474,26 @@ def _columns_made(text: str, line: str) -> int:
         r"= \w+\[[\d,]*?(\d+)\]\S* convolution\(", body))
 
 
+# the four cells with an expert layer, and their configurations
+ROUTED_CELLS = {
+    "serve-moe-window-decode": "mimo-v2.5-ep16-l7",
+    "serve-mla-moe-decode": "axk1-ep16-l5",
+    "serve-moe-whole-mixed-decode": "laguna-xs2-l5",
+    "serve-ssm-latent-moe-chat": "nemotron3-super-ep4-l11",
+}
+
+
+def _routed_programs(cell, device):
+    """(slots, decode step, bucket -> prefill) of a cell, lowered."""
+    from benchmark import model_spec, sizing
+
+    spec = model_spec.load_config(ROUTED_CELLS[cell])
+    with open(os.path.join(model_spec.HERE, "cells", f"{cell}.json")) as f:
+        deployment = json.load(f)["deployment"]
+    return (deployment["num_slots"],
+            *sizing.serve_programs(spec, deployment, device))
+
+
 @pytest.mark.parametrize("program", ["decode", "prefill64"])
 def test_state_space_cells_program_reads_each_weight_once(
         topo, one_chip, as_tpu, program):
@@ -487,14 +507,9 @@ def test_state_space_cells_program_reads_each_weight_once(
     fusions that take the stored matrix itself (no copy or slice of it
     beside them, none a rematerialisation), and each other large matrix
     of the step has one reader."""
-    from benchmark import model_spec, sizing
-
-    spec = model_spec.load_config("nemotron3-super-ep4-l11")
-    with open(os.path.join(model_spec.HERE, "cells",
-                           "serve-ssm-latent-moe-chat.json")) as f:
-        deployment = json.load(f)["deployment"]
-    assert deployment["num_slots"] == 192
-    decode, bucket = sizing.serve_programs(spec, deployment, topo.devices[0])
+    slots, decode, bucket = _routed_programs("serve-ssm-latent-moe-chat",
+                                             topo.devices[0])
+    assert slots == 192
     text = (decode if program == "decode" else bucket(64)).compile().as_text()
     users = _entry_users(text)
     weights = {}
@@ -528,13 +543,7 @@ def test_latent_cells_largest_bucket_attends_in_the_flash_kernel(
     the program. What peaks in the bucket's 1.00 GiB of temporaries is
     then the expert layer's float32 rows (the grouped product's (16,576 x
     7,168) output beside the combine's gather): held as the ceiling."""
-    from benchmark import model_spec, sizing
-
-    spec = model_spec.load_config("axk1-ep16-l5")
-    with open(os.path.join(model_spec.HERE, "cells",
-                           "serve-mla-moe-decode.json")) as f:
-        deployment = json.load(f)["deployment"]
-    _, bucket = sizing.serve_programs(spec, deployment, topo.devices[0])
+    _, _, bucket = _routed_programs("serve-mla-moe-decode", topo.devices[0])
     compiled = bucket(2048).compile()
     text = compiled.as_text()
     kernels = re.findall(r"= \([^=]*\) custom-call\([^\n]*"
@@ -544,6 +553,76 @@ def test_latent_cells_largest_bucket_attends_in_the_flash_kernel(
     # a layer's scores whole: any float32 (..., 2048, 2048) of several heads
     assert not re.findall(r"= f32\[(?:\d+,)+2048,2048\]", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 1.05 * 1024 ** 3
+
+
+# ------------------------------------ the grouped products' row tile (PR 45)
+# a grouped product in a lowered module: tile groups, n_active, the rows,
+# the held experts' stacked matrix
+_GROUPED = re.compile(
+    r"stablehlo\.custom_call @tpu_custom_call\([^\n]*grouped_expert_matmul"
+    r"[^\n]*: \(tensor<(\d+)xi32>, tensor<1xi32>, tensor<(\d+)x(\d+)xbf16>, "
+    r"tensor<(\d+)x\d+x\d+xbf16>\)")
+
+
+@pytest.mark.parametrize("cell", ROUTED_CELLS)
+def test_routed_cells_decode_step_keeps_the_16_row_tile(topo, as_tpu, cell):
+    """An expert gets 4-8 rows of a decode step: every grouped product
+    of the four routed cells' ``jit_step`` walks 16-row tiles (``M / 16``
+    tile groups) over the worst case's rows, as before the tile was a
+    rule."""
+    slots, decode, _ = _routed_programs(cell, topo.devices[0])
+    products = _GROUPED.findall(decode.as_text())
+    assert products
+    for tiles, rows, _, held in products:
+        tiles, rows, held = int(tiles), int(rows), int(held)
+        assert rows == tiles * 16
+        # T * top_k + G * 15 rounded up to tiles, for some top_k of 8 / 22
+        assert any(tiles == -(-(slots * k + held * 15) // 16)
+                   for k in (8, 22)), (tiles, held)
+
+
+def test_latent_cells_largest_bucket_takes_the_rules_tile(topo, as_tpu):
+    """`serve-mla-moe-decode`'s 2,048 bucket (85 rows an expert): its
+    twelve grouped products walk the tile that ``moe.row_tile`` gives at
+    (2,048, 8, 192, 12), the row buffer is the worst case at that tile,
+    and the bucket's temporaries stay under the ceiling held since
+    PR 44."""
+    from ray_tpu.models import moe
+
+    tm = moe.row_tile(2048, 8, 192, 12)
+    assert tm > 16
+    _, _, bucket = _routed_programs("serve-mla-moe-decode", topo.devices[0])
+    lowered = bucket(2048)
+    products = _GROUPED.findall(lowered.as_text())
+    assert len(products) == 12
+    tiles = -(-(2048 * 8 + 12 * (tm - 1)) // tm)
+    assert {(int(t), int(r)) for t, r, _, _ in products} == {
+        (tiles, tiles * tm)}
+    mem = lowered.compile().memory_analysis()
+    assert mem.temp_size_in_bytes < 1.05 * 1024 ** 3
+
+
+@pytest.mark.parametrize("K, N", [(7168, 256), (2048, 1024)])
+def test_grouped_matmul_compiles_at_128_rows(one_chip, K, N):
+    """The largest tile against the latent model's two blocks: (128 x
+    7,168) x (7,168 x 256) for gate and up, (128 x 2,048) x (2,048 x
+    1,024) for down, both operands double-buffered beside a float32
+    output inside the 16 MiB of scoped VMEM; ``_tn`` chooses those
+    columns from the stored widths."""
+    from ray_tpu.ops.pallas import grouped_matmul as gm
+
+    width = {256: 2048, 1024: 7168}[N]            # the stored matrix's
+    assert gm._tn(K, width, 2) == N
+    tm, tiles = 128, 140
+    fn = jax.jit(lambda l, w, g, n: gm.grouped_matmul(
+        l, w, g, n, tm=tm, out_dtype=jnp.float32,
+        name="grouped_expert_matmul_prefill"))
+    text = fn.lower(_sds((tiles * tm, K), jnp.bfloat16, one_chip),
+                    _sds((12, K, width), jnp.bfloat16, one_chip),
+                    _sds((tiles,), jnp.int32, one_chip),
+                    _sds((), jnp.int32, one_chip)).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "grouped_expert_matmul_prefill" in text
 
 
 # ------------------------------------------- the engine's pick of a token

@@ -250,3 +250,132 @@ def test_relu2_experts_on_a_latent_input_against_a_loop(held):
         np.asarray(got), np.maximum(np.asarray(lat) @ np.asarray(up[0]),
                                     0.0) ** 2 @ np.asarray(down[0]),
         rtol=2e-4, atol=2e-5)
+
+
+# ---------------------------------------------------------------------
+# The row tile of the grouped products is a rule over the call's static
+# shapes (PR 45): 16 where an expert gets a decode step's few rows, up to
+# 128 where a prefill's bucket sends it a hundred.
+
+# (tokens of the call, top_k, the router's width, experts held) -> the
+# tile. The decode steps of the four routed cells, then every prefill
+# bucket they run (and the check's own 256-token prompt).
+ROW_TILE_AT = {
+    "axk1-decode-192": ((192, 8, 192, 12), 16),
+    "mimo_v2-decode-128": ((128, 8, 256, 16), 16),
+    "laguna-decode-128": ((128, 8, 256, 256), 16),
+    "nemotron_h-decode-192": ((192, 22, 512, 128), 16),
+    "axk1-2048": ((2048, 8, 192, 12), 128),         # 85 rows an expert
+    "axk1-1024": ((1024, 8, 192, 12), 64),          # 43
+    "axk1-256": ((256, 8, 192, 12), 16),            # 11
+    "mimo_v2-1024": ((1024, 8, 256, 16), 64),       # 32
+    "mimo_v2-512": ((512, 8, 256, 16), 32),         # 16
+    "mimo_v2-256": ((256, 8, 256, 16), 16),         # 8
+    # the whole set held: 256 x (tm - 1) rows of padding against the pairs
+    "laguna-2048": ((2048, 8, 256, 256), 64),       # 64, not 128
+    "laguna-1024": ((1024, 8, 256, 256), 32),       # 32, not 64
+    "laguna-512": ((512, 8, 256, 256), 16),         # 16, not 32
+    "laguna-256": ((256, 8, 256, 256), 16),
+    "nemotron_h-64": ((64, 22, 512, 128), 16),      # 3
+    "nemotron_h-128": ((128, 22, 512, 128), 16),    # 6
+    "nemotron_h-256": ((256, 22, 512, 128), 16),    # 11
+    "nemotron_h-512": ((512, 22, 512, 128), 32),    # 22
+    "nemotron_h-1024": ((1024, 22, 512, 128), 64),  # 44
+    "one-token": ((1, 8, 256, 16), 16),
+    "past-the-largest": ((8192, 8, 64, 4), 128),
+}
+
+
+@pytest.mark.parametrize("call", ROW_TILE_AT)
+def test_row_tile_follows_the_rows_an_expert_expects(call):
+    shapes, tile = ROW_TILE_AT[call]
+    assert moe.row_tile(*shapes) == tile
+    T, top_k, _, held = shapes
+    if tile > moe.ROW_TILES[0]:
+        # the padding it can add to the worst case is no more than the pairs
+        assert held * (tile - 1) <= T * top_k
+
+
+def _dense_experts(x, layer, idx, w, first, count, valid):
+    """sum_k w_k SwiGLU_e(x) over the held experts, a loop in float64."""
+    x64 = np.asarray(x, np.float64)
+    want = np.zeros((x.shape[0], layer["we_down"].shape[2]))
+    for t in range(x.shape[0]):
+        if not valid[t]:
+            continue
+        for e, weight in zip(np.asarray(idx[t]), np.asarray(w[t])):
+            if first <= e < first + count:
+                g = x64[t] @ np.asarray(layer["we_gate"][e - first])
+                u = x64[t] @ np.asarray(layer["we_up"][e - first])
+                want[t] += weight * ((g / (1 + np.exp(-g)) * u)
+                                     @ np.asarray(layer["we_down"][e - first]))
+    return want
+
+
+@pytest.mark.parametrize("tile", moe.ROW_TILES)
+def test_every_tile_computes_the_worst_case_whole(tile):
+    """At a call whose shapes give ``tile``, every token's ONE choice is
+    the same held expert (a huge bias): that expert gets ``T * top_k``
+    rows, the worst case the row buffer is sized for; the rows past
+    ``T - 5`` are padding masked by ``valid``. Against a dense loop over
+    tokens: nothing is dropped, a masked row gives zeros."""
+    E, h, m, first, count = 4, 16, 8, 1, 3
+    T = tile * E - 2                # top_k 1: just under ``tile`` rows each
+    assert moe.row_tile(T, 1, E, count) == tile
+    layer = _held_layer(70 + tile, E=E, h=h, m=m, first=first, count=count)
+    layer["router_bias"] = jnp.zeros((E,)).at[2].set(50.0)
+    x = jax.random.normal(jax.random.key(71), (T, h), jnp.float32)
+    valid = jnp.arange(T) < T - 5
+    y, c = moe.experts_by_share(x, layer, experts_held=(first, count),
+                                top_k=1, scale=2.5, valid=valid)
+    idx, w = moe.route_sigmoid_topk(x, layer["router"],
+                                    layer["router_bias"], 1, 2.5)
+    assert (np.asarray(idx) == 2).all()
+    want = _dense_experts(x, layer, idx, w, first, count, np.asarray(valid))
+    np.testing.assert_allclose(np.asarray(y), want, rtol=2e-4, atol=2e-5)
+    assert not np.asarray(y)[T - 5:].any()
+    calls, pairs, hit, ratio, dropped = np.asarray(c)
+    assert (calls, pairs, hit, dropped) == (1, T - 5, 1, 0)
+    assert ratio == pytest.approx(count)          # all on one of three
+
+
+@pytest.mark.parametrize("tile", moe.ROW_TILES)
+def test_every_tile_gives_what_the_smallest_gives(tile, monkeypatch):
+    """A row's product does not depend on which tile holds it: a routed
+    call over a share (top_k 2, pairs spread over held and absent
+    experts) under each tile against the same call at 16 rows."""
+    T = 96
+    layer = _held_layer(80)
+    x = jax.random.normal(jax.random.key(81), (T, 16), jnp.float32)
+    kw = dict(experts_held=(2, 4), top_k=2, scale=2.5,
+              valid=jnp.arange(T) < T - 7)
+    monkeypatch.setattr(moe, "row_tile", lambda *a: moe.ROW_TILES[0])
+    want, c0 = moe.experts_by_share(x, layer, **kw)
+    monkeypatch.setattr(moe, "row_tile", lambda *a: tile)
+    y, c = moe.experts_by_share(x, layer, **kw)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), rtol=1e-6,
+                               atol=1e-6)
+    np.testing.assert_array_equal(np.asarray(c), np.asarray(c0))
+    assert float(c[4]) == 0 and 0 < float(c[1]) < 2 * (T - 7)
+
+
+@pytest.mark.parametrize("tm", [64, 128])
+def test_grouped_matmul_kernel_interpret_at_a_prefills_tiles(tm):
+    """The kernel in interpret mode against its oracle at the tiles a
+    prefill's bucket takes (the case at 16 is
+    ``tests/test_mimo_v2_serving.py``'s): six tiles of which four hold
+    rows, two of them of one group, a group without a tile; the rows of
+    the tiles past ``n_active`` are not compared (never written)."""
+    from ray_tpu.ops.pallas import grouped_matmul as gm
+
+    ks = jax.random.split(jax.random.key(13), 2)
+    lhs = jax.random.normal(ks[0], (6 * tm, 128), jnp.bfloat16)
+    rhs = jax.random.normal(ks[1], (5, 128, 256), jnp.bfloat16)
+    groups = jnp.array([0, 0, 2, 4, 4, 4], jnp.int32)
+    got = gm.grouped_matmul(lhs, rhs, groups, 4, tm=tm,
+                            out_dtype=jnp.float32, interpret=True)
+    want = gm.grouped_matmul_reference(lhs, rhs, groups, 4, tm=tm,
+                                       out_dtype=jnp.float32)
+    np.testing.assert_allclose(np.asarray(got[:4 * tm]),
+                               np.asarray(want[:4 * tm]), rtol=1e-5,
+                               atol=1e-5)
