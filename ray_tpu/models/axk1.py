@@ -52,7 +52,7 @@ import jax.numpy as jnp
 from ray_tpu.models import moe
 from ray_tpu.models.decoding import _bind_params
 from ray_tpu.models.paged_cache import KVStateManager, PagedConfig
-from ray_tpu.ops.attention import on_tpu, prompt_attention
+from ray_tpu.ops.attention import prompt_attention
 from ray_tpu.ops.norms import rmsnorm
 from ray_tpu.ops.pallas import paged_mla_decode_attention as mla
 from ray_tpu.ops.rope import YarnScaling, apply_rope, rope_frequencies
@@ -291,8 +291,8 @@ def make_decode_step(params: Params, cfg: AxK1Config, page: PagedConfig):
             # a slot that is not running attends nothing, whatever stale
             # length it keeps
             att_len = jnp.where(active, lengths + 1, 0)
-            work = (mla.mla_work_list(att_len, bs, page.max_blocks_per_seq)
-                    if on_tpu() else None)     # once for every layer
+            work = mla.paged_mla_decode_work(      # once for every layer
+                att_len, bs, page.max_blocks_per_seq)
         pool = cache[KIND]
         counters = jnp.zeros((len(moe.COUNTERS),), jnp.float32)
         for l, layer in enumerate(params["layers"]):
@@ -379,10 +379,6 @@ def make_prefill(params: Params, cfg: AxK1Config, page: PagedConfig):
 class AxK1Serving:
     """The model as :class:`ray_tpu.serve.llm.LLMEngine` takes it
     (:mod:`ray_tpu.models.serving`)."""
-
-    # engine mechanisms this model has no builders for yet
-    lacks = ("slot_cache", "speculation", "prefix_cache", "prefill_chunk",
-             "kv_transfer")
 
     def __init__(self, config: AxK1Config):
         self.config = config

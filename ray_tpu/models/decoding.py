@@ -17,7 +17,8 @@ design choices:
 **Where the block lives.** The dense decoder's arithmetic is
 :mod:`ray_tpu.models.llama`'s (``qkv``, ``mlp``, ``embed``, ``logits_f32``).
 :func:`dense_block` strings it into one serving block around an ``attend``
-function; :func:`attend_rows` is the one attention over cached rows. A
+function; :func:`ray_tpu.ops.attention.attend_rows` is the one attention
+over cached rows. A
 builder, here and in :mod:`ray_tpu.models.paged_cache`, adds its index
 arithmetic and its ``attend``: where this call's K and V rows are written,
 and what the queries attend over.
@@ -33,8 +34,9 @@ import jax.numpy as jnp
 
 from ray_tpu.models.llama import (LlamaConfig, Params, embed, logits_f32,
                                   mlp, qkv)
-from ray_tpu.ops.attention import mha_reference, on_tpu
+from ray_tpu.ops.attention import attend_rows, mha_reference
 from ray_tpu.ops.norms import rmsnorm
+from ray_tpu.ops.pallas.decode_attention import slot_decode
 from ray_tpu.ops.rope import rope_frequencies
 from ray_tpu.util.profiling import part
 
@@ -74,40 +76,6 @@ def dense_block(x, layer, c: LlamaConfig, cos, sin, positions, attend,
     with part("mlp"):
         x = x + mlp(rmsnorm(x, layer["mlp_norm"], c.norm_eps), layer)
     return x, state
-
-
-@part("attention")
-def attend_rows(q, ks, vs, q_pos, scale):
-    """q (B, C, H, D) over the row sets ks / vs (B, S, KV, D): key ``j``
-    is visible to query ``i`` of row ``b`` iff ``j <= q_pos[b, i]``
-    (absolute positions, so rows past a slot's length, stale or zero,
-    are never seen).
-
-    A GROUPED einsum (q reshaped (B, C, KV, group, D)), so the rows are
-    never materialized head-repeated — on a (slots, S, KV, D) cache that
-    repeat was group x cache-size of wasted HBM traffic per step."""
-    B, C, H, D = q.shape
-    S, KV = ks.shape[1:3]
-    qg = q.astype(jnp.float32).reshape(B, C, KV, H // KV, D)
-    s = jnp.einsum("bckgd,bskd->bkgcs", qg, ks.astype(jnp.float32)) * scale
-    allowed = jnp.arange(S)[None, None, :] <= q_pos[:, :, None]   # (B,C,S)
-    s = jnp.where(allowed[:, None, None], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bkgcs,bskd->bckgd", p, vs.astype(jnp.float32))
-    return out.reshape(B, C, H, D).astype(q.dtype)
-
-
-def _attend_cached(q, k_cache, v_cache, lengths, scale):
-    """q: (B, 1, H, D) new-token queries; k/v_cache: (B, S, KV, D);
-    lengths: (B,) valid prefix per slot (incl. the new token).
-
-    Dispatches to the Pallas flash-decoding kernel on TPU, to
-    :func:`attend_rows` elsewhere."""
-    if on_tpu():
-        from ray_tpu.ops.pallas.decode_attention import decode_attention
-
-        return decode_attention(q, k_cache, v_cache, lengths, scale=scale)
-    return attend_rows(q, k_cache, v_cache, lengths[:, None] - 1, scale)
 
 
 def _bind_params(jitted, params: Params):
@@ -190,8 +158,8 @@ def make_decode_step(params: Params, config: LlamaConfig):
             with part("kv_store"):
                 kc = kc.at[slot_ids, lengths].set(k[:, 0])
                 vc = vc.at[slot_ids, lengths].set(v[:, 0])
-            return (_attend_cached(q, kc, vc, lengths + 1,
-                                   c.head_dim ** -0.5), (kc, vc))
+            return (slot_decode(q, kc, vc, lengths + 1,
+                                scale=c.head_dim ** -0.5), (kc, vc))
 
         x = embed(params, tokens, c)[:, None, :]                 # (B,1,E)
         x, (new_k, new_v) = _scan_cache(attend, x, params, cache, c,

@@ -51,7 +51,7 @@ from ray_tpu.models import moe
 from ray_tpu.models import paged_cache as pc
 from ray_tpu.models.decoding import _bind_params
 from ray_tpu.models.paged_cache import KVStateManager, PagedConfig
-from ray_tpu.ops.attention import on_tpu, prompt_attention
+from ray_tpu.ops.attention import prompt_attention
 from ray_tpu.ops.norms import rmsnorm
 from ray_tpu.ops.pallas import paged_hybrid_decode_attention as pha
 from ray_tpu.ops.rope import YarnScaling, apply_rope, rope_frequencies
@@ -252,12 +252,9 @@ def _attend(q, kc, vc, li, tables, att_len, cfg, kind, work):
     lengths (None off the TPU, where the oracle attends)."""
     kw = dict(scale=cfg.scale(kind), k_slices=key_slices(cfg),
               dv=cfg.head_dim, window=cfg.window_of(kind))
-    if on_tpu():
-        return pha.paged_hybrid_decode_attention(
-            q, kc, vc, li, tables, att_len, work=work,
-            name=f"paged_hybrid_decode_{kind}", **kw)
-    return pha.paged_hybrid_attention_reference(q, kc, vc, li, tables,
-                                                att_len, **kw)
+    return pha.paged_hybrid_decode(q, kc, vc, li, tables, att_len,
+                                   work=work,
+                                   name=f"paged_hybrid_decode_{kind}", **kw)
 
 
 # ---------------------------------------------------------------- programs
@@ -276,8 +273,7 @@ def make_decode_step(params: Params, cfg: LagunaConfig,
             x = params["embed"].astype(cfg.dtype)[tokens][:, None, :]
         blk, off, att_len = pc.hybrid_decode_rows(tables, lengths, active,
                                                   bs)
-        work = (pc.hybrid_decode_work(att_len, page, cfg.window)
-                if on_tpu() else dict.fromkeys(KINDS))
+        work = pc.hybrid_decode_work(att_len, page, cfg.window)
         pools = pc.hybrid_pools(cache)
         index = dict.fromkeys(KINDS, 0)
         counters = jnp.zeros((len(moe.COUNTERS),), jnp.float32)
@@ -365,10 +361,6 @@ def make_prefill(params: Params, cfg: LagunaConfig,
 class LagunaServing:
     """The model as :class:`ray_tpu.serve.llm.LLMEngine` takes it
     (:mod:`ray_tpu.models.serving`)."""
-
-    # engine mechanisms this model has no builders for yet
-    lacks = ("slot_cache", "speculation", "prefix_cache", "prefill_chunk",
-             "kv_transfer")
 
     def __init__(self, config: LagunaConfig):
         self.config = config

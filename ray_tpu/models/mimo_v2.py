@@ -46,7 +46,7 @@ from ray_tpu.models import moe
 from ray_tpu.models import paged_cache as pc
 from ray_tpu.models.decoding import _bind_params
 from ray_tpu.models.paged_cache import KVStateManager, PagedConfig
-from ray_tpu.ops.attention import on_tpu, prompt_attention
+from ray_tpu.ops.attention import prompt_attention
 from ray_tpu.ops.norms import rmsnorm
 from ray_tpu.ops.pallas import paged_hybrid_decode_attention as pha
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
@@ -256,12 +256,9 @@ def _attend(q, kc, vc, li, tables, att_len, cfg, kind, sink, work):
               dv=cfg.v_head_dim, sink=sink, window=_window(cfg, kind))
     with part("attn_proj"):
         qp = pack_queries(q, cfg, kind)
-    if on_tpu():
-        return pha.paged_hybrid_decode_attention(
-            qp, kc, vc, li, tables, att_len, work=work,
-            name=f"paged_hybrid_decode_{kind}", **kw)
-    return pha.paged_hybrid_attention_reference(qp, kc, vc, li, tables,
-                                                att_len, **kw)
+    return pha.paged_hybrid_decode(qp, kc, vc, li, tables, att_len,
+                                   work=work,
+                                   name=f"paged_hybrid_decode_{kind}", **kw)
 
 
 # ---------------------------------------------------------------- programs
@@ -280,8 +277,7 @@ def make_decode_step(params: Params, cfg: MimoV2Config,
             x = params["embed"].astype(cfg.dtype)[tokens][:, None, :]
         blk, off, att_len = pc.hybrid_decode_rows(tables, lengths, active,
                                                   bs)
-        work = (pc.hybrid_decode_work(att_len, page, cfg.window)
-                if on_tpu() else dict.fromkeys(KINDS))
+        work = pc.hybrid_decode_work(att_len, page, cfg.window)
         pools = pc.hybrid_pools(cache)
         index = dict.fromkeys(KINDS, 0)
         counters = jnp.zeros((len(moe.COUNTERS),), jnp.float32)
@@ -369,10 +365,6 @@ def make_prefill(params: Params, cfg: MimoV2Config,
 class MimoV2Serving:
     """The model as :class:`ray_tpu.serve.llm.LLMEngine` takes it
     (:mod:`ray_tpu.models.serving`)."""
-
-    # engine mechanisms this model has no builders for yet
-    lacks = ("slot_cache", "speculation", "prefix_cache", "prefill_chunk",
-             "kv_transfer")
 
     def __init__(self, config: MimoV2Config):
         self.config = config

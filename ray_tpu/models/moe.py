@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import llama
-from ray_tpu.ops.attention import on_tpu
 from ray_tpu.ops.norms import rmsnorm
 from ray_tpu.ops.rope import rope_frequencies
 from ray_tpu.parallel.sharding import ShardingRules, with_logical_constraint
@@ -387,7 +386,6 @@ def shared_expert(x, layer: Params):
 
 def experts_by_share(x, layer: Params, *, experts_held: Tuple[int, int],
                      top_k: int, scale: float = 1.0, valid=None,
-                     use_kernel: Optional[bool] = None,
                      kernel_name: str = "grouped_expert_matmul",
                      n_group: int = 1, topk_group: int = 1,
                      score: str = "sigmoid", x_experts=None):
@@ -465,11 +463,7 @@ def experts_by_share(x, layer: Params, *, experts_held: Tuple[int, int],
         tile_group = jnp.minimum(
             jnp.searchsorted(pend, tile * tm, side="right"), G - 1)
 
-    if use_kernel is None:
-        use_kernel = on_tpu()
-    mm = functools.partial(
-        gm.grouped_matmul if use_kernel else gm.grouped_matmul_reference,
-        name=kernel_name)
+    mm = functools.partial(gm.grouped_product, name=kernel_name)
     with part("expert_layer"):
         gated = "we_gate" in layer
         if gated:

@@ -62,9 +62,10 @@ from ray_tpu.models import moe
 from ray_tpu.models import paged_cache as pc
 from ray_tpu.models.decoding import _bind_params
 from ray_tpu.models.paged_cache import KVStateManager, PagedConfig
-from ray_tpu.ops.attention import mha_reference, on_tpu
+from ray_tpu.ops.attention import mha_reference
 from ray_tpu.ops.norms import rmsnorm
-from ray_tpu.ops.pallas import ssm_decode_update as ssm_kernel
+from ray_tpu.ops.pallas.paged_decode_attention import paged_decode
+from ray_tpu.ops.pallas.ssm_decode_update import ssm_decode
 from ray_tpu.util.profiling import part
 
 Params = Dict[str, Any]
@@ -345,10 +346,8 @@ def _mamba_decode(x, layer, cfg, ssm, conv, li, active):
         P = cfg.ssm_head_dim
         xdt = xs.astype(F32) * jnp.repeat(dt, P, axis=-1).reshape(xs.shape)
         decay = jnp.repeat(jnp.exp(dta), P, axis=-1).reshape(xs.shape)
-    update = (ssm_kernel.ssm_decode_update if on_tpu()
-              else ssm_kernel.ssm_decode_update_reference)
-    ssm, y = update(ssm, li, xdt, decay, b.astype(F32), c.astype(F32),
-                    active)
+    ssm, y = ssm_decode(ssm, li, xdt, decay, b.astype(F32), c.astype(F32),
+                        active)
     return _out_proj(x, _gate_norm(y, xs, z, layer, cfg), layer), ssm, conv
 
 
@@ -480,8 +479,8 @@ def make_decode_step(params: Params, cfg: NemotronHConfig, page: PagedConfig):
             elif kind == "*":
                 q, k, v = _qkv(x, layer, cfg)
                 pools = pc.store_kv_rows(pools, (li, blk, off), k, v)
-                out = pc._attend_paged(q[:, None], *pools, li, table,
-                                       att_len, scale, work)
+                out = paged_decode(q[:, None], *pools, li, table, att_len,
+                                   scale=scale, work=work)
                 x = _attn_out(x, out[:, 0], layer)
             else:
                 x, c = _experts(x, layer, cfg, active)
@@ -558,10 +557,6 @@ def make_prefill(params: Params, cfg: NemotronHConfig, page: PagedConfig):
 class NemotronHServing:
     """The model as :class:`ray_tpu.serve.llm.LLMEngine` takes it
     (:mod:`ray_tpu.models.serving`)."""
-
-    # engine mechanisms this model has no builders for yet
-    lacks = ("slot_cache", "speculation", "prefix_cache", "prefill_chunk",
-             "kv_transfer")
 
     def __init__(self, config: NemotronHConfig):
         self.config = config

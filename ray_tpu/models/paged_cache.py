@@ -58,7 +58,9 @@ import numpy as np
 from ray_tpu.models.decoding import (_bind_padded, _bind_params,
                                       attend_rows, dense_block)
 from ray_tpu.models.llama import LlamaConfig, Params, embed, logits_f32
-from ray_tpu.ops.attention import mha_reference, on_tpu
+from ray_tpu.ops.attention import mha_reference
+from ray_tpu.ops.pallas.paged_decode_attention import (paged_decode,
+                                                       paged_decode_work)
 from ray_tpu.ops.rope import rope_frequencies
 from ray_tpu.util.profiling import part
 
@@ -121,6 +123,14 @@ class BlockAllocator:
 
     def free_blocks(self) -> int:
         return len(self._free)
+
+    def pools(self, slot_lengths=()) -> Dict[str, dict]:
+        """One kind of state: no row by kind for ``stats()``."""
+        return {}
+
+    def trim(self, slot: int, tokens: int) -> int:
+        """No window passes anything: nothing goes back before the end."""
+        return 0
 
     def blocks_for(self, tokens: int) -> int:
         return -(-tokens // self.page.block_size)
@@ -507,11 +517,11 @@ def hybrid_prefill_blocks(table_rows, true_len, nblk: int, block_size: int):
 def hybrid_decode_work(att_len, page: Dict[str, PagedConfig], window: int):
     """The hybrid decode kernel's work list of each kind for a decode
     step's lengths, built before the layer loop so that a kind's layers
-    share it."""
+    share it (None off the TPU, where the oracle attends)."""
     from ray_tpu.ops.pallas.paged_hybrid_decode_attention import (
-        hybrid_work_list)
+        paged_hybrid_decode_work)
 
-    return {kind: hybrid_work_list(
+    return {kind: paged_hybrid_decode_work(
         att_len, page[kind].block_size, page[kind].max_blocks_per_seq,
         window if kind == "window" else None) for kind in HYBRID_KINDS}
 
@@ -541,30 +551,8 @@ def _decode_work(lengths, page: PagedConfig):
     """The kernel's work list for a decode step's lengths, built before
     the layer scan so that every layer shares it (None off the TPU, where
     the oracle attends)."""
-    if not on_tpu():
-        return None
-    from ray_tpu.ops.pallas.paged_decode_attention import decode_work_list
-
-    return decode_work_list(lengths, page.block_size,
-                            page.max_blocks_per_seq)
-
-
-def _attend_paged(q, k_pool, v_pool, layer, tables, lengths, scale, work):
-    """q (B,1,H,D); pools (L,NB,bs,KV,D), whole; layer () i32; tables
-    (B,MBS); lengths (B,); work from :func:`_decode_work` of the same
-    lengths. The pool is handed over as the layer scan carries it: a
-    per-layer slice here would be a copy of that layer."""
-    if on_tpu():
-        from ray_tpu.ops.pallas.paged_decode_attention import (
-            paged_decode_attention)
-
-        return paged_decode_attention(q, k_pool, v_pool, layer, tables,
-                                      lengths, scale=scale, work=work)
-    from ray_tpu.ops.pallas.paged_decode_attention import (
-        paged_attention_reference)
-
-    return paged_attention_reference(q, k_pool, v_pool, layer, tables,
-                                     lengths, scale=scale)
+    return paged_decode_work(lengths, page.block_size,
+                             page.max_blocks_per_seq)
 
 
 def _scan_layers(attend, x, params: Params, cache: PagedCache,
@@ -693,8 +681,10 @@ def make_paged_decode_step(params: Params, config: LlamaConfig,
             kc, vc, l = state
             kc, vc = store_kv_rows((kc, vc), (l, blk, off), k[:, 0],
                                    v[:, 0])
-            out = _attend_paged(q, kc, vc, l, tables, att_len,
-                                c.head_dim ** -0.5, work)
+            # the pool goes as the layer scan carries it, whole: a
+            # per-layer slice here would be a copy of that layer
+            out = paged_decode(q, kc, vc, l, tables, att_len,
+                               scale=c.head_dim ** -0.5, work=work)
             return out, (kc, vc)
 
         x = embed(params, tokens, c)[:, None, :]                  # (B,1,E)
@@ -821,6 +811,14 @@ def extract_kv(cache: PagedCache, allocator: BlockAllocator, slot: int,
     k = k.reshape(L, nblk * bs, KV, D)[:, :true_len]
     v = v.reshape(L, nblk * bs, KV, D)[:, :true_len]
     return np.asarray(k), np.asarray(v)
+
+
+def prompt_bucket(page: PagedConfig):
+    """``pad(n)``: the padded length of a prompt of ``n`` tokens in a
+    pool of ``page``'s geometry, a bucket of whole blocks, at most a
+    sequence's blocks."""
+    cap = page.max_blocks_per_seq * page.block_size
+    return lambda n: min(pad_to_block_bucket(n, page.block_size), cap)
 
 
 def pad_to_block_bucket(n: int, block_size: int,

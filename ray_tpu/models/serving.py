@@ -1,36 +1,59 @@
 """What the serving engine (:class:`ray_tpu.serve.llm.LLMEngine`) asks
 of a model: one small interface, so that a decoder whose layers, caches
-and programs are not the dense one's is served by the same loop.
+and programs are not the dense one's is served by the same loop. Every
+program the engine runs, every cache it holds and every fact of the
+cache's geometry it uses comes from here; this text is the interface's
+only description.
 
 A serving model has
 
     config            with ``vocab_size`` and ``max_seq``
-    lacks             the engine mechanisms it has no builders for, out
-                      of ``MECHANISMS``; asking for one raises at
-                      construction, naming it
     init_params(key)  weights, where the caller brings none
     paged(params, *, num_slots, max_seq, block_size, pool_tokens)
                       -> :class:`PagedPrograms`
 
-The dense decoder (:class:`DenseDecoder` around a ``LlamaConfig``) gives
-the builders of :mod:`ray_tpu.models.paged_cache` as they are; its other
-mechanisms (slot cache, speculation, prefix caches, chunked prefill, KV
-transfer) stay in the engine, built from its ``LlamaConfig``. A config
-class of another model names its own through ``serving_model()``.
+and ONE MORE BUILDER FOR EACH ENGINE MECHANISM IT HAS. A model without
+the builder has no such attribute, and the engine refuses the mechanism
+by name where it is asked for (at construction; ``submit_prefilled`` at
+the call):
+
+    slot(params, *, num_slots, max_seq) -> :class:`PagedPrograms`
+                      ``kv_cache="slot"``: a cache that reserves
+                      ``max_seq`` rows a slot, in the shape of the paged
+                      one (:class:`SlotReservation` for an allocator)
+    chunked_prefill(params, programs, chunk=None)
+                      ``prefill_chunk``, and the suffix after a prefix
+                      hit: ``call(cache, alloc.table_rows(slot), tokens
+                      (1, C), tokens in the chunk, start, slot) ->
+                      (cache, logits)`` for the cache of ``programs``;
+                      ``chunk`` is the C the engine will always call it
+                      with, refused here if the program cannot take it
+    speculative_verify(params) -> (verify, install_lengths)
+                      ``speculation``, on the slot cache:
+                      ``verify(cache, tokens (B, C), true_lens (B,),
+                      starts (B,)) -> (cache, logits (B, C, vocab))`` and
+                      ``install_lengths(length, new, touched) -> length``
+    block_copy(programs) -> ``copy(cache, src, dst) -> cache``
+    block_bytes(programs) -> bytes of KV state in one block
+                      a prefix cache (with ``chunked_prefill``): the copy
+                      on write of a shared block, and what the cache's
+                      budget is counted in
+    kv_shape(tokens)  KV transfer: the ``(layers, tokens, kv_heads,
+                      head_dim)`` of the k and of the v that
+                      ``PagedPrograms.inject`` takes for a prompt
+
+The dense decoder (:class:`DenseDecoder` around a ``LlamaConfig``) has
+them all, each a call of the builder in :mod:`ray_tpu.models.decoding`,
+:mod:`ray_tpu.models.paged_cache` or :mod:`ray_tpu.models.speculation`.
+A config class of another model names its own serving model through
+``serving_model()``.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Callable, Optional, Tuple
-
-MECHANISMS = {
-    "slot_cache": "kv_cache='slot'",
-    "speculation": "speculation",
-    "prefix_cache": "a prefix cache (prefix_cache / prefix_cache_bytes)",
-    "prefill_chunk": "chunked prefill (prefill_chunk)",
-    "kv_transfer": "KV inject / extract (llm_pd, submit_prefilled)",
-}
 
 
 @dataclasses.dataclass
@@ -39,10 +62,14 @@ class PagedPrograms:
     ``KVStateManager`` (one allocator for each kind of KV state);
     ``prefill(cache, alloc.table_rows(slot), tokens (1, P), true_len,
     slot)`` and ``decode(cache, alloc.device_tables(), tokens (B,),
-    active (B,))`` return ``(cache, logits)``; ``page`` is the geometry
-    of the state that keeps the whole sequence. ``counters`` names the
-    entries of ``cache["counters"]``, which the engine fetches with the
-    logits and sums in ``stats()["model_counters"]``."""
+    active (B,))`` return ``(cache, logits)``; ``inject(cache,
+    alloc.table_rows(slot), k, v, true_len, slot)`` returns the cache
+    with a prompt's KV rows written; ``page`` is the geometry of the
+    state that keeps the whole sequence; ``pad(n)`` is the padded length
+    a prompt of ``n`` tokens is given (left out: a bucket of whole blocks
+    of ``page``). ``counters`` names the entries of
+    ``cache["counters"]``, which the engine fetches with the logits and
+    sums in ``stats()["model_counters"]``."""
 
     alloc: Any
     cache: Any
@@ -51,13 +78,62 @@ class PagedPrograms:
     page: Any
     inject: Optional[Callable] = None
     counters: Tuple[str, ...] = ()
+    pad: Optional[Callable[[int], int]] = None
+
+    def __post_init__(self):
+        if self.pad is None:
+            from ray_tpu.models.paged_cache import prompt_bucket
+
+            self.pad = prompt_bucket(self.page)
+
+
+class SlotReservation:
+    """The slot cache's allocator: the flat reservation written down in
+    the paged allocators' interface. Every slot holds ``max_seq`` rows
+    from the start, so a sequence that long always fits, nothing grows,
+    nothing is given back, and there is no table and no block to report."""
+
+    def __init__(self, max_seq: int):
+        self.max_seq = max_seq
+
+    def fits(self, tokens: int) -> bool:
+        return tokens <= self.max_seq
+
+    def lacking(self, tokens: int, shared: int = 0, headroom: int = 0
+                ) -> int:
+        return 0
+
+    def ensure(self, slot: int, tokens: int) -> bool:
+        return True
+
+    def trim(self, slot: int, tokens: int) -> int:
+        return 0
+
+    def release(self, slot: int) -> None:
+        pass
+
+    def table_rows(self, slot: int):
+        return None
+
+    def device_tables(self):
+        return None
+
+    def free_blocks(self):
+        return None
+
+    def pools(self, slot_lengths=()) -> dict:
+        return {}
+
+
+def _without_table(program):
+    """A slot program behind the paged call: the table is taken and
+    dropped."""
+    return lambda cache, _table, *args: program(cache, *args)
 
 
 class DenseDecoder:
     """The dense decoder of :mod:`ray_tpu.models.llama` behind the
     interface, by the builders it has."""
-
-    lacks: Tuple[str, ...] = ()
 
     def __init__(self, config):
         self.config = config
@@ -82,6 +158,59 @@ class DenseDecoder:
             decode=make_paged_decode_step(params, self.config, page),
             page=page, inject=make_paged_inject(self.config, page))
 
+    def slot(self, params, *, num_slots: int, max_seq: int
+             ) -> PagedPrograms:
+        """The dense decoder's second cache: ONE block of ``max_seq``
+        rows a slot, and the slot programs, which take no table."""
+        from ray_tpu.models.decoding import (
+            init_cache, make_decode_step, make_inject, make_prefill,
+            pad_to_bucket)
+        from ray_tpu.models.paged_cache import PagedConfig
+
+        return PagedPrograms(
+            alloc=SlotReservation(max_seq),
+            cache=init_cache(self.config, num_slots, max_seq),
+            prefill=_without_table(make_prefill(params, self.config)),
+            decode=_without_table(make_decode_step(params, self.config)),
+            page=PagedConfig(num_blocks=1 + num_slots, block_size=max_seq,
+                             max_seq=max_seq),
+            inject=_without_table(make_inject(self.config)),
+            pad=lambda n: min(pad_to_bucket(n), max_seq))
+
+    def chunked_prefill(self, params, programs: PagedPrograms,
+                        chunk: Optional[int] = None):
+        if isinstance(programs.alloc, SlotReservation):
+            from ray_tpu.models.decoding import make_chunked_prefill
+
+            return _without_table(make_chunked_prefill(params, self.config))
+        from ray_tpu.models.paged_cache import make_chunked_paged_prefill
+
+        if chunk is not None and chunk % programs.page.block_size:
+            raise ValueError(
+                f"prefill_chunk={chunk} must be a multiple of "
+                f"kv_block_size={programs.page.block_size}")
+        return make_chunked_paged_prefill(params, self.config, programs.page)
+
+    def speculative_verify(self, params):
+        from ray_tpu.models.decoding import make_batched_spec_verify
+        from ray_tpu.models.speculation import make_length_installer
+
+        return (make_batched_spec_verify(params, self.config),
+                make_length_installer())
+
+    def block_copy(self, programs: PagedPrograms):
+        from ray_tpu.models.paged_cache import make_block_copy
+
+        return make_block_copy(self.config, programs.page)
+
+    def block_bytes(self, programs: PagedPrograms) -> int:
+        return (2 * programs.cache["k"].dtype.itemsize
+                * math.prod(self.kv_shape(programs.page.block_size)))
+
+    def kv_shape(self, tokens: int) -> Tuple[int, int, int, int]:
+        c = self.config
+        return (c.n_layers, tokens, c.n_kv_heads, c.head_dim)
+
 
 def init_from_shapes(shapes, key, std: float, stds: dict, dtype):
     """Seeded weights for a tree of shapes (tuples): each leaf a normal
@@ -98,8 +227,13 @@ def init_from_shapes(shapes, key, std: float, stds: dict, dtype):
         for k, (path, shape) in zip(keys, leaves)])
 
 
-def serving_model(config):
+def serving_model(config=None, preset: str = "tiny"):
     """The serving model of a config object: its own
-    (``config.serving_model()``), or the dense decoder's."""
+    (``config.serving_model()``), or the dense decoder's. Without a
+    config: the dense decoder at the ``preset`` of that name."""
+    if config is None:
+        from ray_tpu.models import llama
+
+        config = llama.CONFIGS[preset]
     own = getattr(config, "serving_model", None)
     return own() if own is not None else DenseDecoder(config)

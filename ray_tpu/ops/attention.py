@@ -48,6 +48,27 @@ def mha_reference(q, k, v, causal: bool = True, scale: Optional[float] = None):
     return jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32)).astype(q.dtype)
 
 
+@part("attention")
+def attend_rows(q, ks, vs, q_pos, scale):
+    """q (B, C, H, D) over the row sets ks / vs (B, S, KV, D): key ``j``
+    is visible to query ``i`` of row ``b`` iff ``j <= q_pos[b, i]``
+    (absolute positions, so rows past a slot's length, stale or zero,
+    are never seen).
+
+    A GROUPED einsum (q reshaped (B, C, KV, group, D)), so the rows are
+    never materialized head-repeated — on a (slots, S, KV, D) cache that
+    repeat was group x cache-size of wasted HBM traffic per step."""
+    B, C, H, D = q.shape
+    S, KV = ks.shape[1:3]
+    qg = q.astype(jnp.float32).reshape(B, C, KV, H // KV, D)
+    s = jnp.einsum("bckgd,bskd->bkgcs", qg, ks.astype(jnp.float32)) * scale
+    allowed = jnp.arange(S)[None, None, :] <= q_pos[:, :, None]   # (B,C,S)
+    s = jnp.where(allowed[:, None, None], s, -1e30)
+    p = jax.nn.softmax(s, axis=-1)
+    out = jnp.einsum("bkgcs,bskd->bckgd", p, vs.astype(jnp.float32))
+    return out.reshape(B, C, H, D).astype(q.dtype)
+
+
 def _sink_softmax(s, sink):
     """Softmax over the last axis of ``s`` (..., H-major as given) with an
     optional per-head sink logit in the denominator only: the sink takes

@@ -32,6 +32,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ray_tpu.ops import attention
+
 
 NEG_INF = -1e30
 _LANES = 128
@@ -142,3 +144,13 @@ def decode_attention(q, k_cache, v_cache, lengths, *, scale: float,
     )(lengths.astype(jnp.int32), qh, k_cache, v_cache)
 
     return out.reshape(B, 1, H, D)
+
+
+def slot_decode(q, k_cache, v_cache, lengths, *, scale: float):
+    """The kernel on a TPU; elsewhere its oracle,
+    :func:`ray_tpu.ops.attention.attend_rows` with each slot's query at
+    its last row."""
+    if attention.on_tpu():
+        return decode_attention(q, k_cache, v_cache, lengths, scale=scale)
+    return attention.attend_rows(q, k_cache, v_cache, lengths[:, None] - 1,
+                                 scale)

@@ -42,6 +42,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ray_tpu.ops import attention
 from ray_tpu.util.profiling import part
 
 NAME = "grouped_expert_matmul"
@@ -119,3 +120,11 @@ def grouped_matmul_reference(lhs, rhs, tile_group, n_active, *, tm: int,
         live = jnp.arange(M // tm) < n_active
         return jnp.where(live[:, None, None], out, 0.0).reshape(
             M, -1).astype(out_dtype)
+
+
+def grouped_product(lhs, rhs, tile_group, n_active, *, tm: int,
+                    out_dtype=None, name: str = NAME):
+    """The kernel on a TPU, its oracle elsewhere."""
+    mm = grouped_matmul if attention.on_tpu() else grouped_matmul_reference
+    return mm(lhs, rhs, tile_group, n_active, tm=tm, out_dtype=out_dtype,
+              name=name)

@@ -53,6 +53,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ray_tpu.ops import attention
 from ray_tpu.util.profiling import part
 
 
@@ -232,8 +233,7 @@ def paged_attention_reference(q, k_pool, v_pool, layer, tables, lengths, *,
                               scale: float):
     """XLA path (and the kernel's correctness oracle), same arguments as
     the kernel: gather the per-slot cache of one layer via the block
-    table, then grouped-einsum attention. Used on CPU and as the
-    non-Pallas fallback in ``models.paged_cache``."""
+    table, then grouped-einsum attention."""
     B, _, H, D = q.shape
     bs, KV = k_pool.shape[2], k_pool.shape[3]
     MBS = tables.shape[1]
@@ -248,3 +248,22 @@ def paged_attention_reference(q, k_pool, v_pool, layer, tables, lengths, *,
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bkgs,bskd->bkgd", p, v.astype(jnp.float32))
     return out.reshape(B, 1, H, D).astype(q.dtype)
+
+
+def paged_decode_work(lengths, block_s: int, max_blocks: int):
+    """:func:`decode_work_list` where :func:`paged_decode` runs the
+    kernel; None where its oracle attends, which walks no list. Built
+    once a decode step, before the layer scan, for every layer."""
+    if not attention.on_tpu():
+        return None
+    return decode_work_list(lengths, block_s, max_blocks)
+
+
+def paged_decode(q, k_pool, v_pool, layer, tables, lengths, *, scale: float,
+                 work=None):
+    """The kernel on a TPU, its oracle elsewhere."""
+    if attention.on_tpu():
+        return paged_decode_attention(q, k_pool, v_pool, layer, tables,
+                                      lengths, scale=scale, work=work)
+    return paged_attention_reference(q, k_pool, v_pool, layer, tables,
+                                     lengths, scale=scale)

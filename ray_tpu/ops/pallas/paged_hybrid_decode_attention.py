@@ -58,6 +58,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ray_tpu.ops import attention
 from ray_tpu.ops.pallas.paged_decode_attention import decode_work_list
 from ray_tpu.util.profiling import part
 
@@ -321,3 +322,25 @@ def paged_hybrid_attention_reference(
         den = den + jnp.exp(sk - m)
     out = jnp.einsum("bkgs,bskd->bkgd", p / den, rows_v)
     return out.reshape(B, H, dv).astype(q.dtype)
+
+
+def paged_hybrid_decode_work(lengths, block_s: int, max_blocks: int,
+                             window: Optional[int] = None):
+    """:func:`hybrid_work_list` where :func:`paged_hybrid_decode` runs
+    the kernel; None where its oracle attends, which walks no list. Built
+    once a decode step for all the layers of a kind."""
+    if not attention.on_tpu():
+        return None
+    return hybrid_work_list(lengths, block_s, max_blocks, window)
+
+
+def paged_hybrid_decode(q, k_pool, v_pool, layer, tables, lengths, *,
+                        work=None, name: Optional[str] = None, **kw):
+    """The kernel on a TPU, its oracle elsewhere; ``kw`` is what both
+    take (``scale``, ``k_slices``, ``dv``, ``window``, ``sink``)."""
+    if attention.on_tpu():
+        return paged_hybrid_decode_attention(
+            q, k_pool, v_pool, layer, tables, lengths, work=work, name=name,
+            **kw)
+    return paged_hybrid_attention_reference(q, k_pool, v_pool, layer, tables,
+                                            lengths, **kw)

@@ -39,7 +39,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ray_tpu.ops.attention import on_tpu
+from ray_tpu.ops import attention
 from ray_tpu.ops.pallas.paged_decode_attention import decode_work_list
 from ray_tpu.util.profiling import part
 
@@ -187,10 +187,18 @@ def paged_mla_attention_reference(q, pool, layer, tables, lengths, *,
     return jnp.where((lengths > 0)[:, None, None], out, 0).astype(q.dtype)
 
 
+def paged_mla_decode_work(lengths, block_s: int, max_blocks: int):
+    """:func:`mla_work_list` where :func:`paged_mla_decode` runs the
+    kernel; None where its oracle attends, which walks no list."""
+    if not attention.on_tpu():
+        return None
+    return mla_work_list(lengths, block_s, max_blocks)
+
+
 def paged_mla_decode(q, pool, layer, tables, lengths, *, scale: float,
                      rank: int, work=None):
     """The kernel on a TPU, its oracle elsewhere."""
-    if on_tpu():
+    if attention.on_tpu():
         return paged_mla_decode_kernel(q, pool, layer, tables, lengths,
                                        scale=scale, rank=rank, work=work)
     return paged_mla_attention_reference(q, pool, layer, tables, lengths,

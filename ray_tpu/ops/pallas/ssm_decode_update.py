@@ -41,6 +41,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ray_tpu.ops import attention
 from ray_tpu.util.profiling import part
 
 NAME = "ssm_decode_update"
@@ -146,3 +147,10 @@ def ssm_decode_update_reference(state, layer: int, xdt, decay, b, c, active):
     y = jnp.sum(new * c[..., None].astype(jnp.float32), axis=2)
     return (state.at[layer].set(new),
             jnp.where(active[:, None, None], y, 0.0))
+
+
+def ssm_decode(state, layer: int, xdt, decay, b, c, active):
+    """The kernel on a TPU, its oracle elsewhere."""
+    update = (ssm_decode_update if attention.on_tpu()
+              else ssm_decode_update_reference)
+    return update(state, layer, xdt, decay, b, c, active)

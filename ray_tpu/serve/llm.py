@@ -16,21 +16,24 @@ bookkeeping, the freeing, the growing and the admitting run while step
 N+1 does (:meth:`LLMEngine._decode_turn`).
 
 The KV cache is paged by default: a shared pool of fixed-size blocks with
-host-side block tables (:mod:`ray_tpu.models.paged_cache`). The model
-stands behind :mod:`ray_tpu.models.serving`: it brings its pool(s), its
-allocator and its two programs, prefill (one compile a padded-length
-bucket) and decode (one compile). For the dense decoder the engine itself
-adds, when asked: the chunked prefill, the radix prefix cache
-(:mod:`ray_tpu.models.prefix_cache`) with its block copy, KV inject for
-prefill/decode disaggregation, and, on ``kv_cache="slot"`` only (the flat
-per-slot cache of :mod:`ray_tpu.models.decoding`, with its own prefill,
-chunk and decode programs), speculation's batched verify. Shapes are
+host-side block tables. The model stands behind
+:mod:`ray_tpu.models.serving`, which describes the whole interface: it
+brings its pool(s), its allocator and its two programs, prefill (one
+compile a padded-length bucket) and decode (one compile), and one more
+builder for each mechanism it has: the chunked prefill, the block copy
+under the radix prefix cache (:mod:`ray_tpu.models.prefix_cache`), KV
+inject for prefill/decode disaggregation, speculation's batched verify,
+and ``kv_cache="slot"`` (a flat ``max_seq`` reservation a slot, handed
+over in the paged cache's shape, so the loop below is written once). The
+engine builds no program of its own but the three that pick tokens, and
+refuses by name a mechanism the model has no builder for. Shapes are
 fixed, so nothing compiles in steady state.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import queue
 import threading
 import time
@@ -206,25 +209,27 @@ class LLMEngine:
 
         import jax
 
-        from ray_tpu.models import llama
-        from ray_tpu.models.decoding import (
-            init_cache, make_batched_spec_verify, make_chunked_prefill,
-            make_decode_step, make_inject, make_prefill)
-
         from ray_tpu.common.compile_cache import compile_cache_counts
-
         from ray_tpu.models.serving import serving_model
 
-        self.config = config or llama.CONFIGS[model]
-        self.model = serving_model(self.config)
-        asked = {"slot_cache": kv_cache == "slot",
-                 "speculation": speculation is not None,
-                 "prefix_cache": (prefix_cache not in (None, "off")
-                                  or (prefix_cache_bytes or 0) > 0),
-                 "prefill_chunk": prefill_chunk is not None}
-        for mechanism in self.model.lacks:
-            if asked.get(mechanism):
-                self._refuse(mechanism)
+        self.model = serving_model(config, model)
+        self.config = self.model.config
+        self.num_slots = num_slots
+        self.max_seq = max_seq or self.config.max_seq
+        self.kv_cache = kv_cache
+        if kv_cache == "paged":
+            if kv_block_size <= 0 or 2048 % kv_block_size:
+                # must divide the prompt padding buckets, or a padded
+                # prompt is no multiple of it and crashes every prefill
+                raise ValueError(
+                    f"kv_block_size={kv_block_size} must divide 2048")
+            build_cache = functools.partial(
+                self.model.paged, block_size=kv_block_size,
+                pool_tokens=kv_pool_tokens or num_slots * self.max_seq)
+        elif kv_cache == "slot":
+            build_cache = self._builder("slot")
+        else:
+            raise ValueError(f"kv_cache={kv_cache!r}: 'paged' or 'slot'")
         # the device this replica's process holds, as jax reports it —
         # stats() carries it so a driver that stays off jax can tell
         from ray_tpu.common import tpu_detect
@@ -237,40 +242,22 @@ class LLMEngine:
         if params is None:
             params = self.model.init_params(jax.random.key(seed))
         self.params = params
-        self.num_slots = num_slots
-        self.max_seq = max_seq or self.config.max_seq
-        if kv_cache not in ("paged", "slot"):
-            raise ValueError(f"kv_cache={kv_cache!r}: 'paged' or 'slot'")
-        if kv_cache == "paged" and (kv_block_size <= 0
-                                    or 2048 % kv_block_size):
-            # must divide the prompt padding buckets or _prompt_pad can
-            # return a non-multiple and crash every prefill
-            raise ValueError(
-                f"kv_block_size={kv_block_size} must divide 2048")
-        self.kv_cache = kv_cache
-        self._counter_names = ()
-        if kv_cache == "paged":
-            programs = self.model.paged(
-                params, num_slots=num_slots, max_seq=self.max_seq,
-                block_size=kv_block_size,
-                pool_tokens=kv_pool_tokens or num_slots * self.max_seq)
-            self._page = programs.page
-            self._alloc = programs.alloc
-            self._cache = programs.cache
-            self._decode = programs.decode
-            self._prefill = programs.prefill
-            self._inject = programs.inject
-            self._counter_names = programs.counters
-            if self._counter_names:
-                # a program's counters are an output of it alone; the
-                # engine takes them out of the cache it hands on
-                # (_take_counters), so every program is given None there
-                self._cache = dict(self._cache, counters=None)
-        else:
-            self._cache = init_cache(self.config, num_slots, self.max_seq)
-            self._decode = make_decode_step(params, self.config)
-            self._prefill = make_prefill(params, self.config)
-            self._inject = make_inject(self.config)
+        programs = build_cache(params, num_slots=num_slots,
+                               max_seq=self.max_seq)
+        self._page = programs.page
+        self._alloc = programs.alloc
+        self._cache = programs.cache
+        self._decode = programs.decode
+        self._prefill = programs.prefill
+        self._inject = programs.inject
+        # bucketed padded length of a prompt (the cache's own rule)
+        self._prompt_pad = programs.pad
+        self._counter_names = programs.counters
+        if self._counter_names:
+            # a program's counters are an output of it alone; the
+            # engine takes them out of the cache it hands on
+            # (_take_counters), so every program is given None there
+            self._cache = dict(self._cache, counters=None)
         # Chunked prefill (vLLM-class / Sarathi): prompts longer than the
         # chunk prefill one fixed-size chunk per engine iteration,
         # interleaved with decode steps of the other slots — a long
@@ -279,19 +266,8 @@ class LLMEngine:
         if prefill_chunk is not None:
             if prefill_chunk <= 0:
                 raise ValueError("prefill_chunk must be positive")
-            if kv_cache == "paged":
-                if prefill_chunk % kv_block_size:
-                    raise ValueError(
-                        f"prefill_chunk={prefill_chunk} must be a "
-                        f"multiple of kv_block_size={kv_block_size}")
-                from ray_tpu.models.paged_cache import \
-                    make_chunked_paged_prefill
-
-                self._chunk_prefill = make_chunked_paged_prefill(
-                    params, self.config, self._page)
-            else:
-                self._chunk_prefill = make_chunked_prefill(
-                    params, self.config)
+            self._chunk_prefill = self._builder("chunked_prefill")(
+                params, programs, prefill_chunk)
         self.prefill_chunk = prefill_chunk
         # slot -> {"req", "tokens", "pos"} for in-progress chunked prefills
         self._prefilling: Dict[int, dict] = {}
@@ -307,20 +283,17 @@ class LLMEngine:
         self._proposer = None
         self._spec_cfg = None
         if speculation is not None:
-            from ray_tpu.models.speculation import (SpeculationConfig,
-                                                    make_length_installer)
+            import jax.numpy as jnp
 
+            from ray_tpu.models.speculation import SpeculationConfig
+
+            build_verify = self._builder("speculative_verify")
             cfg = SpeculationConfig.parse(speculation, default_k=spec_k)
             if kv_cache != "slot":
                 raise ValueError(
                     "speculation currently requires kv_cache='slot'")
-            import jax
-            import jax.numpy as jnp
-
             self._spec_cfg = cfg
-            self._spec_verify = make_batched_spec_verify(params,
-                                                         self.config)
-            self._spec_fix_len = make_length_installer()
+            self._spec_verify, self._spec_fix_len = build_verify(params)
             # device-side argmax so greedy verify rounds transfer (B, C)
             # ids instead of (B, C, vocab) logits
             self._spec_argmax = jax.jit(
@@ -363,23 +336,19 @@ class LLMEngine:
             mode = "radix" if (prefix_cache_bytes or 0) > 0 else "off"
         if mode not in ("radix", "off"):
             raise ValueError(f"prefix_cache={mode!r}: 'radix' or 'off'")
-        if mode == "radix" and kv_cache != "paged":
-            raise ValueError("prefix_cache='radix' requires "
-                             "kv_cache='paged' (it shares pool blocks)")
         self._prefix_mode = mode
         self._prefix_match_faults = 0
         self._prefix_insert_faults = 0
         self._fair_share_skips = 0
         self._radix = None
         if mode == "radix":
-            from ray_tpu.models.paged_cache import (make_block_copy,
-                                                    make_chunked_paged_prefill)
             from ray_tpu.models.prefix_cache import RadixPrefixCache
 
-            c = self.config
-            itemsize = self._cache["k"].dtype.itemsize
-            bytes_per_block = (2 * c.n_layers * kv_block_size
-                               * c.n_kv_heads * c.head_dim * itemsize)
+            build_copy = self._builder("block_copy")
+            if kv_cache != "paged":
+                raise ValueError("prefix_cache='radix' requires "
+                                 "kv_cache='paged' (it shares pool blocks)")
+            bytes_per_block = self.model.block_bytes(programs)
             if prefix_cache_bytes is None:
                 # default: the tree may cache up to half the pool —
                 # pool-pressure eviction reclaims cold blocks anyway,
@@ -389,13 +358,13 @@ class LLMEngine:
             self._radix = RadixPrefixCache(
                 self._alloc, bytes_per_block=bytes_per_block,
                 budget_bytes=prefix_cache_bytes)
-            self._block_copy = make_block_copy(self.config, self._page)
+            self._block_copy = build_copy(programs)
             if self._chunk_prefill is None:
                 # suffix-only prefill after a radix hit rides the chunked
                 # kernel (row-level scatter, arbitrary start) even when
                 # the engine wasn't configured for chunked prefill
-                self._chunk_prefill = make_chunked_paged_prefill(
-                    params, self.config, self._page)
+                self._chunk_prefill = self._builder("chunked_prefill")(
+                    params, programs)
         self._prefix_cache_bytes = prefix_cache_bytes or 0
 
         self._queue: "queue.Queue[_Request]" = queue.Queue()
@@ -435,12 +404,26 @@ class LLMEngine:
         self._thread.start()
 
     # ------------------------------------------------------------- public
-    def _refuse(self, mechanism: str):
-        from ray_tpu.models.serving import MECHANISMS
+    # the builders a model may lack (ray_tpu.models.serving), each in
+    # the words of the engine's option that needs it
+    _MECHANISMS = {
+        "slot": "kv_cache='slot'",
+        "speculative_verify": "speculation",
+        "block_copy": "a prefix cache (prefix_cache / prefix_cache_bytes)",
+        "chunked_prefill": "chunked prefill (prefill_chunk)",
+        "kv_shape": "KV inject / extract (llm_pd, submit_prefilled)",
+    }
 
-        raise ValueError(
-            f"{type(self.config).__name__} is not served with "
-            f"{MECHANISMS[mechanism]}: the model has no builders for it")
+    def _builder(self, name: str):
+        """The model's builder of that name, or the refusal of the
+        mechanism it stands for: a model has what it has builders for."""
+        builder = getattr(self.model, name, None)
+        if builder is None:
+            raise ValueError(
+                f"{type(self.config).__name__} is not served with "
+                f"{self._MECHANISMS[name]}: the model has no builders for "
+                "it")
+        return builder
 
     def _check_vocab(self, prompt: List[int]) -> None:
         """Reject out-of-vocab prompt token ids at submission. On device
@@ -521,15 +504,13 @@ class LLMEngine:
         prompt position's logits."""
         import uuid
 
-        if "kv_transfer" in self.model.lacks:
-            self._refuse("kv_transfer")
+        kv_shape = self._builder("kv_shape")
         if len(prompt) == 0:
             raise ValueError("empty prompt")
         if len(prompt) + max_tokens > self.max_seq:
             raise ValueError("prompt + max_tokens exceeds max_seq")
         k, v = np.asarray(k), np.asarray(v)
-        c = self.config
-        want = (c.n_layers, len(prompt), c.n_kv_heads, c.head_dim)
+        want = kv_shape(len(prompt))
         if k.shape != want or v.shape != want:
             # caller thread: surface the mismatch to the submitter rather
             # than blowing up the engine loop for every in-flight request
@@ -585,19 +566,21 @@ class LLMEngine:
                "kv_cache": self.kv_cache}
         if self._proposer is not None:
             out.update(self._proposer.stats())
-        if self.kv_cache == "paged":
+        free = self._alloc.free_blocks()
+        if free is not None:            # a pool of blocks, not a reservation
             out.update(
                 preemptions=self._preemptions,
-                kv_blocks_free=self._alloc.free_blocks(),
+                kv_blocks_free=free,
                 kv_blocks_total=self._page.num_blocks - 1,
                 kv_block_size=self._page.block_size)
-            if hasattr(self._alloc, "pools"):
-                # one row for each kind of KV state, with the tokens a
-                # decode step reads there now
-                out["kv_pools"] = self._alloc.pools(
-                    [int(self._slot_len[s]) for s in range(self.num_slots)
-                     if self._slots[s] is not None])
-                out["window_blocks_freed"] = self._window_blocks_freed
+        # one row for each kind of KV state, where the allocator keeps
+        # several, with the tokens a decode step reads there now
+        pools = self._alloc.pools(
+            [int(self._slot_len[s]) for s in range(self.num_slots)
+             if self._slots[s] is not None])
+        if pools:
+            out["kv_pools"] = pools
+            out["window_blocks_freed"] = self._window_blocks_freed
         if self._counter_names:
             out["model_counters"] = dict(zip(
                 self._counter_names, self._model_counters.tolist()))
@@ -651,21 +634,10 @@ class LLMEngine:
         self._thread.join(timeout=5)
 
     # ------------------------------------------------------------- engine
-    def _prompt_pad(self, plen: int) -> int:
-        """Bucketed padded prompt length (block-multiple when paged)."""
-        from ray_tpu.models.decoding import pad_to_bucket
-        from ray_tpu.models.paged_cache import pad_to_block_bucket
-
-        if self.kv_cache == "paged":
-            cap = self._page.max_blocks_per_seq * self._page.block_size
-            return min(pad_to_block_bucket(plen, self._page.block_size),
-                       cap)
-        return min(pad_to_bucket(plen), self.max_seq)
-
     def _inject_kv(self, slot: int, k: np.ndarray, v: np.ndarray,
                    true_len: int):
-        """Pad external KV rows to a bucket and write them into `slot`.
-        Paged: the caller must have ensure()d blocks for ``true_len``."""
+        """Pad external KV rows to a bucket and write them into `slot`,
+        for which the caller has ensure()d blocks for ``true_len``."""
         import jax.numpy as jnp
 
         P = self._prompt_pad(true_len)
@@ -675,14 +647,9 @@ class LLMEngine:
             k = np.pad(k, widths)
             v = np.pad(v, widths)
         with self._phases("kv_inject", slot=slot):
-            if self.kv_cache == "paged":
-                self._cache = self._inject(self._cache,
-                                           self._alloc.tables[slot],
-                                           jnp.asarray(k), jnp.asarray(v),
-                                           true_len, slot)
-            else:
-                self._cache = self._inject(self._cache, jnp.asarray(k),
-                                           jnp.asarray(v), true_len, slot)
+            self._cache = self._inject(
+                self._cache, self._alloc.table_rows(slot), jnp.asarray(k),
+                jnp.asarray(v), true_len, slot)
 
     def _free_slot(self) -> Optional[int]:
         for slot in range(self.num_slots):
@@ -738,7 +705,7 @@ class LLMEngine:
         and per-tenant-fair-share gated; an injected
         serve.llm.prefix_insert fault skips the insert with a typed
         counter (nothing is ever half-inserted)."""
-        if self._radix is None or self.kv_cache != "paged":
+        if self._radix is None:
             return
         from ray_tpu.common import faults
 
@@ -794,50 +761,47 @@ class LLMEngine:
             full_prompt = req.prompt + req.output
             plen = len(full_prompt)
             match = None
-            if self.kv_cache == "paged":
-                # ensure plen + 1: this iteration's decode step writes
-                # the first generated token at position plen, which
-                # lives in a NEW block when the prompt is block-aligned.
-                if not self._alloc.fits(plen + 1):
-                    # can never fit, even with the pool idle: fail it
-                    # rather than deadlock the queue
-                    del self._waiting[idx]
-                    req.error = (f"prompt of {plen} tokens exceeds KV "
-                                 "pool capacity")
-                    self._record_finish(req, "error")
-                    req.done.set()
-                    continue
-                if self._radix is not None and req.preload is None:
-                    match = self._radix_match(full_prompt)
-                shared = match.blocks if match is not None else []
-                # watermark: beyond this request's blocks, keep one
-                # growth block of headroom per already-active slot, or
-                # admission starves running requests into preemption
-                headroom = sum(s is not None for s in self._slots)
-                if shared:
-                    # pin the matched blocks FIRST: the pool-pressure
-                    # eviction below must never reclaim them
-                    self._alloc.adopt(slot, shared)
+            # ensure plen + 1: this iteration's decode step writes the
+            # first generated token at position plen, which lives in a NEW
+            # block when the prompt is block-aligned.
+            if not self._alloc.fits(plen + 1):
+                # can never fit, even with the pool idle: fail it rather
+                # than deadlock the queue
+                del self._waiting[idx]
+                req.error = (f"prompt of {plen} tokens exceeds KV "
+                             "pool capacity")
+                self._record_finish(req, "error")
+                req.done.set()
+                continue
+            if self._radix is not None and req.preload is None:
+                match = self._radix_match(full_prompt)
+            shared = match.blocks if match is not None else []
+            # watermark: beyond this request's blocks, keep one growth
+            # block of headroom per already-active slot, or admission
+            # starves running requests into preemption
+            headroom = sum(s is not None for s in self._slots)
+            if shared:
+                # pin the matched blocks FIRST: the pool-pressure eviction
+                # below must never reclaim them
+                self._alloc.adopt(slot, shared)
+            lack = self._alloc.lacking(plen + 1, len(shared), headroom)
+            if lack and self._radix is not None:
+                self._radix.evict_for(lack)
                 lack = self._alloc.lacking(plen + 1, len(shared), headroom)
-                if lack and self._radix is not None:
-                    self._radix.evict_for(lack)
-                    lack = self._alloc.lacking(plen + 1, len(shared),
-                                               headroom)
-                if lack or not self._alloc.ensure(slot, plen + 1):
-                    self._alloc.release(slot)  # un-pin the match
-                    return  # picked request waits for blocks (no bypass)
-                if match is not None and match.cow is not None:
-                    # copy-on-write at the divergence block: ensure()
-                    # placed a private block at the first position past
-                    # the shared prefix; device-copy the cached block's
-                    # rows into it, so the suffix prefill can resume
-                    # MID-BLOCK at the divergence offset while the
-                    # cached original stays read-only for its other
-                    # references.
-                    with self._phases("block_copy", slot=slot):
-                        self._cache = self._block_copy(
-                            self._cache, match.cow[0],
-                            int(self._alloc.tables[slot, len(shared)]))
+            if lack or not self._alloc.ensure(slot, plen + 1):
+                self._alloc.release(slot)  # un-pin the match
+                return  # picked request waits for blocks (no bypass)
+            if match is not None and match.cow is not None:
+                # copy-on-write at the divergence block: ensure() placed a
+                # private block at the first position past the shared
+                # prefix; device-copy the cached block's rows into it, so
+                # the suffix prefill can resume MID-BLOCK at the divergence
+                # offset while the cached original stays read-only for its
+                # other references.
+                with self._phases("block_copy", slot=slot):
+                    self._cache = self._block_copy(
+                        self._cache, match.cow[0],
+                        int(self._alloc.tables[slot, len(shared)]))
             del self._waiting[idx]
             if req.admitted_at is None:
                 req.admitted_at = time.monotonic()
@@ -877,13 +841,9 @@ class LLMEngine:
                 tokens[0, :plen] = full_prompt
                 with self._phases("prefill", pad_len=P, prompt_len=plen,
                                   slot=slot):
-                    if self.kv_cache == "paged":
-                        self._cache, logits = self._prefill(
-                            self._cache, self._alloc.table_rows(slot),
-                            jnp.asarray(tokens), plen, slot)
-                    else:
-                        self._cache, logits = self._prefill(
-                            self._cache, jnp.asarray(tokens), plen, slot)
+                    self._cache, logits = self._prefill(
+                        self._cache, self._alloc.table_rows(slot),
+                        jnp.asarray(tokens), plen, slot)
                     tok = self._fetch(self._draw_first(req, logits, plen),
                                       self._take_counters(),
                                       prefill=True).item()
@@ -920,8 +880,7 @@ class LLMEngine:
         """Dispatch the pick of a request's first token from the logits
         of its prompt's last row: one int32, still on the device. As in
         a turn, a greedy request's is the argmax program's: a
-        deployment that is never asked for a temperature never builds
-        the other."""
+        deployment that never sees a temperature never builds the other."""
         if req.temperature <= 0.0:
             return self._greedy_ids(logits)
         self._sampled_tokens += 1
@@ -1053,13 +1012,9 @@ class LLMEngine:
         buf = np.zeros((1, C), np.int32)
         buf[0, :n] = toks[pos:pos + n]
         with self._phases("prefill_chunk", pad_len=C, slot=slot):
-            if self.kv_cache == "paged":
-                self._cache, logits = self._chunk_prefill(
-                    self._cache, self._alloc.tables[slot], jnp.asarray(buf),
-                    n, pos, slot)
-            else:
-                self._cache, logits = self._chunk_prefill(
-                    self._cache, jnp.asarray(buf), n, pos, slot)
+            self._cache, logits = self._chunk_prefill(
+                self._cache, self._alloc.table_rows(slot), jnp.asarray(buf),
+                n, pos, slot)
             self._chunks_run += 1
             st["pos"] = pos + n
             if st["pos"] < len(toks):
@@ -1120,8 +1075,7 @@ class LLMEngine:
             try:
                 if self._proposer is not None:
                     self._proposer.release(slot)
-                if self.kv_cache == "paged":
-                    self._alloc.release(slot)
+                self._alloc.release(slot)
             finally:
                 # last: a caller that wakes finds its slot and blocks back
                 req.done.set()
@@ -1243,10 +1197,9 @@ class LLMEngine:
                         self._record_finish(req, "error")
                         req.done.set()
                         self._slots[slot] = None
-                        if self.kv_cache == "paged":
-                            # blocks would otherwise leak for good: only
-                            # _maybe_finish/_preempt release them
-                            self._alloc.release(slot)
+                        # blocks would otherwise leak for good: only
+                        # _maybe_finish/_preempt release them
+                        self._alloc.release(slot)
                 self._prefilling.clear()
         self._land_quietly()
 
@@ -1294,17 +1247,15 @@ class LLMEngine:
         # grow BEFORE admitting: otherwise a tight pool admits the queue
         # head (paying its prefill), then immediately preempts it as the
         # youngest slot to feed an older slot's growth — prefill thrash
-        if self.kv_cache == "paged":
-            if hasattr(self._alloc, "trim"):
-                # before growing: what a window has passed goes back to
-                # its pool, so the block the next token needs is there
-                with phase("window_free"):
-                    for slot in range(self.num_slots):
-                        if self._runs_next(slot):
-                            self._window_blocks_freed += self._alloc.trim(
-                                slot, int(self._slot_len[slot]) + 1)
-            with phase("grow"):
-                self._grow_active_slots()
+        # before growing: what a window has passed goes back to its pool,
+        # so the block the next token needs is there
+        with phase("window_free"):
+            for slot in range(self.num_slots):
+                if self._runs_next(slot):
+                    self._window_blocks_freed += self._alloc.trim(
+                        slot, int(self._slot_len[slot]) + 1)
+        with phase("grow"):
+            self._grow_active_slots()
         with phase("admit",
                    waiting=self._queue.qsize() + len(self._waiting)):
             self._admit()
@@ -1378,13 +1329,9 @@ class LLMEngine:
                     tokens = self._merge_ids(
                         jnp.asarray(np.where(seated, self._last_token, -1)),
                         tokens)
-            if self.kv_cache == "paged":
-                self._cache, logits = self._decode(
-                    self._cache, self._alloc.device_tables(), tokens,
-                    jnp.asarray(active))
-            else:
-                self._cache, logits = self._decode(
-                    self._cache, tokens, jnp.asarray(active))
+            self._cache, logits = self._decode(
+                self._cache, self._alloc.device_tables(), tokens,
+                jnp.asarray(active))
             counters = self._take_counters()
             # what the turn needs, from what it holds: the all-greedy
             # turn's program is the argmax alone

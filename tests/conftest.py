@@ -62,6 +62,35 @@ def _fresh_natives():
 
 
 @pytest.fixture
+def kernel_on_cpu(monkeypatch):
+    """Every dispatcher under ``ray_tpu/ops`` asks
+    ``ops.attention.on_tpu()`` whether to build a work list and call its
+    kernel; here it says no. Steer them all from the test, through that
+    one lookup: each kernel, interpreted."""
+    import functools
+
+    from ray_tpu.ops import attention
+    from ray_tpu.ops.pallas import (decode_attention, flash_attention,
+                                    grouped_matmul, paged_decode_attention,
+                                    paged_hybrid_decode_attention,
+                                    paged_mla_decode_attention,
+                                    ssm_decode_update)
+
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    for module, kernel in [
+            (decode_attention, "decode_attention"),
+            (flash_attention, "flash_attention_fwd_pallas"),
+            (flash_attention, "flash_attention_bwd_pallas"),
+            (grouped_matmul, "grouped_matmul"),
+            (paged_decode_attention, "paged_decode_attention"),
+            (paged_hybrid_decode_attention, "paged_hybrid_decode_attention"),
+            (paged_mla_decode_attention, "paged_mla_decode_kernel"),
+            (ssm_decode_update, "ssm_decode_update")]:
+        monkeypatch.setattr(module, kernel, functools.partial(
+            getattr(module, kernel), interpret=True))
+
+
+@pytest.fixture
 def local_cluster():
     """A started single-node framework instance, shut down after the test."""
     import ray_tpu

@@ -6,7 +6,6 @@ kept, 8 experts a token), against the benchmark's plain reference
 (``benchmark/reference/axk1.py``) on seeded random weights."""
 
 import dataclasses
-import functools
 import math
 import os
 import sys
@@ -55,18 +54,6 @@ def make_params(spec, seed, dtype=jnp.bfloat16):
 
 def config(dtype=jnp.bfloat16):
     return dataclasses.replace(ARCH.program_config(SPEC), dtype=dtype)
-
-
-@pytest.fixture
-def kernel_on_cpu(monkeypatch):
-    """The decode step asks ``on_tpu()`` whether to build the work list
-    and call the kernel; here it says no. Steer it from the test: the
-    kernel, interpreted."""
-    monkeypatch.setattr(axk1, "on_tpu", lambda: True)
-    monkeypatch.setattr(mla, "on_tpu", lambda: True)
-    monkeypatch.setattr(
-        mla, "paged_mla_decode_kernel", functools.partial(
-            mla.paged_mla_decode_kernel, interpret=True))
 
 
 # ------------------------------------------- the program and the reference

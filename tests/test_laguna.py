@@ -8,7 +8,6 @@ than hidden, layer 0 dense and full), against the benchmark's plain
 reference (``benchmark/reference/laguna.py``) on seeded random weights."""
 
 import dataclasses
-import functools
 import math
 import os
 import sys
@@ -65,17 +64,6 @@ def make_params(spec, seed, dtype=jnp.bfloat16):
 
 def config(dtype=jnp.bfloat16):
     return dataclasses.replace(ARCH.program_config(SPEC), dtype=dtype)
-
-
-@pytest.fixture
-def kernel_on_cpu(monkeypatch):
-    """The decode step asks ``on_tpu()`` whether to build the work lists
-    and call the kernel; here it says no. Steer it from the test: the
-    kernel, interpreted."""
-    monkeypatch.setattr(laguna, "on_tpu", lambda: True)
-    monkeypatch.setattr(
-        pha, "paged_hybrid_decode_attention", functools.partial(
-            pha.paged_hybrid_decode_attention, interpret=True))
 
 
 # ------------------------------------------- the program and the reference

@@ -3,7 +3,6 @@ by share (``ray_tpu.models.mimo_v2``), at a small size on the CPU,
 against the benchmark's plain reference
 (``benchmark/reference/mimo_v2.py``) on seeded random weights."""
 
-import functools
 import os
 import sys
 
@@ -203,17 +202,6 @@ def test_work_list_is_the_steps_a_call_takes_in_slot_order(lengths, bs,
     assert got[:len(want)] == want
     assert set(got[len(want):]) <= {want[-1] if want
                                     else (len(lengths) - 1, 0)}
-
-
-@pytest.fixture
-def kernel_on_cpu(monkeypatch):
-    """The decode step asks ``on_tpu()`` whether to build the work lists
-    and call the kernel; here it says no. Steer it from the test: the
-    kernel, interpreted."""
-    monkeypatch.setattr(mimo_v2, "on_tpu", lambda: True)
-    monkeypatch.setattr(
-        pha, "paged_hybrid_decode_attention", functools.partial(
-            pha.paged_hybrid_decode_attention, interpret=True))
 
 
 def test_a_stale_slot_between_two_running_ones_is_not_attended(

@@ -9,7 +9,6 @@ first eleven layers), against the benchmark's plain reference
 seeded random weights."""
 
 import dataclasses
-import functools
 import os
 import sys
 import threading
@@ -73,18 +72,6 @@ def padded(tokens, P):
     out = np.zeros((1, P), np.int32)
     out[0, :len(tokens)] = tokens
     return jnp.asarray(out)
-
-
-@pytest.fixture
-def kernel_on_cpu(monkeypatch):
-    """The decode step asks ``on_tpu()`` whether to call the state
-    update's kernel; here it says no. Steer it from the test: the
-    kernel, interpreted (the attention layer keeps its oracle: the
-    paged kernel asks its own module)."""
-    monkeypatch.setattr(nemotron_h, "on_tpu", lambda: True)
-    monkeypatch.setattr(
-        ssm_kernel, "ssm_decode_update", functools.partial(
-            ssm_kernel.ssm_decode_update, interpret=True))
 
 
 # --------------------------------------------------------- the recurrence
@@ -435,12 +422,13 @@ def test_the_engine_serves_it_and_reports_both_kinds_of_state(engine_parts):
 def test_the_check_borrows_the_engine_that_serves_the_weights(
         engine_parts, monkeypatch):
     """The benchmark's comparison runs the engine's OWN programs on the
-    engine's own cache where an engine serves the weights it is handed
-    (a second recurrent state of the cell's size fits no chip beside the
-    first): the compared sequence in the last slot beside neighbours
-    that run, the reference's logits, and an engine that afterwards
-    holds nothing of it and answers as before. Without an engine the
-    same builders over scratch caches give the same numbers."""
+    engine's own cache where it is handed the engine that serves the
+    weights, as ``worker_serve.check`` hands it (a second recurrent
+    state of the cell's size fits no chip beside the first): the
+    compared sequence in the last slot beside neighbours that run, the
+    reference's logits, and an engine that afterwards holds nothing of
+    it and answers as before. Without an engine the same builders over
+    scratch caches give the same numbers."""
     from ray_tpu.serve.llm import LLMEngine
 
     cfg, params = engine_parts
@@ -456,15 +444,16 @@ def test_the_check_borrows_the_engine_that_serves_the_weights(
     assert REF.rel_err(scratch, want) < 5e-5
     eng = LLMEngine(params=params, **ARCH.engine_kwargs(SPEC, DEPLOYMENT))
     try:
-        assert ARCH._engine_serving(params) is eng
-        assert ARCH._engine_serving(dict(params)) is None
+        with pytest.raises(RuntimeError, match="other weights"):
+            ARCH.serve_program_logits(dict(params), SPEC, tokens,
+                                      DEPLOYMENT, prefill=29, engine=eng)
         prompt = list(range(1, 41))
         before = eng.generate(prompt, max_tokens=12)
         with monkeypatch.context() as m:     # no program but the engine's
             m.setattr(nemotron_h, "make_decode_step", None)
             m.setattr(nemotron_h, "make_prefill", None)
             got = ARCH.serve_program_logits(params, SPEC, tokens, DEPLOYMENT,
-                                            prefill=29)
+                                            prefill=29, engine=eng)
         assert np.array_equal(got, scratch)
         st = eng.stats()
         assert st["kv_pools"]["ssm_state"]["slots_live"] == 0
@@ -475,7 +464,7 @@ def test_the_check_borrows_the_engine_that_serves_the_weights(
         with pytest.raises(RuntimeError, match="idle"):   # other slots
             ARCH.serve_program_logits(params, SPEC, tokens,
                                       dict(DEPLOYMENT, num_slots=2),
-                                      prefill=29)
+                                      prefill=29, engine=eng)
     finally:
         eng.shutdown()
 
@@ -589,8 +578,13 @@ def test_the_tiny_defaults_are_a_model_of_their_own():
 
     cfg = nemotron_h.NemotronHConfig()
     model = serving_model(cfg)
-    assert model.lacks == ("slot_cache", "speculation", "prefix_cache",
-                           "prefill_chunk", "kv_transfer")
+    # a model has what it has builders for: this one none of the five
+    # mechanisms beside the paged path, the dense decoder all of them
+    dense = serving_model(preset="debug")
+    for builder in LLMEngine._MECHANISMS:
+        assert not hasattr(model, builder), builder
+        assert callable(getattr(dense, builder)), builder
+    assert len(LLMEngine._MECHANISMS) == 5
     eng = LLMEngine(config=cfg, num_slots=2, max_seq=64, kv_block_size=8)
     try:
         assert len(eng.generate([3, 4, 5], max_tokens=5)) == 5

@@ -65,6 +65,27 @@ class TestAllocator:
         assert pad_to_block_bucket(4000, 64) == 4096
 
 
+def test_the_slot_reservation_answers_as_an_allocator_that_never_lacks():
+    """The slot cache behind the paged interface: ``max_seq`` rows a slot
+    from the start, so a sequence that long fits and one longer never
+    will, nothing is lacking, grown, trimmed or given back, and there is
+    no table and no block to report."""
+    from ray_tpu.models.serving import SlotReservation
+
+    alloc = SlotReservation(max_seq=64)
+    assert alloc.fits(64) and not alloc.fits(65)
+    assert alloc.lacking(64, shared=0, headroom=8) == 0
+    assert alloc.ensure(3, 64) is True and alloc.trim(3, 64) == 0
+    assert alloc.release(3) is None and alloc.ensure(3, 1) is True
+    assert alloc.table_rows(3) is None and alloc.device_tables() is None
+    assert alloc.free_blocks() is None and alloc.pools([5, 9]) == {}
+    # what the one-pool allocator answers to the same two plain calls
+    one_pool = BlockAllocator(PagedConfig(num_blocks=5, block_size=8,
+                                          max_seq=32), num_slots=2)
+    assert one_pool.ensure(0, 9) and one_pool.trim(0, 9) == 0
+    assert one_pool.pools([9]) == {} and one_pool.free_blocks() == 2
+
+
 class TestKernelVsOracle:
     def test_paged_kernel_interpret_matches_reference(self):
         from ray_tpu.ops.pallas.paged_decode_attention import (

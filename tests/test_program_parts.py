@@ -285,3 +285,45 @@ def test_named_scope_is_spelled_once():
                         if "named_scope" in f.read():
                             hits.append(os.path.join(base, name))
     assert hits == []
+
+
+def test_the_platform_is_asked_only_under_ops():
+    """Whether a kernel or its XLA oracle runs is the ops' question:
+    ``on_tpu`` is named in no file of ``ray_tpu/`` outside
+    ``ray_tpu/ops/`` (a model calls an op's dispatcher; each dispatcher
+    asks ``ops.attention.on_tpu()``, the one lookup a test steers)."""
+    hits = []
+    ops = os.path.join(ROOT, "ray_tpu", "ops") + os.sep
+    for base, _, files in os.walk(os.path.join(ROOT, "ray_tpu")):
+        for name in files:
+            path = os.path.join(base, name)
+            if name.endswith(".py") and not path.startswith(ops):
+                with open(path) as f:
+                    if "on_tpu" in f.read():
+                        hits.append(path)
+    assert hits == []
+
+
+def test_the_engine_takes_every_program_from_the_serving_model():
+    """``serve/llm.py`` imports nothing from the modules that build the
+    dense decoder's programs, caches and configs, at any depth of the
+    file: what it runs comes through ``models/serving.py``. (Its AST is
+    read; the module is not imported.)"""
+    import ast
+
+    with open(os.path.join(ROOT, "ray_tpu", "serve", "llm.py")) as f:
+        tree = ast.parse(f.read())
+    behind = ("decoding", "paged_cache", "llama")
+    hits, seen = [], []
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            names = [a.name for a in node.names]
+        elif isinstance(node, ast.ImportFrom):
+            names = [f"{node.module}.{a.name}" for a in node.names]
+        else:
+            continue
+        seen += names
+        hits += [n for n in names for b in behind
+                 if f"ray_tpu.models.{b}." in n + "."]
+    assert hits == []
+    assert any(n.startswith("ray_tpu.models.serving.") for n in seen)
