@@ -41,6 +41,32 @@ the call):
     kv_shape(tokens)  KV transfer: the ``(layers, tokens, kv_heads,
                       head_dim)`` of the k and of the v that
                       ``PagedPrograms.inject`` takes for a prompt
+    block_denoise(params, programs) -> (step, decide)
+                      generation by blocks IN PLACE OF the decode step
+                      (``PagedPrograms.decode`` is then None): a slot
+                      holds a block of ``config.block_length`` positions,
+                      some decided; ``step(cache, alloc.device_tables(),
+                      ids (B, L) i32, decided (B, L) bool, active (B,))
+                      -> (cache, logits (B, L, vocab))`` runs every
+                      active slot's block against its cached prefix,
+                      feeding ``config.mask_token_id`` where a position
+                      is not decided, writes the block's rows after the
+                      slot's ``length`` and, for a slot whose positions
+                      are ALL decided, keeps them: its length grows by
+                      ``L`` (the commit). ``decide(logits, ids, decided,
+                      quota (B,) i32, draw=None) -> (ids, decided, out
+                      (B, L))`` decides ``quota`` more positions of each
+                      slot on the device; a slot that has just committed
+                      gets its block's ids in ``out`` and an undecided
+                      block. The engine seats a prompt's whole blocks by
+                      ``prefill`` (whose mask is the model's own) and its
+                      tail as decided positions of the first block, and
+                      has every step decide ``config.step_quota(n)``
+                      positions of a block that began with ``n``
+                      undecided (the rule is the model's; the engine
+                      only counts); ``draw`` = (temperature (B,),
+                      key, request (B,), length (B,)) where a slot draws
+                      at a temperature
 
 The dense decoder (:class:`DenseDecoder` around a ``LlamaConfig``) has
 them all, each a call of the builder in :mod:`ray_tpu.models.decoding`,
@@ -69,12 +95,13 @@ class PagedPrograms:
     a prompt of ``n`` tokens is given (left out: a bucket of whole blocks
     of ``page``). ``counters`` names the entries of
     ``cache["counters"]``, which the engine fetches with the logits and
-    sums in ``stats()["model_counters"]``."""
+    sums in ``stats()["model_counters"]``. ``decode`` is None for a
+    model that brings ``block_denoise`` in its place."""
 
     alloc: Any
     cache: Any
     prefill: Callable
-    decode: Callable
+    decode: Optional[Callable]
     page: Any
     inject: Optional[Callable] = None
     counters: Tuple[str, ...] = ()

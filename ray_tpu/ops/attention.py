@@ -86,7 +86,8 @@ def _sink_softmax(s, sink):
 
 @part("attention")
 def hybrid_attention_reference(q, k, v, *, scale: float,
-                               window: Optional[int] = None, sink=None):
+                               window: Optional[int] = None, sink=None,
+                               span: int = 1):
     """Causal attention in XLA for one layer of a model that mixes full
     and sliding-window layers: q (B, S, H, Dk), k (B, S, KV, Dk), v
     (B, S, KV, Dv) with ``Dv`` free of ``Dk``; -> (B, S, H, Dv) in
@@ -97,7 +98,9 @@ def hybrid_attention_reference(q, k, v, *, scale: float,
     their own key block and the one before, so key blocks wholly outside
     the window are never read and the temporaries are (S, 2w), not
     (S, S). ``sink`` (H,) float32: a learned per-head logit added to the
-    softmax's denominator."""
+    softmax's denominator. ``span`` > 1 (full layers only): causal between
+    runs of ``span`` positions and full inside a run, query i attends keys
+    ``j // span <= i // span``."""
     B, S, H, Dk = q.shape
     KV, Dv = k.shape[2], v.shape[-1]
     g = H // KV
@@ -108,7 +111,7 @@ def hybrid_attention_reference(q, k, v, *, scale: float,
         s = jnp.einsum("bqkgd,bskd->bkgqs", qf, kf) * scale
         row = jnp.arange(S)[:, None]
         col = jnp.arange(S)[None, :]
-        ok = row >= col
+        ok = row >= col if span == 1 else row // span >= col // span
         if window is not None:
             ok &= row - col < window
         s = jnp.where(ok, s, NEG_INF)
@@ -144,24 +147,29 @@ def hybrid_attention_reference(q, k, v, *, scale: float,
 
 @part("attention")
 def prompt_attention(q, k, v, *, scale: float, window: Optional[int] = None,
-                     sink=None):
+                     sink=None, span: int = 1):
     """Causal attention of a served prompt over itself, one layer:
     :func:`hybrid_attention_reference`'s arguments and result. A full
     layer without a sink (``window`` None or not shorter than the
     prompt) goes through the flash forward kernel on a TPU: operands in
     their own dtype on the MXU, float32 running maximum, sum and
     accumulator, no (S, S) array. A window or a sink, which the kernel
-    has not, and every other backend take the XLA reference."""
+    has not, and every other backend take the XLA reference. ``span``
+    > 1 (a model that generates by blocks of that many positions): the
+    mask is causal between blocks and full inside one, in the kernel's
+    diagonal tiles as in the reference."""
+    if span != 1 and window is not None:
+        raise ValueError("a block-causal layer has no window")
     if (on_tpu() and sink is None
             and (window is None or window >= q.shape[1])):
         from ray_tpu.ops.pallas.flash_attention import flash_attention_fwd_pallas
 
         out, _ = flash_attention_fwd_pallas(
             q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-            v.transpose(0, 2, 1, 3), causal=True, scale=scale)
+            v.transpose(0, 2, 1, 3), causal=True, scale=scale, span=span)
         return out.transpose(0, 2, 1, 3)
     return hybrid_attention_reference(q, k, v, scale=scale, window=window,
-                                      sink=sink)
+                                      sink=sink, span=span)
 
 
 def _fwd_xla(q, k, v, causal, scale):

@@ -86,11 +86,17 @@ def _is_last(iq, ik, *, num_kv_blocks: int, **blocks):
 
 
 def _when_masked(step, iq, ik, *, causal: bool, block_q: int, block_kv: int,
-                 kv_len: int, num_kv_blocks: int):
+                 kv_len: int, num_kv_blocks: int, span: int = 1):
     """Run ``step(mask)`` once. ``mask`` is a function of s_t (block_kv,
     block_q) where a mask can change a score: the diagonal crosses the block
     (its last key lies after its first query), or it is the padded last kv
-    block. Elsewhere it is None and the body builds no iota or compare."""
+    block. Elsewhere it is None and the body builds no iota or compare.
+
+    ``span`` > 1 (a power of two that divides both blocks): a causal call
+    is causal between runs of ``span`` positions and full inside a run, so
+    a query sees the keys up to the last of its own run (``q | (span - 1)``).
+    The runs lie inside the blocks, so which pairs hold work and which the
+    diagonal crosses is as for ``span`` 1."""
 
     def mask(s_t):
         kpos = ik * block_kv + jax.lax.broadcasted_iota(
@@ -99,6 +105,8 @@ def _when_masked(step, iq, ik, *, causal: bool, block_q: int, block_kv: int,
         if causal:
             qpos = iq * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, s_t.shape, 1)
+            if span > 1:
+                qpos = qpos | (span - 1)
             valid = jnp.logical_and(valid, qpos >= kpos)
         return valid
 
@@ -117,7 +125,8 @@ def _when_masked(step, iq, ik, *, causal: bool, block_q: int, block_kv: int,
 
 def _fwd_kernel(iq_ref, ik_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 qs_ref, acc_ref, m_ref, l_ref,
-                *, scale: float, kv_len: int, num_kv_blocks: int, **blocks):
+                *, scale: float, kv_len: int, num_kv_blocks: int,
+                span: int = 1, **blocks):
     t = pl.program_id(1)
     iq = iq_ref[t]
     ik = ik_ref[t]
@@ -151,7 +160,7 @@ def _fwd_kernel(iq_ref, ik_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         m_ref[:] = m_new
 
     _when_masked(_step, iq, ik, kv_len=kv_len, num_kv_blocks=num_kv_blocks,
-                 **blocks)
+                 span=span, **blocks)
 
     @pl.when(_is_last(iq, ik, num_kv_blocks=num_kv_blocks, **blocks))
     def _finalize():
@@ -165,18 +174,24 @@ def _fwd_kernel(iq_ref, ik_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 def flash_attention_fwd_pallas(q, k, v, *, causal: bool, scale: float,
                                block_q: int | None = None,
                                block_kv: int | None = None,
+                               span: int = 1,
                                interpret: bool = False):
     """q: (B, Hq, Sq, Dk); k: (B, Hkv, Skv, Dk); v: (B, Hkv, Skv, Dv).
 
     Returns ``(out, lse)``: out (B, Hq, Sq, Dv) in q.dtype, lse (B, Hq, Sq)
     f32 where ``lse[i] = log(sum_j exp(scale·q_i·k_j))`` over unmasked j.
     The blocks are chosen from the shapes (``_blocks``, by the wider of
-    the two rows) unless given.
+    the two rows) unless given. ``span`` > 1 with ``causal``: causal
+    between runs of ``span`` positions, full inside one (a power of two,
+    at most the lanes, so that it divides every block).
     """
     b, hq, sq, dk = q.shape
     _, hkv, skv, dv = v.shape
     if hq % hkv:
         raise ValueError(f"q heads {hq} not a multiple of kv heads {hkv}")
+    if span != 1 and (not causal or span & (span - 1) or span > _LANES):
+        raise ValueError(f"span {span}: a power of two up to {_LANES}, in "
+                         "a causal call")
     group = hq // hkv
 
     row_bytes = max(dk, dv) * q.dtype.itemsize
@@ -201,7 +216,8 @@ def flash_attention_fwd_pallas(q, k, v, *, causal: bool, scale: float,
         return (bh, 0, iq_ref[t])
 
     kernel = functools.partial(
-        _fwd_kernel, scale=scale, kv_len=skv, num_kv_blocks=nk, **blocks)
+        _fwd_kernel, scale=scale, kv_len=skv, num_kv_blocks=nk, span=span,
+        **blocks)
 
     with part("flash_attention_fwd"):
         out, lse = pl.pallas_call(

@@ -27,7 +27,11 @@ and ``kv_cache="slot"`` (a flat ``max_seq`` reservation a slot, handed
 over in the paged cache's shape, so the loop below is written once). The
 engine builds no program of its own but the three that pick tokens, and
 refuses by name a mechanism the model has no builder for. Shapes are
-fixed, so nothing compiles in steady state.
+fixed, so nothing compiles in steady state. A model may bring a block
+step in place of the decode step (``block_denoise``: a slot then holds a
+block of positions that a step decides some of, and a step that finds it
+wholly decided commits it), which the same loop runs one step ahead of
+the host (:meth:`LLMEngine._dispatch_block`).
 """
 
 from __future__ import annotations
@@ -163,6 +167,21 @@ class _Flight:
     counters: Any
     reqs: List[Optional[_Request]]      # by slot; None: masked out
     sampled: int
+    # a block step's: the tokens each slot commits in it (0: it only
+    # decides), known at dispatch; None: a decode step, one a slot
+    coming: Optional[np.ndarray] = None
+
+
+def seat_blocks(ids, decided, host_ids, host_decided, seated):
+    """The slots' blocks when requests were seated since the step in
+    flight was dispatched: a ``seated`` slot takes the block the host
+    knows (its prompt's tail decided, the rest not), any other keeps
+    what the step before left on the device."""
+    import jax.numpy as jnp
+
+    seated = seated[:, None]
+    return (jnp.where(seated, host_ids, ids),
+            jnp.where(seated, host_decided, decided))
 
 
 class LLMEngine:
@@ -172,7 +191,11 @@ class LLMEngine:
     through :func:`ray_tpu.models.serving.serving_model`: its cache(s),
     its prefill and decode programs and its allocator (one for each kind
     of KV state it keeps). A mechanism the model has no builders for
-    raises ``ValueError`` here, naming it.
+    raises ``ValueError`` here, naming it. A model with no decode step
+    brings ``block_denoise`` and is run by the block turn: its config's
+    ``block_length``, ``mask_token_id`` and ``step_quota`` (the rule's
+    positions a step) are the generation's settings, and the engine has
+    no option for them.
 
     ``kv_cache="paged"`` (default) backs the slots with the model's
     block-table pool: HBM per request tracks tokens actually cached,
@@ -253,6 +276,30 @@ class LLMEngine:
         # bucketed padded length of a prompt (the cache's own rule)
         self._prompt_pad = programs.pad
         self._counter_names = programs.counters
+        # a model without a decode step generates by blocks: its block
+        # step and deciding program, a block a slot on the device, and
+        # the host's counts of it (what a step will do to a slot follows
+        # from them, never from the ids)
+        self._block_step = None
+        self._step_rows = 1         # KV rows a step writes after a length
+        if self._decode is None:
+            import jax.numpy as jnp
+
+            self._block_step, self._block_decide = self._builder(
+                "block_denoise")(params, programs)
+            self._step_rows = B = self.config.block_length
+            self._block_state = (jnp.zeros((num_slots, B), jnp.int32),
+                                 jnp.zeros((num_slots, B), bool))
+            self._seat_blocks = jax.jit(seat_blocks)
+            self._blk_ids = np.zeros((num_slots, B), np.int32)
+            self._blk_decided = np.zeros((num_slots, B), bool)
+            self._blk_seated = np.zeros(num_slots, bool)
+            self._blk_undecided = np.full(num_slots, B, np.int64)
+            self._blk_quota = np.zeros(num_slots, np.int64)
+            self._blk_skip = np.zeros(num_slots, np.int64)
+            self._block_counts = dict.fromkeys(
+                ("block_steps", "slot_steps", "commit_steps",
+                 "blocks_committed", "positions_decided"), 0)
         if self._counter_names:
             # a program's counters are an output of it alone; the
             # engine takes them out of the cache it hands on
@@ -412,6 +459,8 @@ class LLMEngine:
         "block_copy": "a prefix cache (prefix_cache / prefix_cache_bytes)",
         "chunked_prefill": "chunked prefill (prefill_chunk)",
         "kv_shape": "KV inject / extract (llm_pd, submit_prefilled)",
+        "block_denoise": "generation by blocks (it has no decode step "
+                         "either)",
     }
 
     def _builder(self, name: str):
@@ -607,6 +656,12 @@ class LLMEngine:
         out["turns"] = {"overlapped": self._turns_overlapped,
                         "drained": self._turns_drained,
                         "surplus_dropped": self._surplus_dropped}
+        if self._block_step is not None:
+            # steps of the block turn (``steps`` counts them too), the
+            # slots that ran in them, the slot-steps among those that
+            # only committed, the blocks they committed and the
+            # positions the others decided
+            out.update(self._block_counts)
         mem = self._dev.memory_stats() or {}
         out["device"] = dict(self._device,
                              peak_bytes_in_use=mem.get("peak_bytes_in_use"))
@@ -763,8 +818,10 @@ class LLMEngine:
             match = None
             # ensure plen + 1: this iteration's decode step writes the
             # first generated token at position plen, which lives in a NEW
-            # block when the prompt is block-aligned.
-            if not self._alloc.fits(plen + 1):
+            # block when the prompt is block-aligned. (A block step
+            # writes its whole block after the prompt's whole blocks.)
+            need = plen - plen % self._step_rows + self._step_rows
+            if not self._alloc.fits(need):
                 # can never fit, even with the pool idle: fail it rather
                 # than deadlock the queue
                 del self._waiting[idx]
@@ -784,11 +841,11 @@ class LLMEngine:
                 # pin the matched blocks FIRST: the pool-pressure eviction
                 # below must never reclaim them
                 self._alloc.adopt(slot, shared)
-            lack = self._alloc.lacking(plen + 1, len(shared), headroom)
+            lack = self._alloc.lacking(need, len(shared), headroom)
             if lack and self._radix is not None:
                 self._radix.evict_for(lack)
-                lack = self._alloc.lacking(plen + 1, len(shared), headroom)
-            if lack or not self._alloc.ensure(slot, plen + 1):
+                lack = self._alloc.lacking(need, len(shared), headroom)
+            if lack or not self._alloc.ensure(slot, need):
                 self._alloc.release(slot)  # un-pin the match
                 return  # picked request waits for blocks (no bypass)
             if match is not None and match.cow is not None:
@@ -806,6 +863,9 @@ class LLMEngine:
             if req.admitted_at is None:
                 req.admitted_at = time.monotonic()
             matched = match.matched if match is not None else 0
+            if self._block_step is not None:
+                self._admit_block(slot, req, full_prompt)
+                continue
             if req.preload is not None:
                 # PD handoff: prompt KV computed by a prefill replica
                 self._inject_kv(slot, req.preload["k"], req.preload["v"],
@@ -851,6 +911,39 @@ class LLMEngine:
                     self._radix_insert(req, full_prompt, slot)
             self._seat(slot, req, plen)
             self._first_token(slot, tok, full_prompt)
+
+    def _admit_block(self, slot: int, req: _Request,
+                     full_prompt: List[int]) -> None:
+        """Seat a request of a model that generates by blocks: the
+        prompt's whole blocks through the prefill (none: the prefill of
+        an empty prompt, which sets the slot's length), its tail as the
+        decided positions of the slot's first block, which the next
+        dispatch uploads. No token comes of it."""
+        import jax.numpy as jnp
+
+        B = self._step_rows
+        plen = len(full_prompt)
+        tail = plen % B
+        whole = plen - tail
+        P = self._prompt_pad(whole)
+        tokens = np.zeros((1, P), np.int32)
+        tokens[0, :whole] = full_prompt[:whole]
+        with self._phases("prefill", pad_len=P, prompt_len=whole,
+                          slot=slot):
+            self._cache, _ = self._prefill(
+                self._cache, self._alloc.table_rows(slot),
+                jnp.asarray(tokens), whole, slot)
+            counters = self._take_counters()
+            if counters is not None:
+                self._fetch(None, counters, prefill=True)
+        self._seat(slot, req, whole)
+        self._blk_ids[slot] = 0
+        self._blk_ids[slot, :tail] = full_prompt[whole:]
+        self._blk_decided[slot] = np.arange(B) < tail
+        self._blk_seated[slot] = True
+        self._blk_undecided[slot] = B - tail
+        self._blk_quota[slot] = self.config.step_quota(B - tail)
+        self._blk_skip[slot] = tail
 
     def _seat(self, slot: int, req: _Request, cached: int) -> None:
         """``req`` takes ``slot`` with ``cached`` tokens of it in the KV
@@ -1132,7 +1225,11 @@ class LLMEngine:
         req = self._slots[slot]
         if req is None or req.cancelled or slot in self._prefilling:
             return False
-        n = len(req.output) + self._in_flight(slot)
+        n = len(req.output)
+        if self._in_flight(slot):
+            # one token of a decode step, a block step's by its counts
+            coming = self._flight.coming
+            n += 1 if coming is None else int(coming[slot])
         return n < req.max_tokens and len(req.prompt) + n < self.max_seq
 
     def _in_flight(self, slot: int) -> bool:
@@ -1144,17 +1241,19 @@ class LLMEngine:
 
     def _grow_active_slots(self) -> None:
         """Before a decode step each slot that runs in it needs its next
-        token's block. On pool exhaustion, preempt the youngest other
-        active slot; a slot alone in the pool preempts itself. A victim
-        resumes from ``prompt + output``, so the step in flight lands
-        first, and what it finishes gives its blocks back."""
+        token's block (before a block step: its block's rows', which lie
+        in one block of the pool). On pool exhaustion, preempt the
+        youngest other active slot; a slot alone in the pool preempts
+        itself. A victim resumes from ``prompt + output``, so the step in
+        flight lands first, and what it finishes gives its blocks back."""
         bs = self._page.block_size
         for slot in range(self.num_slots):
             # the next token starts a block only at a block's multiple;
             # otherwise the blocks that cover the cached tokens cover it
             if self._slot_len[slot] % bs or not self._runs_next(slot):
                 continue
-            while not self._alloc.ensure(slot, int(self._slot_len[slot]) + 1):
+            while not self._alloc.ensure(
+                    slot, int(self._slot_len[slot]) + self._step_rows):
                 # pool pressure order: evict cold cached prefixes (LRU,
                 # refcount-0-only — a block any live slot references is
                 # untouchable) BEFORE preempting a running request
@@ -1253,7 +1352,7 @@ class LLMEngine:
             for slot in range(self.num_slots):
                 if self._runs_next(slot):
                     self._window_blocks_freed += self._alloc.trim(
-                        slot, int(self._slot_len[slot]) + 1)
+                        slot, int(self._slot_len[slot]) + self._step_rows)
         with phase("grow"):
             self._grow_active_slots()
         with phase("admit",
@@ -1299,7 +1398,8 @@ class LLMEngine:
         # runs instead.
         if self._proposer is not None and self._spec_decode_step(active):
             return True
-        ahead = self._dispatch(active)
+        ahead = (self._dispatch(active) if self._block_step is None
+                 else self._dispatch_block(active))
         self._land()
         self._flight = ahead
         if self._proposer is not None:
@@ -1361,6 +1461,8 @@ class LLMEngine:
         flight, self._flight = self._flight, None
         if flight is None:
             return
+        if flight.coming is not None:
+            return self._land_block(flight)
         with self._phases("logits_fetch", step=flight.step):
             ids = self._fetch(flight.ids, flight.counters)
         self._steps += 1
@@ -1381,6 +1483,105 @@ class LLMEngine:
                 req.output.append(int(tok))
                 self._last_token[slot] = tok
                 self._tokens_generated += 1
+                self._maybe_finish(slot)
+
+    # ---------------------------------------------------- the block turn
+    def _dispatch_block(self, active: np.ndarray) -> _Flight:
+        """One block step of the ``active`` slots and the program that
+        decides positions, both left on the device. What the step does
+        to a slot follows from the host's counts: a slot with nothing
+        undecided commits (its length grows by a block here, its tokens
+        are appended at the fetch, its next block begins), any other
+        decides its quota."""
+        import jax.numpy as jnp
+
+        B = self._step_rows
+        before = self._flight
+        step = self._steps + (before is not None)
+        with self._phases("block_dispatch", step=step):
+            reqs = [r if a else None for r, a in zip(self._slots, active)]
+            if before is None:
+                self._turns_drained += 1
+            else:
+                self._turns_overlapped += 1
+            if self._blk_seated.any():
+                # of copies (asarray may alias the host's buffers): the
+                # host seats the next request while this step runs
+                self._block_state = self._seat_blocks(
+                    *self._block_state, jnp.asarray(self._blk_ids.copy()),
+                    jnp.asarray(self._blk_decided.copy()),
+                    jnp.asarray(self._blk_seated.copy()))
+                self._blk_seated[:] = False
+            ids, decided = self._block_state
+            commits = active & (self._blk_undecided == 0)
+            denoises = active & ~commits
+            quota = np.where(denoises, np.minimum(self._blk_quota,
+                                                  self._blk_undecided), 0)
+            self._cache, logits = self._block_step(
+                self._cache, self._alloc.device_tables(), ids, decided,
+                jnp.asarray(active))
+            counters = self._take_counters()
+            sampled = int(np.count_nonzero(self._draw_temp[denoises] > 0.0))
+            draw = None
+            if not sampled:
+                self._greedy_turns += 1
+            else:
+                self._sampled_turns += 1
+                self._sampled_tokens += int(
+                    quota[self._draw_temp > 0.0].sum())
+                if self._draw_rows is None:
+                    self._draw_rows = (jnp.asarray(self._draw_temp.copy()),
+                                       jnp.asarray(self._draw_request.copy()))
+                temperature, request = self._draw_rows
+                draw = (temperature, self._key, request,
+                        jnp.asarray(self._slot_len.astype(np.int32)))
+            ids, decided, out = self._block_decide(
+                logits, ids, decided, jnp.asarray(quota.astype(np.int32)),
+                draw)
+            self._block_state = (ids, decided)
+            coming = np.where(commits, B - self._blk_skip, 0)
+            self._slot_len[commits] += B
+            self._blk_undecided -= quota
+            self._blk_undecided[commits] = B
+            self._blk_quota[commits] = self.config.step_quota(B)
+            self._blk_skip[commits] = 0
+            n_commits = int(np.count_nonzero(commits))
+            counts = self._block_counts
+            counts["block_steps"] += 1
+            counts["slot_steps"] += int(np.count_nonzero(active))
+            counts["commit_steps"] += n_commits
+            counts["blocks_committed"] += n_commits
+            counts["positions_decided"] += int(quota.sum())
+        return _Flight(step, out, counters, reqs, sampled, coming)
+
+    def _land_block(self, flight: _Flight) -> None:
+        """Read what the block step in flight committed (a block's ids
+        a slot that committed; nothing of the others) and do its
+        bookkeeping: the tokens, cut at ``max_tokens`` or after an EOS,
+        and the finishes they bring."""
+        with self._phases("block_fetch", step=flight.step):
+            ids = self._fetch(flight.ids, flight.counters)
+        self._steps += 1
+        B = self._step_rows
+        committed = np.nonzero(flight.coming)[0]
+        # ONE span around the slots that committed, never one per slot
+        # (a slot that only decided has nothing to book)
+        with self._phases("block_commit", active=len(committed),
+                          sampled=flight.sampled):
+            for slot in committed:
+                req = flight.reqs[slot]
+                if self._slots[slot] is not req:
+                    # it ended (an EOS, a cancel) with the block before
+                    self._surplus_dropped += 1
+                    continue
+                toks = ids[slot, B - flight.coming[slot]:].tolist()
+                toks = toks[:req.max_tokens - len(req.output)]
+                if req.eos_token is not None and req.eos_token in toks:
+                    toks = toks[:toks.index(req.eos_token) + 1]
+                req.output.extend(toks)
+                if req.first_token_at is None:
+                    req.first_token_at = time.monotonic()
+                self._tokens_generated += len(toks)
                 self._maybe_finish(slot)
 
 

@@ -128,14 +128,23 @@ SSM_PARTS = (
 )
 
 
+# What a model that generates by diffusion over blocks adds (PR 48,
+# ``models/sdar.py``): the RMSNorm over a head's width on every query
+# and key head, and the deciding program's arithmetic (the softmax of a
+# block's logits, the pick, the ranking of the undecided positions). The
+# reader's side is ``benchmark/layer_metrics/parts/sdar.json``.
+BLOCK_PARTS = ("qk_norm", "block_decide")
+_VOCABULARY = PARTS + SSM_PARTS + BLOCK_PARTS
+
+
 def part(name: str):
     """``jax.named_scope(name)`` for a ``name`` of :data:`PARTS` (or of
-    :data:`SSM_PARTS`): what is traced inside belongs to that part of
-    the block. Checked while tracing; nothing runs for it on the device
-    or in a loop's turn."""
-    if name not in PARTS and name not in SSM_PARTS:
+    :data:`SSM_PARTS` or :data:`BLOCK_PARTS`): what is traced inside
+    belongs to that part of the block. Checked while tracing; nothing
+    runs for it on the device or in a loop's turn."""
+    if name not in _VOCABULARY:
         raise ValueError(f"{name!r} is no part of a block: one of "
-                         f"{PARTS + SSM_PARTS}")
+                         f"{_VOCABULARY}")
     import jax
 
     return jax.named_scope(name)

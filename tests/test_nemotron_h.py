@@ -578,13 +578,15 @@ def test_the_tiny_defaults_are_a_model_of_their_own():
 
     cfg = nemotron_h.NemotronHConfig()
     model = serving_model(cfg)
-    # a model has what it has builders for: this one none of the five
+    # a model has what it has builders for: this one none of the
     # mechanisms beside the paged path, the dense decoder all of them
+    # but the block step that stands in place of a decode step
     dense = serving_model(preset="debug")
     for builder in LLMEngine._MECHANISMS:
         assert not hasattr(model, builder), builder
-        assert callable(getattr(dense, builder)), builder
-    assert len(LLMEngine._MECHANISMS) == 5
+        assert callable(getattr(dense, builder, None)) == (
+            builder != "block_denoise"), builder
+    assert len(LLMEngine._MECHANISMS) == 6
     eng = LLMEngine(config=cfg, num_slots=2, max_seq=64, kv_block_size=8)
     try:
         assert len(eng.generate([3, 4, 5], max_tokens=5)) == 5
