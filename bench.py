@@ -156,8 +156,8 @@ def run_kernels():
     MBS = PLEN // PBS
     NBLK = PB * MBS
     qd = jax.random.normal(key, (PB, 1, H, D), dt)
-    kp = jax.random.normal(key, (1, NBLK, PBS, PKV, D), dt)  # one layer
-    vp = jax.random.normal(key, (1, NBLK, PBS, PKV, D), dt)
+    kp = jax.random.normal(key, (1, NBLK, PBS, PKV * D), dt)  # one layer
+    vp = jax.random.normal(key, (1, NBLK, PBS, PKV * D), dt)
     tables = jnp.arange(NBLK, dtype=jnp.int32).reshape(PB, MBS)
     lengths = jnp.full((PB,), PLEN, jnp.int32)
     paged = jax.jit(lambda *a: paged_decode_attention(

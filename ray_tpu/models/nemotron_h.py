@@ -189,7 +189,7 @@ def init_params(cfg: NemotronHConfig, key: jax.Array) -> Params:
 def init_cache(cfg: NemotronHConfig, page: PagedConfig, num_slots: int):
     c = cfg
     kv = (c.pattern.count("*"), page.num_blocks, page.block_size,
-          c.n_kv_heads, c.head_dim)
+          c.n_kv_heads * c.head_dim)
     nM, G = c.pattern.count("M"), c.ssm_groups
     return {"k": jnp.zeros(kv, c.dtype), "v": jnp.zeros(kv, c.dtype),
             "length": jnp.zeros((num_slots,), jnp.int32),
@@ -392,21 +392,24 @@ def _qkv(x, layer, cfg):
 @part("kv_store")
 def _store_blocks(pools, li, dest, valid, k, v):
     """A padded prompt's keys and values (P, KV, D) into the blocks
-    ``dest`` (nblk,) of layer ``li``, block by block. Not one scatter of
-    all the blocks (``paged_cache.store_kv_rows``): at 2 KV heads the
-    compiler lays a scatter's operand out with the block's tokens, not
-    its heads, on the sublanes, and copies the WHOLE pool there and back
-    around the write (0.6 ms each way for each of k and v at the cell's
-    pool; my chip run, PR 39)."""
+    ``dest`` (nblk,) of layer ``li``, block by block, each a token's KV
+    heads side by side as the pool holds them. Not one scatter of all
+    the blocks (``paged_cache.store_kv_rows``): while the pool's rows
+    were (KV, D) the compiler laid a scatter's operand out with the
+    block's tokens, not its 2 heads, on the sublanes, and copied the
+    WHOLE pool there and back around the write (0.6 ms each way for each
+    of k and v at the cell's pool; my chip run, PR 39). Kept as measured
+    then; whether the lane-dense pool still needs it was not asked."""
     kc, vc = pools
     bs = kc.shape[2]
 
     def put(pool, rows, j):
-        block = jnp.where(valid[j * bs:(j + 1) * bs, None, None],
-                          rows[j * bs:(j + 1) * bs], 0.0)
+        block = pc.fold_heads(jnp.where(
+            valid[j * bs:(j + 1) * bs, None, None],
+            rows[j * bs:(j + 1) * bs], 0.0))
         return jax.lax.dynamic_update_slice(
             pool, block.astype(pool.dtype)[None, None],
-            (li, dest[j], 0, 0, 0))
+            (li, dest[j], 0, 0))
 
     for j in range(dest.shape[0]):
         kc, vc = put(kc, k, j), put(vc, v, j)
@@ -478,7 +481,8 @@ def make_decode_step(params: Params, cfg: NemotronHConfig, page: PagedConfig):
                                              active)
             elif kind == "*":
                 q, k, v = _qkv(x, layer, cfg)
-                pools = pc.store_kv_rows(pools, (li, blk, off), k, v)
+                pools = pc.store_kv_rows(pools, (li, blk, off),
+                                         pc.fold_heads(k), pc.fold_heads(v))
                 out = paged_decode(q[:, None], *pools, li, table, att_len,
                                    scale=scale, work=work)
                 x = _attn_out(x, out[:, 0], layer)

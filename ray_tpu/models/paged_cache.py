@@ -5,7 +5,7 @@ Replaces the slot model's per-slot ``max_seq`` reservation
 (:mod:`ray_tpu.models.decoding` keeps a (layers, slots, max_seq, KV, D)
 ring) with a shared pool of fixed-size token blocks:
 
-    pool      (layers, num_blocks, block_size, KV, D)
+    pool      (layers, num_blocks, block_size, KV * D)   — a token a row
     tables    (slots, max_blocks_per_seq) int32   — host-owned
     lengths   (slots,) int32                       — device-resident
 
@@ -86,8 +86,10 @@ def init_paged_cache(config: LlamaConfig, page: PagedConfig,
                      num_slots: int, dtype=None) -> PagedCache:
     c = config
     dt = dtype or c.dtype
+    # lane-dense: a token's KV heads side by side in one row, which is
+    # what the decode kernel multiplies and what the chip tiles whole
     shape = (c.n_layers, page.num_blocks, page.block_size,
-             c.n_kv_heads, c.head_dim)
+             c.n_kv_heads * c.head_dim)
     return {
         "k": jnp.zeros(shape, dt),
         "v": jnp.zeros(shape, dt),
@@ -481,6 +483,13 @@ def hybrid_cache(pools, length, counters):
 
 
 @part("kv_store")
+def fold_heads(rows):
+    """(..., KV, D) keys or values as a pool row holds them, a token's KV
+    heads side by side: (..., KV * D)."""
+    return rows.reshape(*rows.shape[:-2], -1)
+
+
+@part("kv_store")
 def store_kv_rows(pool, where, k_rows, v_rows):
     """Write key rows and value rows into one kind's (k, v) pools at
     ``where`` (layer, blocks[, offsets]): in place, inside a jitted
@@ -567,7 +576,7 @@ def _scan_layers(attend, x, params: Params, cache: PagedCache,
     cos, sin = rope_frequencies(c.head_dim, c.max_seq, c.rope_theta)
 
     def body(carry, scanned):
-        x, kc, vc = carry                      # pools (L, NB, bs, KV, D)
+        x, kc, vc = carry                      # pools (L, NB, bs, KV * D)
         layer, l = scanned
         x, (kc, vc) = dense_block(x, layer, c, cos, sin, positions, attend,
                                   (kc, vc, l))
@@ -623,8 +632,10 @@ def make_chunked_paged_prefill(params: Params, config: LlamaConfig,
         def attend(q, k, v, state):
             kc, vc, l = state
             with part("kv_store"):
-                kb = jnp.where(mask_valid[:, None, None], k[0], 0.0)
-                vb = jnp.where(mask_valid[:, None, None], v[0], 0.0)
+                kb = fold_heads(jnp.where(mask_valid[:, None, None], k[0],
+                                          0.0))
+                vb = fold_heads(jnp.where(mask_valid[:, None, None], v[0],
+                                          0.0))
             kc, vc = store_kv_rows((kc, vc), (l, row_blk, row_off), kb, vb)
             # gather the slot's full row set (prefix + this chunk) and
             # attend with absolute-position causal visibility
@@ -679,8 +690,8 @@ def make_paged_decode_step(params: Params, config: LlamaConfig,
 
         def attend(q, k, v, state):
             kc, vc, l = state
-            kc, vc = store_kv_rows((kc, vc), (l, blk, off), k[:, 0],
-                                   v[:, 0])
+            kc, vc = store_kv_rows((kc, vc), (l, blk, off),
+                                   fold_heads(k[:, 0]), fold_heads(v[:, 0]))
             # the pool goes as the layer scan carries it, whole: a
             # per-layer slice here would be a copy of that layer
             out = paged_decode(q, kc, vc, l, tables, att_len,
@@ -714,7 +725,7 @@ def make_paged_prefill(params: Params, config: LlamaConfig,
     def prefill(params: Params, cache: PagedCache, table_row, tokens,
                 true_len, slot, pad_len: int):
         nblk = pad_len // bs
-        blocks_shape = (nblk, bs, c.n_kv_heads, c.head_dim)
+        blocks_shape = (nblk, bs, c.n_kv_heads * c.head_dim)
         positions = jnp.arange(pad_len)[None, :]
         mask_valid = positions[0] < true_len                  # (P,)
         # rows past true_len write into the null block
@@ -746,8 +757,9 @@ def make_paged_prefill(params: Params, config: LlamaConfig,
 
 def make_paged_inject(config: LlamaConfig, page: PagedConfig):
     """inject(cache, table_row (MBS,) i32, k, v, true_len, slot) → cache.
-    k/v are (layers, P, KV, D) with P a multiple of block_size; rows at
-    or beyond true_len must be zero. The KV-transfer half of PD
+    k/v are (layers, P, KV, D), or (layers, P, KV * D) as
+    :func:`extract_kv` gives them, with P a multiple of block_size; rows
+    at or beyond true_len must be zero. The KV-transfer half of PD
     disaggregation and the prefix cache, over blocks."""
     c = config
     bs = page.block_size
@@ -759,8 +771,8 @@ def make_paged_inject(config: LlamaConfig, page: PagedConfig):
         nblk = pad_len // bs
         dest = jnp.where(jnp.arange(nblk) * bs < true_len,
                          table_row[:nblk], 0)
-        kb = k.reshape(c.n_layers, nblk, bs, c.n_kv_heads, c.head_dim)
-        vb = v.reshape(c.n_layers, nblk, bs, c.n_kv_heads, c.head_dim)
+        kb = k.reshape(c.n_layers, nblk, bs, -1)
+        vb = v.reshape(c.n_layers, nblk, bs, -1)
         kc = cache["k"].at[:, dest].set(kb.astype(cache["k"].dtype))
         vc = cache["v"].at[:, dest].set(vb.astype(cache["v"].dtype))
         new_len = cache["length"].at[slot].set(true_len)
@@ -801,15 +813,16 @@ def make_block_copy(config: LlamaConfig, page: PagedConfig):
 
 def extract_kv(cache: PagedCache, allocator: BlockAllocator, slot: int,
                true_len: int) -> Tuple[np.ndarray, np.ndarray]:
-    """Device→host copy of one slot's cached KV rows [0, true_len):
-    gathers the slot's blocks and trims. The PD/prefix-cache export."""
+    """Device→host copy of one slot's cached KV rows [0, true_len), as
+    the pool holds them, (layers, true_len, KV * D) each: gathers the
+    slot's blocks and trims. The PD/prefix-cache export."""
     bs = allocator.page.block_size
     nblk = allocator.blocks_for(true_len)
     ids = allocator.tables[slot, :nblk]
     k, v = jax.device_get((cache["k"][:, ids], cache["v"][:, ids]))
-    L, _, _, KV, D = k.shape
-    k = k.reshape(L, nblk * bs, KV, D)[:, :true_len]
-    v = v.reshape(L, nblk * bs, KV, D)[:, :true_len]
+    L, _, _, W = k.shape
+    k = k.reshape(L, nblk * bs, W)[:, :true_len]
+    v = v.reshape(L, nblk * bs, W)[:, :true_len]
     return np.asarray(k), np.asarray(v)
 
 

@@ -39,7 +39,7 @@ Three programs, the engine's to run (:mod:`ray_tpu.models.serving`):
   device.
 
 The cache is the dense paged one (``k`` / ``v`` pools of (layers, blocks,
-block size, KV, D), a :class:`BlockAllocator`), carried whole and
+block size, KV * D), a :class:`BlockAllocator`), carried whole and
 updated in place; ``cache["counters"]`` is a program's expert-layer
 counters summed over its layers.
 """
@@ -56,7 +56,7 @@ import jax.numpy as jnp
 from ray_tpu.models import moe
 from ray_tpu.models.decoding import _bind_padded, _bind_params
 from ray_tpu.models.paged_cache import (BlockAllocator, PagedConfig,
-                                        store_kv_rows)
+                                        fold_heads, store_kv_rows)
 from ray_tpu.ops.attention import prompt_attention
 from ray_tpu.ops.norms import rmsnorm
 from ray_tpu.ops.pallas.paged_decode_attention import (paged_decode,
@@ -155,7 +155,7 @@ def init_params(cfg: SdarConfig, key: jax.Array) -> Params:
 # ------------------------------------------------------------------- cache
 def init_cache(cfg: SdarConfig, page: PagedConfig, num_slots: int):
     shape = (cfg.n_layers, page.num_blocks, page.block_size,
-             cfg.n_kv_heads, cfg.head_dim)
+             cfg.n_kv_heads * cfg.head_dim)
     return {"k": jnp.zeros(shape, cfg.dtype),
             "v": jnp.zeros(shape, cfg.dtype),
             "length": jnp.zeros((num_slots,), jnp.int32),
@@ -232,10 +232,11 @@ def make_prefill(params: Params, cfg: SdarConfig, page: PagedConfig):
         row = jnp.arange(pad_len)
         valid = row < true_len
         with part("kv_store"):
-            # row by row, as the block step writes: a write of whole
-            # (block size, KV, D) blocks makes the chip's compiler lay
-            # the WHOLE pool out anew around it where KV heads are fewer
-            # than a tile's sublanes (four pool-sized copies a prefill)
+            # row by row, as the block step writes: while the pool's
+            # rows were (KV, D), a write of whole (block size, KV, D)
+            # blocks made the chip's compiler lay the WHOLE pool out
+            # anew around it where KV heads are fewer than a tile's
+            # sublanes (four pool-sized copies a prefill)
             blk = jnp.where(valid, table_row[row // bs], 0)
             off = row % bs
         pool = (cache["k"], cache["v"])
@@ -247,7 +248,8 @@ def make_prefill(params: Params, cfg: SdarConfig, page: PagedConfig):
             with part("attn_proj"):
                 x = x + jnp.einsum("bshd,hde->bse", out,
                                    layer["wo"].astype(x.dtype))
-            pool = store_kv_rows(pool, (l, blk, off), k[0], v[0])
+            pool = store_kv_rows(pool, (l, blk, off), fold_heads(k[0]),
+                                 fold_heads(v[0]))
             with part("mlp"):
                 normed = rmsnorm(x[0], layer["mlp_norm"], cfg.norm_eps)
             y, c = _mlp(normed, layer, cfg, valid,
@@ -299,7 +301,8 @@ def make_block_step(params: Params, cfg: SdarConfig, page: PagedConfig):
         counters = jnp.zeros((len(moe.COUNTERS),), jnp.float32)
         for l, layer in enumerate(params["layers"]):
             q, k, v = _qkv(x, layer, cfg, cos, sin, positions)
-            kc, vc = pool = store_kv_rows(pool, (l, blk, off), k, v)
+            kc, vc = pool = store_kv_rows(pool, (l, blk, off),
+                                          fold_heads(k), fold_heads(v))
             with part("attn_proj"):
                 # the queries of one KV head together, position after
                 # position: the kernel's (B x group) rows a KV head

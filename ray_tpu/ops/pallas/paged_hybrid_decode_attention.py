@@ -1,31 +1,31 @@
-"""Paged decode attention for a model that mixes full and sliding-window
-layers (Pallas TPU): one new query token per slot against that slot's
-paged KV, where a key is not as wide as a value, a window layer reads
-only the blocks its window still touches, and a learned per-head sink
-logit may sit in the softmax's denominator.
-
-A second kernel beside ``paged_decode_attention.py`` and not an
-extension of it: the dense pool is (L, NB, bs, KV, D) with one width for
-keys and values, and its kernel's numbers are the benchmark's baseline.
-Here the pools are lane-dense, one row a token:
+"""Paged decode attention over lane-dense pools (Pallas TPU): one new
+query token per slot against that slot's paged KV. The ONE body of every
+paged pool of whole K/V rows: a model that mixes full and sliding-window
+layers, where a key is not as wide as a value, a window layer reads only
+the blocks its window still touches, and a learned per-head sink logit
+may sit in the softmax's denominator; and the dense pools
+(``paged_decode_attention.py``, which calls this kernel with a key as
+wide as a value, no window and no sink, under its own name). The pools
+are lane-dense, one row a token:
 
     k_pool   (L, NB, bs, Wk)       every kv head's key, packed by the
                                    model in chunks of ``c`` columns
     v_pool   (L, NB, bs, KV * Dv)
     q        (B, H, n * c)         packed like a key: kv head j's key is
                                    the columns ``k_slices[j]`` (n starts,
-                                   each c wide) of a row, in q's order
+                                   each c wide) of a row, in q's order;
+                                   row h's kv head is ``h // (H // KV)``
 
 so a 192-wide key needs no padding to 256 lanes: the model packs it into
 whole chunks (``ray_tpu.models.mimo_v2.pack_keys``) and every slice the
 kernel takes is aligned. ``layer``, ``tables``, ``lengths`` and the work
-list ride as scalar prefetch, as in the dense kernel.
+list ride as scalar prefetch.
 
-The grid is the WORK LIST (:func:`hybrid_work_list`, the dense kernel's
-``decode_work_list`` with a first block and G blocks a step): one step
-for each run of G logical blocks of a slot that the call must read, in
-slot order, and none for any other; its bound is the list's length, a
-traced scalar. A full layer's blocks are a slot's ``0 .. ceil(length /
+The grid is the WORK LIST (:func:`hybrid_work_list`:
+``paged_decode_attention.decode_work_list`` with a first block and G
+blocks a step): one step for each run of G logical blocks of a slot that
+the call must read, in slot order, and none for any other; its bound is
+the list's length, a traced scalar. A full layer's blocks are a slot's ``0 .. ceil(length /
 bs) - 1``, G = 4 of them a step; a window layer's start at the block
 that holds position ``length - window`` and are at most
 ``blocks_in_window``, all in ONE step: blocks wholly behind the window

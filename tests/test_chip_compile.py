@@ -81,8 +81,8 @@ def _pool_movers(text: str, pool) -> list:
     """Instructions of a compiled module that copy or slice a whole
     layer of the KV pool, or the pool: a ``copy``, ``dynamic-slice`` or
     ``dynamic-update-slice`` (alone or as a fusion named after it) with
-    an operand or result of shape (NB, bs, KV, D) or (L, NB, bs, KV, D).
-    The in-place scatter of the new rows is none of these."""
+    an operand or result of the pool's shape, (L, NB, bs, KV * D), or a
+    layer's. The in-place scatter of the new rows is none of these."""
     per_layer = ",".join(str(d) for d in pool.shape[1:])
     shape = re.compile(rf"bf16\[({pool.shape[0]},)?{per_layer}\]")
     mover = re.compile(r"= \S+ (copy|dynamic-slice|dynamic-update-slice)\("
@@ -165,13 +165,17 @@ def test_decode_attention_compiles_at_1b_widths(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-# (slots, heads, kv heads, head dim, block, table columns, pool layers and
-# blocks): the 1b engine's two block sizes, then the dense serve cells' own
-# (mistral-7b-l16: 32 slots of a 4,096-token max_seq over a 49,152-token pool)
+# (slots, query rows, kv heads, head dim, block, table columns, pool layers
+# and blocks): the 1b engine's two block sizes, then the dense serve cells'
+# own (mistral-7b-l16: 32 slots of a 4,096-token max_seq over a 49,152-token
+# pool), the block-diffusion cell's (4 positions x 32 heads a slot) and the
+# state-space cell's one attention layer in its period (2 KV heads)
 PAGED_DECODE_SHAPES = {
     "1b-block64": (8, H, KV, D, 64, 128, CFG.n_layers, 1025),
     "1b-block16": (8, H, KV, D, 16, 512, CFG.n_layers, 4097),
     "dense-serve-cells": (32, 32, 8, 128, 64, 64, 16, 769),
+    "blockdiff-cell": (128, 128, 4, 128, 64, 32, 7, 3073),
+    "state-space-cell": (192, 32, 2, 128, 64, 32, 1, 6145),
 }
 
 
@@ -182,14 +186,14 @@ def test_paged_decode_attention_compiles_at_1b_widths(one_chip, shape):
 
     slots, h, kv, d, block_size, cols, layers, nb = PAGED_DECODE_SHAPES[shape]
     q = _sds((slots, 1, h, d), jnp.bfloat16, one_chip)
-    pool = _sds((layers, nb, block_size, kv, d), jnp.bfloat16, one_chip)
+    pool = _sds((layers, nb, block_size, kv * d), jnp.bfloat16, one_chip)
     layer = _sds((), jnp.int32, one_chip)
     tables = _sds((slots, cols), jnp.int32, one_chip)
     lens = _sds((slots,), jnp.int32, one_chip)
     fn = jax.jit(lambda q, k, v, l, t, n: paged_decode_attention(
         q, k, v, l, t, n, scale=d ** -0.5))
-    compiled = fn.lower(q, pool, pool, layer, tables, lens).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = fn.lower(q, pool, pool, layer, tables, lens).compile().as_text()
+    assert "tpu_custom_call" in text and "%paged_decode_attention" in text
 
 
 @pytest.mark.parametrize("kind", ["full", "window"])
@@ -396,25 +400,20 @@ def _lower_chunk(one_chip, cfg=CFG, pad_len=256):
         pad_len=pad_len)
 
 
-# The 1b engine shapes with the 128-wide heads of the benchmark's cells
-# (16 x 128 = 32 x 64: same hidden size, same weights' bytes). A pool
-# whose rows are 64 wide is another matter: the device keeps
-# bf16[L, NB, bs, KV, 64] with the BLOCK axis on the lanes (its default
-# layout for a minor dimension under 128), so every program that touches
-# rows or blocks, before this change and after it, first turns the whole
-# pool row-major and turns it back at the end. That is the layout's
-# cost, not the scan's (ROADMAP, Speed), and the strict xfail below
-# keeps it in sight until the pool's layout is pinned.
+# The 1b engine shapes, and the same with the 128-wide heads of the
+# benchmark's cells (16 x 128 = 32 x 64: same hidden size, same weights'
+# bytes). The pool's rows are a token's KV heads side by side, ``KV x D``
+# wide, so a head of 64 columns does not set the device's layout: a minor
+# dimension under 128 would put the BLOCK axis on the lanes, and every
+# program that touches rows or blocks would turn the whole pool row-major
+# and back around its loop (ROADMAP S9).
 CFG_D128 = dataclasses.replace(CFG, n_heads=16, head_dim=128)
 
 
-@pytest.mark.parametrize("lower, cfg", [
-    (_lower_decode, CFG_D128), (_lower_prefill, CFG_D128),
-    (_lower_chunk, CFG_D128),
-    pytest.param(_lower_decode, CFG, marks=pytest.mark.xfail(
-        strict=True, reason="head_dim 64: the pool's device layout is "
-        "not row-major, the program re-lays it out around the loop"))],
-    ids=["decode", "prefill512", "chunk256", "decode-head64"])
+@pytest.mark.parametrize("cfg", [CFG_D128, CFG], ids=["head128", "head64"])
+@pytest.mark.parametrize("lower", [_lower_decode, _lower_prefill,
+                                   _lower_chunk],
+                         ids=["decode", "prefill512", "chunk256"])
 def test_1b_serving_program_holds_no_second_pool(one_chip, as_tpu, lower,
                                                  cfg):
     """The pool is a donated argument that the layer scan carries and
@@ -492,6 +491,59 @@ def _routed_programs(cell, device):
         deployment = json.load(f)["deployment"]
     return (deployment["num_slots"],
             *sizing.serve_programs(spec, deployment, device))
+
+
+# the cells over a dense paged pool, ``tokens' KV heads side by side``:
+# (configuration, {program: most GiB the compile may sum to}); "decode" is
+# the cell's step for all slots (the block step where blocks are denoised)
+GIB = 1024 ** 3
+DENSE_POOL_CELLS = {
+    "serve-batch-decode": ("mistral-7b-l16", {"decode": 10.1}),
+    "serve-ssm-latent-moe-chat": ("nemotron3-super-ep4-l11",
+                                  {"decode": 13.0}),
+    # cells/serve-blockdiff-moe-decode.json: 12.50 the step, 11.92 and
+    # 12.09 the 64 and 1,024 buckets (the code's 0.01 GiB beside them)
+    "serve-blockdiff-moe-decode": ("sdar-30b-a3b-l7", {
+        "decode": 12.55, "prefill64": 11.97, "prefill1024": 12.14}),
+}
+
+
+@pytest.mark.parametrize("cell, program", [
+    (cell, program) for cell, (_, programs) in DENSE_POOL_CELLS.items()
+    for program in programs])
+def test_dense_pool_cells_program_holds_no_second_pool(topo, as_tpu, cell,
+                                                       program):
+    """The programs of the three cells' models that write and read a
+    lane-dense pool, at the cells' own sizes: the pool is donated and
+    updated in place, nothing copies or re-lays-out the pool or a layer
+    of it (2 and 4 KV heads made the compiler do that around a write of
+    whole ``(bs, KV, D)`` blocks), the temporaries hold nothing of its
+    size, the step's attention is ``paged_decode_attention``, and the
+    whole stays within what the cell's file states."""
+    from benchmark import model_spec, sizing
+
+    config, programs = DENSE_POOL_CELLS[cell]
+    with open(os.path.join(model_spec.HERE, "cells", f"{cell}.json")) as f:
+        deployment = json.load(f)["deployment"]
+    decode, bucket = sizing.serve_programs(
+        model_spec.load_config(config), deployment, topo.devices[0])
+    lowered = (decode if program == "decode"
+               else bucket(int(program.removeprefix("prefill"))))
+    pool = lowered.args_info[0][1]["k"]
+    pool = jax.ShapeDtypeStruct(pool.shape, pool.dtype)
+    assert pool.shape[2:] == (deployment["kv_block_size"], pool.shape[3])
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert not _pool_movers(text, pool)
+    # the step's attention is the kernel under its own name (once in a
+    # layer scan's body, once a layer where the layers are unrolled)
+    kernels = re.findall(r"%paged_decode_attention[.\d]* = ", text)
+    assert len(kernels) in ((1, pool.shape[0]) if program == "decode"
+                            else (0,))
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < _nbytes(pool) // 2
+    assert mem.alias_size_in_bytes >= 2 * _nbytes(pool)
+    assert _total_bytes(mem) < programs[program] * GIB
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill64"])

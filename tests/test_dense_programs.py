@@ -138,9 +138,12 @@ def _padded(tokens, width):
 
 
 def _same_rows(got, plain_s, n):
+    """A paged pool's rows hold a token's KV heads side by side."""
     _, _, k, v = plain_s
-    np.testing.assert_allclose(got[0], k[:, :n], **TOL)
-    np.testing.assert_allclose(got[1], v[:, :n], **TOL)
+    np.testing.assert_allclose(got[0].reshape(k[:, :n].shape), k[:, :n],
+                               **TOL)
+    np.testing.assert_allclose(got[1].reshape(v[:, :n].shape), v[:, :n],
+                               **TOL)
 
 
 def slot_prefill(params, plain):

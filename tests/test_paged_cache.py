@@ -94,8 +94,8 @@ class TestKernelVsOracle:
         B, H, KV, D, NB, bs, MBS = 2, 4, 2, 16, 7, 16, 3
         k1, k2, k3, k4 = jax.random.split(jax.random.key(1), 4)
         q = jax.random.normal(k1, (B, 1, H, D), jnp.float32)
-        kp = jax.random.normal(k2, (NB, bs, KV, D), jnp.float32)
-        vp = jax.random.normal(k3, (NB, bs, KV, D), jnp.float32)
+        kp = jax.random.normal(k2, (NB, bs, KV * D), jnp.float32)
+        vp = jax.random.normal(k3, (NB, bs, KV * D), jnp.float32)
         # slot 0 uses blocks [3, 5], slot 1 blocks [1, 2, 6]
         tables = jnp.array([[3, 5, 0], [1, 2, 6]], jnp.int32)
         lengths = jnp.array([20, 41], jnp.int32)
@@ -118,8 +118,8 @@ class TestKernelVsOracle:
         L, B, H, KV, D, NB, MBS = 3, 2, 4, 2, 128, 7, 3
         k1, k2, k3 = jax.random.split(jax.random.key(2), 3)
         q = jax.random.normal(k1, (B, 1, H, D), jnp.float32)
-        kp = jax.random.normal(k2, (L, NB, bs, KV, D), jnp.float32)
-        vp = jax.random.normal(k3, (L, NB, bs, KV, D), jnp.float32)
+        kp = jax.random.normal(k2, (L, NB, bs, KV * D), jnp.float32)
+        vp = jax.random.normal(k3, (L, NB, bs, KV * D), jnp.float32)
         tables = jnp.array([[3, 5, 0], [1, 2, 6]], jnp.int32)
         lengths = jnp.array([bs + 4, 2 * bs + 9], jnp.int32)
         kernel = jax.jit(lambda l: paged_decode_attention(
@@ -141,6 +141,15 @@ class TestKernelVsOracle:
             outs.append(np.asarray(got))
         assert np.abs(outs[0] - outs[1]).max() > 1e-2   # layers differ
 
+    def test_a_row_does_not_divide_into_the_querys_width(self):
+        from ray_tpu.ops.pallas.paged_decode_attention import paged_decode
+
+        q = jnp.zeros((1, 1, 4, 16), jnp.float32)
+        pool = jnp.zeros((1, 2, 8, 40), jnp.float32)
+        with pytest.raises(ValueError, match="pool rows of 40"):
+            paged_decode(q, pool, pool, 0, jnp.zeros((1, 1), jnp.int32),
+                         jnp.ones((1,), jnp.int32), scale=1.0)
+
 
 # lengths of four slots over a table of three blocks, as multiples of the
 # block size ``bs`` plus a rest: (blocks, rest) -> blocks * bs + rest
@@ -151,13 +160,29 @@ RAGGED = {
     "full-table": [(3, 0), (0, 5), (3, 0), (2, 15)],
     "every-slot-empty": [(0, 0)] * 4,
 }
+# the same over a table of SIX blocks, which the kernel's four blocks a
+# step do not divide: a slot past four blocks takes a second step whose
+# last blocks lie past the table's end
+RAGGED_TWO_STEPS = {
+    "one-past-a-step": [(4, 1), (0, 0), (4, 0), (3, 15)],
+    "one-past-a-block": [(1, 1), (1, 0), (5, 1), (0, 0)],
+    "last-step-past-the-table": [(6, 0), (5, 3), (0, 0), (5, 0)],
+    "every-table-full": [(6, 0)] * 4,
+}
+# the three dense cells' kernel shapes: query rows, KV heads, head width
+# (the block step's rows are 4 positions x 32 heads, KV-major)
+CELL_SHAPES = {"mistral": (32, 8, 128), "block-step": (128, 4, 128),
+               "two-kv-heads": (8, 2, 128)}
 
 
-def _ragged_case(case, bs):
+def _ragged_case(case, bs, shape=(4, 2, 16)):
     """q, a one-layer pool, tables and lengths of a ragged case: each slot
     owns distinct blocks for its live pairs, the null block past them."""
-    B, H, KV, D, MBS, NB = 4, 4, 2, 16, 3, 14
-    lengths = np.array([n * bs + r for n, r in RAGGED[case]], np.int32)
+    H, KV, D = shape
+    spans = RAGGED.get(case) or RAGGED_TWO_STEPS[case]
+    B, MBS = 4, 3 if case in RAGGED else 6
+    NB = B * MBS + 2
+    lengths = np.array([n * bs + r for n, r in spans], np.int32)
     tables = np.zeros((B, MBS), np.int32)
     ids = iter(np.random.default_rng(3).permutation(np.arange(1, NB)))
     for s, n in enumerate(lengths):
@@ -165,18 +190,39 @@ def _ragged_case(case, bs):
             tables[s, j] = next(ids)
     k1, k2, k3 = jax.random.split(jax.random.key(5), 3)
     q = jax.random.normal(k1, (B, 1, H, D), jnp.float32)
-    kp = jax.random.normal(k2, (1, NB, bs, KV, D), jnp.float32)
-    vp = jax.random.normal(k3, (1, NB, bs, KV, D), jnp.float32)
+    kp = jax.random.normal(k2, (1, NB, bs, KV * D), jnp.float32)
+    vp = jax.random.normal(k3, (1, NB, bs, KV * D), jnp.float32)
     return q, kp, vp, jnp.asarray(tables), jnp.asarray(lengths)
 
 
+def _by_head_loop(q, kp, vp, tables, lengths, scale, kv_of_row):
+    """Plain numpy, a query row at a time: row r of slot s against the
+    ``D`` columns of KV head ``kv_of_row(r)`` in the slot's first
+    ``lengths[s]`` token rows."""
+    q, kp, vp = (np.asarray(a, np.float64) for a in (q, kp, vp))
+    B, _, H, D = q.shape
+    bs = kp.shape[2]
+    out = np.zeros((B, 1, H, D))
+    for s, n in enumerate(np.asarray(lengths)):
+        if not n:
+            continue
+        k_rows, v_rows = (p[0, np.asarray(tables)[s]].reshape(
+            -1, p.shape[-1])[:n] for p in (kp, vp))
+        for r in range(H):
+            cols = slice(kv_of_row(r) * D, (kv_of_row(r) + 1) * D)
+            logit = k_rows[:, cols] @ q[s, 0, r] * scale
+            w = np.exp(logit - logit.max())
+            out[s, 0, r] = w @ v_rows[:, cols] / w.sum()
+    return out
+
+
 class TestKernelWalksLiveBlocksOnly:
-    """The kernel takes a step for a (slot, block) pair only if the block
-    holds cached tokens: the work list is exactly those pairs, a slot of
-    length 0 gets a row of zeros, and no other block is read."""
+    """The kernel takes a step for a run of a slot's blocks only if one
+    of them holds cached tokens: the work list is exactly those, a slot
+    of length 0 gets a row of zeros, and no other block is read."""
 
     @pytest.mark.parametrize("bs", [16, 64])
-    @pytest.mark.parametrize("case", RAGGED)
+    @pytest.mark.parametrize("case", [*RAGGED, *RAGGED_TWO_STEPS])
     def test_ragged_lengths_match_the_oracle(self, case, bs):
         from ray_tpu.ops.pallas.paged_decode_attention import (
             paged_attention_reference, paged_decode_attention)
@@ -191,8 +237,61 @@ class TestKernelWalksLiveBlocksOnly:
                                    rtol=2e-3, atol=2e-3)
         assert not got[~live].any()              # zeros, not garbage
 
+    @pytest.mark.parametrize("shape", CELL_SHAPES)
     @pytest.mark.parametrize("case", ["empty-slot-between-running",
-                                      "full-table", "every-slot-empty"])
+                                      "last-step-past-the-table"])
+    def test_the_dispatcher_at_the_cells_widths(self, case, shape,
+                                                kernel_on_cpu):
+        """``paged_decode`` as the programs call it (the kernel, its
+        work list handed in) at each dense cell's query rows, KV heads
+        and head width, against the oracle and against a loop over
+        query rows in which row r reads KV head ``r // (H // KV)``: in
+        the block step's order, position-major inside a KV head, that
+        is ``r // (block_length * group)``."""
+        from ray_tpu.ops.pallas.paged_decode_attention import (
+            paged_attention_reference, paged_decode, paged_decode_work)
+
+        H, KV, D = CELL_SHAPES[shape]
+        q, kp, vp, tables, lengths = _ragged_case(case, 16, (H, KV, D))
+        work = paged_decode_work(lengths, 16, tables.shape[1])
+        assert work is not None
+        got = np.asarray(paged_decode(q, kp, vp, 0, tables, lengths,
+                                      scale=D ** -0.5, work=work))
+        want = np.asarray(paged_attention_reference(
+            q, kp, vp, 0, tables, lengths, scale=D ** -0.5))
+        live = np.asarray(lengths) > 0       # an empty slot's row: zeros
+        np.testing.assert_allclose(got[live], want[live],
+                                   rtol=2e-3, atol=2e-3)
+        np.testing.assert_allclose(
+            got, _by_head_loop(q, kp, vp, tables, lengths, D ** -0.5,
+                               lambda r: r // (H // KV)),
+            rtol=2e-3, atol=2e-3)
+
+    def test_block_step_rows_reach_their_own_kv_head(self, kernel_on_cpu):
+        """The block step's query (S, B, H, D) in the order it hands the
+        kernel (KV head, position, head in the group) and back: every
+        position's every head gets what that head gets alone."""
+        from ray_tpu.ops.pallas.paged_decode_attention import paged_decode
+
+        Bl, KV, g, D, bs = 4, 2, 2, 16, 16
+        _, kp, vp, tables, lengths = _ragged_case(
+            "one-past-a-step", bs, (KV * g, KV, D))
+        S = tables.shape[0]
+        q = jax.random.normal(jax.random.key(9), (S, Bl, KV * g, D))
+        qk = q.reshape(S, Bl, KV, g, D).transpose(0, 2, 1, 3, 4)
+        out = paged_decode(qk.reshape(S, 1, KV * Bl * g, D), kp, vp, 0,
+                           tables, lengths, scale=0.25)
+        out = np.asarray(out).reshape(S, KV, Bl, g, D).transpose(
+            0, 2, 1, 3, 4).reshape(S, Bl, KV * g, D)
+        for b in range(Bl):
+            alone = paged_decode(q[:, b][:, None], kp, vp, 0, tables,
+                                 lengths, scale=0.25)
+            np.testing.assert_allclose(out[:, b], np.asarray(alone)[:, 0],
+                                       rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("case", ["empty-slot-between-running",
+                                      "full-table", "every-slot-empty",
+                                      "last-step-past-the-table"])
     def test_no_block_outside_the_work_list_is_read(self, case):
         from ray_tpu.ops.pallas.paged_decode_attention import (
             paged_decode_attention)
@@ -231,6 +330,35 @@ class TestKernelWalksLiveBlocksOnly:
         # list), so an index map that runs ahead names a real block
         assert set(got[len(want):]) <= {want[-1] if want
                                         else (len(lengths) - 1, 0)}
+
+    @pytest.mark.parametrize("lengths, bs, mbs", [
+        ([19, 0, 33, 7], 16, 3), ([96, 96, 96], 16, 6), ([65, 64, 1], 16, 6),
+        ([0, 0], 16, 5), ([2048] * 3, 64, 32), ([700, 9], 64, 4)])
+    def test_the_dispatchers_list_is_every_fourth_pair(self, lengths, bs,
+                                                       mbs, kernel_on_cpu,
+                                                       monkeypatch):
+        """``paged_decode_work``: the list the kernel walks, a slot's
+        blocks four a step (all of a table under four wide), as long as
+        a full batch's steps and the lookahead's one more; None where
+        the oracle attends."""
+        from ray_tpu.ops import attention
+        from ray_tpu.ops.pallas.paged_decode_attention import (
+            paged_decode_work)
+
+        G = min(4, mbs)
+        n_work, slot, block = paged_decode_work(
+            jnp.asarray(lengths, jnp.int32), bs, mbs)
+        want = [(s, j) for s, n in enumerate(lengths)
+                for j in range(0, min(-(-n // bs), mbs), G)]
+        assert int(n_work) == len(want)
+        assert slot.shape == block.shape == (len(lengths) * -(-mbs // G)
+                                             + 1,)
+        got = list(zip(np.asarray(slot).tolist(), np.asarray(block).tolist()))
+        assert got[:len(want)] == want
+        assert set(got[len(want):]) <= {want[-1] if want
+                                        else (len(lengths) - 1, 0)}
+        monkeypatch.setattr(attention, "on_tpu", lambda: False)
+        assert paged_decode_work(jnp.asarray(lengths), bs, mbs) is None
 
 
 class TestWritesInPlace:
@@ -298,7 +426,7 @@ class TestWritesInPlace:
                                           before[n][:, same])
             for blk, off in written:                   # in every layer
                 assert (after[:, blk, off] != before[n][:, blk, off]
-                        ).any(axis=(1, 2)).all()
+                        ).any(axis=1).all()
         np.testing.assert_array_equal(np.asarray(cache["length"]),
                                       [6, 18, 3])
 
@@ -328,7 +456,7 @@ class TestWritesInPlace:
                 np.asarray(cache["length"]),
                 np.where(active, np.asarray(lengths) + 1, lengths))
             changed = {n: (np.asarray(cache[n]) != before[n]).any(
-                axis=(0, 3, 4)) for n in ("k", "v")}       # (NB, bs)
+                axis=(0, 3)) for n in ("k", "v")}          # (NB, bs)
             live = {(int(al.tables[s, lengths[s] // 16]), lengths[s] % 16)
                     for s in (0, 2)}
             for n in ("k", "v"):
@@ -416,13 +544,13 @@ class TestPagedEqualsSlot:
         cache, logits0 = prefill(cache, al.tables[0], jnp.asarray(tokens),
                                  len(prompt), 0)
         k, v = extract_kv(cache, al, 0, len(prompt))
-        assert k.shape == (cfg.n_layers, len(prompt), cfg.n_kv_heads,
-                           cfg.head_dim)
+        assert k.shape == (cfg.n_layers, len(prompt),
+                           cfg.n_kv_heads * cfg.head_dim)
 
         # inject into slot 1 (pad rows to a block multiple, zeros beyond)
         pad = P - len(prompt)
-        kp = np.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        vp = np.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        kp = np.pad(k, ((0, 0), (0, pad), (0, 0)))
+        vp = np.pad(v, ((0, 0), (0, pad), (0, 0)))
         al.ensure(1, len(prompt))
         cache = inject(cache, al.tables[1], kp, vp, len(prompt), 1)
 

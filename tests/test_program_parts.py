@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from ray_tpu.models import axk1, laguna, llama, mimo_v2, nemotron_h
+from ray_tpu.models import axk1, laguna, llama, mimo_v2, nemotron_h, sdar
 from ray_tpu.models.serving import serving_model
 from ray_tpu.models.training import (OptimizerConfig, init_train_state,
                                      make_train_step)
@@ -224,6 +224,107 @@ def test_vocabulary_dot_under_head_and_pool_writes_under_kv_store(programs,
                   "[" + ",".join(map(str, s)) + "]" in result for s in pools)]
     assert writes and {part_of(p) for p in writes} == STATE_WRITES[model], \
         writes
+
+
+# ----------------------------------- the kernels, in a module made for a TPU
+def _step_for_a_tpu(cfg, monkeypatch):
+    """The model's step for all slots (the block step where blocks are
+    denoised), traced with the dispatchers told they are on a TPU and
+    lowered for one: the kernels are custom calls in it."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    served = serving_model(cfg)
+    params = served.init_params(jax.random.key(0))
+    p = served.paged(params, num_slots=SLOTS, max_seq=MAX_SEQ,
+                     block_size=BLOCK, pool_tokens=SLOTS * MAX_SEQ)
+    running = (jnp.zeros((SLOTS,), jnp.int32), jnp.ones((SLOTS,), bool))
+    if p.decode is None:
+        step, _ = served.block_denoise(params, p)
+        shape = (SLOTS, cfg.block_length)
+        running = (jnp.zeros(shape, jnp.int32), jnp.zeros(shape, bool),
+                   running[1])
+    else:
+        step = p.decode
+    return step.jitted.trace(params, p.cache, p.alloc.device_tables(),
+                             *running).lower(lowering_platforms=("tpu",))
+
+
+@pytest.mark.parametrize("model, calls", [
+    ("dense", 1), ("nemotron_h", 1), ("sdar", 4)])
+def test_a_dense_pools_step_attends_in_the_kernel_of_its_name(
+        model, calls, monkeypatch):
+    """The step over a dense paged pool (the dense decoder's layer scan,
+    the state-space model's one attention layer in its period, the block
+    step's four layers): its attention is the custom call whose
+    ``kernel_name`` is ``paged_decode_attention``, under the part of
+    that name, and no other kernel attends. The benchmark finds the
+    kernel's time and its roofline share by those two names."""
+    cfg = {**MODELS, "sdar": sdar.SdarConfig}[model]()
+    lowered = _step_for_a_tpu(cfg, monkeypatch)
+    kernels = re.findall(r'kernel_name = "(\w+)"', lowered.as_text())
+    assert kernels.count("paged_decode_attention") == calls
+    assert not [k for k in kernels if "attention" in k
+                and k != "paged_decode_attention"]
+    paths = [_OP_NAME.search(line).group(1)
+             for line in hlo_text(lowered).splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(paths) == len(kernels)
+    mine = [p for p in paths if part_of(p) == "paged_decode_attention"]
+    assert len(mine) == calls, paths
+
+
+def _without_locations(lowered):
+    """The module's text and its kernels' bodies with no file, line or
+    call stack in them: a kernel's body travels serialised inside its
+    call's ``backend_config``, locations and all, so it is parsed and
+    printed again."""
+    import base64
+    import json
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    text = lowered.compiler_ir().operation.get_asm(enable_debug_info=False)
+    config = re.compile(r'backend_config = "([^"]*)"')
+    bodies = []
+    for raw in config.findall(text):
+        body = json.loads(raw.replace("\\22", '"').replace(
+            "\\5C", "\\")).get("custom_call_config", {}).get("body")
+        if body:
+            ctx = mlir.make_ir_context()
+            ctx.allow_unregistered_dialects = True
+            with ctx:
+                bodies.append(ir.Module.parse(base64.b64decode(
+                    body)).operation.get_asm(enable_debug_info=False))
+    return config.sub('backend_config = "..."', text), bodies
+
+
+# sha256 of the step made for a TPU at the model's default (tiny) config,
+# and of its hybrid decode kernels' bodies, taken on the tree BEFORE the
+# dense pools' kernel became a caller of the hybrid body (PR 49's parent)
+HYBRID_STEPS = {
+    "mimo_v2": ("72aa5a7aa5fbc374a1ff6c2641d12ccf6177d6d26437c1e2c99f58e1a30"
+                "6a394", 2),
+    "laguna": ("7062fc35cd061758b554daf5ae29b29e5dc0637549fef06105997a5a7390"
+               "1839", 2),
+}
+
+
+@pytest.mark.parametrize("model", HYBRID_STEPS)
+def test_the_hybrid_models_step_is_the_one_it_was(model, monkeypatch):
+    """The hybrid kernel's body now serves the dense pools too. What it
+    is for its first callers did not move with that: their decode step,
+    the kernels' bodies and blocks a step included, is text for text the
+    one recorded. (A change that means to move these programs records
+    the new digest here, and says so.)"""
+    import hashlib
+
+    text, bodies = _without_locations(
+        _step_for_a_tpu(MODELS[model](), monkeypatch))
+    digest, kinds = HYBRID_STEPS[model]
+    hybrid = {b for b in bodies if "paged_hybrid_decode" in b}
+    assert len(hybrid) == kinds            # a body a kind of layer
+    whole = hashlib.sha256("\n".join([text, *bodies]).encode())
+    assert whole.hexdigest() == digest
 
 
 def test_what_a_state_space_model_adds_is_under_its_own_parts(programs):
