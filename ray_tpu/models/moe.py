@@ -413,7 +413,11 @@ def experts_by_share(x, layer: Params, *, experts_held: Tuple[int, int],
     The (token, expert) pairs whose expert is held are sorted by expert
     and laid out in tiles of ``tm`` rows, one expert a tile; three
     grouped products (``ops/pallas/grouped_matmul.py``) run over the
-    tiles that hold rows; the weighted rows are gathered back by token.
+    tiles that hold rows; each token's weighted sum over the pairs
+    placed here is ``ops/pallas/expert_combine.py``'s (a kernel that
+    copies only those pairs' rows where a small share of the router's
+    experts is held, ``kernel_serves``; XLA's gather of every pair's row
+    where the set is whole).
     ``tm`` is chosen HERE, by :func:`row_tile` from ``T``, ``top_k``,
     the router's width and ``G``: 16 for a decode step, up to 128 for a
     prefill's bucket; no caller and no option names it. The row buffer
@@ -421,7 +425,7 @@ def experts_by_share(x, layer: Params, *, experts_held: Tuple[int, int],
     rounded to tiles. ``kernel_name`` names the products' custom calls
     in a trace.
     """
-    from ray_tpu.ops.pallas import grouped_matmul as gm
+    from ray_tpu.ops.pallas import expert_combine, grouped_matmul as gm
 
     T = x.shape[0]
     xe = x if x_experts is None else x_experts
@@ -479,12 +483,12 @@ def experts_by_share(x, layer: Params, *, experts_held: Tuple[int, int],
             row_pair = jnp.zeros(T * top_k, jnp.int32).at[order].set(
                 row_sorted.astype(jnp.int32)).reshape(T, top_k)
             placed = held & (row_pair < M)
-            # rows of tiles past n_active were never written: select,
-            # never multiply, or what lies there leaks through a zero
-            # weight
-            y_pairs = jnp.where(placed[..., None],
-                                y_rows[jnp.minimum(row_pair, M - 1)], 0.0)
-            y = jnp.sum(y_pairs * w[..., None], axis=1)
+            # rows of tiles past n_active were never written: the XLA
+            # form selects and the kernel copies placed rows only; neither
+            # multiplies, or what lies there leaks through a zero weight
+            y = expert_combine.combine(
+                y_rows, row_pair, placed, w,
+                held=G, experts=layer["router"].shape[1])
     with part("expert_dispatch"):       # the step's counters: group sizes
         pairs = jnp.sum(sizes).astype(jnp.float32)
         counters = jnp.stack([

@@ -70,8 +70,9 @@ def kernel_on_cpu(monkeypatch):
     import functools
 
     from ray_tpu.ops import attention
-    from ray_tpu.ops.pallas import (decode_attention, flash_attention,
-                                    grouped_matmul, paged_decode_attention,
+    from ray_tpu.ops.pallas import (decode_attention, expert_combine,
+                                    flash_attention, grouped_matmul,
+                                    paged_decode_attention,
                                     paged_hybrid_decode_attention,
                                     paged_mla_decode_attention,
                                     ssm_decode_update)
@@ -79,6 +80,7 @@ def kernel_on_cpu(monkeypatch):
     monkeypatch.setattr(attention, "on_tpu", lambda: True)
     for module, kernel in [
             (decode_attention, "decode_attention"),
+            (expert_combine, "expert_combine"),
             (flash_attention, "flash_attention_fwd_pallas"),
             (flash_attention, "flash_attention_bwd_pallas"),
             (grouped_matmul, "grouped_matmul"),

@@ -586,15 +586,22 @@ def test_state_space_cells_program_reads_each_weight_once(
             assert not [n for n in readers if "remat" in n], readers
 
 
+# the temporaries of `serve-mla-moe-decode`'s 2,048 bucket: 0.654 GiB by
+# this compile since the combine is a kernel (PR 50: no (2,048, 8, 7,168)
+# float32 gather beside the grouped products' rows; 1.036 before), held
+# 5% over
+LATENT_BUCKET_TEMP_GIB = 0.69
+
+
 def test_latent_cells_largest_bucket_attends_in_the_flash_kernel(
         topo, as_tpu):
     """`serve-mla-moe-decode`'s 2,048 bucket, where every prompt of the
     cell lands: each of the five layers' expanded attention is ONE
     ``flash_attention_fwd`` call, and no float32 array of a layer's
     whole scores (64 x 2,048 x 2,048 x 4 B = 1.07 GB in XLA) is left in
-    the program. What peaks in the bucket's 1.00 GiB of temporaries is
-    then the expert layer's float32 rows (the grouped product's (16,576 x
-    7,168) output beside the combine's gather): held as the ceiling."""
+    the program. What peaks in the bucket's temporaries is then the
+    expert layer's float32 rows (the grouped product's (17,920 x 7,168)
+    output; the combine gathers nothing): held as the ceiling."""
     _, _, bucket = _routed_programs("serve-mla-moe-decode", topo.devices[0])
     compiled = bucket(2048).compile()
     text = compiled.as_text()
@@ -604,7 +611,8 @@ def test_latent_cells_largest_bucket_attends_in_the_flash_kernel(
     assert all(k.startswith("= (bf16[64,2048,128]") for k in kernels)
     # a layer's scores whole: any float32 (..., 2048, 2048) of several heads
     assert not re.findall(r"= f32\[(?:\d+,)+2048,2048\]", text)
-    assert compiled.memory_analysis().temp_size_in_bytes < 1.05 * 1024 ** 3
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < LATENT_BUCKET_TEMP_GIB * 1024 ** 3)
 
 
 # ------------------------------------ the grouped products' row tile (PR 45)
@@ -637,8 +645,7 @@ def test_latent_cells_largest_bucket_takes_the_rules_tile(topo, as_tpu):
     """`serve-mla-moe-decode`'s 2,048 bucket (85 rows an expert): its
     twelve grouped products walk the tile that ``moe.row_tile`` gives at
     (2,048, 8, 192, 12), the row buffer is the worst case at that tile,
-    and the bucket's temporaries stay under the ceiling held since
-    PR 44."""
+    and the bucket's temporaries stay under the ceiling."""
     from ray_tpu.models import moe
 
     tm = moe.row_tile(2048, 8, 192, 12)
@@ -651,7 +658,7 @@ def test_latent_cells_largest_bucket_takes_the_rules_tile(topo, as_tpu):
     assert {(int(t), int(r)) for t, r, _, _ in products} == {
         (tiles, tiles * tm)}
     mem = lowered.compile().memory_analysis()
-    assert mem.temp_size_in_bytes < 1.05 * 1024 ** 3
+    assert mem.temp_size_in_bytes < LATENT_BUCKET_TEMP_GIB * 1024 ** 3
 
 
 @pytest.mark.parametrize("K, N", [(7168, 256), (2048, 1024)])
@@ -675,6 +682,89 @@ def test_grouped_matmul_compiles_at_128_rows(one_chip, K, N):
                     _sds((), jnp.int32, one_chip)).compile().as_text()
     assert "tpu_custom_call" in text
     assert "grouped_expert_matmul_prefill" in text
+
+
+# --------------------------- the combine follows the pairs held (PR 50)
+# (tokens, top_k, the rows' width, rows of the buffer): the three
+# share-held cells' longest bucket and decode step
+COMBINE_CALLS = {
+    "axk1-2048": (2048, 8, 7168, 17920),
+    "mimo_v2-1024": (1024, 8, 4096, 9216),
+    "nemotron_h-1024": (1024, 22, 1024, 30592),
+    "axk1-decode-192": (192, 8, 7168, 1728),
+    "mimo_v2-decode-128": (128, 8, 4096, 1264),
+    "nemotron_h-decode-192": (192, 22, 1024, 6144),
+}
+
+
+@pytest.mark.parametrize("call", COMBINE_CALLS)
+def test_expert_combine_compiles_at_the_share_held_cells_shapes(one_chip,
+                                                                call):
+    """The combine's kernel at the three share-held models' widths and
+    rows: the chip's compiler takes the copies of aligned 8-row groups
+    (it refuses a one-row slice of the tiled float32 buffer), the ring
+    and the output block fit the scoped VMEM, the pair lists fit SMEM,
+    and nothing outside the kernel is a temporary."""
+    from ray_tpu.ops.pallas import expert_combine as ec
+
+    T, k, h, M = COMBINE_CALLS[call]
+    compiled = jax.jit(ec.expert_combine).lower(
+        _sds((M, h), jnp.float32, one_chip), _sds((T, k), jnp.int32, one_chip),
+        _sds((T, k), jnp.bool_, one_chip),
+        _sds((T, k), jnp.float32, one_chip)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "expert_combine" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < T * k * 16
+
+
+def _combine_kernels(text: str) -> int:
+    return len(re.findall(r'kernel_name = "expert_combine"', text))
+
+
+@pytest.mark.parametrize("cell, bucket, top_k, width, layers, decode_too", [
+    ("serve-mla-moe-decode", 2048, 8, 7168, 4, True),
+    ("serve-moe-window-decode", 1024, 8, 4096, 6, True),
+    ("serve-ssm-latent-moe-chat", 1024, 22, 1024, 5, False)])
+def test_share_held_cells_combine_in_the_kernel(topo, as_tpu, cell, bucket,
+                                                top_k, width, layers,
+                                                decode_too):
+    """Where a chip holds a share of the experts, the longest bucket
+    combines in the kernel, once a routed layer, and holds no (T, top_k,
+    h) float32 array: nothing gathers every pair's row. So does the
+    decode step where a sixteenth is held; where a quarter is
+    (`nemotron_h`, a gather of 17 MB) the step keeps the XLA form, which
+    is faster there (``expert_combine.kernel_serves``), and is the
+    parent's program."""
+    slots, decode, prefill = _routed_programs(cell, topo.devices[0])
+    for rows, lowered, kernel in ((slots, decode, decode_too),
+                                  (bucket, prefill(bucket), True)):
+        text = lowered.as_text()
+        assert _combine_kernels(text) == (layers if kernel else 0)
+        gather = f"tensor<{rows}x{top_k}x{width}xf32>"
+        assert (gather in text) != kernel
+
+
+def test_whole_held_cells_combine_in_xla(topo, as_tpu):
+    """`laguna` and `sdar` hold their whole expert sets: every pair is
+    placed, the dense gather moves no row in vain, and their decode step
+    and block step keep the XLA form (the (T, top_k, h) float32 gather
+    is there, the kernel is not)."""
+    from benchmark import model_spec, sizing
+
+    slots, decode, _ = _routed_programs("serve-moe-whole-mixed-decode",
+                                        topo.devices[0])
+    text = decode.as_text()
+    assert _combine_kernels(text) == 0
+    assert f"tensor<{slots}x8x2048xf32>" in text
+    cell = "serve-blockdiff-moe-decode"
+    with open(os.path.join(model_spec.HERE, "cells", f"{cell}.json")) as f:
+        deployment = json.load(f)["deployment"]
+    block_step, _ = sizing.serve_programs(
+        model_spec.load_config(DENSE_POOL_CELLS[cell][0]), deployment,
+        topo.devices[0])
+    text = block_step.as_text()
+    assert _combine_kernels(text) == 0
+    assert f"tensor<{deployment['num_slots'] * 4}x8x2048xf32>" in text
 
 
 # ------------------------------------------- the engine's pick of a token

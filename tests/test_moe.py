@@ -379,3 +379,180 @@ def test_grouped_matmul_kernel_interpret_at_a_prefills_tiles(tm):
     np.testing.assert_allclose(np.asarray(got[:4 * tm]),
                                np.asarray(want[:4 * tm]), rtol=1e-5,
                                atol=1e-5)
+
+
+# ---------------------------------------------------------------------
+# The combine follows the pairs the chip holds (PR 50): a kernel copies
+# only the rows of placed pairs; the XLA form, which gathers every
+# pair's row, is its oracle.
+
+def _combine_case(T, k, h, M, placed, seed):
+    """Every placed pair a row of its own somewhere in (M, h), as the
+    dispatch lays them; every OTHER row of the buffer poisoned with NaN
+    and inf (the rows of tiles past ``n_active`` and the padding inside a
+    tile, which nothing may read); the row indices of pairs that are not
+    placed point anywhere, poisoned rows and ``M`` itself included."""
+    rng = np.random.default_rng(seed)
+    placed = np.asarray(placed, bool)
+    n = int(placed.sum())
+    assert placed.shape == (T, k) and n <= M
+    row_pair = rng.integers(0, M + 1, (T, k)).astype(np.int32)
+    rows = rng.permutation(M)[:n]
+    row_pair[placed] = rows
+    y_rows = np.where(rng.random((M, 1)) < 0.5, np.nan, np.inf) * np.ones(
+        (1, h))
+    y_rows[1::3] *= -1
+    y_rows[rows] = rng.standard_normal((n, h))
+    w = rng.random((T, k)).astype(np.float32) + 0.1
+    return (jnp.asarray(y_rows, jnp.float32), jnp.asarray(row_pair),
+            jnp.asarray(placed), jnp.asarray(w))
+
+
+def _bernoulli(T, k, p, seed):
+    return np.random.default_rng(seed).random((T, k)) < p
+
+
+# case -> (T, k, h, M, placed (T, k))
+COMBINE_CASES = {
+    "a-sixteenth-placed": (64, 8, 1024, 96, _bernoulli(64, 8, 1 / 16, 1)),
+    "the-set-held-whole": (32, 8, 1024, 272, np.ones((32, 8), bool)),
+    "tokens-with-no-placed-pair": (
+        48, 8, 1024, 96,
+        _bernoulli(48, 8, 0.5, 2) & (np.arange(48) % 3 == 0)[:, None]),
+    "a-padded-tail-masked-by-valid": (
+        64, 8, 1024, 160,
+        _bernoulli(64, 8, 0.25, 3) & (np.arange(64) < 41)[:, None]),
+    "n_active-0": (32, 8, 1024, 64, np.zeros((32, 8), bool)),
+    "top-22-a-quarter-placed": (32, 22, 1024, 208,
+                                _bernoulli(32, 22, 0.25, 4)),
+    "56-lane-tiles-wide": (32, 8, 7168, 48, _bernoulli(32, 8, 1 / 8, 5)),
+    "tokens-no-tile-divides": (21, 8, 1024, 64, _bernoulli(21, 8, 0.3, 6)),
+    "more-pairs-than-the-ring": (
+        16, 22, 1024, 360, np.ones((16, 22), bool)),
+}
+
+
+@pytest.mark.parametrize("case", COMBINE_CASES)
+def test_expert_combine_kernel_interpret_against_the_xla_form(
+        case, monkeypatch):
+    """The kernel in interpret mode: what the XLA form gives to float32
+    rounding (the kernel sums in the order j = 0 .. k - 1), zeros for a
+    token with no placed pair, and nothing of a poisoned row in any
+    output (select, never multiply)."""
+    from ray_tpu.ops.pallas import expert_combine as ec
+
+    T, k, h, M, placed = COMBINE_CASES[case]
+    if case == "more-pairs-than-the-ring":
+        # the smallest ring, two tokens' pairs: the copies wrap it
+        monkeypatch.setattr(ec, "_RING_BYTES", 0)
+        assert 2 * k <= ec._depth(k, h) == 64 < placed.sum() // 5
+    args = _combine_case(T, k, h, M, placed, seed=len(case))
+    got = np.asarray(ec.expert_combine(*args, interpret=True))
+    want = np.asarray(ec.expert_combine_reference(*args))
+    assert got.shape == (T, h) and got.dtype == np.float32
+    assert np.isfinite(got).all() and np.isfinite(want).all()
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    assert not got[~placed.any(axis=1)].any()
+    assert (np.abs(got[placed.any(axis=1)]).max(axis=1) > 0).all()
+
+
+# how each routed model calls the layer, at its tiny default widths with
+# an eighth of its experts held (where the front takes the kernel at any
+# size): (hidden, experts' width, E, top_k, held, gated, further arguments)
+SHARE_CALLS = {
+    "mimo_v2": (64, 64, 16, 4, (4, 2), True, dict(score="sigmoid")),
+    "axk1": (64, 64, 32, 8, (8, 4), True,
+             dict(score="sigmoid", scale=2.5, n_group=8, topk_group=4)),
+    "laguna": (64, 64, 16, 4, (8, 2), True,
+               dict(score="softmax", scale=2.5)),
+    "nemotron_h": (64, 32, 16, 4, (0, 2), False,
+                   dict(score="sigmoid", scale=5.0)),
+}
+
+
+@pytest.mark.parametrize("model", SHARE_CALLS)
+def test_experts_by_share_with_the_combine_kernel_is_the_oracles(
+        model, kernel_on_cpu, monkeypatch):
+    """``experts_by_share`` where a share is held takes the kernel on a
+    TPU (here: interpreted, the grouped products too): against itself
+    with the XLA form in the kernel's place, equal to 1e-6 of the
+    largest value (the k-sum's order at most), counters bit for bit."""
+    from ray_tpu.ops.pallas import expert_combine as ec
+
+    hidden, width, E, k, held, gated, kw = SHARE_CALLS[model]
+    T, m = 40, 32
+    ks = jax.random.split(jax.random.key(90), 7)
+    n = lambda k_, s, std: jax.random.normal(k_, s, jnp.float32) * std  # noqa: E731
+    layer = {"router": n(ks[0], (hidden, E), hidden ** -0.5),
+             "we_up": n(ks[1], (held[1], width, m), width ** -0.5),
+             "we_down": n(ks[2], (held[1], m, width), m ** -0.5)}
+    if gated:
+        layer["we_gate"] = n(ks[3], (held[1], width, m), width ** -0.5)
+    if kw["score"] == "sigmoid":
+        layer["router_bias"] = n(ks[4], (E,), 0.05)
+    x = n(ks[5], (T, hidden), 1.0)
+    xe = None if width == hidden else n(ks[6], (T, width), 1.0)
+    call = lambda: moe.experts_by_share(  # noqa: E731
+        x, layer, experts_held=held, top_k=k, valid=jnp.arange(T) < T - 6,
+        x_experts=xe, **kw)
+    calls = []
+    kernel = ec.expert_combine
+    monkeypatch.setattr(ec, "expert_combine", lambda *a, **kws: (
+        calls.append(a[1].shape), kernel(*a, **kws))[1])
+    y, c = call()
+    assert calls == [(T, k)]
+    monkeypatch.setattr(ec, "expert_combine", lambda *a, **kws: (
+        ec.expert_combine_reference(*a)))
+    want, c0 = call()
+    assert 0 < float(c[1]) < (T - 6) * k and float(c[4]) == 0
+    np.testing.assert_array_equal(np.asarray(c), np.asarray(c0))
+    np.testing.assert_allclose(
+        np.asarray(y), np.asarray(want), rtol=1e-6,
+        atol=1e-6 * float(np.abs(np.asarray(want)).max()))
+    assert not np.asarray(y)[T - 6:].any()
+
+
+# (T, top_k, the rows' width, experts held, the router's) -> the kernel
+# or the XLA form: every program of the five routed cells
+KERNEL_SERVES = {
+    "axk1-decode-192": ((192, 8, 7168, 12, 192), True),
+    "axk1-2048": ((2048, 8, 7168, 12, 192), True),
+    "axk1-256": ((256, 8, 7168, 12, 192), True),
+    "mimo_v2-decode-128": ((128, 8, 4096, 16, 256), True),
+    "mimo_v2-1024": ((1024, 8, 4096, 16, 256), True),
+    "mimo_v2-256": ((256, 8, 4096, 16, 256), True),
+    # a quarter held: from a gather of 32 MiB on
+    "nemotron_h-decode-192": ((192, 22, 1024, 128, 512), False),   # 17 MB
+    "nemotron_h-64": ((64, 22, 1024, 128, 512), False),
+    "nemotron_h-256": ((256, 22, 1024, 128, 512), False),          # 23 MB
+    "nemotron_h-512": ((512, 22, 1024, 128, 512), True),           # 46 MB
+    "nemotron_h-1024": ((1024, 22, 1024, 128, 512), True),         # 92 MB
+    # the set held whole: never
+    "laguna-decode-128": ((128, 8, 2048, 256, 256), False),
+    "laguna-2048": ((2048, 8, 2048, 256, 256), False),
+    "sdar-block-step-512": ((512, 8, 2048, 128, 128), False),
+    "sdar-1024": ((1024, 8, 2048, 128, 128), False),
+}
+
+
+@pytest.mark.parametrize("call", KERNEL_SERVES)
+def test_the_combines_form_follows_the_share_held(call):
+    """The one rule under ``ops/``, from the call's static shapes (my
+    chip runs, PR 50: the layer alone with either form at each of these
+    shapes)."""
+    from ray_tpu.ops.pallas import expert_combine as ec
+
+    shapes, kernel = KERNEL_SERVES[call]
+    assert ec.kernel_serves(*shapes) is kernel
+
+
+def test_the_whole_held_set_combines_in_xla(kernel_on_cpu, monkeypatch):
+    """Every pair of a set held whole is placed: the dense gather moves
+    no row in vain, and the front keeps the XLA form on a TPU too."""
+    from ray_tpu.ops.pallas import expert_combine as ec
+
+    monkeypatch.setattr(ec, "expert_combine", lambda *a, **kw: 1 / 0)
+    layer = _held_layer(91, first=0, count=8)
+    x = jax.random.normal(jax.random.key(92), (24, 16), jnp.float32)
+    y, c = moe.experts_by_share(x, layer, experts_held=(0, 8), top_k=2)
+    assert float(c[1]) == 48 and np.isfinite(np.asarray(y)).all()

@@ -272,6 +272,34 @@ def test_a_dense_pools_step_attends_in_the_kernel_of_its_name(
     assert len(mine) == calls, paths
 
 
+def test_a_share_held_steps_combine_is_the_kernel_under_its_part(
+        monkeypatch):
+    """Where a share of the experts is held (2 of 16 here) the step made
+    for a TPU combines in the custom call ``expert_combine``, once a
+    routed layer; its path lies under the part of that name (which
+    ``decode_expert_dispatch_dev_ms`` and the ``prefill_expert*_dev_ms``
+    metrics read) and every scope on the path is a name of the
+    vocabulary. The default config holds the set whole: no such call."""
+    import dataclasses
+
+    whole = mimo_v2.MimoV2Config()
+    assert 'kernel_name = "expert_combine"' not in _step_for_a_tpu(
+        whole, monkeypatch).as_text()
+    cfg = dataclasses.replace(whole, experts_held=(4, 2))
+    lowered = _step_for_a_tpu(cfg, monkeypatch)
+    kernels = re.findall(r'kernel_name = "(\w+)"', lowered.as_text())
+    assert kernels.count("expert_combine") == sum(cfg.moe_layers) > 0
+    paths = [_OP_NAME.search(line).group(1)
+             for line in hlo_text(lowered).splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    mine = [p for p in paths if part_of(p) == "expert_combine"]
+    assert len(mine) == sum(cfg.moe_layers), paths
+    for path in mine:
+        root, *scopes, primitive = path.split("/")
+        assert root.startswith("jit(") and primitive == "pallas_call"
+        assert scopes and set(scopes) <= set(VOCABULARY), path
+
+
 def _without_locations(lowered):
     """The module's text and its kernels' bodies with no file, line or
     call stack in them: a kernel's body travels serialised inside its
