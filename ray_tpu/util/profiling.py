@@ -134,12 +134,18 @@ SSM_PARTS = (
 # block's logits, the pick, the ranking of the undecided positions). The
 # reader's side is ``benchmark/layer_metrics/parts/sdar.json``.
 BLOCK_PARTS = ("qk_norm", "block_decide")
-_VOCABULARY = PARTS + SSM_PARTS + BLOCK_PARTS
+# What a model whose layers run several times a token adds (PR 52,
+# ``models/ouro.py``): the two norms AFTER a block's sublayers, and what
+# stands between two passes (the norm, the exit gate, the exit
+# distribution and the choice of the pass whose state the head reads).
+# The reader's side is ``benchmark/layer_metrics/parts/ouro.json``.
+LOOP_PARTS = ("post_norm", "exit_gate")
+_VOCABULARY = PARTS + SSM_PARTS + BLOCK_PARTS + LOOP_PARTS
 
 
 def part(name: str):
     """``jax.named_scope(name)`` for a ``name`` of :data:`PARTS` (or of
-    :data:`SSM_PARTS` or :data:`BLOCK_PARTS`): what is traced inside
+    :data:`SSM_PARTS`, :data:`BLOCK_PARTS` or :data:`LOOP_PARTS`): what is traced inside
     belongs to that part of the block. Checked while tracing; nothing
     runs for it on the device or in a loop's turn."""
     if name not in _VOCABULARY:

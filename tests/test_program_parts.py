@@ -43,7 +43,7 @@ MODELS = {
 }
 ROUTED = ("mimo_v2", "axk1", "laguna", "nemotron_h")
 VOCABULARY = (profiling.PARTS + profiling.SSM_PARTS
-              + profiling.BLOCK_PARTS)
+              + profiling.BLOCK_PARTS + profiling.LOOP_PARTS)
 # the parts under which a decode step writes its per-request state: the
 # KV pools everywhere; a state-space model's recurrent state and
 # convolution columns under its own
