@@ -161,6 +161,7 @@ def run_speculation_bench(model: str, n_requests: int = 8,
     import numpy as np
 
     import jax
+    import jax.numpy as jnp
 
     from ray_tpu.models import llama
     from ray_tpu.serve.llm import LLMEngine
@@ -187,8 +188,12 @@ def run_speculation_bench(model: str, n_requests: int = 8,
     )
     rows = []
     for label, kw in configs:
-        engine = LLMEngine(config=cfg, params=params, num_slots=num_slots,
-                           kv_cache="slot", seed=0, **kw)
+        # a copy: the engine owns the tree it is given (on a TPU it
+        # re-lays wq / wk / wv and donates them), and the next engine and
+        # the draft row read ``params`` itself
+        engine = LLMEngine(config=cfg, params=jax.tree.map(jnp.copy, params),
+                           num_slots=num_slots, kv_cache="slot", seed=0,
+                           **kw)
         # warmup compiles prefill bucket + decode/verify (+ draft)
         # paths: a repetitive prompt guarantees ngram proposals (verify
         # program), a structureless one the no-proposal plain-decode

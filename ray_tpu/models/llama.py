@@ -161,6 +161,34 @@ def init_params(config: LlamaConfig, key: jax.Array) -> Params:
     return params
 
 
+# Where a stacked projection (L, E, heads, D) lies in a serving device's
+# memory, major to minor: heads outside is what :func:`qkv`'s products
+# read, so a layer's slice is multiplied where it lies (``wq`` / ``wk``:
+# ``E`` on the sublanes; ``wv``, whose product is not rotated: ``E`` on the
+# lanes). In the default order the chip's compiler stages each slice and
+# copies it into that order first, every layer of every step; ``wv``'s
+# slice is still staged in a decode step, in any of the six orders, and
+# in this one it is not copied again (PERF.md section 6, PR 53).
+SERVING_LAYOUT = {"wq": (0, 2, 1, 3), "wk": (0, 2, 1, 3), "wv": (0, 2, 3, 1)}
+
+
+def serving_layout(params: Params) -> Params:
+    """``params`` with ``layers.wq`` / ``wk`` / ``wv`` laid out on their
+    own devices as :data:`SERVING_LAYOUT` says: shapes and values as they
+    were, every other leaf the caller's own. The three leaves given are
+    DONATED, one at a time, so one leaf at the most exists twice and the
+    caller's buffers of them are gone. A program jitted without
+    ``in_shardings`` compiles for the layout its argument arrives in."""
+    from jax.experimental.layout import Format, Layout
+
+    layers = dict(params["layers"])
+    for name, order in SERVING_LAYOUT.items():
+        w = layers[name]
+        layers[name] = jax.device_put(
+            w, Format(Layout(major_to_minor=order), w.sharding), donate=True)
+    return {**params, "layers": layers}
+
+
 # The pieces of the block: the train forward below and the serving programs
 # (``models/decoding.py::dense_block``) are made of the same ones.
 @part("attn_proj")

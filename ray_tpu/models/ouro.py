@@ -34,7 +34,8 @@ head runs once a program, on the state the gate selected.
 ``cache["counters"]`` is the exit distribution summed over a program's
 running rows (:func:`counter_names`). The model brings no other builder:
 the engine refuses the slot cache, chunked prefill, speculation, prefix
-reuse and KV transfer by name.
+reuse and KV transfer by name. Its ``place`` is the dense decoder's: the
+block's projections are :func:`llama.qkv`'s.
 """
 
 from __future__ import annotations
@@ -47,7 +48,8 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.decoding import _bind_padded, _bind_params
-from ray_tpu.models.llama import embed, logits_f32, mlp, qkv
+from ray_tpu.models.llama import (embed, logits_f32, mlp, qkv,
+                                  serving_layout)
 from ray_tpu.models.paged_cache import (BlockAllocator, PagedConfig,
                                         _decode_work, fold_heads,
                                         store_kv_rows)
@@ -358,13 +360,16 @@ def make_prefill(params: Params, cfg: OuroConfig, page: PagedConfig):
 class OuroServing:
     """The model as :class:`ray_tpu.serve.llm.LLMEngine` takes it
     (:mod:`ray_tpu.models.serving`): the paged cache, the prefill and the
-    decode step, and no other builder."""
+    decode step, no other builder, and where its weights lie."""
 
     def __init__(self, config: OuroConfig):
         self.config = config
 
     def init_params(self, key):
         return init_params(self.config, key)
+
+    def place(self, params):
+        return serving_layout(params)        # the block's is llama.qkv
 
     def paged(self, params, *, num_slots: int, max_seq: int,
               block_size: int, pool_tokens: int):

@@ -68,6 +68,29 @@ the call):
                       key, request (B,), length (B,)) where a slot draws
                       at a temperature
 
+and, whether it has any of those or none, ONE OPTIONAL MEMBER:
+
+    place(params) -> params
+                      the weights as the model's programs read them on
+                      this device: same tree, shapes and values, some
+                      leaves in another device layout (the dense block's
+                      ``wq`` / ``wk`` / ``wv``:
+                      :func:`ray_tpu.models.llama.serving_layout`). The
+                      engine calls it once, on a TPU, on the weights it
+                      made or was given and before it builds a program,
+                      and OWNS what it was given from there on: a leaf
+                      that is re-laid is donated, the caller's buffer of
+                      it is gone and ``engine.params`` is the tree that
+                      is served, because 16 GB hold one copy of the
+                      weights beside a pool, not one and a leaf's second.
+                      A model without the member is served the arrays it
+                      was given; off the TPU every model is
+                      (``stats()["weights_relaid_bytes"]`` says how many
+                      bytes were re-laid). The builders are jitted
+                      without ``in_shardings``, so a program is compiled
+                      for the layout its weights arrive in, whoever calls
+                      the builder
+
 The dense decoder (:class:`DenseDecoder` around a ``LlamaConfig``) has
 them all, each a call of the builder in :mod:`ray_tpu.models.decoding`,
 :mod:`ray_tpu.models.paged_cache` or :mod:`ray_tpu.models.speculation`.
@@ -169,6 +192,11 @@ class DenseDecoder:
         from ray_tpu.models import llama
 
         return llama.init_params(self.config, key)
+
+    def place(self, params):
+        from ray_tpu.models import llama
+
+        return llama.serving_layout(params)
 
     def paged(self, params, *, num_slots: int, max_seq: int,
               block_size: int, pool_tokens: int) -> PagedPrograms:

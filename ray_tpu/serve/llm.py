@@ -264,12 +264,30 @@ class LLMEngine:
         self._compile_cache = compile_cache_counts()
         if params is None:
             params = self.model.init_params(jax.random.key(seed))
+        # on a TPU the weights lie as the model's programs read them
+        # (``place``, where the model has it): re-laid leaves are donated,
+        # so the tree that is served is this one and no other
+        self._weights_relaid_bytes = 0
+        place = getattr(self.model, "place", None)
+        if place is not None and jax.default_backend() == "tpu":
+            given = jax.tree.leaves(params)
+            params = place(params)
+            self._weights_relaid_bytes = sum(
+                new.nbytes for old, new in zip(given,
+                                               jax.tree.leaves(params))
+                if new is not old)
         self.params = params
         programs = build_cache(params, num_slots=num_slots,
                                max_seq=self.max_seq)
         self._page = programs.page
         self._alloc = programs.alloc
         self._cache = programs.cache
+        if self._weights_relaid_bytes:
+            # re-laid leaves are committed to the device, so what a program
+            # returns is: a cache that began uncommitted would make a
+            # program's first call and its later ones two compiles, the
+            # second wherever a prompt first meets a bucket again (no copy)
+            self._cache = jax.device_put(self._cache, dev)
         self._decode = programs.decode
         self._prefill = programs.prefill
         self._inject = programs.inject
@@ -612,7 +630,9 @@ class LLMEngine:
                    round(self._spec_accepted / self._spec_proposed, 4)
                    if self._spec_proposed else None),
                "speculation": self.speculation,
-               "kv_cache": self.kv_cache}
+               "kv_cache": self.kv_cache,
+               # weights the model's ``place`` laid out anew (0: none)
+               "weights_relaid_bytes": self._weights_relaid_bytes}
         if self._proposer is not None:
             out.update(self._proposer.stats())
         free = self._alloc.free_blocks()

@@ -790,6 +790,67 @@ def test_sample_ids_compiles_at_the_cells_shapes(one_chip, slots, vocab):
     assert mem.output_size_in_bytes <= 4096     # (slots,) int32, tiled
 
 
+# ------------------------- the dense projections are read where they lie
+def _placed(tree):
+    """Shapes as ``llama.serving_layout`` leaves arrays: ``wq`` / ``wk`` /
+    ``wv`` in the program's own device layout, on their own sharding."""
+    from jax.experimental.layout import Format, Layout
+
+    if "layers" not in tree:
+        return tree
+    layers = dict(tree["layers"])
+    for name, order in llama.SERVING_LAYOUT.items():
+        a = layers[name]
+        layers[name] = _sds(a.shape, a.dtype, Format(
+            Layout(major_to_minor=order), a.sharding))
+    return {**tree, "layers": layers}
+
+
+@pytest.mark.parametrize("cell, config, program", [
+    ("serve-batch-decode", "mistral-7b-l16", "decode"),
+    ("serve-batch-decode", "mistral-7b-l16", "prefill256"),
+    ("serve-looped-dense-decode", "ouro-2.6b", "decode"),
+    ("serve-looped-dense-decode", "ouro-2.6b", "prefill128")])
+@pytest.mark.parametrize("lies", ["as_made", "placed"])
+def test_placed_projections_are_not_staged_by_the_layer_scan(
+        topo, one_chip, as_tpu, monkeypatch, lies, cell, config, program):
+    """The two dense models at their cells' sizes. With the weights as
+    they are made, the layer scan of the decode step and of a prefill
+    bucket stages a layer's slice of ``wq``, ``wk`` and ``wv`` (a
+    ``dynamic-slice`` fusion of ``bf16[1,E,H,128]`` each) and copies it
+    into the order the product reads. Lowered for the layout ``place``
+    gives the engine's weights, the bucket holds neither and the step at
+    most ``wv``'s. (``benchmark.sizing.on`` lowers with default layouts;
+    the formats are steered in from here.)"""
+    from benchmark import model_spec, sizing
+
+    if lies == "placed":
+        plain = sizing.on
+        monkeypatch.setattr(sizing, "on",
+                            lambda sharding, tree: _placed(plain(sharding,
+                                                                 tree)))
+    spec = model_spec.load_config(config)
+    with open(os.path.join(model_spec.HERE, "cells", f"{cell}.json")) as f:
+        deployment = json.load(f)["deployment"]
+    decode, bucket = sizing.serve_programs(spec, deployment, topo.devices[0])
+    compiled = (decode if program == "decode" else
+                bucket(int(program.removeprefix("prefill")))).compile()
+    orders = {name: fmt.layout.major_to_minor for name, fmt in
+              compiled.input_formats[0][0]["layers"].items()}
+    for name, order in llama.SERVING_LAYOUT.items():
+        assert orders[name] == (order if lies == "placed" else (0, 1, 2, 3))
+    assert orders["wo"] == (0, 1, 2, 3) and orders["w_gate"] == (0, 1, 2)
+    text = compiled.as_text()
+    slice_ = rf"bf16\[1,{spec['hidden_size']},\d+,128\]"
+    staged = re.findall(rf"dynamic-slice_fusion[.\d]* = {slice_}", text)
+    copied = re.findall(rf" = {slice_}\S* copy\(", text)
+    if lies == "as_made":
+        assert len(staged) == 3 and copied
+    else:
+        most = 1 if program == "decode" else 0
+        assert len(staged) <= most and len(copied) <= most
+
+
 # ----------------------------------------------------------------- CPU only
 def test_tiny_decode_module_holds_no_weights():
     """A closed-over array lowers to a literal. `tiny` has 89 MB of
