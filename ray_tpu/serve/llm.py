@@ -57,6 +57,10 @@ class _Request:
     done: threading.Event = dataclasses.field(
         default_factory=threading.Event)
     output: List[int] = dataclasses.field(default_factory=list)
+    # when the engine appended each token of ``output``, on
+    # time.monotonic(): written BEFORE the token, by the engine thread
+    # alone, so a poll() that sees a token finds its time (no lock)
+    landed_at: List[float] = dataclasses.field(default_factory=list)
     error: Optional[str] = None
     enqueued_at: float = dataclasses.field(default_factory=time.monotonic)
     # KV handed off from a prefill replica (PD disaggregation): dict with
@@ -461,6 +465,15 @@ class LLMEngine:
         # the loop's named phases (spans in a profiler capture, counters
         # in stats()) and the last finished requests' lifecycle records
         self._phases = profiling.Phases("rt.engine.")
+        # a token's way to its caller (poll() writes these, under
+        # _pending_lock) and what the admissions were made of (the
+        # engine thread's): stats()["delivery"], stats()["admissions"]
+        self._delivery = dict.fromkeys(
+            ("polls", "polls_empty", "tokens_picked"), 0)
+        self._pickup_walls = profiling.wall_counts()
+        self._admissions = dict.fromkeys(
+            ("prefills", "prompt_tokens", "padded_tokens",
+             "turns_admitting", "also_waiting"), 0)
         self._finished = 0
         self._recent: "collections.deque[list]" = collections.deque(
             maxlen=512)
@@ -600,13 +613,22 @@ class LLMEngine:
             ent = self._pending.get(request_id)
             if ent is None:
                 return {"chunks": [], "done": True}
-            ent["last_poll"] = time.monotonic()
+            now = ent["last_poll"] = time.monotonic()
             req = ent["req"]
             out = list(req.output)   # snapshot (engine thread appends)
             chunks = out[ent["sent"]:]
+            self._delivery["polls"] += 1
+            if chunks:
+                # how long the oldest token this poll hands over lay
+                # there: every token is in one such wait
+                self._delivery["tokens_picked"] += len(chunks)
+                profiling.count_wall(self._pickup_walls,
+                                     now - req.landed_at[ent["sent"]])
+            else:
+                self._delivery["polls_empty"] += 1
             ent["sent"] = len(out)
             if chunks and req.first_picked_at is None:
-                req.first_picked_at = ent["last_poll"]
+                req.first_picked_at = now
                 if req.record is not None:
                     req.record[3] = req.first_picked_at
             finished = req.done.is_set() and ent["sent"] >= len(req.output)
@@ -687,6 +709,17 @@ class LLMEngine:
                              peak_bytes_in_use=mem.get("peak_bytes_in_use"))
         out["compile_cache"] = dict(self._compile_cache)
         out["phases"] = self._phases.snapshot()
+        # each phase's wall as a distribution (profiling.WALL_EDGES_S)
+        out["phase_walls"] = self._phases.walls()
+        # poll()s, those that found no token, the tokens handed over and,
+        # over the same edges, how long the oldest of a poll's tokens had
+        # lain in ``output`` (one count for each poll that took any)
+        out["delivery"] = dict(self._delivery,
+                               pickup_wall_counts=list(self._pickup_walls))
+        # blocking prefill programs run, their prompts' tokens and their
+        # buckets', the turns that ran at least one, and the requests
+        # still waiting behind each one picked (summed)
+        out["admissions"] = dict(self._admissions)
         out["requests"] = {"finished": self._finished,
                            "recent": [list(r) for r in list(self._recent)]}
         return out
@@ -890,8 +923,9 @@ class LLMEngine:
                 # PD handoff: prompt KV computed by a prefill replica
                 self._inject_kv(slot, req.preload["k"], req.preload["v"],
                                 plen)
-                tok = np.asarray(self._draw_first(
-                    req, req.preload["logits"], plen)).item()
+                ids = self._draw_first(req, req.preload["logits"], plen)
+                with self._phases("prefill_fetch", step=self._turn_step()):
+                    tok = self._fetch(ids).item()
                 req.preload = None  # free the host copy
             elif matched > 0:
                 # radix hit: the adopted blocks already hold the prefix
@@ -919,14 +953,18 @@ class LLMEngine:
                 P = self._prompt_pad(plen)
                 tokens = np.zeros((1, P), np.int32)
                 tokens[0, :plen] = full_prompt
+                self._count_prefill(plen, P)
+                step = self._turn_step()
                 with self._phases("prefill", pad_len=P, prompt_len=plen,
-                                  slot=slot):
+                                  slot=slot, step=step):
                     self._cache, logits = self._prefill(
                         self._cache, self._alloc.table_rows(slot),
                         jnp.asarray(tokens), plen, slot)
-                    tok = self._fetch(self._draw_first(req, logits, plen),
-                                      self._take_counters(),
-                                      prefill=True).item()
+                    ids = self._draw_first(req, logits, plen)
+                    counters = self._take_counters()
+                    with self._phases("prefill_fetch", step=step):
+                        tok = self._fetch(ids, counters,
+                                          prefill=True).item()
                 if self._radix is not None:
                     self._radix_insert(req, full_prompt, slot)
             self._seat(slot, req, plen)
@@ -948,14 +986,17 @@ class LLMEngine:
         P = self._prompt_pad(whole)
         tokens = np.zeros((1, P), np.int32)
         tokens[0, :whole] = full_prompt[:whole]
+        self._count_prefill(whole, P)
+        step = self._turn_step()
         with self._phases("prefill", pad_len=P, prompt_len=whole,
-                          slot=slot):
+                          slot=slot, step=step):
             self._cache, _ = self._prefill(
                 self._cache, self._alloc.table_rows(slot),
                 jnp.asarray(tokens), whole, slot)
             counters = self._take_counters()
             if counters is not None:
-                self._fetch(None, counters, prefill=True)
+                with self._phases("prefill_fetch", step=step):
+                    self._fetch(None, counters, prefill=True)
         self._seat(slot, req, whole)
         self._blk_ids[slot] = 0
         self._blk_ids[slot, :tail] = full_prompt[whole:]
@@ -964,6 +1005,21 @@ class LLMEngine:
         self._blk_undecided[slot] = B - tail
         self._blk_quota[slot] = self.config.step_quota(B - tail)
         self._blk_skip[slot] = tail
+
+    def _turn_step(self) -> int:
+        """The number a prefill's spans carry: the decode step in flight
+        (the one ``decode_dispatch`` carried this turn), which the
+        prefill runs behind on the device; with none in flight, the
+        number the next one will carry."""
+        return self._steps if self._flight is None else self._flight.step
+
+    def _count_prefill(self, prompt_len: int, padded_len: int) -> None:
+        """One more blocking prefill: ``stats()["admissions"]``."""
+        adm = self._admissions
+        adm["prefills"] += 1
+        adm["prompt_tokens"] += prompt_len
+        adm["padded_tokens"] += padded_len
+        adm["also_waiting"] += len(self._waiting)
 
     def _seat(self, slot: int, req: _Request, cached: int) -> None:
         """``req`` takes ``slot`` with ``cached`` tokens of it in the KV
@@ -981,9 +1037,11 @@ class LLMEngine:
         cache and ``tok`` from its last row: it decodes from the next
         step on, which takes ``tok`` from the host."""
         req = self._slots[slot]
+        now = time.monotonic()
+        req.landed_at.append(now)
         req.output.append(tok)
         if req.first_token_at is None:
-            req.first_token_at = time.monotonic()
+            req.first_token_at = now
         self._last_token[slot] = tok
         if self._proposer is not None:
             self._proposer.admit(slot, cached)
@@ -1059,15 +1117,18 @@ class LLMEngine:
             # (B, C, vocab) logits off-device only when some slot samples
             # (a real vocab makes the difference ~(k+1)x the decode path's
             # per-step transfer)
-            greedy_np = np.asarray(self._spec_argmax(all_logits))
+            argmax_ids = self._spec_argmax(all_logits)
             need_full = any(self._slots[s].temperature > 0.0
                             for s in infos)
-            logits_np = np.asarray(all_logits) if need_full else None
+            with self._phases("spec_fetch", step=self._steps):
+                greedy_np = self._fetch(argmax_ids)
+                logits_np = self._fetch(all_logits) if need_full else None
         # the acceptance rule's draws are the host's, one generator a
         # verify round, seeded by the step count after its increment
         self._steps += 1
         self._turns_drained += 1
         rng = np.random.default_rng(self._steps)
+        landed = time.monotonic()
         accepted_map: Dict[int, int] = {}
         touched = np.zeros(self.num_slots, bool)
         new_lens = np.zeros(self.num_slots, np.int32)
@@ -1089,6 +1150,7 @@ class LLMEngine:
             emitted = emitted[:max(1, room)]
             if req.eos_token is not None and req.eos_token in emitted:
                 emitted = emitted[:emitted.index(req.eos_token) + 1]
+            req.landed_at.extend([landed] * len(emitted))
             req.output.extend(emitted)
             self._last_token[slot] = emitted[-1]
             # the last emitted token is pending (not yet cached), so the
@@ -1124,7 +1186,8 @@ class LLMEngine:
         n = min(C, len(toks) - pos)
         buf = np.zeros((1, C), np.int32)
         buf[0, :n] = toks[pos:pos + n]
-        with self._phases("prefill_chunk", pad_len=C, slot=slot):
+        step = self._turn_step()
+        with self._phases("prefill_chunk", pad_len=C, slot=slot, step=step):
             self._cache, logits = self._chunk_prefill(
                 self._cache, self._alloc.table_rows(slot), jnp.asarray(buf),
                 n, pos, slot)
@@ -1133,7 +1196,9 @@ class LLMEngine:
             if st["pos"] < len(toks):
                 return
             req, plen = st["req"], len(toks)
-            tok = np.asarray(self._draw_first(req, logits, plen)).item()
+            ids = self._draw_first(req, logits, plen)
+            with self._phases("prefill_fetch", step=step):
+                tok = self._fetch(ids).item()
         del self._prefilling[slot]
         if self._radix is not None:
             self._radix_insert(req, toks, slot)
@@ -1154,7 +1219,9 @@ class LLMEngine:
                ) -> np.ndarray:
         """A program's token ids on the host and, in the same transfer,
         the model's ``counters`` of that program (no further sync: they
-        are outputs of it)."""
+        are outputs of it). The engine thread's one blocking read of the
+        device: every call of it sits in a phase named ``*_fetch``
+        (tests/test_llm_phases.py walks the class and holds that)."""
         if counters is None:
             return np.asarray(ids)
         import jax
@@ -1377,7 +1444,10 @@ class LLMEngine:
             self._grow_active_slots()
         with phase("admit",
                    waiting=self._queue.qsize() + len(self._waiting)):
+            prefills = self._admissions["prefills"]
             self._admit()
+            if self._admissions["prefills"] > prefills:
+                self._admissions["turns_admitting"] += 1
         # one prefill chunk per iteration: bounded interference with the
         # decode of already-active slots (vLLM-class chunked prefill)
         if self._prefilling:
@@ -1487,6 +1557,7 @@ class LLMEngine:
             ids = self._fetch(flight.ids, flight.counters)
         self._steps += 1
         ran = [slot for slot, r in enumerate(flight.reqs) if r is not None]
+        landed = time.monotonic()
         # ONE span around the slots' loop, never one per slot; the
         # tokens are picked already, what is left is bookkeeping
         with self._phases("sample", active=len(ran),
@@ -1500,6 +1571,7 @@ class LLMEngine:
                     self._surplus_dropped += 1
                     continue
                 tok = ids[slot]
+                req.landed_at.append(landed)
                 req.output.append(int(tok))
                 self._last_token[slot] = tok
                 self._tokens_generated += 1
@@ -1584,6 +1656,7 @@ class LLMEngine:
         self._steps += 1
         B = self._step_rows
         committed = np.nonzero(flight.coming)[0]
+        landed = time.monotonic()
         # ONE span around the slots that committed, never one per slot
         # (a slot that only decided has nothing to book)
         with self._phases("block_commit", active=len(committed),
@@ -1598,9 +1671,10 @@ class LLMEngine:
                 toks = toks[:req.max_tokens - len(req.output)]
                 if req.eos_token is not None and req.eos_token in toks:
                     toks = toks[:toks.index(req.eos_token) + 1]
+                req.landed_at.extend([landed] * len(toks))
                 req.output.extend(toks)
                 if req.first_token_at is None:
-                    req.first_token_at = time.monotonic()
+                    req.first_token_at = landed
                 self._tokens_generated += len(toks)
                 self._maybe_finish(slot)
 

@@ -1,72 +1,32 @@
-"""Kernel-level profiling hooks (reference: Ray exposes torch/nsight
-profilers via runtime hooks; the TPU-native equivalent is the XLA/jax
-profiler, whose traces open in TensorBoard/Perfetto and show per-kernel
-MXU/HBM utilization).
+"""What a loop and a program say about themselves to a profiler capture
+(the XLA/jax profiler, whose traces open in TensorBoard/Perfetto) and,
+with no capture running, to whoever asks for ``stats()``.
 
-Four entry points:
+Two entry points:
 
-- :func:`profile` — context manager around a training/serving region;
-  writes an XLA profiler trace directory (the evidence artifact for
-  perf work, e.g. the MFU investigations in PERF_PLAN.md).
-- :func:`annotate` — named sub-region inside a profile (TraceAnnotation)
-  so framework phases (data load, step, collective) are visible between
-  kernels.
 - :class:`Phases` — the named phases of one loop: each is a
   TraceAnnotation on the profiler's clock AND a row of always-on
   counters (count, wall seconds and, sampled, the thread's CPU
-  seconds), so the same names read the same work with and without a
-  capture.
+  seconds) AND a distribution of its wall, so the same names read the
+  same work with and without a capture.
 
 - :func:`part` — the named part of a block that a device operation
   belongs to (``jax.named_scope`` under ONE vocabulary, :data:`PARTS`):
   metadata of the compiled instructions, read from a capture by
   ``benchmark/layer_metrics/_dev_ms_by_part.py``.
 
-``profile`` and ``annotate`` degrade to no-ops when jax's profiler is
-unavailable (e.g. a worker without jax initialized), so library code
-can call them unconditionally. This module imports without jax.
+A capture itself is started with ``jax.profiler`` directly. The spans
+degrade to no-ops when jax's profiler is unavailable (e.g. a worker
+without jax initialized). This module imports without jax.
 """
 
 from __future__ import annotations
 
+import bisect
 import contextlib
 import functools
-import logging
-import os
 import time
-from typing import Dict, Iterator, List, Optional
-
-logger = logging.getLogger(__name__)
-
-
-@contextlib.contextmanager
-def profile(logdir: str) -> Iterator[str]:
-    """Capture an XLA profiler trace of the enclosed region into
-    ``logdir`` (one subdirectory per capture). Returns the logdir so
-    callers can print/record the artifact path."""
-    os.makedirs(logdir, exist_ok=True)
-    try:
-        import jax
-
-        jax.profiler.start_trace(logdir,
-                                 create_perfetto_trace=False)
-        started = True
-    except Exception as e:  # noqa: BLE001 — no device/profiler: no-op
-        logger.debug("profiler unavailable: %s", e)
-        started = False
-    t0 = time.monotonic()
-    try:
-        yield logdir
-    finally:
-        if started:
-            try:
-                import jax
-
-                jax.profiler.stop_trace()
-                logger.info("profile trace (%.1fs) written to %s",
-                            time.monotonic() - t0, logdir)
-            except Exception as e:  # noqa: BLE001
-                logger.warning("stop_trace failed: %s", e)
+from typing import Dict, List
 
 
 class _NoAnnotation(contextlib.nullcontext):
@@ -83,12 +43,6 @@ def _annotations():
         return jax.profiler.TraceAnnotation, jax.profiler.StepTraceAnnotation
     except Exception:  # noqa: BLE001 — no jax: spans are no-ops
         return _NoAnnotation, _NoAnnotation
-
-
-def annotate(name: str, **attrs):
-    """Named region inside a capture (shows as a host-side bar above the
-    device kernels it launched); ``attrs`` become the event's stats."""
-    return _annotations()[0](name, **attrs)
 
 
 # The parts of a block, for the DEVICE's operations: every operation of
@@ -156,8 +110,30 @@ def part(name: str):
     return jax.named_scope(name)
 
 
+# The edges every distribution of walls shares: geometric, sixteen a
+# doubling from 16 us to a little over 4 s, so a bucket is 4.4% wide and
+# a percentile read from the counts (linear inside its bucket, as
+# ``benchmark/layer_metrics/_phase_walls.py`` reads the difference of two
+# snapshots) lies within that of the exact one wherever in its bucket
+# the walls sit. ``counts`` has one bucket more
+# than there are edges: ``counts[i]`` holds the walls in
+# ``[WALL_EDGES_S[i - 1], WALL_EDGES_S[i])``, the first what is shorter
+# than every edge, the last what is longer.
+WALL_EDGES_S = tuple(16e-6 * 2.0 ** (i / 16) for i in range(18 * 16 + 1))
+
+
+def wall_counts() -> List[int]:
+    """An empty distribution over :data:`WALL_EDGES_S`."""
+    return [0] * (len(WALL_EDGES_S) + 1)
+
+
+def count_wall(counts: List[int], wall_s: float) -> None:
+    counts[bisect.bisect_right(WALL_EDGES_S, wall_s)] += 1
+
+
 class _Phase:
-    """One entry into a phase: the annotation, and on exit the row."""
+    """One entry into a phase: the annotation, and on exit the row and
+    the wall's bucket."""
 
     __slots__ = ("_owner", "_name", "_span", "_t0", "_c0", "_children",
                  "_cpu")
@@ -188,6 +164,8 @@ class _Phase:
         row = self._owner._rows.get(self._name)
         if row is None:
             row = self._owner._rows[self._name] = [0, 0.0, 0.0, 0.0, 0.0]
+            self._owner._walls[self._name] = wall_counts()
+        count_wall(self._owner._walls[self._name], wall)
         self_wall = wall - self._children[0]
         row[0] += 1
         row[1] += wall
@@ -213,6 +191,11 @@ class Phases:
     computes on the host is time the thread was not running: waiting
     for the GIL, or descheduled.
 
+    Beside the row each phase keeps its WALL as a distribution
+    (:meth:`walls`, over :data:`WALL_EDGES_S`): a sum says what a turn
+    costs, a percentile of a client's gaps is made by the turns that
+    were long.
+
     Always on. With no capture running an annotation costs about half
     a microsecond, but the thread's CPU clock is a system call: 6 us in
     a small process on the v5e's sandboxed host and about 25 us in the
@@ -231,6 +214,7 @@ class Phases:
         self._prefix = prefix
         self._span, self._step_span = _annotations()
         self._rows: Dict[str, List[float]] = {}
+        self._walls: Dict[str, List[int]] = {}
         self._stack: List[List[float]] = []
         self._turns = 0
         self._cpu = True
@@ -250,20 +234,10 @@ class Phases:
     def snapshot(self) -> Dict[str, List[float]]:
         return {name: list(row) for name, row in list(self._rows.items())}
 
-
-def device_memory_stats() -> Optional[dict]:
-    """Live HBM stats of the first addressable device (bytes in use /
-    limit), or None off-device. Cheap enough to poll from monitors."""
-    try:
-        import jax
-
-        dev = jax.local_devices()[0]
-        stats = dev.memory_stats()
-        if not stats:
-            return None
-        return {"bytes_in_use": stats.get("bytes_in_use", 0),
-                "bytes_limit": stats.get("bytes_limit", 0),
-                "peak_bytes_in_use": stats.get("peak_bytes_in_use", 0),
-                "platform": dev.platform}
-    except Exception:  # noqa: BLE001
-        return None
+    def walls(self) -> dict:
+        """``{"edges_s": [...], "counts": {phase: [...]}}``: every
+        phase's wall (the whole of an entry, children included) counted
+        into :data:`WALL_EDGES_S`' buckets, one increment an exit."""
+        return {"edges_s": list(WALL_EDGES_S),
+                "counts": {name: list(counts) for name, counts
+                           in list(self._walls.items())}}

@@ -10,6 +10,7 @@ import os
 import re
 import sys
 import threading
+import time
 
 import jax
 import jax.numpy as jnp
@@ -617,6 +618,41 @@ def test_a_geometry_that_cuts_a_block_is_refused(parts, kwargs, message):
     cfg, params = parts
     with pytest.raises(ValueError, match=message):
         LLMEngine(config=cfg, params=params, num_slots=2, **kwargs)
+
+
+def test_the_block_turns_waits_are_fetch_phases(parts):
+    """The block turn's account: the wait for a prefill (its counters;
+    no token comes of it) is ``prefill_fetch`` inside ``prefill``, the
+    wait for a block step ``block_fetch``, and a streamed answer's
+    tokens are counted on their way out, a block at a time."""
+    eng = engine(parts)
+    try:
+        rid = eng.submit([7, 8, 9, 10, 11, 12], max_tokens=8)
+        out = []
+        while True:
+            st = eng.poll(rid)
+            out.extend(st["chunks"])
+            if st["done"]:
+                break
+            time.sleep(0.002)
+        eng.generate([3, 4, 5], max_tokens=4)
+        st = eng.stats()
+    finally:
+        eng.shutdown()
+    rows, adm = st["phases"], st["admissions"]
+    assert len(out) == 8
+    assert adm["prefills"] == rows["prefill"][0] \
+        == rows["prefill_fetch"][0] == 2
+    # whole blocks only: 4 of 6 tokens, and none of 3
+    assert adm["prompt_tokens"] == 4 and adm["padded_tokens"] >= 4
+    assert rows["prefill"][1] >= rows["prefill_fetch"][1] > 0.0
+    assert rows["block_fetch"][0] == rows["block_dispatch"][0] \
+        == st["block_steps"]
+    assert "logits_fetch" not in rows
+    assert sum(r[2] for r in rows.values()) == pytest.approx(
+        rows["turn"][1], rel=0.01)
+    assert st["delivery"]["tokens_picked"] == 8
+    assert 1 <= sum(st["delivery"]["pickup_wall_counts"]) <= 8
 
 
 def test_a_dense_models_turn_is_what_it_was():

@@ -1,11 +1,11 @@
 """Tracing spans: local nesting, cross-process propagation through task
 submission, chrome-trace export (reference: ray.util.tracing OTel
-task-span wrappers), plus the profiling hook no-op guarantees."""
+task-span wrappers)."""
 
 import pytest
 
 import ray_tpu
-from ray_tpu.util import profiling, tracing
+from ray_tpu.util import tracing
 
 
 @pytest.fixture(autouse=True)
@@ -70,20 +70,6 @@ class TestCrossProcess:
             assert parent_id is not None
         finally:
             ray_tpu.shutdown()
-
-
-class TestProfilingHooks:
-    def test_profile_noop_safe(self, tmp_path):
-        # must not raise even where the profiler can't start
-        with profiling.profile(str(tmp_path / "trace")) as d:
-            with profiling.annotate("region"):
-                x = sum(range(100))
-        assert x == 4950 and d
-
-    def test_device_memory_stats_shape(self):
-        st = profiling.device_memory_stats()
-        if st is not None:
-            assert "bytes_in_use" in st and "platform" in st
 
 
 class TestRequestSpans:
