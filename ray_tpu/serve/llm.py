@@ -59,8 +59,15 @@ class _Request:
     output: List[int] = dataclasses.field(default_factory=list)
     # when the engine appended each token of ``output``, on
     # time.monotonic(): written BEFORE the token, by the engine thread
-    # alone, so a poll() that sees a token finds its time (no lock)
+    # alone, so a poll() that sees a token finds its time (no lock).
+    # :meth:`land` writes the three in this order: time, token, then
+    # ``fresh``, so a reader that ``fresh`` woke finds both
     landed_at: List[float] = dataclasses.field(default_factory=list)
+    # what wakes this request's reader (LLMEngine.wait_fresh): set after
+    # every landing and at the request's end. A request has one if it
+    # came through submit() or submit_prefilled(), whose callers read it
+    # as it grows; generate()'s caller waits for ``done`` alone
+    fresh: Optional[threading.Event] = None
     error: Optional[str] = None
     enqueued_at: float = dataclasses.field(default_factory=time.monotonic)
     # KV handed off from a prefill replica (PD disaggregation): dict with
@@ -92,6 +99,22 @@ class _Request:
     # the request's place in the order the engine took requests from its
     # queue: with a token's position, what that token's draw is keyed by
     number: int = 0
+
+    def land(self, tokens: List[int], at: float) -> None:
+        """The engine thread's one way to hand over tokens: their time,
+        the tokens, then the reader's wake-up (``Event.set`` takes the
+        event's own lock, which nobody holds for long; no other lock)."""
+        self.landed_at.extend([at] * len(tokens))
+        self.output.extend(tokens)
+        if self.fresh is not None:
+            self.fresh.set()
+
+    def end(self) -> None:
+        """Finished, failed or cancelled, with ``error`` written by now:
+        a blocked generate() and a waiting reader both wake."""
+        self.done.set()
+        if self.fresh is not None:
+            self.fresh.set()
 
 
 def _parse_req_spec(speculation) -> Optional[dict]:
@@ -465,11 +488,13 @@ class LLMEngine:
         # the loop's named phases (spans in a profiler capture, counters
         # in stats()) and the last finished requests' lifecycle records
         self._phases = profiling.Phases("rt.engine.")
-        # a token's way to its caller (poll() writes these, under
-        # _pending_lock) and what the admissions were made of (the
-        # engine thread's): stats()["delivery"], stats()["admissions"]
+        # a token's way to its caller (poll() and wait_fresh() write
+        # these, under _pending_lock) and what the admissions were made
+        # of (the engine thread's): stats()["delivery"],
+        # stats()["admissions"]
         self._delivery = dict.fromkeys(
-            ("polls", "polls_empty", "tokens_picked"), 0)
+            ("polls", "polls_empty", "tokens_picked", "waits_woken",
+             "waits_timed_out"), 0)
         self._pickup_walls = profiling.wall_counts()
         self._admissions = dict.fromkeys(
             ("prefills", "prompt_tokens", "padded_tokens",
@@ -544,7 +569,8 @@ class LLMEngine:
                temperature: float = 0.0,
                eos_token: Optional[int] = None,
                speculation=None, tenant: Optional[str] = None) -> str:
-        """Enqueue without blocking; poll with :meth:`poll` (drives the
+        """Enqueue without blocking; poll with :meth:`poll`, after
+        :meth:`wait_fresh` or at the caller's own pace (drives the
         proxy's SSE token streaming)."""
         import uuid
 
@@ -555,7 +581,8 @@ class LLMEngine:
         self._check_vocab(prompt)
         req = _Request(list(prompt), max_tokens, temperature, eos_token,
                        spec=_parse_req_spec(speculation), tenant=tenant,
-                       trace_ctx=tracing.current_context())
+                       trace_ctx=tracing.current_context(),
+                       fresh=threading.Event())
         rid = uuid.uuid4().hex
         with self._pending_lock:
             self._pending[rid] = {"req": req, "sent": 0}
@@ -573,6 +600,8 @@ class LLMEngine:
         if ent is None:
             return False
         ent["req"].cancelled = True
+        # its reader's next poll() finds no entry: done
+        ent["req"].fresh.set()
         return True
 
     def submit_prefilled(self, prompt: List[int], k, v, logits,
@@ -599,12 +628,30 @@ class LLMEngine:
         req = _Request(list(prompt), max_tokens, temperature, eos_token,
                        preload={"k": k, "v": v,
                                 "logits": np.asarray(logits)},
-                       trace_ctx=tracing.current_context())
+                       trace_ctx=tracing.current_context(),
+                       fresh=threading.Event())
         rid = uuid.uuid4().hex
         with self._pending_lock:
             self._pending[rid] = {"req": req, "sent": 0}
         self._queue.put(req)
         return rid
+
+    def wait_fresh(self, request_id: str, timeout: float) -> None:
+        """Block until the engine has landed something for the request
+        since the last call (a token, its end), at most ``timeout``
+        seconds; then :meth:`poll`. The order is wait, clear, poll: what
+        lands after the clear leaves the event set, so the next call
+        returns at once and nothing waits out a ``timeout`` unseen."""
+        with self._pending_lock:
+            ent = self._pending.get(request_id)
+        if ent is None:
+            return          # cancelled, swept or drained: poll() says so
+        fresh = ent["req"].fresh
+        woken = fresh.wait(timeout)
+        fresh.clear()
+        with self._pending_lock:
+            self._delivery["waits_woken" if woken
+                           else "waits_timed_out"] += 1
 
     def poll(self, request_id: str) -> Dict[str, Any]:
         """New tokens since the last poll + done flag. The entry is dropped
@@ -711,9 +758,11 @@ class LLMEngine:
         out["phases"] = self._phases.snapshot()
         # each phase's wall as a distribution (profiling.WALL_EDGES_S)
         out["phase_walls"] = self._phases.walls()
-        # poll()s, those that found no token, the tokens handed over and,
-        # over the same edges, how long the oldest of a poll's tokens had
-        # lain in ``output`` (one count for each poll that took any)
+        # poll()s, those that found no token, the tokens handed over, the
+        # wait_fresh()s that the engine ended and those that ran into
+        # their time-out, and, over the same edges, how long the oldest
+        # of a poll's tokens had lain in ``output`` (one count for each
+        # poll that took any)
         out["delivery"] = dict(self._delivery,
                                pickup_wall_counts=list(self._pickup_walls))
         # blocking prefill programs run, their prompts' tokens and their
@@ -863,7 +912,7 @@ class LLMEngine:
             if req.cancelled:
                 del self._waiting[idx]
                 self._record_finish(req, "cancelled")
-                req.done.set()
+                req.end()
                 continue
             # preempted requests resume by recomputing prompt+generated
             full_prompt = req.prompt + req.output
@@ -881,7 +930,7 @@ class LLMEngine:
                 req.error = (f"prompt of {plen} tokens exceeds KV "
                              "pool capacity")
                 self._record_finish(req, "error")
-                req.done.set()
+                req.end()
                 continue
             if self._radix is not None and req.preload is None:
                 match = self._radix_match(full_prompt)
@@ -1038,8 +1087,7 @@ class LLMEngine:
         step on, which takes ``tok`` from the host."""
         req = self._slots[slot]
         now = time.monotonic()
-        req.landed_at.append(now)
-        req.output.append(tok)
+        req.land([tok], now)
         if req.first_token_at is None:
             req.first_token_at = now
         self._last_token[slot] = tok
@@ -1150,8 +1198,7 @@ class LLMEngine:
             emitted = emitted[:max(1, room)]
             if req.eos_token is not None and req.eos_token in emitted:
                 emitted = emitted[:emitted.index(req.eos_token) + 1]
-            req.landed_at.extend([landed] * len(emitted))
-            req.output.extend(emitted)
+            req.land(emitted, landed)
             self._last_token[slot] = emitted[-1]
             # the last emitted token is pending (not yet cached), so the
             # accepted cache length is start + len(emitted); rejected
@@ -1258,7 +1305,7 @@ class LLMEngine:
                 self._alloc.release(slot)
             finally:
                 # last: a caller that wakes finds its slot and blocks back
-                req.done.set()
+                req.end()
 
     def _record_finish(self, req: _Request, status: str) -> None:
         """One row for a request that finished, failed or was cancelled,
@@ -1381,7 +1428,7 @@ class LLMEngine:
                     if req is not None:
                         req.error = f"engine step failed: {e!r}"
                         self._record_finish(req, "error")
-                        req.done.set()
+                        req.end()
                         self._slots[slot] = None
                         # blocks would otherwise leak for good: only
                         # _maybe_finish/_preempt release them
@@ -1571,8 +1618,7 @@ class LLMEngine:
                     self._surplus_dropped += 1
                     continue
                 tok = ids[slot]
-                req.landed_at.append(landed)
-                req.output.append(int(tok))
+                req.land([int(tok)], landed)
                 self._last_token[slot] = tok
                 self._tokens_generated += 1
                 self._maybe_finish(slot)
@@ -1671,8 +1717,7 @@ class LLMEngine:
                 toks = toks[:req.max_tokens - len(req.output)]
                 if req.eos_token is not None and req.eos_token in toks:
                     toks = toks[:toks.index(req.eos_token) + 1]
-                req.landed_at.extend([landed] * len(toks))
-                req.output.extend(toks)
+                req.land(toks, landed)
                 if req.first_token_at is None:
                     req.first_token_at = landed
                 self._tokens_generated += len(toks)
@@ -1739,13 +1784,27 @@ class LLMServer:
         replica holding the longest cached prefix."""
         return self.engine.prefix_digest()
 
+    # the longest a stream goes without looking at its request: the net
+    # under the wake-up. It hangs below any decode turn or prefill that
+    # is served (10-70 ms a program), so between two landings it does
+    # not fire: at the old poll's 5 ms it fired twice a 10 ms turn, each
+    # time an empty poll, and no token came sooner for it
+    _STREAM_WAIT_S = 0.1
+
     def stream(self, prompt_or_request, **kwargs):
         """Generator-protocol streaming (round 11): tokens yield as the
         engine produces them, and the proxy's SSE path PUSHES each one to
         the client over the streaming-generator protocol — no proxy→
         replica poll RPCs.  The wait on the engine is replica-local (this
         generator runs on the replica's executor thread, never an event
-        loop).  ``submit``/``poll`` stay for pre-generator callers."""
+        loop) and is a wake-up, not a poll period: the engine thread
+        writes a landed token's time, then the token, then sets the
+        request's event (``_Request.land``; the same event at its end,
+        its failure and its cancel), and this loop waits on the event,
+        clears it and polls, in that order, so a token that lands
+        between a poll and the next wait ends that wait at once.
+        ``_STREAM_WAIT_S`` bounds a wait whatever the engine does.
+        ``submit``/``poll`` stay for pre-generator callers."""
         prompt, kw = self._parse(prompt_or_request, kwargs)
         request_id = self.engine.submit(
             prompt, kw.get("max_tokens", 64), kw.get("temperature", 0.0),
@@ -1754,6 +1813,7 @@ class LLMServer:
         from ray_tpu.serve.proxy import SSEBatch
 
         while True:
+            self.engine.wait_fresh(request_id, self._STREAM_WAIT_S)
             st = self.engine.poll(request_id)
             chunks = st["chunks"]
             if len(chunks) == 1:
@@ -1766,7 +1826,6 @@ class LLMServer:
                 yield SSEBatch(chunks)
             if st["done"]:
                 return
-            time.sleep(0.005)
 
     def stats(self) -> Dict[str, Any]:
         return self.engine.stats()

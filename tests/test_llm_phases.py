@@ -22,7 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.models import llama
-from ray_tpu.serve.llm import LLMEngine
+from ray_tpu.serve.llm import LLMEngine, LLMServer
 from ray_tpu.util import profiling
 
 CFG = llama.CONFIGS["debug"]
@@ -265,6 +265,185 @@ class TestRequestRecords:
             eng.shutdown()
         assert sorted(r[STATUS] for r in recent) == ["cancelled"] * 2
         assert all(r[FIN] >= r[ENQ] for r in recent)
+
+
+def _server(params, **kw):
+    kw.setdefault("num_slots", 4)
+    kw.setdefault("max_seq", 64)
+    return LLMServer(config=CFG, params=params, kv_cache="paged", **kw)
+
+
+def _device_paced(eng, seconds):
+    """The decode step waits as a device would, the interpreter lock
+    free: a reader that a landing woke has the time of a step to pick it
+    up, as on the chip, whatever else this machine runs."""
+    decode = eng._decode
+
+    def paced(*args):
+        time.sleep(seconds)
+        return decode(*args)
+
+    eng._decode = paced
+
+
+def _read(srv, out, prompt, n):
+    for item in srv.stream(prompt, max_tokens=n):
+        out.extend(item if isinstance(item, list) else [item])
+
+
+STREAMS = [([5, 17, 99], 40), ([6, 17, 99], 40), ([7, 17, 99], 33),
+           ([1, 2, 3, 4, 5, 6], 36)]            # the last admitted later
+
+
+@pytest.fixture(scope="module")
+def streamed(params):
+    """Three streams at once through ``LLMServer.stream`` and a fourth
+    admitted while they run; then the same prompts through generate().
+    The net is hung two seconds down, where no step of this model on a
+    busy CPU reaches it: what arrives, a wake-up brought."""
+    srv = _server(params)
+    srv._STREAM_WAIT_S = 2.0
+    eng = srv.engine
+    try:
+        for prompt, _ in STREAMS[::3]:
+            eng.generate(prompt, 2)             # compile outside the diff
+        _device_paced(eng, 0.005)
+        before = eng.stats()["delivery"]
+        outs = [[] for _ in STREAMS]
+        threads = [threading.Thread(target=_read, args=(srv, out, *job))
+                   for out, job in zip(outs, STREAMS)]
+        for t in threads[:3]:
+            t.start()
+        deadline = time.monotonic() + 60
+        while not outs[0] and time.monotonic() < deadline:
+            time.sleep(0.001)
+        threads[3].start()                      # mid-stream
+        for t in threads:
+            t.join(120)
+        alive = [t.is_alive() for t in threads]
+        after = eng.stats()["delivery"]
+        want = [eng.generate(prompt, n) for prompt, n in STREAMS]
+    finally:
+        eng.shutdown()
+    return {"outs": outs, "want": want, "alive": alive,
+            "delivery": {k: after[k] - before[k] for k in before
+                         if k != "pickup_wall_counts"}}
+
+
+class TestAStreamedTokenWakesItsReader:
+    def test_streams_yield_generates_tokens_none_lost_or_doubled(
+            self, streamed):
+        assert streamed["alive"] == [False] * len(STREAMS)
+        assert streamed["outs"] == streamed["want"]
+        assert [len(out) for out in streamed["outs"]] \
+            == [n for _, n in STREAMS]
+
+    def test_the_wake_up_carries_the_tokens_and_the_net_does_not(
+            self, streamed):
+        d = streamed["delivery"]
+        picked = sum(n for _, n in STREAMS)
+        assert d["tokens_picked"] == picked
+        # a wait before every poll, ended one way or the other
+        assert d["polls"] == d["waits_woken"] + d["waits_timed_out"]
+        # a landing is a wake-up, but for a stream's last (its end comes
+        # with it); a tenth of room for readers this machine kept from
+        # their poll for a whole step, who find two landings behind one
+        assert d["waits_woken"] >= picked - len(STREAMS) - picked // 10, d
+        # nothing waited out two seconds, and a wake-up finds nothing
+        # only at a stream's end or behind a poll that was late itself
+        assert d["waits_timed_out"] == 0, d
+        assert d["polls_empty"] <= picked // 10, d
+
+    def test_an_engine_that_only_generated_never_waited(self, params):
+        eng = _engine(params)
+        try:
+            reqs = []
+            put = eng._queue.put
+
+            def seen(req):
+                reqs.append(req)
+                put(req)
+
+            eng._queue.put = seen
+            outs = [eng.generate([3, 1, 4], 5), eng.generate([1, 5], 9)]
+            delivery = eng.stats()["delivery"]
+        finally:
+            eng.shutdown()
+        assert [len(out) for out in outs] == [5, 9]
+        assert len(reqs) == 2 and all(r.fresh is None for r in reqs)
+        assert {k: v for k, v in delivery.items()
+                if k != "pickup_wall_counts"} == dict.fromkeys(
+            ("polls", "polls_empty", "tokens_picked", "waits_woken",
+             "waits_timed_out"), 0)
+        assert sum(delivery["pickup_wall_counts"]) == 0
+
+    @pytest.mark.parametrize("ending", ["cancelled", "failed"])
+    def test_a_streams_end_is_a_wake_up_too(self, params, ending):
+        """A reader waiting with a time-out of a minute is back at once
+        when its request is cancelled or its engine's step fails."""
+        eng = _engine(params)
+        try:
+            eng.generate([9, 9], 2)
+            _device_paced(eng, 0.02)            # tokens 20 ms apart
+            rid = eng.submit([1, 2, 3], 40)
+            req = eng._pending[rid]["req"]
+            eng.wait_fresh(rid, 60)             # its first token
+            assert eng.poll(rid)["chunks"]
+
+            def end_it():
+                if ending == "cancelled":
+                    assert eng.cancel(rid)
+                else:
+                    def broken(*args):
+                        raise RuntimeError("no such chip")
+                    eng._decode = broken
+
+            # every landing before the end wakes the reader as well: the
+            # wait that the end itself ends is the last one
+            threading.Timer(0.05, end_it).start()
+            t0 = time.monotonic()
+            while not (req.done.is_set() or req.cancelled):
+                eng.wait_fresh(rid, 60)
+                assert time.monotonic() - t0 < 30
+            if ending == "cancelled":
+                eng.wait_fresh(rid, 60)         # no entry: back at once
+                assert time.monotonic() - t0 < 30
+                assert eng.poll(rid) == {"chunks": [], "done": True}
+                assert req.done.wait(60)
+            else:
+                with pytest.raises(RuntimeError, match="no such chip"):
+                    eng.poll(rid)
+            assert eng.stats()["delivery"]["waits_timed_out"] == 0
+        finally:
+            eng.shutdown()
+
+    def test_a_stream_nothing_wakes_still_ends_by_the_time_out(
+            self, params):
+        srv = _server(params)
+        eng = srv.engine
+        try:
+            want = eng.generate([2, 7, 1], 12)
+            before = eng.stats()["delivery"]
+            put = eng._queue.put
+
+            def deaf(req):
+                req.fresh.set = lambda: None    # the engine's wake-ups
+                put(req)                        # go nowhere
+
+            eng._queue.put = deaf
+            out = []
+            reader = threading.Thread(target=_read,
+                                      args=(srv, out, [2, 7, 1], 12))
+            reader.start()
+            reader.join(120)
+            assert not reader.is_alive()
+            after = eng.stats()["delivery"]
+        finally:
+            eng.shutdown()
+        assert out == want
+        assert after["waits_woken"] == before["waits_woken"]
+        assert after["waits_timed_out"] > before["waits_timed_out"]
+        assert after["tokens_picked"] - before["tokens_picked"] == 12
 
 
 def test_spans_land_in_a_capture_with_their_attributes(params, tmp_path):
