@@ -7,16 +7,11 @@ two over the turns in which the engine thread's CPU
 phases gives None.
 
 ``admit_ms_per_step``: what admission and prefill add to a decode step
-(the whole of ``admit``, prefill included, over the decode steps).
-``gil_wait_pct``: over the phases that only compute on the host, the
-share of their wall time in which the engine thread was not running:
-waiting for the GIL (the stream generators poll under it) or
-descheduled."""
+(the whole of ``admit``, prefill included, over the decode steps)."""
 
 from _lib import counters
 
-HOST_ONLY = ("grow", "admit", "decode_dispatch", "sample")
-WALL, TIMED_SELF_WALL, TIMED_SELF_CPU = 1, 3, 4
+WALL = 1
 
 
 def delta(a, b, name, column):
@@ -32,8 +27,4 @@ def read(run, what):
     if what == "admit_ms_per_step":
         steps = c[1]["steps"] - c[0]["steps"]
         return 1e3 * delta(a, b, "admit", WALL) / steps if steps else None
-    if what == "gil_wait_pct":
-        wall = sum(delta(a, b, n, TIMED_SELF_WALL) for n in HOST_ONLY)
-        cpu = sum(delta(a, b, n, TIMED_SELF_CPU) for n in HOST_ONLY)
-        return 100.0 * (wall - cpu) / wall if wall > 0 else None
     raise ValueError(f"_phase: no reading called {what!r}")

@@ -53,7 +53,7 @@ def watchdog() -> None:
                           flush=True)
             except OSError:
                 pass
-        cluster.stop_everything(cluster.descendants(), grace_s=0.0)
+        cluster.stop_everything(cluster.census(), grace_s=0.0)
         os._exit(3)
 
     timer = threading.Timer(RUN_LIMIT_S, abort)
@@ -115,6 +115,7 @@ def serve_cell(args, cell, spec, mix, cellfile, out_dir) -> dict:
     device = info["device"]
     peaks = check_device(device, cell["chips"], "replica", args.rehearse)
     say(f"replica ready in {ready_s:.1f}s on {device}")
+    cluster.census()        # controller, proxy and replica are up
     check = _call(handle, "check")
     say(f"check {check}")
 
@@ -153,11 +154,13 @@ def serve_cell(args, cell, spec, mix, cellfile, out_dir) -> dict:
     t_open = start_at + mix["lead_s"]
     t_close = t_open + args.seconds
     t_trace = t_open + mix["trace_offset_s"]
+    say(f"load starts; the window of {args.seconds:g}s opens in "
+        f"{t_open - time.monotonic():.1f}s")
 
     def on_tick(now):
-        def fire(name, method):
+        def fire(name, method, *args):
             marks[name] = now
-            refs[name] = handle.options(method_name=method).remote()
+            refs[name] = handle.options(method_name=method).remote(*args)
         if "open" not in marks and now >= t_open:
             fire("open", "bench_info")
         if "close" not in marks and now >= t_close:
@@ -166,7 +169,7 @@ def serve_cell(args, cell, spec, mix, cellfile, out_dir) -> dict:
             if "trace_start" not in marks and now >= t_trace:
                 fire("trace_start", "trace_start")
             if "trace_stop" not in marks and now >= t_trace + mix["trace_s"]:
-                fire("trace_stop", "trace_stop")
+                fire("trace_stop", "trace_stop", mix["trace_s"])
 
     requests = traffic_gen.request_stream(mix, args.seed, vocab,
                                           args.seconds)
@@ -275,6 +278,7 @@ def train_cell(args, cell, spec, mix, cellfile, out_dir) -> dict:
     from benchmark.worker_train import train_loop
 
     t_fit = time.monotonic()
+    cluster.census_in(10.0)     # the worker is up, its set-up under way
     result = JaxTrainer(
         train_loop,
         train_loop_config={"spec": spec, "mix": mix, "job": cellfile["job"],
@@ -377,6 +381,7 @@ def main() -> int:
                     "a measurement")
     args = ap.parse_args()
     watchdog()
+    cluster.exit_on_signals()
 
     bench = load_json(ROOT, "BENCHMARK.json")
     cells = {w["name"]: w for w in bench["workloads"]}

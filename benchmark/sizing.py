@@ -7,11 +7,14 @@ its adapter lowers them (``lower_serve_programs``, ``train_setup``).
 
 from __future__ import annotations
 
+import collections
+import re
+
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec
 
-from benchmark import model_spec
+from benchmark import model_spec, trace_reduce
 
 HBM_BYTES = 16 * 1024 ** 3            # one v5e chip
 
@@ -19,6 +22,17 @@ HBM_BYTES = 16 * 1024 ** 3            # one v5e chip
 def total_bytes(mem) -> int:
     return (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+
+
+def kernel_calls(text: str) -> dict:
+    """{kernel: its custom calls in a compiled program's text}, by the
+    instruction name the program gives its ``pallas_call`` (the compiler
+    numbers the instances: ``%paged_decode_attention.5``)."""
+    ops = (trace_reduce.parse_op(line.strip()) for line in text.splitlines()
+           if "tpu_custom_call" in line)
+    return collections.Counter(
+        re.sub(r"\.\d+$", "", name.split("%")[-1])
+        for name, opcode, _ in ops if opcode == "custom-call:tpu_custom_call")
 
 
 def on(sharding, tree):

@@ -44,6 +44,11 @@ TINY = {
     "reduced": []}
 DEPLOYMENT = {"num_slots": 3, "max_seq": 512, "kv_block_size": 64,
               "kv_pool_tokens": 1024, "max_ongoing_requests": 16}
+MIX = {"kind": "closed_loop_handle", "clients": 6, "block": 16,
+       "prompt_len": {"dist": "uniform", "min": 40, "max": 100},
+       "output_len": {"dist": "fixed", "value": 6, "min": 6, "max": 6},
+       "temperature": 0.0, "lead_s": 1.0, "drain_s": 30.0,
+       "trace_offset_s": 0.5, "trace_s": 1.0}
 # the other block's engine takes its pool in blocks, a deployment key
 # that only its adapter knows
 OTHER_ENGINE = '''
@@ -71,6 +76,20 @@ def _put(tmp_path, rel, obj):
     assert not path.exists()
     path.parent.mkdir(exist_ok=True)
     path.write_text(obj if isinstance(obj, str) else json.dumps(obj))
+
+
+def _enter(bench):
+    """The new configuration and its cell, as entries of the list."""
+    bench["configs"].append({
+        "name": "new-tiny", "source": "test",
+        "file": "benchmark/configs/new-tiny.json", "reduced": [],
+        "why": "test"})
+    bench["workloads"].append({
+        "name": "new-cell", "config": "new-tiny", "traffic": "new-mix",
+        "chips": 1, "why": "test"})
+    for m in bench["end_to_end"]:
+        if m["name"] == "output_tokens_per_s":
+            m["workloads"].append("new-cell")
 
 
 def _run(tmp_path, trace):
@@ -174,27 +193,13 @@ def test_a_new_cell_is_files_and_entries_only(tmp_path, block):
     bench = _copy(tmp_path)
     before = _hashes(tmp_path / "benchmark")
     added, compared, own_metrics = block(tmp_path)
-    _put(tmp_path, "traffic/new-mix.json", {
-        "kind": "closed_loop_handle", "clients": 6, "block": 16,
-        "prompt_len": {"dist": "uniform", "min": 40, "max": 100},
-        "output_len": {"dist": "fixed", "value": 6, "min": 6, "max": 6},
-        "temperature": 0.0, "lead_s": 1.0, "drain_s": 30.0,
-        "trace_offset_s": 0.5, "trace_s": 1.0})
+    _put(tmp_path, "traffic/new-mix.json", MIX)
     _put(tmp_path, "layer_metrics/new_metric.py",
          "def read(run):\n    return float(run['raw']['close']['stats']"
          "['steps'] - run['raw']['open']['stats']['steps'])\n")
     _put(tmp_path, "layer_metrics/new_alias.json",
          {"reader": "_engine_step_ms"})
-    bench["configs"].append({
-        "name": "new-tiny", "source": "test",
-        "file": "benchmark/configs/new-tiny.json", "reduced": [],
-        "why": "test"})
-    bench["workloads"].append({
-        "name": "new-cell", "config": "new-tiny", "traffic": "new-mix",
-        "chips": 1, "why": "test"})
-    for m in bench["end_to_end"]:
-        if m["name"] == "output_tokens_per_s":
-            m["workloads"].append("new-cell")
+    _enter(bench)
     for name in ["new_metric", "new_alias"] + own_metrics:
         bench["per_layer"].append({
             "name": name, "unit": "count", "better": "higher",

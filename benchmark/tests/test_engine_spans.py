@@ -50,17 +50,18 @@ class TestPhaseReaders:
         assert bench_run.load_reader("step_admit_ms.chat")(run) \
             == pytest.approx(40.0)
 
-    def test_gil_wait_is_wall_less_cpu_of_the_host_only_phases(self):
+    def test_a_reading_the_reader_does_not_have_is_refused_by_name(self):
+        """``gil_wait_pct`` went with its entry in PR 56 (it read 32 to 69
+        on one tree): an alias that still asks for it does not read 0."""
         run = serve_run(stats(50, self.A), stats(150, self.B))
-        # self wall 0.1 + 1.0 + 1.0 + 8.0, self cpu 0.1 + 0.5 + 0.9 + 6.0:
-        # the fetch, which waits for the device, is not among them
-        assert bench_run.load_reader("engine_gil_wait_pct.chat")(run) \
-            == pytest.approx(100 * (10.1 - 7.5) / 10.1)
+        phase = bench_run.load_reader("_phase")
+        with pytest.raises(ValueError, match="gil_wait_pct"):
+            phase(run, what="gil_wait_pct")
+        assert phase(run, what="admit_ms_per_step") == pytest.approx(40.0)
 
     def test_a_program_without_phases_gives_no_number(self):
         run = serve_run(stats(50), stats(150))
         assert bench_run.load_reader("step_admit_ms.chat")(run) is None
-        assert bench_run.load_reader("engine_gil_wait_pct.chat")(run) is None
         assert bench_run.load_reader("step_admit_ms.chat")(
             {"raw": {"losses": []}}) is None
 
@@ -121,8 +122,7 @@ def _engine_loop_entries():
     e2e = {w: m["name"] for m in bench["end_to_end"] if m["name"] != "setup_s"
            for w in m["workloads"]}
     return [(m, e2e) for m in bench["per_layer"] if m["name"].startswith((
-        "queue_wait", "request_prefill", "pickup_lag", "step_admit",
-        "engine_gil_wait"))]
+        "queue_wait", "request_prefill", "pickup_lag", "step_admit"))]
 
 
 @pytest.mark.parametrize("m, e2e", _engine_loop_entries(),

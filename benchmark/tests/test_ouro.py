@@ -15,7 +15,7 @@ import sys
 import jax
 import pytest
 
-from benchmark import model_spec, sizing, traffic_gen
+from benchmark import fold, model_spec, sizing, traffic_gen
 from benchmark import run as bench_run
 
 BENCH = model_spec.HERE
@@ -135,6 +135,37 @@ def test_the_file_keeps_every_published_key_and_cuts_nothing():
         early_exit_threshold=1.0)
 
 
+# what no other configuration has: the looped block's own mechanism
+OWN = ("loop_step_hbm_roofline", "kv_pool_live_pct", "expected_exit_pass",
+       "decode_post_norm_dev_ms", "decode_exit_gate_dev_ms")
+# the readings of the engine, the decode step's parts that this block has
+# (``parts/base.json``) and the prefill that it shares with other cells,
+# by their names up to a tag: the cell's PR brought `<name>.loop`, a fold
+# takes a tag off, and neither is this test's business
+SHARED = ("replica_ready_s", "slot_occupancy_pct", "engine_step_ms",
+          "decode_step_dev_ms", "device_idle_pct", "overlapped_turn_pct",
+          "paged_decode_roofline", "paged_decode_dev_ms",
+          "prefill_dev_share_pct", "decode_attn_proj_dev_ms",
+          "decode_dense_mlp_dev_ms", "decode_head_dev_ms",
+          "decode_kv_store_dev_ms", "decode_unnamed_dev_ms",
+          "prefill_attention_dev_ms", "prefill_unnamed_dev_ms",
+          "turn_decode_wait_ms", "turn_prefill_wait_ms", "turn_host_ms",
+          "prompts_per_admitting_turn", "gap_after_prefill_ms",
+          "gap_in_turn_ms", "other_programs_dev_ms")
+
+
+def _parts(name):
+    with open(os.path.join(BENCH, "layer_metrics", "parts",
+                           name + ".json")) as f:
+        return json.load(f)["parts"]
+
+
+def _mine():
+    """The per-layer entries that list the cell."""
+    return [m for m in BENCHMARK["per_layer"]
+            if CELL in m.get("workloads", ())]
+
+
 def test_the_cell_and_the_lists_it_joins():
     cells = {w["name"]: w for w in BENCHMARK["workloads"]}
     assert cells[CELL] == dict(cells[CELL], config=NAME, chips=1,
@@ -142,25 +173,35 @@ def test_the_cell_and_the_lists_it_joins():
     assert len(cells[CELL]["why"]) <= 200
     lists = {m["name"]: m.get("workloads")
              for m in BENCHMARK["end_to_end"] + BENCHMARK["per_layer"]}
-    # (not "the last of the list": the next cell is appended after it)
     assert CELL in lists["output_tokens_per_s"]
+    # the two that move setup_s list no cells: reported here as everywhere
     for name in ("compiles_in_window", "peak_hbm_gb"):
-        assert lists[name] is None and name + ".loop" not in lists
-    mine = sorted(n for n, w in lists.items() if w and CELL in w
-                  and n != "output_tokens_per_s")
-    assert len(mine) == 21 and len(BENCHMARK["per_layer"]) <= 120
-    # the accepted entries gained no cell: the new cell has tagged copies
-    assert all(n.endswith(".loop") or n in (
-        "loop_step_hbm_roofline", "kv_pool_live_pct", "expected_exit_pass",
-        "decode_post_norm_dev_ms", "decode_exit_gate_dev_ms")
-        for n in mine), mine
-    for name in mine:
-        assert lists[name] == [CELL], name
-    with open(os.path.join(BENCH, "layer_metrics", "parts",
-                           "ouro.json")) as f:
-        from ray_tpu.util import profiling
+        assert lists[name] is None
+    assert {m["moves"] for m in _mine()} == {"output_tokens_per_s",
+                                             "setup_s"}
+    # no entry the cell lists reads a part of another block's
+    parts = {name: _parts(name) for name in ("base", "ouro")}
+    from ray_tpu.util import profiling
 
-        assert tuple(json.load(f)["parts"]) == profiling.LOOP_PARTS
+    assert tuple(parts["ouro"]) == profiling.LOOP_PARTS
+    known = {"unnamed", *parts["base"], *parts["ouro"]}
+    for m in _mine():
+        reader, args = fold.resolved(ROOT, m["name"])
+        if reader == "_dev_ms_by_part":
+            assert set(args.get("parts", ())) <= known, m["name"]
+
+
+@pytest.mark.parametrize("name", OWN)
+def test_the_cell_reports_an_entry_of_its_own_mechanism(name):
+    assert [m for m in _mine() if m["name"] == name], name
+    assert callable(bench_run.load_reader(name))
+
+
+@pytest.mark.parametrize("base", SHARED)
+def test_the_cell_is_in_the_list_of_a_shared_entry(base):
+    """Under whatever tag, once: one entry of that reading lists it."""
+    assert len([m for m in _mine()
+                if m["name"].split(".")[0] == base]) == 1, base
 
 
 def test_a_configuration_that_is_not_this_block_exits_by_name(monkeypatch):
@@ -328,8 +369,8 @@ TINY = dict(
     head_dim=16, max_position_embeddings=1024, reduced=[])
 TINY.pop("published")
 LOOP_METRICS = ("kv_pool_live_pct", "expected_exit_pass",
-                "slot_occupancy_pct.loop", "engine_step_ms.loop",
-                "overlapped_turn_pct.loop", "replica_ready_s.loop",
+                "slot_occupancy_pct", "engine_step_ms",
+                "overlapped_turn_pct", "replica_ready_s",
                 "compiles_in_window")
 
 
@@ -390,7 +431,7 @@ def test_a_tiny_configuration_of_the_block_runs_through_the_harness(
     assert set(LOOP_METRICS) <= set(got), sorted(got)
     assert 0 < got["kv_pool_live_pct"]["value"] <= 100
     assert 0 < got["expected_exit_pass"]["value"] < 3
-    assert "paged_decode_roofline.loop" not in got       # no kernel here
+    assert "paged_decode_roofline" not in got            # no kernel here
     # a checkout without the program's module: the driver refuses the
     # cell before it starts anything
     os.remove(tmp_path / "ray_tpu")

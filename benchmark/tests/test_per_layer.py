@@ -7,6 +7,7 @@ import os
 
 import pytest
 
+from benchmark import fold
 from benchmark import run as bench_run
 
 FOLDER = os.path.join(bench_run.HERE, "layer_metrics")
@@ -14,21 +15,18 @@ with open(os.path.join(bench_run.ROOT, "BENCHMARK.json")) as _f:
     BENCH = json.load(_f)
 CELLS = [w["name"] for w in BENCH["workloads"]]
 E2E = {m["name"]: m.get("workloads", CELLS) for m in BENCH["end_to_end"]}
-CAP = 128       # the contract's
+CAP = fold.CAP  # the contract's
 
 
 def resolved(name):
     """(the ``.py`` an entry's name ends at, the arguments it is called
-    with) through however many alias files."""
-    path = os.path.join(FOLDER, name + ".json")
-    if not os.path.exists(path):
+    with) through however many alias files: the fold's own rule."""
+    alias = fold.alias(bench_run.ROOT, name)
+    if alias is None:
         assert os.path.exists(os.path.join(FOLDER, name + ".py")), name
-        return name, {}
-    with open(path) as f:
-        alias = json.load(f)
-    assert set(alias) <= {"reader", "args"}, name
-    reader, args = resolved(alias["reader"])
-    return reader, dict(args, **alias.get("args", {}))
+    else:
+        assert set(alias) <= {"reader", "args"}, name
+    return fold.resolved(bench_run.ROOT, name)
 
 
 def test_the_list_is_within_the_contracts_cap_and_names_each_metric_once():
@@ -64,8 +62,8 @@ def test_copies_of_a_reader_differ_by_a_tag_alone():
     in ``moves``, ``unit``, ``better`` and ``source`` are copies. A PR
     that adds a cell may append ``<metric>.<tag>`` (it may edit no
     entry's ``workloads``); a ``benchmark`` PR folds them (README, "the
-    fold"). PR 41 left none. So copies share their name up to the tag,
-    and at most one of them has none."""
+    fold": ``benchmark/fold.py``). So copies share their name up to the
+    tag, and at most one of them has none."""
     seen = {}
     for m in BENCH["per_layer"]:
         reader, args = resolved(m["name"])

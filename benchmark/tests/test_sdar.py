@@ -15,7 +15,8 @@ import sys
 import jax
 import pytest
 
-from benchmark import model_spec, sizing, traffic_gen
+from benchmark import fold, model_spec, sizing, traffic_gen
+from benchmark import run as bench_run
 
 BENCH = model_spec.HERE
 ROOT = os.path.dirname(BENCH)
@@ -149,6 +150,34 @@ def test_the_file_keeps_every_published_key_but_the_depth():
     assert "denoising_steps" not in ARCH.engine_kwargs.__code__.co_varnames
 
 
+# what no other configuration has: the block step's own readings
+OWN = ("block_step_dev_ms", "block_step_attention_dev_ms",
+       "block_step_attention_roofline", "block_step_qkv_store_dev_ms",
+       "block_step_experts_dev_ms", "block_step_head_dev_ms",
+       "block_step_decide_dev_ms", "block_step_unnamed_dev_ms",
+       "tokens_per_block_step", "commit_step_share_pct",
+       "expert_layer_dev_ms.bd")
+# the readings of the engine, the routed layer and the prefill that the
+# cell shares with other cells, by their names up to a tag: the cell's PR
+# brought `<name>.bd`, a fold takes a tag off, and neither is this
+# test's business
+SHARED = ("replica_ready_s", "engine_step_ms", "device_idle_pct",
+          "overlapped_turn_pct", "grouped_expert_matmul_roofline",
+          "expert_pairs_per_step", "expert_load_max_over_mean",
+          "expert_pairs_dropped", "experts_hit_pct", "prefill_dev_share_pct",
+          "prefill_flash_dev_ms", "prefill_experts_dev_ms",
+          "prefill_expert_products_dev_ms", "prefill_unnamed_dev_ms",
+          "turn_decode_wait_ms", "turn_prefill_wait_ms", "turn_host_ms",
+          "prompts_per_admitting_turn", "gap_after_prefill_ms",
+          "gap_in_turn_ms", "other_programs_dev_ms")
+
+
+def _mine():
+    """The per-layer entries that list the cell."""
+    return [m for m in BENCHMARK["per_layer"]
+            if CELL in m.get("workloads", ())]
+
+
 def test_the_cell_and_the_lists_it_joins():
     cells = {w["name"]: w for w in BENCHMARK["workloads"]}
     assert cells[CELL] == dict(cells[CELL], config=NAME, chips=1,
@@ -156,36 +185,43 @@ def test_the_cell_and_the_lists_it_joins():
     assert len(cells[CELL]["why"]) <= 200
     lists = {m["name"]: m.get("workloads")
              for m in BENCHMARK["end_to_end"] + BENCHMARK["per_layer"]}
-    assert lists["output_tokens_per_s"][-1] == CELL
+    assert CELL in lists["output_tokens_per_s"]
     # the two entries that move setup_s and list no cells are reported
-    # here as everywhere (their readers find the run's own numbers): they
-    # keep no list, and the cell brings no copy of them
+    # here as everywhere (their readers find the run's own numbers)
     for name in ("compiles_in_window", "peak_hbm_gb"):
-        assert lists[name] is None and name + ".bd" not in lists
-    mine = [n for n, w in lists.items() if w and CELL in w]
-    assert "slot_occupancy_pct" not in mine
-    assert not [n for n in mine if n.startswith("decode_")]
-    for name in ("block_step_dev_ms", "tokens_per_block_step",
-                 "commit_step_share_pct", "block_step_attention_roofline",
-                 "grouped_expert_matmul_roofline.bd",
-                 "block_step_experts_dev_ms", "block_step_head_dev_ms",
-                 "block_step_decide_dev_ms", "block_step_unnamed_dev_ms",
-                 "engine_step_ms.bd", "device_idle_pct.bd",
-                 "overlapped_turn_pct.bd", "expert_layer_dev_ms.bd",
-                 "expert_pairs_per_step.bd", "expert_load_max_over_mean.bd",
-                 "expert_pairs_dropped.bd", "prefill_dev_share_pct.bd",
-                 "prefill_flash_dev_ms.bd", "prefill_experts_dev_ms.bd",
-                 "prefill_expert_products_dev_ms.bd",
-                 "prefill_unnamed_dev_ms.bd", "replica_ready_s.bd"):
-        assert lists[name] == [CELL], name
-    # no program answers to another's name
+        assert lists[name] is None
+    mine = [m["name"] for m in _mine()]
+    # the engine runs no decode step here: a block step, and no slot of
+    # it is ever half full
+    assert not [n for n in mine if n.startswith(("decode_",
+                                                 "slot_occupancy"))]
+    # no program answers to another's name, no part to another block's
+    parts = {"unnamed"}      # what no part of any block names
+    for name in ("base", "sdar"):
+        with open(os.path.join(BENCH, "layer_metrics", "parts",
+                               name + ".json")) as f:
+            parts.update(json.load(f)["parts"])
     for name in mine:
-        path = os.path.join(BENCH, "layer_metrics", name + ".json")
-        if os.path.exists(path):
-            with open(path) as f:
-                program = json.load(f).get("args", {}).get("program", "")
-            assert program in ("", "^jit_block_step", "^jit_block_decide",
-                               "^jit_prefill"), name
+        reader, args = fold.resolved(ROOT, name)
+        assert args.get("program", "") in (
+            "", "^jit_block_step", "^jit_block_decide", "^jit_prefill"), name
+        if reader == "_dev_ms_by_part":
+            assert set(args.get("parts", ())) <= parts, name
+
+
+@pytest.mark.parametrize("name", OWN)
+def test_the_cell_reports_an_entry_of_its_own_mechanism(name):
+    assert [m for m in _mine() if m["name"] == name], name
+    assert callable(bench_run.load_reader(name))
+
+
+@pytest.mark.parametrize("base", SHARED)
+def test_the_cell_is_in_the_list_of_a_shared_entry(base):
+    """Under whatever tag, once: one entry of that reading lists it
+    (``expert_layer_dev_ms.bd`` reads the block step and is the cell's
+    own)."""
+    assert len([m for m in _mine()
+                if m["name"].split(".")[0] == base]) == 1, base
 
 
 def test_a_configuration_that_is_not_this_block_exits_by_name():
@@ -299,8 +335,6 @@ def test_the_cells_programs_fit_one_chip(device, monkeypatch):
 
 # ------------------------------------------------------ the block counters
 def test_the_block_counters_reader():
-    from benchmark import run as bench_run
-
     tokens = bench_run.load_reader("tokens_per_block_step")
     share = bench_run.load_reader("commit_step_share_pct")
 
@@ -334,10 +368,10 @@ TINY = dict(
     max_position_embeddings=1024, mask_token_id=250, reduced=[])
 TINY.pop("published")
 BLOCK_METRICS = ("tokens_per_block_step", "commit_step_share_pct",
-                 "engine_step_ms.bd", "overlapped_turn_pct.bd",
-                 "expert_pairs_per_step.bd", "expert_load_max_over_mean.bd",
-                 "expert_pairs_dropped.bd", "experts_hit_pct.bd",
-                 "replica_ready_s.bd", "compiles_in_window")
+                 "engine_step_ms", "overlapped_turn_pct",
+                 "expert_pairs_per_step", "expert_load_max_over_mean",
+                 "expert_pairs_dropped", "experts_hit_pct",
+                 "replica_ready_s", "compiles_in_window")
 
 
 def test_a_tiny_configuration_of_the_block_runs_through_the_harness(
@@ -396,9 +430,9 @@ def test_a_tiny_configuration_of_the_block_runs_through_the_harness(
     assert line["compared"]["greedy_probe_differs"]["value"] == 0
     got = line["metrics"]
     assert set(BLOCK_METRICS) <= set(got), sorted(got)
-    assert got["expert_pairs_dropped.bd"]["value"] == 0
+    assert got["expert_pairs_dropped"]["value"] == 0
     # every expert is held: 2 pairs a position, 4 positions a running slot
-    assert 0 < got["expert_pairs_per_step.bd"]["value"] <= 3 * 4 * 2
+    assert 0 < got["expert_pairs_per_step"]["value"] <= 3 * 4 * 2
     assert 0 < got["tokens_per_block_step"]["value"] <= 4 / 3
     assert 25 < got["commit_step_share_pct"]["value"] < 50
     assert "block_step_attention_roofline" not in got    # no kernel

@@ -104,13 +104,22 @@ class BenchServer(LLMServer):
         self._tracing = (trace_dir, time.monotonic())
         return self._tracing[1]
 
-    def trace_stop(self) -> dict:
-        """Stop the profiler and reduce the trace here, where jax is."""
+    def trace_stop(self, span_s: float = 0.0) -> dict:
+        """Stop the profiler ``span_s`` after it STARTED and reduce the
+        trace here, where jax is. This call and ``trace_start`` run on two
+        threads of the replica: a start that takes seconds on a busy host
+        must not meet its own stop before it has returned."""
         import jax
 
         from benchmark import trace_reduce
 
-        trace_dir, _ = self._tracing
+        deadline = time.monotonic() + 120.0
+        while self._tracing is None:
+            if time.monotonic() > deadline:
+                raise RuntimeError("trace_stop: the profiler never started")
+            time.sleep(0.01)
+        trace_dir, started = self._tracing
+        time.sleep(max(0.0, started + span_s - time.monotonic()))
         jax.profiler.stop_trace()
         self._tracing = None
         path = trace_reduce.find_xplane(trace_dir)
