@@ -309,13 +309,18 @@ def row_tile(T: int, top_k: int, n_experts: int, held: int) -> int:
 
 @part("router")
 def route_sigmoid_topk(x, router, bias, top_k: int, scale: float = 1.0,
-                       n_group: int = 1, topk_group: int = 1):
+                       n_group: int = 1, topk_group: int = 1,
+                       norm_eps: float = 0.0):
     """``noaux_tc`` routing: scores ``s = sigmoid(x_f32 @ W_r)`` in
     float32 over every expert; the ``top_k`` largest of ``s + b`` are
     chosen (``b`` the stored correction bias, used for the choice only;
     None where the model stores none); the weights are ``s`` at the
-    chosen, divided by their sum, times ``scale``. x (T, h) -> (idx
-    (T, k) int32, weights (T, k) f32).
+    chosen, divided by their sum (plus ``norm_eps`` where a family
+    adds one; 0 adds nothing to the program), times ``scale``. x (T, h)
+    -> (idx (T, k) int32, weights (T, k) f32). A gradient reaches
+    ``router`` through the gathered ``s`` and their normalisation; the
+    choice (``top_k`` of ``s + b``) is integers and carries none, so
+    ``bias`` gets zeros.
 
     Group-limited (``n_group`` > 1): the experts are ``n_group`` runs of
     equal length; a group's score is the sum of its two largest ``s +
@@ -335,7 +340,8 @@ def route_sigmoid_topk(x, router, bias, top_k: int, scale: float = 1.0,
         c = jnp.where(kept[:, :, None], grouped, -jnp.inf).reshape(T, E)
     _, idx = jax.lax.top_k(c, top_k)
     w = jnp.take_along_axis(s, idx, axis=-1)
-    w = w / jnp.sum(w, axis=-1, keepdims=True) * scale
+    total = jnp.sum(w, axis=-1, keepdims=True)
+    w = w / (total + norm_eps if norm_eps else total) * scale
     return idx.astype(jnp.int32), w
 
 
@@ -384,11 +390,127 @@ def shared_expert(x, layer: Params):
     return swiglu(x, layer["ws_gate"], layer["ws_up"], layer["ws_down"])
 
 
+@jax.custom_vjp
+def _rows_of_tokens(x, token_of_row, order, row_sorted, held):
+    """The row buffer of a dispatch: row ``r`` is token
+    ``token_of_row[r]``'s ``x``, a row that is no pair's (``T``) zeros.
+    ``order``, ``row_sorted`` and ``held`` (the dispatch's own integers:
+    the pairs sorted by expert, each sorted pair's row, which pairs are
+    held here) are not read going forward. They make the TRANSPOSE a
+    combine with unit weights: a token gathers the rows of its pairs
+    placed here and sums them in float32, where ``jax.grad`` of the
+    gather would scatter-add every row of the worst-case buffer."""
+    del order, row_sorted, held
+    return jnp.concatenate(
+        [x, jnp.zeros((1, x.shape[1]), x.dtype)])[token_of_row]
+
+
+def _pair_rows(order, row_sorted, held, M: int):
+    """(row of each (token, expert) pair (T, k), ``M`` where it has
+    none; whether the pair is placed here) from the dispatch's sort."""
+    T, top_k = held.shape
+    row_pair = jnp.zeros(T * top_k, jnp.int32).at[order].set(
+        row_sorted.astype(jnp.int32)).reshape(T, top_k)
+    return row_pair, held & (row_pair < M)
+
+
+def _rows_fwd(x, token_of_row, order, row_sorted, held):
+    return (_rows_of_tokens(x, token_of_row, order, row_sorted, held),
+            _pair_rows(order, row_sorted, held, token_of_row.shape[0]))
+
+
+def _rows_bwd(res, d_rows):
+    row_pair, placed = res
+    picked = jnp.where(
+        placed[..., None],
+        d_rows[jnp.minimum(row_pair, d_rows.shape[0] - 1)], 0)
+    return (jnp.sum(picked.astype(jnp.float32), axis=1).astype(d_rows.dtype),
+            None, None, None, None)
+
+
+_rows_of_tokens.defvjp(_rows_fwd, _rows_bwd)
+
+
+def _down_combine_rows(act, we_down, tile_group, n_active, order, row_sorted,
+                       held, w, tm, name, experts):
+    """The down product and the combine as the layer runs them:
+    -> (y (T, h) float32, placed (T, k) bool, and for the backward the
+    product's rows and each pair's row)."""
+    from ray_tpu.ops.pallas import expert_combine, grouped_matmul as gm
+
+    y_rows = gm.grouped_product(act, we_down, tile_group, n_active, tm=tm,
+                                out_dtype=jnp.float32, name=name)
+    with part("expert_combine"):
+        row_pair, placed = _pair_rows(order, row_sorted, held, act.shape[0])
+        # rows of tiles past n_active were never written: the XLA
+        # form selects and the kernel copies placed rows only; neither
+        # multiplies, or what lies there leaks through a zero weight
+        y = expert_combine.combine(
+            y_rows, row_pair, placed, w,
+            held=we_down.shape[0], experts=experts)
+    return y, placed, y_rows, row_pair
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10))
+def _down_and_combine(act, we_down, tile_group, n_active, order, row_sorted,
+                      held, w, tm: int, name: str, experts: int):
+    """``act`` (M, m) through the held experts' ``we_down`` and each
+    token's weighted sum over its pairs placed here: -> (y (T, h)
+    float32, placed (T, k) bool). ONE backward for the two, so that the
+    float32 gradient of the (M, h) row buffer is never built: a row
+    GATHERS its token's ``dy`` in ``act``'s dtype, already times its
+    pair's weight (a row is at most one placed pair's, so nothing is
+    scattered into the buffer; a row that is no pair's gets zeros), and
+    that is what the products' two gradients multiply
+    (``grouped_matmul.product_grads``); a weight's gradient is its row's
+    dot with its token's ``dy``. Rows no placed pair names are read for
+    no gradient that is kept."""
+    return _down_combine_rows(act, we_down, tile_group, n_active, order,
+                              row_sorted, held, w, tm, name, experts)[:2]
+
+
+def _down_and_combine_fwd(act, we_down, tile_group, n_active, order,
+                          row_sorted, held, w, tm, name, experts):
+    y, placed, y_rows, row_pair = _down_combine_rows(
+        act, we_down, tile_group, n_active, order, row_sorted, held, w, tm,
+        name, experts)
+    return (y, placed), (act, we_down, tile_group, n_active, y_rows,
+                         row_pair, placed, w)
+
+
+def _down_and_combine_bwd(tm, name, experts, res, cotangents):
+    from ray_tpu.ops.pallas import grouped_matmul as gm
+
+    act, we_down, tile_group, n_active, y_rows, row_pair, placed, w = res
+    dy = cotangents[0]
+    T, top_k = row_pair.shape
+    M = act.shape[0]
+    with part("expert_combine"):
+        rows = jnp.where(placed, row_pair, M).reshape(-1)
+        token_of_row = jnp.full((M,), T, jnp.int32).at[rows].set(
+            jnp.repeat(jnp.arange(T, dtype=jnp.int32), top_k), mode="drop")
+        w_row = jnp.zeros((M,), jnp.float32).at[rows].set(
+            w.astype(jnp.float32).reshape(-1), mode="drop")
+        dy_rows = jnp.concatenate(
+            [dy.astype(act.dtype), jnp.zeros((1, dy.shape[1]), act.dtype)]
+        )[token_of_row].astype(jnp.float32)
+        dot_row = jnp.sum(y_rows.astype(jnp.float32) * dy_rows, axis=1)
+        dw = jnp.where(placed, dot_row[jnp.minimum(row_pair, M - 1)], 0.0)
+        d_rows = (dy_rows * w_row[:, None]).astype(act.dtype)
+    d_act, d_down = gm.product_grads(act, we_down, d_rows, tile_group,
+                                     n_active, tm=tm, name=name)
+    return (d_act, d_down, None, None, None, None, None, dw.astype(w.dtype))
+
+
+_down_and_combine.defvjp(_down_and_combine_fwd, _down_and_combine_bwd)
+
+
 def experts_by_share(x, layer: Params, *, experts_held: Tuple[int, int],
                      top_k: int, scale: float = 1.0, valid=None,
                      kernel_name: str = "grouped_expert_matmul",
                      n_group: int = 1, topk_group: int = 1,
-                     score: str = "sigmoid", x_experts=None):
+                     score: str = "sigmoid", x_experts=None,
+                     norm_eps: float = 0.0):
     """The routed MLP of one layer on the chip that holds
     ``experts_held = (first, count)``: x (T, h) -> (y (T, h) float32,
     the partial sum over the experts held; counters (5,) float32 in the
@@ -424,8 +546,15 @@ def experts_by_share(x, layer: Params, *, experts_held: Tuple[int, int],
     stays the worst case at any tile, ``T * top_k + G * (tm - 1)`` rows
     rounded to tiles. ``kernel_name`` names the products' custom calls
     in a trace.
+
+    ONE layer for serving and training: ``jax.grad`` goes through it.
+    The products, the rows' gather and the down product with the
+    combine each carry a backward of their own (``grouped_product``,
+    :func:`_rows_of_tokens`, :func:`_down_and_combine`); the sort, the
+    tiles and the counters are integers and carry none. ``norm_eps`` is
+    :func:`route_sigmoid_topk`'s.
     """
-    from ray_tpu.ops.pallas import expert_combine, grouped_matmul as gm
+    from ray_tpu.ops.pallas import grouped_matmul as gm
 
     T = x.shape[0]
     xe = x if x_experts is None else x_experts
@@ -438,7 +567,7 @@ def experts_by_share(x, layer: Params, *, experts_held: Tuple[int, int],
     else:
         idx, w = route_sigmoid_topk(
             x, layer["router"], layer.get("router_bias"), top_k, scale,
-            n_group, topk_group)
+            n_group, topk_group, norm_eps)
     n_tiles = -(-(T * top_k + G * (tm - 1)) // tm)
     M = n_tiles * tm
     with part("expert_dispatch"):
@@ -459,8 +588,7 @@ def experts_by_share(x, layer: Params, *, experts_held: Tuple[int, int],
             - ustart[g_of], M)
         token_of_row = jnp.full((M,), T, jnp.int32).at[row_sorted].set(
             (order // top_k).astype(jnp.int32), mode="drop")
-        x_rows = jnp.concatenate(
-            [xe, jnp.zeros((1, xe.shape[1]), xe.dtype)])[token_of_row]
+        x_rows = _rows_of_tokens(xe, token_of_row, order, row_sorted, held)
         n_active = pend[-1] // tm
         tile = jnp.minimum(jnp.arange(n_tiles),
                            jnp.maximum(n_active - 1, 0))
@@ -477,18 +605,9 @@ def experts_by_share(x, layer: Params, *, experts_held: Tuple[int, int],
                 out_dtype=jnp.float32)
         act = (jax.nn.silu(gate) * up if gated
                else jnp.square(jax.nn.relu(up))).astype(xe.dtype)
-        y_rows = mm(act, layer["we_down"], tile_group, n_active, tm=tm,
-                    out_dtype=jnp.float32)
-        with part("expert_combine"):
-            row_pair = jnp.zeros(T * top_k, jnp.int32).at[order].set(
-                row_sorted.astype(jnp.int32)).reshape(T, top_k)
-            placed = held & (row_pair < M)
-            # rows of tiles past n_active were never written: the XLA
-            # form selects and the kernel copies placed rows only; neither
-            # multiplies, or what lies there leaks through a zero weight
-            y = expert_combine.combine(
-                y_rows, row_pair, placed, w,
-                held=G, experts=layer["router"].shape[1])
+        y, placed = _down_and_combine(
+            act, layer["we_down"], tile_group, n_active, order, row_sorted,
+            held, w, tm, kernel_name, layer["router"].shape[1])
     with part("expert_dispatch"):       # the step's counters: group sizes
         pairs = jnp.sum(sizes).astype(jnp.float32)
         counters = jnp.stack([

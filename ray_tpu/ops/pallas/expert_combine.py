@@ -45,6 +45,7 @@ NAME = "expert_combine"
 _GROUP = 8                    # rows of a float32 tile: what one copy moves
 _TOKENS = 16                  # tokens a grid step
 _RING_BYTES = 6 << 20
+_CALL_PAIRS = 1 << 16         # pairs a call prefetches: 576 KiB of SMEM
 _GATHER_BYTES = 32 << 20      # kernel_serves: the gather's array from which
                               # the kernel wins at a quarter of the pairs
 
@@ -108,11 +109,21 @@ def _kernel(count_ref, src_ref, w_ref, y_hbm, o_ref, ring, sem, state, *,
 
 
 def expert_combine(y_rows, row_pair, placed, w, *, interpret: bool = False):
-    """See the module's text."""
+    """See the module's text. A call prefetches its pairs' source rows
+    and weights into SMEM (1 MiB a core), so more than ``_CALL_PAIRS``
+    pairs (a train step's 32,768 tokens; no serving program comes near)
+    are combined in runs of tokens, one call a run over the same
+    ``y_rows``."""
     T, k = row_pair.shape
     M, h = y_rows.shape
     if M % _GROUP:
         raise ValueError(f"rows {M} not a multiple of the group {_GROUP}")
+    run = _CALL_PAIRS // k // _TOKENS * _TOKENS
+    if T > run:
+        return jnp.concatenate([
+            expert_combine(y_rows, row_pair[t:t + run], placed[t:t + run],
+                           w[t:t + run], interpret=interpret)
+            for t in range(0, T, run)])
     tq = _TOKENS
     pad = -T % tq
     if pad:                   # a tail of tokens with no placed pair
@@ -180,7 +191,9 @@ def combine(y_rows, row_pair, placed, w, *, held: int, experts: int):
     """The kernel on a TPU where :func:`kernel_serves` the call (a share
     of the router's ``experts`` is ``held``: most pairs are placed
     elsewhere and the kernel moves only those placed here); its oracle
-    elsewhere."""
+    elsewhere. Its transpose is part of the expert layer's backward
+    (``models/moe.py::_down_and_combine``), which never builds the
+    float32 gradient of ``y_rows``."""
     if attention.on_tpu() and kernel_serves(
             *row_pair.shape, y_rows.shape[1], held, experts):
         return expert_combine(y_rows, row_pair, placed, w)

@@ -46,6 +46,7 @@ from ray_tpu.ops import attention
 from ray_tpu.util.profiling import part
 
 NAME = "grouped_expert_matmul"
+NAME_DW = "grouped_expert_matmul_dw"
 _RHS_BLOCK_BYTES = 4 << 20
 
 
@@ -122,9 +123,137 @@ def grouped_matmul_reference(lhs, rhs, tile_group, n_active, *, tm: int,
             M, -1).astype(out_dtype)
 
 
-def grouped_product(lhs, rhs, tile_group, n_active, *, tm: int,
+def grouped_matmul_dw_reference(lhs, dout, tile_group, n_active, *, tm: int,
+                                groups: int, out_dtype=None,
+                                name: str = NAME_DW):
+    """XLA path (and the ``dw`` kernel's oracle): each live tile's
+    ``lhs_tile^T dout_tile`` in float32, summed into its group's matrix;
+    a group with no tile gets zeros."""
+    M, K = lhs.shape
+    out_dtype = out_dtype or lhs.dtype
+    with part(name):
+        live = (jnp.arange(M // tm) < n_active)[:, None, None]
+        per_tile = jnp.einsum(
+            "itk,itn->ikn", lhs.reshape(M // tm, tm, K),
+            jnp.where(live, dout.reshape(M // tm, tm, -1), 0),
+            preferred_element_type=jnp.float32)
+        return jnp.zeros((groups,) + per_tile.shape[1:], jnp.float32).at[
+            tile_group].add(jnp.where(live, per_tile, 0.0)).astype(out_dtype)
+
+
+def _dw_kernel(group_ref, active_ref, lhs_ref, dout_ref, o_ref, acc_ref):
+    i = pl.program_id(1)
+    n_active = active_ref[0]
+    g = group_ref[i]
+    live = i < n_active
+
+    @pl.when(live & ((i == 0) | (group_ref[jnp.maximum(i - 1, 0)] != g)))
+    def _():
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    @pl.when(live)
+    def _():
+        acc_ref[...] += jax.lax.dot_general(
+            lhs_ref[...], dout_ref[...], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    # the group's last tile writes its matrix; the block goes back to HBM
+    # when the next tile names another group, or at the grid's end
+    @pl.when(live & ((i == n_active - 1)
+                     | (group_ref[jnp.minimum(i + 1, pl.num_programs(1) - 1)]
+                        != g)))
+    def _():
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+def grouped_matmul_dw(lhs, dout, tile_group, n_active, *, tm: int,
+                      groups: int, out_dtype=None, name: str = NAME_DW,
+                      interpret: bool = False):
+    """The weights' gradient of :func:`grouped_matmul`: lhs (M, K) and
+    dout (M, N) in the same tiles of ``tm`` rows -> (groups, K, N), group
+    ``g`` the sum over its tiles of ``lhs_tile^T dout_tile``, accumulated
+    in float32 in VMEM over the group's consecutive tiles and written
+    once. The grid is the forward's, (N / tn, M / tm) with the row tiles
+    innermost; tiles at or past ``n_active`` repeat the last live tile's
+    indices and add nothing. A group that no tile names is NOT written:
+    :func:`grouped_product`'s backward masks it."""
+    M, K = lhs.shape
+    N = dout.shape[1]
+    if M % tm:
+        raise ValueError(f"rows {M} not a multiple of the tile {tm}")
+    out_dtype = out_dtype or lhs.dtype
+    tn = _tn(K, N, 4)          # the float32 accumulator is the block that counts
+
+    def tile(i, active_ref):
+        return jnp.minimum(i, jnp.maximum(active_ref[0] - 1, 0))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(N // tn, M // tm),
+        in_specs=[
+            pl.BlockSpec((tm, K), lambda n, i, g, a: (tile(i, a), 0)),
+            pl.BlockSpec((tm, tn), lambda n, i, g, a: (tile(i, a), n)),
+        ],
+        out_specs=pl.BlockSpec((None, K, tn),
+                               lambda n, i, g, a: (g[tile(i, a)], 0, n)),
+        scratch_shapes=[pltpu.VMEM((K, tn), jnp.float32)],
+    )
+    with part(name):
+        return pl.pallas_call(
+            _dw_kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((groups, K, N), out_dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary")),
+            interpret=interpret,
+            name=name,
+        )(tile_group.astype(jnp.int32),
+          jnp.asarray(n_active, jnp.int32).reshape(1), lhs, dout)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def grouped_product(lhs, rhs, tile_group, n_active, tm: int,
                     out_dtype=None, name: str = NAME):
-    """The kernel on a TPU, its oracle elsewhere."""
+    """The kernel on a TPU, its oracle elsewhere. Differentiable in
+    ``lhs`` and ``rhs``: the rows' gradient is the same product over the
+    same tiles against the transposed matrices, the matrices' gradient
+    :func:`grouped_matmul_dw` (custom call ``grouped_expert_matmul_dw``
+    whatever ``name`` is). Rows of tiles at or past ``n_active`` carry
+    no gradient in either direction: what lies in them, forward or
+    backward, is never read."""
     mm = grouped_matmul if attention.on_tpu() else grouped_matmul_reference
     return mm(lhs, rhs, tile_group, n_active, tm=tm, out_dtype=out_dtype,
               name=name)
+
+
+def _product_fwd(lhs, rhs, tile_group, n_active, tm, out_dtype, name):
+    return (grouped_product(lhs, rhs, tile_group, n_active, tm, out_dtype,
+                            name), (lhs, rhs, tile_group, n_active))
+
+
+def product_grads(lhs, rhs, dout, tile_group, n_active, *, tm: int,
+                  name: str = NAME):
+    """(``dlhs``, ``drhs``) of :func:`grouped_product` for a cotangent
+    ``dout`` (M, N) in ``lhs``'s dtype: the rows' gradient is the same
+    product over the same tiles against the transposed matrices, under
+    the forward's ``name``; the matrices' gradient is
+    :func:`grouped_matmul_dw`, zeros for a group that no live tile names
+    (the kernel never writes it)."""
+    dlhs = grouped_product(dout, jnp.swapaxes(rhs, 1, 2), tile_group,
+                           n_active, tm, lhs.dtype, name)
+    dw = (grouped_matmul_dw if attention.on_tpu()
+          else grouped_matmul_dw_reference)
+    drhs = dw(lhs, dout, tile_group, n_active, tm=tm, groups=rhs.shape[0],
+              out_dtype=rhs.dtype)
+    named = jnp.zeros(rhs.shape[0], bool).at[tile_group].max(
+        jnp.arange(tile_group.shape[0]) < n_active)
+    return dlhs, jnp.where(named[:, None, None], drhs, 0)
+
+
+def _product_bwd(tm, out_dtype, name, res, dout):
+    lhs, rhs, tile_group, n_active = res
+    return (*product_grads(lhs, rhs, dout.astype(lhs.dtype), tile_group,
+                           n_active, tm=tm, name=name), None, None)
+
+
+grouped_product.defvjp(_product_fwd, _product_bwd)

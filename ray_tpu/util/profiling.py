@@ -94,12 +94,25 @@ BLOCK_PARTS = ("qk_norm", "block_decide")
 # distribution and the choice of the pass whose state the head reads).
 # The reader's side is ``benchmark/layer_metrics/parts/ouro.json``.
 LOOP_PARTS = ("post_norm", "exit_gate")
-_VOCABULARY = PARTS + SSM_PARTS + BLOCK_PARTS + LOOP_PARTS
+# What a gated short-convolution mixer adds (PR 57, ``models/lfm2.py``):
+# its in and out projections, the three taps of the causal depthwise
+# convolution, and the two elementwise gates around it. The reader's
+# side is ``benchmark/layer_metrics/parts/lfm2.json``.
+CONV_PARTS = ("conv_proj", "short_conv", "conv_gate")
+# What a TRAINED expert layer adds (PR 57): the kernel of the grouped
+# product's weight gradient, under its custom call's own name
+# (``ops/pallas/grouped_matmul.py``; the rows' gradient is the forward's
+# kernel under the forward's name). A tuple of its own because
+# ``PARTS`` is ``parts/base.json``, a file of the benchmark's; the
+# reader's side is ``benchmark/layer_metrics/parts/expert_grad.json``.
+EXPERT_GRAD_PARTS = ("grouped_expert_matmul_dw",)
+_VOCABULARY = (PARTS + SSM_PARTS + BLOCK_PARTS + LOOP_PARTS + CONV_PARTS
+               + EXPERT_GRAD_PARTS)
 
 
 def part(name: str):
     """``jax.named_scope(name)`` for a ``name`` of :data:`PARTS` (or of
-    :data:`SSM_PARTS`, :data:`BLOCK_PARTS` or :data:`LOOP_PARTS`): what is traced inside
+    any other tuple here whose name ends in ``PARTS``): what is traced inside
     belongs to that part of the block. Checked while tracing; nothing
     runs for it on the device or in a loop's turn."""
     if name not in _VOCABULARY:

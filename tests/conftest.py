@@ -84,6 +84,7 @@ def kernel_on_cpu(monkeypatch):
             (flash_attention, "flash_attention_fwd_pallas"),
             (flash_attention, "flash_attention_bwd_pallas"),
             (grouped_matmul, "grouped_matmul"),
+            (grouped_matmul, "grouped_matmul_dw"),
             (paged_decode_attention, "paged_decode_attention"),
             (paged_hybrid_decode_attention, "paged_hybrid_decode_attention"),
             (paged_mla_decode_attention, "paged_mla_decode_kernel"),

@@ -684,6 +684,35 @@ def test_grouped_matmul_compiles_at_128_rows(one_chip, K, N):
     assert "grouped_expert_matmul_prefill" in text
 
 
+@pytest.mark.parametrize("K, N", [(2048, 1536), (1536, 2048)])
+def test_the_weight_gradients_kernel_compiles_at_a_train_steps_tiles(
+        one_chip, K, N):
+    """``grouped_expert_matmul_dw`` at the train cell's widths (an
+    expert's gate / up: rows 2,048 wide against cotangents 1,536 wide;
+    its down: the reverse), 128-row tiles in bfloat16 contracted over
+    the ROWS, a float32 (K, tn) accumulator in VMEM beside both operands
+    double-buffered; and the combine's kernel for one run of 16,384
+    tokens, whose pairs ride in SMEM (32,768 tokens in one call were
+    refused: 1.1 MiB of a core's 1 MiB)."""
+    from ray_tpu.ops.pallas import expert_combine, grouped_matmul as gm
+
+    tm, tiles = 128, 96
+    fn = jax.jit(lambda l, d, g, n: gm.grouped_matmul_dw(
+        l, d, g, n, tm=tm, groups=16))
+    text = fn.lower(_sds((tiles * tm, K), jnp.bfloat16, one_chip),
+                    _sds((tiles * tm, N), jnp.bfloat16, one_chip),
+                    _sds((tiles,), jnp.int32, one_chip),
+                    _sds((), jnp.int32, one_chip)).compile().as_text()
+    assert "tpu_custom_call" in text and gm.NAME_DW in text
+    T, k = 32768, 4
+    assert expert_combine._CALL_PAIRS // k == T // 2
+    run = jax.jit(expert_combine.expert_combine).lower(
+        _sds((T * k + 2048, 2048), jnp.float32, one_chip),
+        _sds((T, k), jnp.int32, one_chip), _sds((T, k), jnp.bool_, one_chip),
+        _sds((T, k), jnp.float32, one_chip)).compile().as_text()
+    assert run.count("tpu_custom_call") == 2
+
+
 # --------------------------- the combine follows the pairs held (PR 50)
 # (tokens, top_k, the rows' width, rows of the buffer): the three
 # share-held cells' longest bucket and decode step

@@ -43,7 +43,8 @@ MODELS = {
 }
 ROUTED = ("mimo_v2", "axk1", "laguna", "nemotron_h")
 VOCABULARY = (profiling.PARTS + profiling.SSM_PARTS
-              + profiling.BLOCK_PARTS + profiling.LOOP_PARTS)
+              + profiling.BLOCK_PARTS + profiling.LOOP_PARTS
+              + profiling.CONV_PARTS + profiling.EXPERT_GRAD_PARTS)
 # the parts under which a decode step writes its per-request state: the
 # KV pools everywhere; a state-space model's recurrent state and
 # convolution columns under its own
@@ -388,6 +389,72 @@ def test_the_train_step_splits_into_forward_recompute_and_backward(
     assert block <= recompute and block <= backward and block <= forward
     assert {"embed", "head", "loss", "optimizer"} <= forward
     assert "optimizer" not in backward | recompute
+
+
+@pytest.fixture(scope="module")
+def lfm2_train_text():
+    """The train step of the gated-convolution model held by share, made
+    for a TPU (the kernels' calls are in it; nothing runs)."""
+    from ray_tpu.models import lfm2
+
+    cfg = lfm2.Lfm2Config(experts_held=(2, 2), dtype=jnp.float32)
+    rules = ShardingRules()
+    opt = lfm2.frozen_buffers(OptimizerConfig(warmup_steps=1).make(),
+                              lfm2.param_shapes(cfg))
+    mesh = make_mesh(MeshConfig(dp=1), devices=jax.devices()[:1])
+    with jax.sharding.set_mesh(mesh), pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        state, _ = init_train_state(
+            lambda key: lfm2.init_params(cfg, key),
+            lfm2.param_logical_axes(cfg), opt, mesh, rules,
+            jax.random.key(0))
+        step = make_train_step(lambda p, b: lfm2.loss_fn(p, b, cfg, rules),
+                               opt, mesh, rules, donate=False)
+        return hlo_text(step.trace(
+            state, {"tokens": jnp.zeros((2, 128), jnp.int32)}).lower(
+                lowering_platforms=("tpu",)))
+
+
+def test_the_convolution_models_train_step_lies_under_its_parts(
+        lfm2_train_text):
+    """Forward, recomputation AND backward: ``jax.named_scope`` survives
+    the transposition, so a backward operation carries its forward's
+    part; the weight gradient's kernel has the one name of its own."""
+    n, bare = _named_share(lfm2_train_text)
+    assert n > 100
+    assert len(bare) <= 0.05 * n, (n, bare)
+    ops = operations(lfm2_train_text)
+    by_phase = {"forward": set(), "recompute": set(), "backward": set()}
+    for op, _, path in ops:
+        if op not in HEAVY and op != "multiply":
+            continue
+        phase = ("recompute" if "rematted_computation" in path else
+                 "backward" if "transpose(jvp" in path else "forward")
+        by_phase[phase].add(part_of(path))
+    block = set(profiling.CONV_PARTS) | {
+        "qk_norm", "attn_proj", "router", "expert_dispatch", "expert_layer",
+        "expert_combine", "grouped_expert_matmul", "mlp"}
+    for phase, seen in by_phase.items():
+        assert block <= seen, (phase, sorted(block - seen))
+    assert "grouped_expert_matmul_dw" in by_phase["backward"]
+    assert "grouped_expert_matmul_dw" not in (by_phase["forward"]
+                                              | by_phase["recompute"])
+    assert {"embed", "head", "loss", "optimizer"} <= by_phase["forward"]
+    kernels = [part_of(path) for op, _, path in ops if op == "custom-call"
+               and part_of(path) in ("grouped_expert_matmul",
+                                     "grouped_expert_matmul_dw")]
+    routed = 4
+    # three products forward, three recomputed, three rows' gradients
+    assert kernels.count("grouped_expert_matmul") == 9 * routed
+    assert kernels.count("grouped_expert_matmul_dw") == 3 * routed
+
+
+def test_the_convolution_parts_are_a_tuple_of_their_own():
+    assert profiling.CONV_PARTS == ("conv_proj", "short_conv", "conv_gate")
+    assert profiling.EXPERT_GRAD_PARTS == ("grouped_expert_matmul_dw",)
+    assert not set(profiling.CONV_PARTS + profiling.EXPERT_GRAD_PARTS) & set(
+        profiling.PARTS + profiling.SSM_PARTS + profiling.BLOCK_PARTS
+        + profiling.LOOP_PARTS)
 
 
 def test_a_name_outside_the_vocabulary_raises_at_trace_time():
