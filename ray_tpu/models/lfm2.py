@@ -240,7 +240,8 @@ def routed_ffn(y, layer: Params, config: Lfm2Config):
         y.reshape(B * S, h), layer, experts_held=config.experts_held,
         top_k=config.top_k, scale=config.route_scale,
         norm_eps=ROUTE_NORM_EPS)
-    return out.astype(y.dtype).reshape(B, S, h), counters
+    with part("expert_layer"):
+        return out.astype(y.dtype).reshape(B, S, h), counters
 
 
 def _block(x, layer: Params, cos, sin, *, i: int, config: Lfm2Config,
@@ -272,7 +273,7 @@ def _block(x, layer: Params, cos, sin, *, i: int, config: Lfm2Config,
 def hidden(params: Params, tokens: jax.Array, config: Lfm2Config,
            rules: Optional[ShardingRules] = None):
     """tokens (B, S) int32 -> (the stream after the last layer (B, S, h),
-    the routed layers' counters summed, (5,) in ``moe.COUNTERS``' order)."""
+    the routed layers' counters summed, in ``moe.COUNTERS``' order)."""
     c = config
     rules = rules or ShardingRules()
     tokens = with_logical_constraint(tokens, ("batch", "seq"), rules)

@@ -270,14 +270,19 @@ def loss_fn(params: Params, batch: Dict[str, jax.Array], config: MoEConfig,
 # deployment holds ``experts_held = (first, count)`` of the router's
 # experts, routes every token over ALL of them and computes the part of
 # the result that its own experts give. No capacity and no dropped
-# token: the row buffer is sized for the worst case (every pair routed
-# here) and a step fills what the routing sends. The partial sum is the
-# layer's output; on one chip nothing stands in for the absent experts.
+# token: the row buffer holds twice the rows the chip expects where the
+# worst case (every pair routed here) would be mostly dead rows, and the
+# rows past it take further passes over the same buffer, which run only
+# when there are such rows. The partial sum is the layer's output; on
+# one chip nothing stands in for the absent experts.
 
 # rows of one expert a tile: from the bf16 sublanes to the MXU's 128 rows
 ROW_TILES = (16, 32, 64, 128)
 COUNTERS = ("expert_layer_calls", "expert_pairs", "experts_hit",
-            "expert_load_max_over_mean", "expert_pairs_dropped")
+            "expert_load_max_over_mean", "expert_pairs_dropped",
+            "expert_extra_passes")
+# bound_serves: the dead rows' bytes from which a bounded buffer wins
+_DEAD_BYTES = 32 << 20
 
 
 def row_tile(T: int, top_k: int, n_experts: int, held: int) -> int:
@@ -287,10 +292,11 @@ def row_tile(T: int, top_k: int, n_experts: int, held: int) -> int:
     router's ``n_experts`` expects from ``T`` tokens whether the chip
     holds all of them or a share (an expert's rows scatter around that
     mean: a tile of just the mean is two tiles for half the experts);
-    then halved while the padding it can add to the worst case, ``held *
+    then halved while the padding it can add to the buffer, ``held *
     (tm - 1)`` rows, is more than the ``T * top_k`` pairs themselves
     (the XLA around the products works on every row of the buffer, and
-    a chip that holds the whole expert set pads 256 last tiles).
+    a chip that holds the whole expert set pads 256 last tiles). How
+    many rows the buffer has at that tile is :func:`pass_rows`'s.
 
     The product loads each 128 x 128 tile of an expert's matrix into
     the MXU once per row tile, so 16 rows a tile pay a weight load for
@@ -305,6 +311,42 @@ def row_tile(T: int, top_k: int, n_experts: int, held: int) -> int:
     while tm > ROW_TILES[0] and held * (tm - 1) > pairs:
         tm //= 2
     return tm
+
+
+def pass_rows(T: int, top_k: int, n_experts: int, held: int, tm: int) -> int:
+    """Rows of the buffer one pass of the held experts works on, from the
+    call's static shapes alone: TWICE the pairs a chip that holds
+    ``held`` of the router's ``n_experts`` expects from ``T`` tokens (a
+    chip's pairs scatter around that mean far less than one expert's; at
+    the train cell's 49,152 tokens the seeded router places 1.008 of
+    it), never more than all ``T * top_k``, plus the padding ``held``
+    last tiles can add, rounded to tiles. Where the set is held whole
+    every pair is placed here and this is the worst case itself. Rows
+    the sort lays past it take further passes (:func:`_passes`)."""
+    pairs = T * top_k
+    here = pairs if held >= n_experts else min(
+        pairs, 2 * -(-pairs * held // n_experts))
+    return -(-(here + held * (tm - 1)) // tm) * tm
+
+
+def bound_serves(dead_rows: int, row_bytes: int) -> bool:
+    """Whether a buffer of :func:`pass_rows` rows is the form a call
+    takes, whose worst case has ``dead_rows`` more, each ``row_bytes``
+    wide (a row of the experts' input and one of their inner width, in
+    the input's dtype: what the XLA around the products gathers, casts
+    and activates for every row of the buffer, live or not). A MEASURED
+    rule, as ``expert_combine.kernel_serves``: from 32 MiB of dead rows.
+    The layer alone on the chip gains 0.003-0.006 ms a MiB: the train
+    step's 672 MiB 14.6 ms a layer, forward and backward (111.3 ->
+    96.7); the share-held serve configurations' buckets of 512 to 2,048
+    tokens, 40-252 MiB, 0.11-0.75 ms a layer. Under it lie their 256
+    buckets and EVERY decode step (10-24 MiB: 0.06-0.15 ms of a layer's
+    1.3-2.5 alone, which is not the reason to engage): a step's few
+    hundred pairs scatter around the expected share as a bucket's
+    thousands do not, and a skewed step must not pay a second pass,
+    whose fixed parts are the combine's, the pairs' scatter and a loop's
+    trip. My chip runs, PR 58: PERF.md section 6."""
+    return dead_rows * row_bytes >= _DEAD_BYTES
 
 
 @part("router")
@@ -505,6 +547,118 @@ def _down_and_combine_bwd(tm, name, experts, res, cotangents):
 _down_and_combine.defvjp(_down_and_combine_fwd, _down_and_combine_bwd)
 
 
+def _pass(p, xe, w, weights, order, held, row_sorted, pend, n_tiles: int,
+          tm: int, kernel_name: str, experts: int):
+    """One pass of the held experts over a buffer of ``n_tiles`` tiles:
+    pass ``p`` takes rows ``[p * M, (p + 1) * M)`` of the sort (``M =
+    n_tiles * tm``; ``row_sorted`` is each sorted pair's row over ALL
+    passes, ``pend`` the groups' padded ends) and a pair whose row lies
+    in another pass is not placed in this one. ``p`` None is the one
+    pass of a buffer that holds the worst case: the program it had
+    before there were passes, operation for operation (every decode
+    step's). -> (y (T, h) float32, the sum over the pairs placed in this
+    pass; placed (T, k) bool).
+    Plainly differentiable in ``xe``, ``w`` and ``weights`` (``we_gate``
+    where the experts are gated, ``we_up``, ``we_down``)."""
+    from ray_tpu.ops.pallas import grouped_matmul as gm
+
+    T, top_k = held.shape
+    G = weights["we_down"].shape[0]
+    M = n_tiles * tm
+    with part("expert_dispatch"):
+        if p is not None:
+            row_sorted = row_sorted - p * M
+            row_sorted = jnp.where(row_sorted >= 0, row_sorted, M)
+        token_of_row = jnp.full((M,), T, jnp.int32).at[row_sorted].set(
+            (order // top_k).astype(jnp.int32), mode="drop")
+        x_rows = _rows_of_tokens(xe, token_of_row, order, row_sorted, held)
+        n_active = pend[-1] // tm
+        tiles = jnp.arange(n_tiles)
+        if p is not None:
+            tiles = tiles + p * n_tiles
+        tile = jnp.minimum(tiles, jnp.maximum(n_active - 1, 0))
+        tile_group = jnp.minimum(
+            jnp.searchsorted(pend, tile * tm, side="right"), G - 1)
+        if p is not None:
+            n_active = jnp.clip(n_active - p * n_tiles, 0, n_tiles)
+
+    mm = functools.partial(gm.grouped_product, name=kernel_name)
+    with part("expert_layer"):
+        gated = "we_gate" in weights
+        if gated:
+            gate = mm(x_rows, weights["we_gate"], tile_group, n_active, tm=tm,
+                      out_dtype=jnp.float32)
+        up = mm(x_rows, weights["we_up"], tile_group, n_active, tm=tm,
+                out_dtype=jnp.float32)
+        act = (jax.nn.silu(gate) * up if gated
+               else jnp.square(jax.nn.relu(up))).astype(xe.dtype)
+        return _down_and_combine(
+            act, weights["we_down"], tile_group, n_active, order, row_sorted,
+            held, w, tm, kernel_name, experts)
+
+
+def _trips(pend, rows: int):
+    """Passes of ``rows`` rows that hold every row the sort laid out."""
+    return -(-pend[-1] // rows)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10))
+def _passes(xe, w, weights, order, held, row_sorted, pend, n_tiles: int,
+            tm: int, kernel_name: str, experts: int):
+    """The held experts over a BOUNDED buffer of ``n_tiles`` tiles: as
+    many passes (:func:`_pass`) as the sort has rows for, ``ceil(pend[-1]
+    / M)`` trips of a loop whose count is read on the device: one at the
+    load the buffer was sized for, more ONLY when the router sends this
+    chip more than that. -> (y (T, h) float32, the sum of the passes'
+    sums; the pairs placed in them; the passes run; int32 scalars).
+
+    A backward of its own, because ``jax.grad`` of a loop (or of a
+    ``cond`` between a small and a worst-case branch) would keep every
+    trip's (both branches') residuals, several arrays of the buffer's
+    rows each: the residuals here are the function's inputs, and the
+    backward repeats the loop, computes a pass again and transposes it
+    (what ``jax.checkpoint`` around a block does to a plain layer: under
+    it the forward runs once here too, its recomputation has nothing to
+    keep), summing the passes' gradients in the inputs' own dtypes."""
+    static = (n_tiles, tm, kernel_name, experts)
+
+    def one(p, carry):
+        y_p, placed = _pass(p, xe, w, weights, order, held, row_sorted, pend,
+                            *static)
+        return carry[0] + y_p, carry[1] + jnp.sum(placed, dtype=jnp.int32)
+
+    with part("expert_layer"):
+        trips = _trips(pend, n_tiles * tm)
+        y = jnp.zeros((xe.shape[0], weights["we_down"].shape[2]),
+                      jnp.float32)
+        return (*jax.lax.fori_loop(0, trips, one, (y, jnp.int32(0))), trips)
+
+
+def _passes_fwd(xe, w, weights, order, held, row_sorted, pend, *static):
+    return (_passes(xe, w, weights, order, held, row_sorted, pend, *static),
+            (xe, w, weights, order, held, row_sorted, pend))
+
+
+def _passes_bwd(n_tiles, tm, kernel_name, experts, res, cotangents):
+    xe, w, weights, *ints = res
+    dy = cotangents[0]
+
+    def one(p, grads):
+        _, transpose = jax.vjp(
+            lambda *a: _pass(p, *a, *ints, n_tiles, tm, kernel_name,
+                             experts)[0], xe, w, weights)
+        return jax.tree.map(jnp.add, grads, transpose(dy))
+
+    with part("expert_layer"):
+        grads = jax.lax.fori_loop(
+            0, _trips(ints[-1], n_tiles * tm), one,
+            jax.tree.map(jnp.zeros_like, (xe, w, weights)))
+    return (*grads, None, None, None, None)
+
+
+_passes.defvjp(_passes_fwd, _passes_bwd)
+
+
 def experts_by_share(x, layer: Params, *, experts_held: Tuple[int, int],
                      top_k: int, scale: float = 1.0, valid=None,
                      kernel_name: str = "grouped_expert_matmul",
@@ -513,7 +667,7 @@ def experts_by_share(x, layer: Params, *, experts_held: Tuple[int, int],
                      norm_eps: float = 0.0):
     """The routed MLP of one layer on the chip that holds
     ``experts_held = (first, count)``: x (T, h) -> (y (T, h) float32,
-    the partial sum over the experts held; counters (5,) float32 in the
+    the partial sum over the experts held; counters float32 in the
     order of ``COUNTERS``).
 
     What is ROUTED and what is MULTIPLIED may be two arrays (latent
@@ -542,24 +696,32 @@ def experts_by_share(x, layer: Params, *, experts_held: Tuple[int, int],
     where the set is whole).
     ``tm`` is chosen HERE, by :func:`row_tile` from ``T``, ``top_k``,
     the router's width and ``G``: 16 for a decode step, up to 128 for a
-    prefill's bucket; no caller and no option names it. The row buffer
-    stays the worst case at any tile, ``T * top_k + G * (tm - 1)`` rows
-    rounded to tiles. ``kernel_name`` names the products' custom calls
-    in a trace.
+    prefill's bucket; no caller and no option names it. So are the rows
+    of the buffer: the worst case, ``T * top_k + G * (tm - 1)`` rows
+    rounded to tiles, where the set is held whole or the rows that
+    would lie dead are too few to repay a second code path
+    (:func:`bound_serves`: every decode step); else :func:`pass_rows`,
+    twice the share the chip expects. Pairs the sort lays past the
+    buffer are NOT dropped: they take further passes over the same rows
+    (:func:`_passes`: a loop of one trip at that load), which run only
+    when there are such pairs and are counted in
+    ``expert_extra_passes``; ``expert_pairs_dropped`` (held less placed,
+    over all passes) is 0 whatever the router does.
+    ``kernel_name`` names the products' custom calls in a trace.
 
     ONE layer for serving and training: ``jax.grad`` goes through it.
-    The products, the rows' gather and the down product with the
-    combine each carry a backward of their own (``grouped_product``,
-    :func:`_rows_of_tokens`, :func:`_down_and_combine`); the sort, the
+    The products, the rows' gather, the down product with the combine
+    and the loop over a bounded buffer's passes each carry a backward of
+    their own (``grouped_product``, :func:`_rows_of_tokens`,
+    :func:`_down_and_combine`, :func:`_passes`); the sort, the
     tiles and the counters are integers and carry none. ``norm_eps`` is
     :func:`route_sigmoid_topk`'s.
     """
-    from ray_tpu.ops.pallas import grouped_matmul as gm
-
     T = x.shape[0]
     xe = x if x_experts is None else x_experts
     first, G = experts_held
-    tm = row_tile(T, top_k, layer["router"].shape[1], G)
+    E = layer["router"].shape[1]
+    tm = row_tile(T, top_k, E, G)
     if score == "softmax":
         idx, w = route_softmax_topk(x, layer["router"], top_k, scale)
     elif score != "sigmoid":
@@ -568,8 +730,14 @@ def experts_by_share(x, layer: Params, *, experts_held: Tuple[int, int],
         idx, w = route_sigmoid_topk(
             x, layer["router"], layer.get("router_bias"), top_k, scale,
             n_group, topk_group, norm_eps)
-    n_tiles = -(-(T * top_k + G * (tm - 1)) // tm)
-    M = n_tiles * tm
+    weights = {name: layer[name] for name in ("we_gate", "we_up", "we_down")
+               if name in layer}
+    worst = -(-(T * top_k + G * (tm - 1)) // tm) * tm
+    M = pass_rows(T, top_k, E, G, tm)
+    if not bound_serves(worst - M, (xe.shape[1] + layer["we_up"].shape[2])
+                        * xe.dtype.itemsize):
+        M = worst
+    far = -(-worst // M) * M           # a row past every pass's
     with part("expert_dispatch"):
         local = idx - first
         held = (local >= 0) & (local < G)
@@ -585,29 +753,12 @@ def experts_by_share(x, layer: Params, *, experts_held: Tuple[int, int],
         g_of = jnp.minimum(skey, G - 1)
         row_sorted = jnp.where(
             skey < G, (pend - padded)[g_of] + jnp.arange(T * top_k)
-            - ustart[g_of], M)
-        token_of_row = jnp.full((M,), T, jnp.int32).at[row_sorted].set(
-            (order // top_k).astype(jnp.int32), mode="drop")
-        x_rows = _rows_of_tokens(xe, token_of_row, order, row_sorted, held)
-        n_active = pend[-1] // tm
-        tile = jnp.minimum(jnp.arange(n_tiles),
-                           jnp.maximum(n_active - 1, 0))
-        tile_group = jnp.minimum(
-            jnp.searchsorted(pend, tile * tm, side="right"), G - 1)
-
-    mm = functools.partial(gm.grouped_product, name=kernel_name)
-    with part("expert_layer"):
-        gated = "we_gate" in layer
-        if gated:
-            gate = mm(x_rows, layer["we_gate"], tile_group, n_active, tm=tm,
-                      out_dtype=jnp.float32)
-        up = mm(x_rows, layer["we_up"], tile_group, n_active, tm=tm,
-                out_dtype=jnp.float32)
-        act = (jax.nn.silu(gate) * up if gated
-               else jnp.square(jax.nn.relu(up))).astype(xe.dtype)
-        y, placed = _down_and_combine(
-            act, layer["we_down"], tile_group, n_active, order, row_sorted,
-            held, w, tm, kernel_name, layer["router"].shape[1])
+            - ustart[g_of], far)
+    passes = (xe, w, weights, order, held, row_sorted, pend)
+    if M == worst:
+        y, placed = _pass(None, *passes, M // tm, tm, kernel_name, E)
+    else:
+        y, placed, trips = _passes(*passes, M // tm, tm, kernel_name, E)
     with part("expert_dispatch"):       # the step's counters: group sizes
         pairs = jnp.sum(sizes).astype(jnp.float32)
         counters = jnp.stack([
@@ -615,5 +766,7 @@ def experts_by_share(x, layer: Params, *, experts_held: Tuple[int, int],
             jnp.sum(sizes > 0).astype(jnp.float32),
             jnp.max(sizes) * G / jnp.maximum(pairs, 1.0),
             jnp.sum(held).astype(jnp.float32)
-            - jnp.sum(placed).astype(jnp.float32)])
+            - jnp.sum(placed).astype(jnp.float32),
+            jnp.float32(0.0) if M == worst
+            else jnp.maximum(trips - 1, 0).astype(jnp.float32)])
     return y, counters

@@ -10,8 +10,10 @@ against that expert's matrix out of a stacked (experts, K, N) tensor.
     -> out      (M, N)      rows of tiles at or past ``n_active`` are NOT
                             written (the caller masks them)
 
-``M`` is static and sized for the worst case (every row routed here, no
-capacity, nothing dropped); a step usually fills a small part of it.
+``M`` is static: the caller's row buffer (``models/moe.py::pass_rows``:
+the worst case, every row routed here, or twice the share a chip expects,
+the rows past it taking further calls; no capacity, nothing dropped); a
+step usually fills a part of it.
 ``tile_group`` and ``n_active`` ride as scalar prefetch. The grid is
 (N / tn, M / tm) with the row tiles innermost: consecutive tiles of one
 expert name the same (K, tn) block of its matrix, which is then fetched

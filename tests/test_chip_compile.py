@@ -644,8 +644,11 @@ def test_routed_cells_decode_step_keeps_the_16_row_tile(topo, as_tpu, cell):
 def test_latent_cells_largest_bucket_takes_the_rules_tile(topo, as_tpu):
     """`serve-mla-moe-decode`'s 2,048 bucket (85 rows an expert): its
     twelve grouped products walk the tile that ``moe.row_tile`` gives at
-    (2,048, 8, 192, 12), the row buffer is the worst case at that tile,
-    and the bucket's temporaries stay under the ceiling."""
+    (2,048, 8, 192, 12), the row buffer is ``moe.pass_rows``' at that
+    tile (PR 58: 3,584 rows of the worst case's 17,920, 252 MiB of dead
+    rows, ``moe.bound_serves``; the products stand in the body of the
+    loop over the buffer's passes, once a layer), and the bucket's
+    temporaries stay under the ceiling."""
     from ray_tpu.models import moe
 
     tm = moe.row_tile(2048, 8, 192, 12)
@@ -654,9 +657,11 @@ def test_latent_cells_largest_bucket_takes_the_rules_tile(topo, as_tpu):
     lowered = bucket(2048)
     products = _GROUPED.findall(lowered.as_text())
     assert len(products) == 12
-    tiles = -(-(2048 * 8 + 12 * (tm - 1)) // tm)
+    rows = moe.pass_rows(2048, 8, 192, 12, tm)
+    assert rows == 3584 and moe.bound_serves(
+        -(-(2048 * 8 + 12 * (tm - 1)) // tm) * tm - rows, (7168 + 2048) * 2)
     assert {(int(t), int(r)) for t, r, _, _ in products} == {
-        (tiles, tiles * tm)}
+        (rows // tm, rows)}
     mem = lowered.compile().memory_analysis()
     assert mem.temp_size_in_bytes < LATENT_BUCKET_TEMP_GIB * 1024 ** 3
 
@@ -758,7 +763,8 @@ def test_share_held_cells_combine_in_the_kernel(topo, as_tpu, cell, bucket,
                                                 top_k, width, layers,
                                                 decode_too):
     """Where a chip holds a share of the experts, the longest bucket
-    combines in the kernel, once a routed layer, and holds no (T, top_k,
+    combines in the kernel, once a routed layer (in the body of the loop
+    over its bounded buffer's passes, PR 58), and holds no (T, top_k,
     h) float32 array: nothing gathers every pair's row. So does the
     decode step where a sixteenth is held; where a quarter is
     (`nemotron_h`, a gather of 17 MB) the step keeps the XLA form, which

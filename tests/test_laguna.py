@@ -418,7 +418,7 @@ def test_no_pair_is_dropped_when_routing_piles_onto_one_expert():
     kw = dict(experts_held=(0, 16), top_k=4, scale=2.5, score="softmax")
     y, c = moe.experts_by_share(x, layer, **kw)
     want, _ = REF.routed_mlp(x, layer, SPEC, held=(0, 16))
-    calls, pairs, hit, ratio, dropped = np.asarray(c)
+    calls, pairs, hit, ratio, dropped, _ = np.asarray(c)
     assert dropped == 0 and pairs == 4 * 129 and calls == 1 and hit <= 16
     assert ratio >= 16 * 129 / pairs - 1e-3          # largest over mean
     assert REF.rel_err(y, want) < 1e-4

@@ -362,7 +362,7 @@ def test_no_token_is_dropped_when_routing_piles_onto_one_held_expert():
     y, c = moe.experts_by_share(x, _share(layer, 4, 4),
                                 experts_held=(4, 4), top_k=4)
     want, _ = REF.routed_mlp(x, _share(layer, 4, 4), SPEC, held=(4, 4))
-    calls, pairs, hit, ratio, dropped = np.asarray(c)
+    calls, pairs, hit, ratio, dropped, _ = np.asarray(c)
     assert dropped == 0 and pairs >= 129 and calls == 1
     assert ratio >= 4 * 129 / pairs - 1e-3           # largest over mean
     assert REF.rel_err(y, want) < 1e-4

@@ -162,8 +162,9 @@ def _held_layer(seed, E=8, h=16, m=8, bias=True, first=2, count=4):
 
 # y[:, :4], y.sum() and the counters of the PARENT's experts_by_share
 # (ed2e22b, computed there; the change gave the same bits on that
-# machine: CHANGES.md, PR 39). Rows 4 or 5 are masked by ``valid`` or
-# routed to experts that are not held.
+# machine: CHANGES.md, PR 39; the sixth, ``expert_extra_passes``, is PR
+# 58's and 0 where the buffer holds the worst case). Rows 4 or 5 are
+# masked by ``valid`` or routed to experts that are not held.
 PINNED = {
     "sigmoid-bias": (dict(score="sigmoid"), 40, [
         [0.044440378, -0.78733015, 0.14460886, -0.19048885],
@@ -171,7 +172,7 @@ PINNED = {
         [-0.16568334, -0.29388976, 0.14310952, 0.24648732],
         [0.17422789, 0.36128876, 0.61535853, 0.39945549],
         [0.0, 0.0, 0.0, 0.0], [0.0, 0.0, 0.0, 0.0]],
-        9.5898094, [1.0, 6.0, 3.0, 2.6666667, 0.0]),
+        9.5898094, [1.0, 6.0, 3.0, 2.6666667, 0.0, 0.0]),
     "sigmoid-grouped": (dict(score="sigmoid", n_group=4, topk_group=2), 41, [
         [0.83885831, 0.64135939, -0.63467962, -0.35841301],
         [0.71907121, 0.50111294, -0.40957868, -0.64344692],
@@ -179,7 +180,7 @@ PINNED = {
         [0.0, 0.0, 0.0, 0.0],
         [-0.10283951, 0.19438042, 0.41202286, -0.17959227],
         [0.0, 0.0, 0.0, 0.0]],
-        -3.6533864, [1.0, 5.0, 4.0, 1.6, 0.0]),
+        -3.6533864, [1.0, 5.0, 4.0, 1.6, 0.0, 0.0]),
     "softmax": (dict(score="softmax"), 42, [
         [0.80854398, -0.088838473, -0.11247842, 0.20547146],
         [-0.21342658, -0.4947252, 0.222248, 2.1686323],
@@ -187,7 +188,7 @@ PINNED = {
         [0.60256702, 0.93020451, -0.32830495, 1.0740455],
         [-0.18605082, 0.27420703, -0.056373611, 0.07928504],
         [0.0, 0.0, 0.0, 0.0]],
-        -3.4974942, [1.0, 6.0, 4.0, 1.3333334, 0.0]),
+        -3.4974942, [1.0, 6.0, 4.0, 1.3333334, 0.0, 0.0]),
 }
 
 
@@ -296,6 +297,117 @@ def test_row_tile_follows_the_rows_an_expert_expects(call):
         assert held * (tile - 1) <= T * top_k
 
 
+# ---------------------------------------------------------------------
+# The rows of one pass and where the bound engages are rules over the
+# call's static shapes (PR 58). The shapes come from the benchmark's own
+# files: every routed configuration's step (the slots of its cell; the
+# train cell's tokens), its widths and the experts it holds.
+
+def _routed_calls():
+    """{cell: (T, top_k, router's width, held, the experts' input and
+    inner widths)} of the step of every cell whose configuration routes."""
+    import json
+    import os
+
+    root = os.path.join(os.path.dirname(__file__), "..")
+
+    def load(*path):
+        with open(os.path.join(root, *path)) as f:
+            return json.load(f)
+
+    calls = {}
+    for cell in load("BENCHMARK.json")["workloads"]:
+        config = load("benchmark", "configs", cell["config"] + ".json")
+        held = config.get("n_routed_experts", config.get("num_experts"))
+        if held is None:
+            continue
+        run = load("benchmark", "cells", cell["name"] + ".json")
+        if "deployment" in run:     # a decode step, or a step over blocks
+            T = run["deployment"]["num_slots"] * config.get("block_length", 1)
+        else:
+            T = run["job"]["batch"] * load(
+                "benchmark", "traffic", cell["traffic"] + ".json")["seq"]
+        calls[cell["name"]] = (
+            T, config["num_experts_per_tok"],
+            config.get("router_width", held), held,
+            config.get("moe_latent_size", config["hidden_size"]),
+            config["moe_intermediate_size"])
+    return calls
+
+
+def _buffer(T, top_k, E, held, h, m):
+    """(the worst case's rows, the rows of a pass, whether the bound
+    engages) as ``experts_by_share`` works them out, bfloat16 rows."""
+    tm = moe.row_tile(T, top_k, E, held)
+    worst = -(-(T * top_k + held * (tm - 1)) // tm) * tm
+    rows = moe.pass_rows(T, top_k, E, held, tm)
+    return worst, rows, moe.bound_serves(worst - rows, (h + m) * 2)
+
+
+def test_the_train_cells_buffer_is_twice_the_share_it_expects():
+    calls = _routed_calls()
+    assert calls["train-moe-conv-8k"] == (49152, 4, 64, 16, 2048, 1536)
+    assert _buffer(*calls["train-moe-conv-8k"]) == (198656, 100352, True)
+
+
+@pytest.mark.parametrize("cell", [
+    "serve-moe-window-decode", "serve-mla-moe-decode",
+    "serve-ssm-latent-moe-chat", "serve-moe-whole-mixed-decode",
+    "serve-blockdiff-moe-decode"])
+def test_no_decode_step_bounds_its_buffer(cell):
+    """A buffer of 1-8k rows has nothing to give, and a skewed step must
+    not pay a second pass: a share-held step's bound is under its worst
+    case and does not engage; a set held whole has no bound."""
+    T, top_k, E, held, h, m = _routed_calls()[cell]
+    worst, rows, engaged = _buffer(T, top_k, E, held, h, m)
+    assert not engaged and worst <= 8192
+    assert (rows == worst) == (held == E)
+    assert held == E or rows < 0.7 * worst
+
+
+# (tokens, top_k, the router's width, held, input and inner widths) -> the
+# rows of a pass, whether it engages: the share-held configurations'
+# prefill buckets, measured both ways on the chip (PERF.md section 6, PR 58)
+BOUND_AT = {
+    "axk1-2048": ((2048, 8, 192, 12, 7168, 2048), 3584, True),
+    "axk1-1024": ((1024, 8, 192, 12, 7168, 2048), 1792, True),
+    "axk1-512": ((512, 8, 192, 12, 7168, 2048), 896, True),
+    "axk1-256": ((256, 8, 192, 12, 7168, 2048), 448, False),
+    "mimo_v2-1024": ((1024, 8, 256, 16, 4096, 2048), 2048, True),
+    "mimo_v2-512": ((512, 8, 256, 16, 4096, 2048), 1024, True),
+    "mimo_v2-256": ((256, 8, 256, 16, 4096, 2048), 496, False),
+    "nemotron_h-1024": ((1024, 22, 512, 128, 1024, 2688), 19328, True),
+    "nemotron_h-512": ((512, 22, 512, 128, 1024, 2688), 9600, True),
+    "nemotron_h-256": ((256, 22, 512, 128, 1024, 2688), 4736, False),
+    "laguna-2048": ((2048, 8, 256, 256, 2048, 512), 32512, False),
+    "sdar-1024": ((1024, 8, 128, 128, 2048, 768), 16256, False),
+    "one-token": ((1, 8, 256, 16, 4096, 2048), 256, False),
+    "every-pair-is-fewer-than-twice-the-share": (
+        (49152, 4, 6, 4, 2048, 1536), 197120, False),
+}
+
+
+@pytest.mark.parametrize("call", BOUND_AT)
+def test_the_bound_follows_the_share_held_and_the_dead_rows_bytes(call):
+    shapes, rows, engaged = BOUND_AT[call]
+    worst, got, on = _buffer(*shapes)
+    assert (got, on) == (rows, engaged)
+    T, top_k, E, held = shapes[:4]
+    assert got % moe.row_tile(T, top_k, E, held) == 0 and got <= worst
+    if held == E or 2 * held >= E:
+        assert got == worst         # all pairs provided for: one pass
+
+
+def test_a_layer_whose_bound_does_not_engage_counts_no_extra_pass():
+    """At the tiny shapes of every other test here the buffer is the
+    worst case's and the sixth counter the constant 0."""
+    layer = _held_layer(90)
+    x = jax.random.normal(jax.random.key(91), (96, 16), jnp.float32)
+    _, c = moe.experts_by_share(x, layer, experts_held=(2, 4), top_k=2)
+    assert c.shape == (len(moe.COUNTERS),) and float(c[5]) == 0.0
+    assert moe.COUNTERS[5] == "expert_extra_passes"
+
+
 def _dense_experts(x, layer, idx, w, first, count, valid):
     """sum_k w_k SwiGLU_e(x) over the held experts, a loop in float64."""
     x64 = np.asarray(x, np.float64)
@@ -334,7 +446,7 @@ def test_every_tile_computes_the_worst_case_whole(tile):
     want = _dense_experts(x, layer, idx, w, first, count, np.asarray(valid))
     np.testing.assert_allclose(np.asarray(y), want, rtol=2e-4, atol=2e-5)
     assert not np.asarray(y)[T - 5:].any()
-    calls, pairs, hit, ratio, dropped = np.asarray(c)
+    calls, pairs, hit, ratio, dropped, _ = np.asarray(c)
     assert (calls, pairs, hit, dropped) == (1, T - 5, 1, 0)
     assert ratio == pytest.approx(count)          # all on one of three
 

@@ -405,3 +405,161 @@ def test_the_routers_eps_is_an_argument_that_defaults_to_nothing():
         lambda x: moe.route_sigmoid_topk(
             x, layer["router"], layer["router_bias"], 2, 1.0, 1, 1, 0.0)
     ).lower(x).as_text()
+
+
+# ------------------------------------------- the bounded row buffer (PR 58)
+# A share-held layer's buffer holds twice the rows the chip expects
+# (``moe.pass_rows``) where ``moe.bound_serves``; at these tiny shapes the
+# threshold is steered, 0 engaging the bound wherever a share is held.
+
+def _bounded(monkeypatch, on: bool):
+    monkeypatch.setattr(moe, "_DEAD_BYTES", 0 if on else 1 << 62)
+
+
+def _value_and_grads(x, layer, held, cot, checkpoint=True):
+    """(y, counters, (dx, every leaf's gradient)) as a train step takes
+    them: under ``jax.checkpoint(nothing_saveable)`` and ``jax.grad``."""
+    def loss(x, layer):
+        y, counters = _share(x, layer, held, 2)
+        return jnp.sum(y * cot), (y, counters)
+
+    if checkpoint:
+        loss = jax.checkpoint(
+            loss, policy=jax.checkpoint_policies.nothing_saveable)
+    with jax.default_matmul_precision("highest"):
+        (_, (y, counters)), grads = jax.jit(jax.value_and_grad(
+            loss, (0, 1), has_aux=True))(x, layer)
+    return y, dict(zip(moe.COUNTERS, np.asarray(counters))), grads
+
+
+def _every_pair_held(layer, held):
+    """A bias that puts the two held experts into every token's top-2."""
+    return dict(layer, router_bias=layer["router_bias"].at[
+        held[0]:held[0] + held[1]].set(10.0))
+
+
+@pytest.mark.parametrize("kernels", [False, True], ids=["xla", "kernels"])
+def test_at_the_expected_load_the_bounded_layer_is_the_unbounded_bit_for_bit(
+        kernels, request, monkeypatch):
+    """2 of 16 experts held, 250 tokens, top-2: 75 pairs land here where
+    63 are expected and 188 (+ padding: 192 rows) are provided for. One
+    pass; ``y``, the counters and every gradient EQUAL what the buffer of
+    576 rows gives."""
+    if kernels:
+        request.getfixturevalue("kernel_on_cpu")
+    held, T = (2, 2), 250
+    assert moe.pass_rows(T, 2, 16, 2, moe.row_tile(T, 2, 16, 2)) == 192
+    layer = _layer(held=held, E=16)
+    x = jax.random.normal(jax.random.key(9), (T, 32), F32)
+    cot = jax.random.normal(jax.random.key(10), (T, 32), F32)
+    _bounded(monkeypatch, False)
+    y0, c0, g0 = _value_and_grads(x, layer, held, cot)
+    _bounded(monkeypatch, True)
+    y1, c1, g1 = _value_and_grads(x, layer, held, cot)
+    assert c1 == c0 and c1["expert_extra_passes"] == 0
+    assert 0 < c1["expert_pairs"] <= 192 - 2 * 31
+    assert np.array_equal(y0, y1)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(g0),
+                            jax.tree.leaves(g1)):
+        assert np.array_equal(a, b), jax.tree_util.keystr(path)
+
+
+# (router's width, tokens) -> passes: every token's two pairs on the two
+# held experts, each expert's rows padded to its tile
+WORST = {"two-passes": (8, 250, 2), "three-passes": (16, 250, 3),
+         "a-last-pass-partly-live": (16, 100, 3)}
+
+
+@pytest.mark.parametrize("kernels", [False, True], ids=["xla", "kernels"])
+@pytest.mark.parametrize("case", WORST, ids=list(WORST))
+def test_rows_past_the_bound_take_further_passes_and_none_is_dropped(
+        case, kernels, request, monkeypatch):
+    """The worst case: every pair is placed here. The passes number
+    ``ceil(pend[-1] / M_b)``, nothing is dropped, and ``y`` and the
+    gradients of ``x``, the router and the three expert matrices are the
+    unbounded layer's and the loop's to float32 summation order."""
+    if kernels:
+        request.getfixturevalue("kernel_on_cpu")
+    E, T, passes = WORST[case]
+    held = (2, 2)
+    layer = _every_pair_held(_layer(held=held, E=E), held)
+    x = jax.random.normal(jax.random.key(11), (T, 32), F32)
+    cot = jax.random.normal(jax.random.key(12), (T, 32), F32)
+    tm = moe.row_tile(T, 2, E, 2)
+    rows = moe.pass_rows(T, 2, E, 2, tm)
+    pend = 2 * -(-T // tm) * tm
+    assert -(-pend // rows) == passes
+    _bounded(monkeypatch, False)
+    y0, c0, g0 = _value_and_grads(x, layer, held, cot)
+    _bounded(monkeypatch, True)
+    y1, c1, g1 = _value_and_grads(x, layer, held, cot)
+    assert c0["expert_extra_passes"] == 0
+    assert c1["expert_extra_passes"] == passes - 1
+    assert c1["expert_pairs"] == 2 * T and c1["expert_pairs_dropped"] == 0
+    assert {**c1, "expert_extra_passes": 0.0} == c0
+    with jax.default_matmul_precision("highest"):
+        want = _loop(x, layer, held, 2)
+    np.testing.assert_allclose(np.asarray(y1), np.asarray(want), rtol=1e-4,
+                               atol=1e-5)
+    np.testing.assert_allclose(np.asarray(y1), np.asarray(y0), rtol=1e-5,
+                               atol=1e-6)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(g1),
+                            jax.tree.leaves(g0)):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-5,
+            err_msg=jax.tree_util.keystr(path))
+    assert np.asarray(g1[1]["router"]).any() and np.asarray(g1[0]).any()
+    assert not np.asarray(g1[1]["router_bias"]).any()
+
+
+def test_the_further_passes_gradient_is_jax_grads_without_a_checkpoint(
+        monkeypatch):
+    """The same step with no ``jax.checkpoint`` around the layer: the
+    further passes' own backward does not lean on the recomputation."""
+    held, (E, T, _) = (2, 2), WORST["three-passes"]
+    layer = _every_pair_held(_layer(held=held, E=E), held)
+    x = jax.random.normal(jax.random.key(11), (T, 32), F32)
+    cot = jax.random.normal(jax.random.key(12), (T, 32), F32)
+    _bounded(monkeypatch, True)
+    _, c1, g1 = _value_and_grads(x, layer, held, cot)
+    _, c2, g2 = _value_and_grads(x, layer, held, cot, checkpoint=False)
+    assert c1 == c2 and c1["expert_extra_passes"] == 2
+    for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g2)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6,
+                                   atol=1e-7)
+
+
+@pytest.mark.parametrize("case", ["one-pass", "three-passes"])
+def test_garbage_past_a_passes_n_active_reaches_neither_y_nor_a_gradient(
+        case, monkeypatch):
+    """A product that leaves NaN in the rows of every tile at or past
+    ``n_active`` (the kernel never writes them: anything may lie there),
+    forward and in both transposes: the bounded layer's output and
+    gradients stay what the clean product gives, in the first pass (most
+    of whose buffer is past ``n_active`` at the expected load) and in a
+    last pass of which four tiles of five are live."""
+    held = (2, 2)
+    E, T, _ = WORST["a-last-pass-partly-live"]
+    layer = _layer(held=held, E=E)
+    if case == "three-passes":
+        layer = _every_pair_held(layer, held)
+    x = jax.random.normal(jax.random.key(13), (T, 32), F32)
+    cot = jax.random.normal(jax.random.key(14), (T, 32), F32)
+    _bounded(monkeypatch, True)
+    y0, c0, g0 = _value_and_grads(x, layer, held, cot)
+    clean, traced = gm.grouped_matmul_reference, []
+
+    def poisoned(lhs, rhs, tile_group, n_active, *, tm, **kw):
+        traced.append(lhs.shape)
+        out = clean(lhs, rhs, tile_group, n_active, tm=tm, **kw)
+        dead = jnp.repeat(jnp.arange(lhs.shape[0] // tm) >= n_active, tm)
+        return jnp.where(dead[:, None], jnp.nan, out)
+
+    monkeypatch.setattr(gm, "grouped_matmul_reference", poisoned)
+    y1, c1, g1 = _value_and_grads(x, layer, held, cot)
+    assert c1 == c0 and len(traced) >= 6 and {s[0] for s in traced} == {80}
+    assert c1["expert_extra_passes"] == (2 if case == "three-passes" else 0)
+    assert np.isfinite(np.asarray(y1)).all() and np.array_equal(y0, y1)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(g1),
+                            jax.tree.leaves(g0)):
+        assert np.array_equal(a, b), jax.tree_util.keystr(path)
